@@ -2,7 +2,7 @@
 """Measure bytes-accessed / FLOPs of the headline train steps via XLA cost
 analysis of the *lowered* (never executed) step — works on CPU, so the
 77→55 GB ResNet byte claim and any f32-residual dtype regression are
-machine-checkable without the TPU (VERDICT r4 item 1b).
+machine-checkable without the TPU.
 
 The numbers here calibrate tests/test_byte_budget.py's pinned budgets.
 
@@ -113,10 +113,6 @@ def main():
     ap.add_argument("--batch", type=int, default=None)
     ap.add_argument("--recompute", action="store_true")
     args = ap.parse_args()
-
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
     out = {}
     if args.model in ("resnet", "both"):

@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Diagnose the ResNet-50 step: where do the 97 ms go?
 
-Round-3 perf work (VERDICT r2 item 1). Produces:
+Round-3 perf work. Produces:
   - compiled cost analysis (FLOPs, bytes) of the session's jitted step
   - a scan of the optimized HLO for f32 convolutions (MXU rate killers)
   - timing: session.run loop vs direct jitted-call loop (isolates Python

@@ -947,7 +947,10 @@ class ExecutionPlan:
                     f"{t.name} shape {t.shape}")
             np_dtype = dtypes_mod.narrowed_if_no_x64(
                 t.dtype.base_dtype).np_dtype
-            avals[t.name] = jax.ShapeDtypeStruct(shp, np_dtype)
+            # the sharding the feed will be staged with: an AOT
+            # executable rejects arguments sharded otherwise
+            avals[t.name] = jax.ShapeDtypeStruct(
+                shp, np_dtype, sharding=sess._feed_sharding(step, t))
         with sess._lock:
             rng_key = sess._ensure_base_key()
             state = dict(sess._variable_store.values)
@@ -1052,6 +1055,8 @@ class BaseSession:
         # 13-24 s warmup_plus_compile_s (bench.py warm_start row).
         # The jax cache dir is PROCESS-GLOBAL (see ConfigProto doc):
         # once set it outlives this Session and applies to later ones.
+        # Where JAX_COMPILATION_CACHE_DIR is set it wins over both
+        # (compiler/aot.py holds the one rule).
         cache_dir = (getattr(config, "compile_cache_dir", None)
                      if config is not None else None) \
             or os.environ.get("STF_COMPILE_CACHE")
@@ -2122,7 +2127,7 @@ class BaseSession:
                                              allow_operation=False)
             if t.dtype.base_dtype.name in ("int64", "uint64", "float64"):
                 # the once-per-process narrowing notice lives HERE, at
-                # the session boundary, not per-op (VERDICT weak #6)
+                # the session boundary, not per-op
                 dtypes_mod.warn_64bit_narrowing_once(f"feed {t.name!r}")
             if isinstance(v, TensorHandle):
                 # feed-by-handle: the holder receives the handle string;
@@ -2545,10 +2550,9 @@ class BaseSession:
 
             logging.warning(msg)
 
-    def _staged_feed(self, step, tensor, value):
-        """Hot-path feed staging (shard_feed-annotated placeholders get
-        their NamedSharding so GSPMD partitions the step; each host
-        contributes its slice on pods). Two-level staging slot: whether
+    def _feed_sharding(self, step, tensor):
+        """The NamedSharding a shard_feed-annotated placeholder stages
+        with under the current mesh, else None. Two-level slot: whether
         a tensor is annotated at all is cached per (plan, tensor) — the
         common unannotated feed pays one dict hit — and the committed
         NamedSharding is cached per mesh identity, so the current mesh
@@ -2561,12 +2565,12 @@ class BaseSession:
             spec = tensor.op.attrs.get("sharding")
             step.feed_shardings[tensor.name] = spec
         if spec is None:
-            return value
+            return None
         from ..parallel.mesh import current_mesh
 
         mesh = current_mesh()
         if mesh is None:
-            return value
+            return None
         import jax
 
         cached = step.feed_shardings.get((tensor.name, "ns"))
@@ -2574,7 +2578,18 @@ class BaseSession:
             cached = (mesh, jax.sharding.NamedSharding(
                 mesh.jax_mesh, jax.sharding.PartitionSpec(*spec)))
             step.feed_shardings[(tensor.name, "ns")] = cached
-        return jax.device_put(value, cached[1])
+        return cached[1]
+
+    def _staged_feed(self, step, tensor, value):
+        """Hot-path feed staging (shard_feed-annotated placeholders get
+        their NamedSharding so GSPMD partitions the step; each host
+        contributes its slice on pods)."""
+        ns = self._feed_sharding(step, tensor)
+        if ns is None:
+            return value
+        import jax
+
+        return jax.device_put(value, ns)
 
     def _apply_declared_shardings(self, names):
         """Move variables with a declared sharding onto the mesh (one-time

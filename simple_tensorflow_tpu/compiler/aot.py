@@ -31,26 +31,47 @@ from ..framework import lowering as lowering_mod
 
 
 _persistent_cache_dir: Optional[str] = None
+_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def enable_persistent_cache(cache_dir: str) -> None:
-    """Persist compiled executables under ``cache_dir`` (survives process
-    restarts; subsequent compiles of the same HLO are disk hits)."""
+def enable_persistent_cache(cache_dir: Optional[str] = None) -> str:
+    """Persist compiled executables across processes (subsequent
+    compiles of the same HLO are disk hits). The one rule for where:
+
+    - ``JAX_COMPILATION_CACHE_DIR`` set: JAX's own handling of it
+      stands — the directory and its thresholds. ``cache_dir`` is
+      ignored and nothing here touches the cache's configuration.
+      (Whoever placed the cache may also have capped its size: on the
+      chip tool's machine the cap is 192 MiB, and writing every
+      sub-second compile into it made one smoke run's entries outgrow
+      the cap, so a second run found none of them — PR 21.)
+    - unset: ``cache_dir`` if given, else ``<checkout>/.jax_cache`` (a
+      fixed path — the path is part of the cache key, so a directory
+      that moves never hits); everything is cached, however fast the
+      compile was.
+
+    Returns the directory in effect."""
     import jax
 
     global _persistent_cache_dir
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    # cache everything, however fast the compile was
+    env_dir = os.environ.get(_CACHE_ENV)
+    if env_dir:
+        return env_dir
+    _persistent_cache_dir = cache_dir or _CHECKOUT_CACHE
+    os.makedirs(_persistent_cache_dir, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", _persistent_cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    _persistent_cache_dir = cache_dir
+    return _persistent_cache_dir
 
 
 def persistent_cache_dir() -> Optional[str]:
-    """The enabled persistent-cache directory, or None. The kernel
+    """The persistent-cache directory in effect, or None. The kernel
     registry persists its micro-autotune verdicts alongside it
     (stf.kernels; docs/PERFORMANCE.md)."""
-    return _persistent_cache_dir
+    return os.environ.get(_CACHE_ENV) or _persistent_cache_dir
 
 
 class _CompiledBundle:

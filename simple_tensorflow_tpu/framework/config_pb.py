@@ -71,6 +71,8 @@ class ConfigProto:
     full compile again (the 13-24 s/process ``warmup_plus_compile_s``
     in bench.py). None (default) falls back to the ``STF_COMPILE_CACHE``
     environment variable; empty/unset leaves persistent caching off.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, that directory is used
+    and both of these are ignored.
     PROCESS-GLOBAL: the underlying jax compilation-cache directory is
     process-wide state — the first Session that sets it points every
     later compile in the process (including Sessions constructed with

@@ -460,7 +460,7 @@ class CostEstimate:
         unreachable — every step pays Python dispatch + device launch +
         result sync, so the prediction is floored at
         HOST_DISPATCH_FLOOR_S before being compared with measurements
-        (VERDICT weak #4: tiny bench configs printed
+        (tiny bench configs printed
         measured_over_predicted ≈ 108 against a 75 µs 'prediction').
         Pass ``dispatch_floor_s=0`` for the raw roofline number."""
         if dispatch_floor_s is None:
@@ -606,10 +606,12 @@ def predicted_vs_measured(fetches, feeds: Sequence[Tensor] = (),
         out["measured_sec_per_step"] = float(f"{measured_seconds:.4g}")
         out["measured_over_predicted"] = round(
             float(measured_seconds) / max(pred_s, 1e-12), 3)
-        # model FLOPs utilization from the unrounded estimate (the
-        # summary()'s tflops rounds small programs to 0)
-        out["mfu"] = round(
-            perf.mfu(est.flops, float(measured_seconds)), 6)
+        if perf.has_peak():
+            # model FLOPs utilization from the unrounded estimate (the
+            # summary()'s tflops rounds small programs to 0); never
+            # against the CPU's nominal planning figures
+            out["mfu"] = round(
+                perf.mfu(est.flops, float(measured_seconds)), 6)
     return out
 
 

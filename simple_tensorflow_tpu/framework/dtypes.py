@@ -240,7 +240,7 @@ def as_dtype(value) -> DType:
     raise TypeError(f"Cannot convert {value!r} to a DType")
 
 
-# -- 64-bit narrowing (VERDICT weak #6) --------------------------------------
+# -- 64-bit narrowing --------------------------------------
 #
 # TPUs have no int64/float64 datapath; with jax_enable_x64 off (the
 # default), 64-bit requests compute in 32 bits. The divergence is

@@ -337,8 +337,14 @@ def execute_ops(ctx: LoweringContext, op_list: Sequence[Operation],
     pruning happened in prune(), and every fed tensor is already bound
     in ctx.env before the trace starts."""
     from ..kernels import registry as _kernels
+    from ..parallel.mesh import current_mesh
 
-    with _kernels.activate(ctx.kernel_mode):
+    mesh = current_mesh()
+    # GSPMD partitions whatever traces under a multi-device mesh
+    # outside shard_map — and cannot partition a Mosaic kernel
+    auto = (mesh is not None and mesh.size > 1
+            and not getattr(ctx, "in_shard_map", False))
+    with _kernels.activate(ctx.kernel_mode, auto_partitioned=auto):
         _execute_ops_inner(ctx, op_list)
 
 

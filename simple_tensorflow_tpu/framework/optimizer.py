@@ -35,6 +35,7 @@ valid.
 from __future__ import annotations
 
 import copy
+import functools
 import time
 from typing import Any, Callable, Dict, List, Optional, Set, Tuple
 
@@ -57,6 +58,38 @@ _metric_pass_runs = monitoring.Counter(
 _metric_pass_rewrites = monitoring.Counter(
     "/stf/graph/optimizer/pass_rewrites",
     "PassManager pass invocations that changed the graph", "pass")
+
+_metric_folded_ops = monitoring.Counter(
+    "/stf/graph/optimizer/plan_folded_ops",
+    "ops Session plans evaluated at plan time instead of lowering")
+
+
+@functools.lru_cache(None)
+def _fold_device():
+    """The CPU device constant folding evaluates on, whatever the
+    default backend: folded values are NumPy constants of the plan, so
+    the chip must run the same graph the CPU tests pin. Asked for once;
+    a process with no CPU backend raises here, not at every fold."""
+    import jax
+
+    return jax.devices("cpu")[0]
+
+
+def _fold(od, args, attrs):
+    """``od.pure_fn`` on constant (NumPy) inputs, or None where the op
+    cannot be evaluated from such values — it then stays in the plan
+    and lowers like any other op. A device, compile or runtime failure
+    is not such a case and propagates."""
+    import jax
+
+    with jax.default_device(_fold_device()):
+        try:
+            return od.pure_fn(*args, **attrs)
+        except jax.errors.JaxRuntimeError:
+            raise
+        except Exception:  # noqa: BLE001 — the op's own argument errors
+            return None
+
 
 _FOLDABLE_BLOCKLIST = {"Placeholder", "PlaceholderWithDefault", "Const",
                        "VariableV2", "VarRead", "Assign"}
@@ -282,8 +315,6 @@ def constant_folding(graph_def: Dict,
     holds on every iteration). Seeded CapturedInput nodes are never
     themselves replaced (the body signature must survive), only their
     consumers fold."""
-    import jax
-
     from . import graph_io
 
     out = copy.deepcopy(graph_def)
@@ -359,12 +390,9 @@ def constant_folding(graph_def: Dict,
         attrs = {k: graph_io._decode_attr(v)
                  for k, v in n.get("attr", {}).items()
                  if not k.startswith("_") and k != "dtype"}
-        try:
-            with jax.default_device(jax.devices("cpu")[0]):
-                result = od.pure_fn(
-                    *[values[r[0]][r[1]] for r in in_refs], **attrs)
-        except Exception:
-            new_nodes.append(n)  # fold failure leaves the node alone
+        result = _fold(od, [values[r[0]][r[1]] for r in in_refs], attrs)
+        if result is None:
+            new_nodes.append(n)  # not foldable: the node stays
             continue
         outs = (list(result) if isinstance(result, (list, tuple))
                 else [result])
@@ -1182,8 +1210,6 @@ def optimize_pruned(op_list, fed_tensors, keep_tensors, const_seed=None,
     Ops are foldable/CSE-able only via ``pure_fn`` (stateless by
     construction: RNG, variables, placeholders, host IO all register with
     ``lower=`` and/or ``is_stateful`` and are excluded)."""
-    import jax
-
     const_env: Dict[Any, Any] = dict(const_seed or {})
     alias: Dict[Any, Any] = {}
     sigs: Dict[str, Any] = {}  # signature -> canonical op
@@ -1226,12 +1252,7 @@ def optimize_pruned(op_list, fed_tensors, keep_tensors, const_seed=None,
                                          for t in resolved_ins):
             attrs = {k: v for k, v in op.attrs.items()
                      if not k.startswith("_")}
-            try:
-                with jax.default_device(jax.devices("cpu")[0]):
-                    out = od.pure_fn(
-                        *[const_env[t] for t in resolved_ins], **attrs)
-            except Exception:
-                out = None  # fold failure leaves the op alone
+            out = _fold(od, [const_env[t] for t in resolved_ins], attrs)
             if out is not None:
                 outs = (list(out) if isinstance(out, (list, tuple))
                         else [out])
@@ -1240,6 +1261,7 @@ def optimize_pruned(op_list, fed_tensors, keep_tensors, const_seed=None,
                         sum(o.nbytes for o in outs) <= _FOLD_MAX_BYTES):
                     for t, v in zip(op.outputs, outs):
                         const_env[t] = v
+                    _metric_folded_ops.get_cell().increase_by(1)
                     continue  # folded: op never lowers
         if pure:
             sig = repr((op.type,

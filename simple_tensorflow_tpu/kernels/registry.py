@@ -114,23 +114,33 @@ class activate:
     """Context manager: pin the decision mode for this thread while a
     Session lowers (framework/lowering.py execute_ops wraps its trace
     loop in one, carrying ConfigProto(kernel_registry=...)). ``None``
-    leaves the current/default mode in effect. Re-entrant."""
+    leaves the current/default mode in effect. Re-entrant.
 
-    def __init__(self, mode: Optional[str]):
+    ``auto_partitioned``: the ops being traced lower under a
+    multi-device mesh OUTSIDE shard_map, i.e. GSPMD partitions them.
+    Mosaic kernels cannot be partitioned automatically, so on a TPU
+    every decision in this scope takes the XLA lowering (reason
+    ``mesh_auto_partitioned``); inside a shard_map body the nested
+    scope clears the flag and per-shard kernels route normally."""
+
+    def __init__(self, mode: Optional[str], auto_partitioned: bool = False):
         if mode is not None and mode not in MODES:
             raise ValueError(f"kernel registry mode must be one of {MODES}, "
                              f"got {mode!r}")
         self._mode = mode
+        self._auto = bool(auto_partitioned)
         self._prev = None
 
     def __enter__(self):
-        self._prev = getattr(_state, "mode", None)
+        self._prev = (getattr(_state, "mode", None),
+                      getattr(_state, "auto_partitioned", False))
         if self._mode is not None:
             _state.mode = self._mode
+        _state.auto_partitioned = self._auto
         return self
 
     def __exit__(self, *exc):
-        _state.mode = self._prev
+        _state.mode, _state.auto_partitioned = self._prev
         return False
 
 
@@ -138,6 +148,12 @@ def backend() -> str:
     import jax
 
     return jax.default_backend()
+
+
+def device_kind() -> str:
+    import jax
+
+    return jax.devices()[0].device_kind
 
 
 # -- kernel definitions -------------------------------------------------------
@@ -211,7 +227,8 @@ def aval_key(*arrays, **statics) -> Tuple:
 
 # -- autotune cache -----------------------------------------------------------
 
-# (op_type, key, backend) -> {"verdict", "pallas_s", "xla_s"}
+# (op_type, key, backend, device_kind) -> {"verdict", "pallas_s",
+# "xla_s"}: a verdict timed on one device is never replayed on another
 _measured: Dict[Tuple, Dict[str, Any]] = {}
 _measured_loaded_from: Optional[str] = None
 _AUTOTUNE_FILE = "stf_kernel_autotune.json"
@@ -225,12 +242,9 @@ def _cache_file() -> Optional[str]:
     """Persist verdicts alongside the persistent compile cache (PR 5):
     the same directory that makes process restarts disk-hit their XLA
     compiles makes them skip re-measuring."""
-    try:
-        from ..compiler import aot
+    from ..compiler import aot
 
-        d = aot.persistent_cache_dir()
-    except Exception:
-        return None
+    d = aot.persistent_cache_dir()
     if not d:
         return None
     return os.path.join(d, _AUTOTUNE_FILE)
@@ -254,7 +268,8 @@ def _load_persisted() -> None:
 
     for rec in raw.get("verdicts", []):
         try:
-            k = (rec["op"], _tuplify(rec["key"]), rec["backend"])
+            k = (rec["op"], _tuplify(rec["key"]), rec["backend"],
+                 rec["device_kind"])
             _measured.setdefault(k, {
                 "verdict": rec["verdict"],
                 "pallas_s": rec.get("pallas_s"),
@@ -269,9 +284,10 @@ def _persist() -> None:
     if path is None:
         return
     recs = []
-    for (op, key, bk), v in _measured.items():
+    for (op, key, bk, kind), v in _measured.items():
         recs.append({"op": op, "key": _jsonable(key), "backend": bk,
-                     "verdict": v["verdict"], "pallas_s": v.get("pallas_s"),
+                     "device_kind": kind, "verdict": v["verdict"],
+                     "pallas_s": v.get("pallas_s"),
                      "xla_s": v.get("xla_s")})
     tmp = f"{path}.tmp.{os.getpid()}"
     try:
@@ -308,8 +324,11 @@ def _time_thunk(fn, args, kwargs) -> float:
 
 def _measure(kd: KernelDef, key, bk: str) -> str:
     """Micro-autotune: time both lowerings on representative inputs,
-    persist the verdict. Called at most once per (op, key, backend)."""
-    cache_key = (kd.op_type, key, bk)
+    persist the verdict. Called at most once per (op, key, backend,
+    device kind). A lowering that fails to compile or run here would
+    fail the same way in the plan being traced, so the failure
+    propagates, naming what was being timed — it is never a verdict."""
+    cache_key = (kd.op_type, key, bk, device_kind())
     hit = _measured.get(cache_key)
     if hit is not None:
         return hit["verdict"]
@@ -319,14 +338,16 @@ def _measure(kd: KernelDef, key, bk: str) -> str:
         return v or ("xla" if bk != "tpu" else "pallas")
     metric_autotune_runs.get_cell(kd.op_type).increase_by(1)
     args, kwargs = kd.make_case(key)
-    try:
-        t_p = _time_thunk(kd.impls["pallas"], args, kwargs)
-        t_x = _time_thunk(kd.impls["xla"], args, kwargs)
-    except Exception:  # noqa: BLE001 — measurement must never sink a trace
-        verdict = "xla" if bk != "tpu" else "pallas"
-        _measured[cache_key] = {"verdict": verdict, "pallas_s": None,
-                                "xla_s": None}
-        return verdict
+    times = {}
+    for impl in ("pallas", "xla"):
+        try:
+            times[impl] = _time_thunk(kd.impls[impl], args, kwargs)
+        except Exception as e:
+            raise RuntimeError(
+                f"kernel autotune: timing the {impl!r} lowering of "
+                f"{kd.op_type} failed on backend {bk!r} for key "
+                f"{key!r}") from e
+    t_p, t_x = times["pallas"], times["xla"]
     verdict = "pallas" if t_p <= t_x else "xla"
     _measured[cache_key] = {"verdict": verdict, "pallas_s": t_p,
                             "xla_s": t_x}
@@ -348,7 +369,7 @@ def record_measurement(op_type: str, key, pallas_s: float,
     Returns the resulting verdict. Cached decisions are invalidated for
     this op so the next decide() re-reads the cache."""
     verdict = "pallas" if pallas_s <= xla_s else "xla"
-    _measured[(op_type, key, backend())] = {
+    _measured[(op_type, key, backend(), device_kind())] = {
         "verdict": verdict, "pallas_s": float(pallas_s),
         "xla_s": float(xla_s)}
     _persist()
@@ -364,8 +385,8 @@ def clear_measurements() -> None:
 
 # -- decisions ----------------------------------------------------------------
 
-# (op_type, key, mode, backend) -> (impl_name, reason): the same trace
-# signature always routes the same way within a process
+# (op_type, key, mode, backend, auto_partitioned) -> (impl_name, reason):
+# the same trace signature always routes the same way within a process
 _decisions: Dict[Tuple, Tuple[str, str]] = {}
 
 
@@ -377,7 +398,8 @@ def decide(op_type: str, key, mode: Optional[str] = None,
     kd = _KERNELS[op_type]
     mode = mode or current_mode()
     bk = backend()
-    cache_key = (op_type, key, mode, bk)
+    auto = bk == "tpu" and getattr(_state, "auto_partitioned", False)
+    cache_key = (op_type, key, mode, bk, auto)
     with _lock:
         hit = _decisions.get(cache_key)
     if hit is None:
@@ -386,7 +408,8 @@ def decide(op_type: str, key, mode: Optional[str] = None,
         # stall every other thread's routing decisions; racing threads
         # at worst measure redundantly, and first-publish wins so the
         # cached decision stays stable
-        computed = _decide_uncached(kd, key, mode, bk)
+        computed = (("xla", "mesh_auto_partitioned") if auto
+                    else _decide_uncached(kd, key, mode, bk))
         with _lock:
             hit = _decisions.setdefault(cache_key, computed)
     impl, reason = hit
@@ -408,7 +431,7 @@ def _decide_uncached(kd: KernelDef, key, mode: str, bk: str):
         return ("pallas", "forced")
     # auto: measured verdict wins over everything else
     _load_persisted()
-    m = _measured.get((kd.op_type, key, bk))
+    m = _measured.get((kd.op_type, key, bk, device_kind()))
     if m is not None:
         return (m["verdict"], "autotune")
     verdict, reason = kd.cost_gate(key, bk)
@@ -427,7 +450,7 @@ def decisions_snapshot() -> List[Dict[str, Any]]:
     with _lock:
         return [{"op": op, "key": repr(key), "mode": mode,
                  "backend": bk, "impl": impl, "reason": reason}
-                for (op, key, mode, bk), (impl, reason)
+                for (op, key, mode, bk, _auto), (impl, reason)
                 in sorted(_decisions.items(), key=lambda kv: kv[0][0])]
 
 
@@ -446,17 +469,11 @@ def _backend_if_initialized() -> Optional[str]:
     jax = sys.modules.get("jax")
     if jax is None:
         return None
-    try:
-        from jax._src import xla_bridge
+    from jax._src import xla_bridge
 
-        if not xla_bridge.backends_are_initialized():
-            return None
-    except Exception:  # noqa: BLE001 — private API moved: best effort
-        pass
-    try:
-        return jax.default_backend()
-    except Exception:  # noqa: BLE001
+    if not xla_bridge.backends_are_initialized():
         return None
+    return jax.default_backend()
 
 
 def snapshot() -> Dict[str, Any]:
@@ -474,8 +491,8 @@ def snapshot() -> Dict[str, Any]:
         "routed": routed,
         "fallback": fallback,
         "autotune_runs": autotune,
-        "measured": {f"{op}|{bk}": v["verdict"]
-                     for (op, _k, bk), v in _measured.items()},
+        "measured": {f"{op}|{bk}|{kind}": v["verdict"]
+                     for (op, _k, bk, kind), v in _measured.items()},
     }
 
 
@@ -521,7 +538,7 @@ def routing_report(ops, mode: Optional[str] = None,
             elif mode == "force":
                 impl, reason = "pallas", "forced"
             else:
-                m = _measured.get((kd.op_type, key, bk))
+                m = _measured.get((kd.op_type, key, bk, device_kind()))
                 if m is not None:
                     impl, reason = m["verdict"], "autotune"
                 else:

@@ -63,7 +63,7 @@ def resnet_forward(x, num_classes=1000, depth=50, training=True,
     the backward pass (stf.recompute_grad / jax.checkpoint): cuts the
     dominant byte sink of the training step — saved block activations —
     at ~1.3x forward FLOPs, which ResNet can afford on v5e where the
-    step is HBM-bandwidth-bound (artifacts/resnet_perf_diagnosis.md).
+    step is HBM-bandwidth-bound (an older chip run; ROADMAP S2).
 
     conv0_space_to_depth=True reformulates the stem (the MLPerf TPU
     recipe): space_to_depth(block 2) turns the 3-channel 224px input

@@ -33,7 +33,7 @@ op_registry.register_pure("Identity", lambda x: x)
 op_registry.register_pure("Snapshot", lambda x: x)
 # 64-bit out_types narrow through narrowed_if_no_x64 (one boundary
 # warning per process instead of jax's per-callsite truncation warning;
-# VERDICT weak #6, docs/MIGRATION.md "64-bit dtypes")
+# docs/MIGRATION.md "64-bit dtypes")
 op_registry.register_pure("Shape", lambda x, out_type=None: jnp.asarray(
     x.shape, dtype=(dtypes_mod.narrowed_if_no_x64(out_type).np_dtype
                     if out_type else jnp.int32)))

@@ -3,21 +3,38 @@
 These kernels replace the reference's hand-written CUDA kernels
 (ref: tensorflow/core/kernels/*_gpu.cu.cc) with Mosaic/Pallas programs tiled
 for the MXU/VPU. On non-TPU backends (the CPU test mesh) every kernel runs
-in interpret mode, so numerics tests are backend-independent.
+in interpret mode (:func:`use_interpret`), so numerics tests are
+backend-independent.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 
 
-@functools.lru_cache(None)
 def use_interpret() -> bool:
-    """Pallas compiles natively only on TPU; interpret elsewhere."""
-    return jax.default_backend() != "tpu"
+    """Whether a kernel traced now must run in Pallas interpret mode.
+
+    Mosaic compiles for the TPU only, so this follows the platform the
+    enclosing computation lowers for: the ``jax.default_device`` scope
+    if one is open, else the default backend. Asked at every trace,
+    never cached — a process is not frozen into whatever the first call
+    saw. The kernel modules call it as ``common.use_interpret()`` so a
+    test that compiles for a described (unattached) TPU steers all of
+    them by patching this one attribute.
+
+    Interpreting while the default backend is a TPU means the kernel
+    silently left the chip: an error, not a mode."""
+    dev = jax.config.jax_default_device
+    platform = getattr(dev, "platform", dev) or jax.default_backend()
+    if platform == "tpu":
+        return False
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"Pallas kernel traced for platform {platform!r} while the "
+            "default backend is a TPU: it would run in interpret mode")
+    return True
 
 
 def round_up(x: int, m: int) -> int:

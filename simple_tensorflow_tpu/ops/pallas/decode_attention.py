@@ -48,61 +48,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import NEG_INF, cdiv, pad_dim, round_up, use_interpret
+from . import common
+from .common import NEG_INF, cdiv, pad_dim, round_up
 
 DEFAULT_BLOCK_L = 128
 _HI = jax.lax.Precision.HIGHEST
-
-
-def _decode_kernel(q_ref, k_ref, v_ref, len_ref, bias_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, sm_scale, block_l, num_lb,
-                   has_bias):
-    b = pl.program_id(0)
-    lb = pl.program_id(1)
-
-    @pl.when(lb == 0)
-    def _():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    length = len_ref[b]
-    # a block wholly past this sequence's live length contributes nothing
-    live = lb * block_l < length
-
-    @pl.when(live)
-    def _():
-        q = q_ref[:]                                   # (H, D)
-        k = k_ref[:]                                   # (block_l, H, D)
-        v = v_ref[:]
-        # per-head contraction: batch dim H, contract D -> (H, block_l)
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-            precision=_HI if q.dtype == jnp.float32 else None) * sm_scale
-        if has_bias:
-            s = s + bias_ref[:]                        # (1, block_l) f32
-        span = lb * block_l + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(span < length, s, NEG_INF)
-
-        m_prev, l_prev = m_scr[:], l_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)                         # (H, block_l)
-        alpha = jnp.exp(m_prev - m_new)                # (H, 1)
-        m_scr[:] = m_new
-        l_scr[:] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        # P·V with batch dim H: (H, block_l) x (block_l, H, D) -> (H, D)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v,
-            dimension_numbers=(((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-            precision=_HI if v.dtype == jnp.float32 else None)
-
-    @pl.when(lb == num_lb - 1)
-    def _():
-        l_safe = jnp.where(l_scr[:] == 0.0, 1.0, l_scr[:])
-        o_ref[:] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
 
 
 def _decode_block_kernel(q_ref, k_ref, v_ref, len_ref, bias_ref, o_ref,
@@ -171,12 +121,12 @@ def _decode_attention_block(q, k_cache, v_cache, lengths, *, bias,
         sm_scale = 1.0 / (d ** 0.5)
     lengths = jnp.asarray(lengths, jnp.int32)
 
-    align = 8 if use_interpret() else 128
+    align = 8 if common.use_interpret() else 128
     block_l = min(block_l, round_up(max_len, align))
     lp = round_up(max_len, block_l)
-    hp = h if use_interpret() else round_up(h, 8)
-    kqp = kq if use_interpret() else round_up(kq, 8)
-    dp = d if use_interpret() else round_up(d, 64)
+    hp = h if common.use_interpret() else round_up(h, 8)
+    kqp = kq if common.use_interpret() else round_up(kq, 8)
+    dp = d if common.use_interpret() else round_up(d, 64)
 
     # ride the query block on the sublane axis next to heads
     qt = jnp.transpose(q, (0, 2, 1, 3))                # (B, H, Kq, D)
@@ -224,7 +174,7 @@ def _decode_attention_block(q, k_cache, v_cache, lengths, *, bias,
             flops=int(4 * b * kq * h * max_len * d),
             bytes_accessed=(kk.size + vv.size + qq.size) * q.dtype.itemsize,
             transcendentals=b * kq * h * max_len),
-        interpret=use_interpret(),
+        interpret=common.use_interpret(),
     )(*operands)
     return jnp.transpose(o[:, :h, :kq, :d], (0, 2, 1, 3))
 
@@ -248,64 +198,13 @@ def decode_attention(q, k_cache, v_cache, lengths, *, bias=None,
         return _decode_attention_block(
             q, k_cache, v_cache, lengths, bias=bias, sm_scale=sm_scale,
             block_l=block_l, causal_offset=bool(causal_offset))
-    b, h, d = q.shape
-    max_len = k_cache.shape[1]
-    if sm_scale is None:
-        sm_scale = 1.0 / (d ** 0.5)
-    lengths = jnp.asarray(lengths, jnp.int32)
-
-    align = 8 if use_interpret() else 128
-    block_l = min(block_l, round_up(max_len, align))
-    lp = round_up(max_len, block_l)
-    hp = h if use_interpret() else round_up(h, 8)
-    dp = d if use_interpret() else round_up(d, 64)
-
-    qq = pad_dim(pad_dim(q, 1, hp), 2, dp)
-    kk = pad_dim(pad_dim(pad_dim(k_cache, 1, lp), 2, hp), 3, dp)
-    vv = pad_dim(pad_dim(pad_dim(v_cache, 1, lp), 2, hp), 3, dp)
-    num_lb = cdiv(lp, block_l)
-
-    has_bias = bias is not None
-    in_specs = [
-        pl.BlockSpec((None, hp, dp), lambda i, j: (i, 0, 0)),
-        pl.BlockSpec((None, block_l, hp, dp), lambda i, j: (i, j, 0, 0)),
-        pl.BlockSpec((None, block_l, hp, dp), lambda i, j: (i, j, 0, 0)),
-        pl.BlockSpec(memory_space=pltpu.SMEM),
-    ]
-    operands = [qq, kk, vv, lengths]
-    if has_bias:
-        bb = jax.lax.stop_gradient(
-            jnp.asarray(bias, jnp.float32).reshape(b, max_len))
-        bb = pad_dim(bb, 1, lp, value=NEG_INF).reshape(b, 1, lp)
-        in_specs.append(pl.BlockSpec((None, 1, block_l),
-                                     lambda i, j: (i, 0, j)))
-        operands.append(bb)
-    else:
-        # keep the kernel arity static: a zero-length dummy never read
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        operands.append(jnp.zeros((1,), jnp.float32))
-
-    kernel = functools.partial(_decode_kernel, sm_scale=float(sm_scale),
-                               block_l=block_l, num_lb=num_lb,
-                               has_bias=has_bias)
-    o = pl.pallas_call(
-        kernel,
-        grid=(b, num_lb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, hp, dp), lambda i, j: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hp, dp), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((hp, 1), jnp.float32),
-            pltpu.VMEM((hp, 1), jnp.float32),
-            pltpu.VMEM((hp, dp), jnp.float32),
-        ],
-        cost_estimate=pl.CostEstimate(
-            flops=int(4 * b * h * max_len * d),
-            bytes_accessed=(kk.size + vv.size + qq.size) * q.dtype.itemsize,
-            transcendentals=b * h * max_len),
-        interpret=use_interpret(),
-    )(*operands)
-    return o[:, :h, :d]
+    # the single query is a query block of one: Mosaic refuses the
+    # (H, D) x (block_l, H, D) contraction a dedicated 1-row kernel
+    # needs (no non-contracting lhs dimension), and with Kq = 1 the
+    # causal_offset=False mask is exactly "positions < lengths[b]"
+    return _decode_attention_block(
+        q[:, None], k_cache, v_cache, lengths, bias=bias,
+        sm_scale=sm_scale, block_l=block_l, causal_offset=False)[:, 0]
 
 
 def decode_attention_xla(q, k_cache, v_cache, lengths, *, bias=None,

@@ -24,7 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import cdiv, counter_keep_mask, pad_dim, round_up, use_interpret
+from . import common
+from .common import cdiv, counter_keep_mask, pad_dim, round_up
 
 BLOCK_ROWS = 256
 _VMEM_BLOCK_BUDGET = 4 * 1024 * 1024
@@ -79,7 +80,7 @@ def _fwd(x, residual, bias, seed, rate, block_rows):
             flops=4 * rows * n,
             bytes_accessed=3 * rows * n * x.dtype.itemsize,
             transcendentals=0),
-        interpret=use_interpret(),
+        interpret=common.use_interpret(),
     )(*operands)
 
 

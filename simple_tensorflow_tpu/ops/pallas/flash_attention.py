@@ -44,8 +44,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import common
 from .common import (NEG_INF, cdiv, counter_keep_mask, mix32, pad_dim,
-                     round_up, use_interpret)
+                     round_up)
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -198,7 +199,7 @@ def _fwd(q, k, v, bias, seed, sm_scale, causal, block_q, block_k, kv_true,
             flops=int(4 * bh * q_len * kv_true * d * (0.5 if causal else 1.0)),
             bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize,
             transcendentals=bh * q_len * kv_true),
-        interpret=use_interpret(),
+        interpret=common.use_interpret(),
     )(*operands)
     return o, lse
 
@@ -372,7 +373,7 @@ def _bwd_with_delta(sm_scale, causal, block_q, block_k, kv_true,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        interpret=use_interpret(),
+        interpret=common.use_interpret(),
     )(q, k, v, g, lse, delta, *aux_ops)
 
     dqk = functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
@@ -395,7 +396,7 @@ def _bwd_with_delta(sm_scale, causal, block_q, block_k, kv_true,
                                lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, q_len, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=use_interpret(),
+        interpret=common.use_interpret(),
     )(q, k, v, g, lse, delta, *aux_ops)
     grads = [dq, dk, dv]
     # bias is a constant mask under differentiation (stop_gradient'd in the
@@ -502,7 +503,7 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None, bias=None,
     if dropout_rate > 0.0 and dropout_seed is None:
         raise ValueError("flash attention dropout needs dropout_seed")
 
-    align = 8 if use_interpret() else 128
+    align = 8 if common.use_interpret() else 128
     block_q = min(block_q, round_up(q_len, align))
     block_k = min(block_k, round_up(kv_len, align))
     qp_len = round_up(q_len, block_q)
@@ -512,7 +513,7 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None, bias=None,
     # (q/k/v/o and all three gradients) — measured as ~11 GB/step of pure
     # padding traffic on BERT-base. Only odd sizes pad, to the next half
     # tile.
-    dp = d if use_interpret() else round_up(d, 64)
+    dp = d if common.use_interpret() else round_up(d, 64)
 
     qq = pad_dim(pad_dim(q.reshape(b * h, q_len, d), 1, qp_len), 2, dp)
     kk = pad_dim(pad_dim(k.reshape(b * h, kv_len, d), 1, kp_len), 2, dp)

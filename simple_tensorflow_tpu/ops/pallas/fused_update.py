@@ -31,7 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import cdiv, pad_dim, round_up, use_interpret
+from . import common
+from .common import cdiv, pad_dim, round_up
 
 LANES = 128
 BLOCK_ROWS = 256
@@ -110,7 +111,7 @@ def adam_update(p, m, v, g, alpha, *, beta1, beta2, eps):
             bytes_accessed=(p.size * p.dtype.itemsize * 2
                             + 5 * m.size * m.dtype.itemsize),
             transcendentals=n),
-        interpret=use_interpret(),
+        interpret=common.use_interpret(),
     )(p2, m2, v2, g2, alpha1)
     return (np_.reshape(-1)[:n], nm.reshape(-1)[:n], nv.reshape(-1)[:n])
 
@@ -173,6 +174,6 @@ def momentum_update(p, acc, g, lr, mu, *, use_nesterov=False):
             bytes_accessed=(p.size * p.dtype.itemsize * 2
                             + 3 * acc.size * acc.dtype.itemsize),
             transcendentals=0),
-        interpret=use_interpret(),
+        interpret=common.use_interpret(),
     )(p2, a2, g2, lr1, mu1)
     return (np_.reshape(-1)[:n], nacc.reshape(-1)[:n])

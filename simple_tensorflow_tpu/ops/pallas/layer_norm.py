@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import cdiv, pad_dim, round_up, use_interpret
+from . import common
+from .common import cdiv, pad_dim, round_up
 
 DEFAULT_BLOCK_ROWS = 256
 
@@ -86,7 +87,7 @@ def _fwd(x, gamma, beta, eps, block_rows):
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
-        interpret=use_interpret(),
+        interpret=common.use_interpret(),
     )(x, gamma, beta)
     return o, mean, rstd
 
@@ -126,7 +127,7 @@ def _ln_bwd_rule(eps, block_rows, res, g):
             jax.ShapeDtypeStruct((1, n), jnp.float32),
             jax.ShapeDtypeStruct((1, n), jnp.float32),
         ],
-        interpret=use_interpret(),
+        interpret=common.use_interpret(),
     )(x, gamma, mean, rstd, g)
     return dx, dg[0].astype(gamma.dtype), db[0].astype(beta.dtype)
 

@@ -18,7 +18,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import cdiv, pad_dim, round_up, use_interpret
+from . import common
+from .common import cdiv, pad_dim, round_up
 
 TILE_M = 128
 TILE_N = 128
@@ -86,7 +87,7 @@ def quant_matmul(x, wq, w_scale, *, out_dtype=None):
     mp, np_ = round_up(m, TILE_M), round_up(n, TILE_N)
     # k pads to a multiple of tile_k: a ragged final k-block would
     # accumulate out-of-bounds garbage (no in-kernel contraction mask)
-    tile_k = min(TILE_K, round_up(k, 8 if use_interpret() else 128))
+    tile_k = min(TILE_K, round_up(k, 8 if common.use_interpret() else 128))
     kp = round_up(k, tile_k)
     xq = pad_dim(pad_dim(xq, 0, mp), 1, kp)
     x_scale = pad_dim(x_scale.reshape(m, 1), 0, mp)
@@ -119,7 +120,7 @@ def quant_matmul(x, wq, w_scale, *, out_dtype=None):
                             + mp * 4 + np_ * 4 * cdiv(mp, TILE_M)
                             + mp * np_ * 4),
             transcendentals=0),
-        interpret=use_interpret(),
+        interpret=common.use_interpret(),
     )(xq, wq, x_scale.astype(jnp.float32), w_scale.astype(jnp.float32))
     return out[:m, :n]
 

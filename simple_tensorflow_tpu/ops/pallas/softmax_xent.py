@@ -23,7 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import NEG_INF, cdiv, pad_dim, round_up, use_interpret
+from . import common
+from .common import NEG_INF, cdiv, pad_dim, round_up
 
 DEFAULT_BLOCK_ROWS = 256
 DEFAULT_BLOCK_VOCAB = 2048
@@ -128,7 +129,7 @@ def _fwd(logits, labels, block_rows, block_vocab, smoothing):
             pltpu.VMEM((block_rows, 1), jnp.float32),
             pltpu.VMEM((block_rows, 1), jnp.float32),
         ],
-        interpret=use_interpret(),
+        interpret=common.use_interpret(),
     )(logits, labels)
     return loss, lse
 
@@ -159,7 +160,7 @@ def _xent_bwd_rule(block_rows, block_vocab, smoothing, res, g):
         ],
         out_specs=pl.BlockSpec((block_rows, bv), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows, vocab), logits.dtype),
-        interpret=use_interpret(),
+        interpret=common.use_interpret(),
     )(logits, labels, lse, g)
     return dx, None
 

@@ -106,34 +106,10 @@ def current_mesh() -> Optional[Mesh]:
 
 
 def get_shard_map():
-    """jax.shard_map across the supported JAX versions (renamed from
-    jax.experimental.shard_map; the ``check_rep`` kwarg became
-    ``check_vma``). Callers use the NEW spelling (``check_vma``); on a
-    jax whose shard_map still takes ``check_rep`` (e.g. the pinned
-    0.4.x) the wrapper translates — and drops kwargs the resident
-    version knows under neither name rather than TypeError-ing."""
-    import inspect
+    """``jax.shard_map`` (callers pass ``check_vma``)."""
+    from jax import shard_map
 
-    try:
-        from jax import shard_map as sm
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        params = inspect.signature(sm).parameters
-    except (TypeError, ValueError):  # pragma: no cover
-        return sm
-    if "check_vma" in params:
-        return sm
-
-    def compat_shard_map(*args, **kwargs):
-        if "check_vma" in kwargs:
-            val = kwargs.pop("check_vma")
-            if "check_rep" in params:
-                kwargs["check_rep"] = val
-        kwargs = {k: v for k, v in kwargs.items() if k in params}
-        return sm(*args, **kwargs)
-
-    return compat_shard_map
+    return shard_map
 
 
 def make_mesh(axes: Dict[str, int], devices=None) -> Mesh:

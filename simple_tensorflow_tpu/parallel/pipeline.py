@@ -306,18 +306,17 @@ op_registry.register("PipelineTrain", lower=_lower_pipeline_train)
 
 def _device_memory_budget(frac=0.6):
     """Usable HBM for activation stashes: memory_stats when the backend
-    reports it, else the v5e's 16 GB, scaled by ``frac`` (params,
-    optimizer state, and XLA scratch own the rest)."""
+    reports it, else the chip table's capacity (utils/perf: published
+    for a known accelerator, nominal for the CPU, an error for an
+    unknown one), scaled by ``frac`` (params, optimizer state, and XLA
+    scratch own the rest). Every device of a mesh is the same chip, so
+    the first one speaks for all."""
     import jax
 
-    try:
-        stats = jax.devices()[0].memory_stats() or {}
-        limit = stats.get("bytes_limit")
-        if limit:
-            return frac * float(limit)
-    except Exception:
-        pass
-    return frac * 16e9
+    from ..utils import perf
+
+    limit = (jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+    return frac * float(limit or perf.chip_hbm_bytes())
 
 
 def pipeline_train(stage_fn, loss_fn, params, x, targets, *,

@@ -6,13 +6,16 @@ ctypes — no build-time Python binding dependency.)
 
 Provides: crc32c, TFRecord reader/writer, arena allocator, flat graph
 prune/topo-sort, and the C-API graph builder used by tests. All callers
-must handle ``available() == False`` (no toolchain, build failure).
+must handle ``available() == False`` (no toolchain, STF_DISABLE_NATIVE);
+a build that was attempted and failed raises instead.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
+import shutil
 import subprocess
 import threading
 from typing import Iterator, List, Optional, Sequence
@@ -33,21 +36,30 @@ _LIB_NAMES = ("libstf_runtime.so",)
 
 
 def _find_or_build() -> Optional[str]:
-    candidates = [os.path.join(_CC_DIR, n) for n in _LIB_NAMES]
-    candidates += [os.path.join(os.path.dirname(__file__), n)
-                   for n in _LIB_NAMES]
-    for c in candidates:
+    """The library built from the sources on disk. Inside a checkout
+    ``make`` decides: it rebuilds when any source, header or the
+    Makefile is newer than the library and is a no-op otherwise, so a
+    stale ``.so`` is never loaded. A failed build raises with the
+    compiler's stderr. Without a toolchain or a ``runtime_cc/`` (an
+    installed package) a prebuilt library beside this module is used,
+    else None."""
+    lib = os.path.join(_CC_DIR, _LIB_NAMES[0])
+    if os.path.isdir(_CC_DIR) and shutil.which("make"):
+        # one build at a time across processes (xdist workers, data
+        # workers): flock the Makefile itself, no extra file
+        with open(os.path.join(_CC_DIR, "Makefile")) as lock_f:
+            fcntl.flock(lock_f, fcntl.LOCK_EX)
+            proc = subprocess.run(["make", "-C", _CC_DIR, "-j4"],
+                                  capture_output=True, text=True,
+                                  timeout=240)
+        if proc.returncode != 0:
+            raise RuntimeError(
+                f"building {lib} failed (make exit {proc.returncode}):\n"
+                f"{proc.stderr}")
+        return lib
+    for c in (lib, os.path.join(os.path.dirname(__file__), _LIB_NAMES[0])):
         if os.path.exists(c):
             return c
-    if os.path.isdir(_CC_DIR):
-        try:
-            subprocess.run(["make", "-C", _CC_DIR, "-j4"], check=True,
-                           capture_output=True, timeout=240)
-        except Exception:
-            return None
-        p = os.path.join(_CC_DIR, _LIB_NAMES[0])
-        if os.path.exists(p):
-            return p
     return None
 
 
@@ -76,10 +88,9 @@ def _bind(lib):
 
     lib.StfRecordReaderOpen.argtypes = [c.c_char_p, c.c_void_p]
     lib.StfRecordReaderOpen.restype = c.c_void_p
-    if hasattr(lib, "StfRecordReaderOpenBuffered"):  # newer .so only
-        lib.StfRecordReaderOpenBuffered.argtypes = [c.c_char_p, c.c_int64,
-                                                    c.c_void_p]
-        lib.StfRecordReaderOpenBuffered.restype = c.c_void_p
+    lib.StfRecordReaderOpenBuffered.argtypes = [c.c_char_p, c.c_int64,
+                                                c.c_void_p]
+    lib.StfRecordReaderOpenBuffered.restype = c.c_void_p
     lib.StfRecordReaderNext.argtypes = [c.c_void_p, c.POINTER(u8p),
                                         c.POINTER(c.c_size_t), c.c_void_p]
     lib.StfRecordReaderNext.restype = c.c_int
@@ -128,15 +139,12 @@ def _bind(lib):
         c.POINTER(c.c_char_p), c.POINTER(c.c_int32), c.POINTER(c.c_int64),
         c.c_int32, c.POINTER(c.c_void_p), c.POINTER(c.c_uint8), c.c_void_p]
     lib.StfParseExamplesDense.restype = c.c_int
-    # hasattr-gated: a stale .so built before ISSUE 19 lacks the ragged
-    # entry point; the Python layer then falls back to the slow path
-    if hasattr(lib, "StfParseExamplesRagged"):
-        lib.StfParseExamplesRagged.argtypes = [
-            c.POINTER(c.POINTER(c.c_uint8)), c.POINTER(c.c_size_t),
-            c.c_int64, c.POINTER(c.c_char_p), c.POINTER(c.c_int32),
-            c.POINTER(c.c_int64), c.c_int32, c.POINTER(c.c_void_p),
-            c.POINTER(c.c_int64), c.c_void_p]
-        lib.StfParseExamplesRagged.restype = c.c_int
+    lib.StfParseExamplesRagged.argtypes = [
+        c.POINTER(c.POINTER(c.c_uint8)), c.POINTER(c.c_size_t),
+        c.c_int64, c.POINTER(c.c_char_p), c.POINTER(c.c_int32),
+        c.POINTER(c.c_int64), c.c_int32, c.POINTER(c.c_void_p),
+        c.POINTER(c.c_int64), c.c_void_p]
+    lib.StfParseExamplesRagged.restype = c.c_int
     return lib
 
 
@@ -151,10 +159,7 @@ def _load():
         path = _find_or_build()
         if path is None:
             return None
-        try:
-            _lib = _bind(ctypes.CDLL(path))
-        except OSError:
-            _lib = None
+        _lib = _bind(ctypes.CDLL(path))
         return _lib
 
 
@@ -227,7 +232,7 @@ def read_tfrecord_chunks(path: str, batch: int = 256,
     """
     lib = _load()
     with _Status(lib) as st:
-        if buffer_size and hasattr(lib, "StfRecordReaderOpenBuffered"):
+        if buffer_size:
             h = lib.StfRecordReaderOpenBuffered(
                 path.encode(), int(buffer_size), st.handle)
         else:
@@ -317,7 +322,7 @@ def parse_examples_dense(serialized, names, kinds, sizes):
 
 def ragged_parse_available() -> bool:
     lib = _load()
-    return lib is not None and hasattr(lib, "StfParseExamplesRagged")
+    return lib is not None
 
 
 def parse_examples_ragged(serialized, names, kinds, caps, pad_id=-1):
@@ -335,7 +340,7 @@ def parse_examples_ragged(serialized, names, kinds, caps, pad_id=-1):
     length 0).
     """
     lib = _load()
-    if lib is None or not hasattr(lib, "StfParseExamplesRagged"):
+    if lib is None:
         raise RuntimeError("native ragged parser unavailable")
     n = len(serialized)
     nf = len(names)

@@ -2,8 +2,8 @@
 operation blows through its deadline.
 
 A fused ``run_steps`` window or a serving batch that normally takes
-milliseconds and suddenly takes minutes is WEDGED (device hang, relay
-stall, deadlock) — and by the time a human looks, the evidence is gone.
+milliseconds and suddenly takes minutes is WEDGED (device hang,
+deadlock) — and by the time a human looks, the evidence is gone.
 Callers arm the watchdog around such operations with a deadline derived
 from their own trailing average:
 

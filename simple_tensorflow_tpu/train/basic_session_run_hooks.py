@@ -289,8 +289,8 @@ class StepCounterHook(SessionRunHook):
                 if perf_report is not None:
                     self.last_perf = perf_report
                     logging.info(
-                        "perf: mfu=%.4g measured/predicted=%.3g",
-                        perf_report.get("mfu", 0.0),
+                        "perf: mfu=%s measured/predicted=%.3g",
+                        perf_report.get("mfu", "not measured"),
                         perf_report.get("measured_over_predicted", 0.0))
                 if self._summary_writer is not None:
                     self._summary_writer.add_summary_value(
@@ -592,9 +592,9 @@ class ProfilerHook(SessionRunHook):
         stats = getattr(run_metadata, "step_stats", None) or {}
         cost = getattr(run_metadata, "cost_graph", None) or {}
         wall = stats.get("wall_time_s")
-        if wall and cost.get("flops"):
-            from ..utils import perf
+        from ..utils import perf
 
+        if wall and cost.get("flops") and perf.has_peak():
             logging.info(
                 "ProfilerHook step %d: wall=%.4gs xla_flops=%.3g "
                 "mfu=%.4g trace=%s", step, wall, cost["flops"],

@@ -455,6 +455,13 @@ class Saver:
                         raise errors.NotFoundError(
                             None, None,
                             f"Key {key} not found in checkpoint {save_path}")
+                    if value.dtype.kind == "V" and meta.get("dtype"):
+                        # npz keeps the bytes of a dtype NumPy does not
+                        # know natively (bfloat16) but reads them back
+                        # as void: the index recorded the real dtype
+                        import jax.numpy as jnp
+
+                        value = value.view(jnp.dtype(meta["dtype"]))
                     name = v.var_name if hasattr(v, "var_name") else key
                     sess._variable_store.load(name, value, v
                                               if hasattr(v, "dtype") else None)

@@ -1,6 +1,6 @@
 """stf.nest: structure flatten/pack utilities
 (ref: tensorflow/python/util/nest.py — the public structure helpers TF
-programs use everywhere; VERDICT missing #5).
+programs use everywhere).
 
 Reference semantics, pinned exactly (where ``jax.tree_util`` — the
 machinery the lowering itself uses — differs, the structural walk here

@@ -18,55 +18,65 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
-# per-chip peaks (bf16 FLOP/s, HBM bytes/s)
-_CHIP_SPECS = {
-    "v5e": (197e12, 819e9),
-    "v5 lite": (197e12, 819e9),
-    "v5p": (459e12, 2765e9),
-    "v4": (275e12, 1228e9),
-    "v3": (123e12, 900e9),
-    "v2": (46e12, 700e9),
+# Published per-chip figures, keyed by the ``device_kind`` JAX reports:
+# (peak bf16 FLOP/s, HBM bytes/s, HBM bytes). The one table of the
+# repo — bench.py and the kernel cost gate read it too. An accelerator
+# that is not in it is an error, never a default.
+#   "TPU v5 lite" (v5e): Google Cloud documentation, "TPU v5e" —
+#   197 TFLOP/s bf16, 819 GB/s and 16 GB of HBM per chip.
+CHIP_TABLE = {
+    "TPU v5 lite": (197e12, 819e9, 16e9),
 }
-_DEFAULT_SPEC = (197e12, 819e9)
-
-_CHIP_HBM_BYTES = {
-    "v5e": 16e9,
-    "v5 lite": 16e9,
-    "v5p": 95e9,
-    "v4": 32e9,
-    "v3": 32e9,
-    "v2": 16e9,
-}
-_DEFAULT_HBM = 16e9
+# The CPU has no published peak. These are NOMINAL figures so the
+# planning maths (cost gate, remat, microbatch sizing) has something to
+# divide by under tier-1; no utilization is ever computed from them
+# (mfu/roofline refuse the CPU).
+_CPU_NOMINAL = (1e12, 100e9, 4e9)
 
 
-def chip_spec(device=None):
-    """(peak_flops, peak_hbm_bw) for the attached device."""
+def published_chip(device=None):
+    """(peak_flops, peak_hbm_bw, hbm_bytes) of an accelerator in
+    CHIP_TABLE; any other device, the CPU included, raises."""
     import jax
 
     d = device or jax.devices()[0]
-    kind = getattr(d, "device_kind", "").lower()
-    for key, spec in _CHIP_SPECS.items():
-        if key in kind:
-            return spec
-    if d.platform == "cpu":
-        return (1e12, 100e9)  # nominal, for CI math
-    return _DEFAULT_SPEC
+    kind = getattr(d, "device_kind", "")
+    if kind not in CHIP_TABLE:
+        raise ValueError(
+            f"no published peak for device kind {kind!r} (platform "
+            f"{d.platform!r}): add it to utils.perf.CHIP_TABLE with its "
+            "source")
+    return CHIP_TABLE[kind]
+
+
+def has_peak(device=None) -> bool:
+    """Whether a utilization may be computed on this device: False on
+    the CPU (nominal figures only). An accelerator answers True even
+    when it is missing from CHIP_TABLE — asking for its peak then
+    raises, which is the point."""
+    import jax
+
+    return (device or jax.devices()[0]).platform != "cpu"
+
+
+def _planning_chip(device=None):
+    import jax
+
+    d = device or jax.devices()[0]
+    return _CPU_NOMINAL if d.platform == "cpu" else published_chip(d)
+
+
+def chip_spec(device=None):
+    """(peak_flops, peak_hbm_bw) for planning maths on the attached
+    device — published for an accelerator, nominal for the CPU."""
+    return _planning_chip(device)[:2]
 
 
 def chip_hbm_bytes(device=None):
     """Per-chip HBM capacity for the attached device (memory-planning
-    inputs: remat decisions, pipeline microbatch sizing)."""
-    import jax
-
-    d = device or jax.devices()[0]
-    kind = getattr(d, "device_kind", "").lower()
-    for key, size in _CHIP_HBM_BYTES.items():
-        if key in kind:
-            return size
-    if d.platform == "cpu":
-        return 4e9  # nominal, for CI math
-    return _DEFAULT_HBM
+    inputs: remat decisions, pipeline microbatch sizing) — published
+    for an accelerator, nominal for the CPU."""
+    return _planning_chip(device)[2]
 
 
 class StepTimer:
@@ -291,9 +301,11 @@ def collective_bytes_of(compiled) -> Dict[str, float]:
 
 
 def mfu(step_flops: float, step_seconds: float, device=None) -> float:
-    """Model FLOPs Utilization: achieved/peak."""
-    peak, _ = chip_spec(device)
-    if step_seconds <= 0 or peak <= 0:
+    """Model FLOPs Utilization: achieved/peak. Only against a published
+    peak: on the CPU (or an accelerator missing from CHIP_TABLE) this
+    raises instead of dividing by a nominal figure."""
+    peak = published_chip(device)[0]
+    if step_seconds <= 0:
         return 0.0
     return step_flops / step_seconds / peak
 
@@ -302,8 +314,9 @@ def roofline(step_flops: float, step_bytes: float, device=None
              ) -> Dict[str, float]:
     """Arithmetic intensity vs the machine ridge point: intensity >
     ridge -> compute-bound (good: MXU busy); below -> HBM-bound (fuse
-    more / recompute instead of re-reading)."""
-    peak_flops, peak_bw = chip_spec(device)
+    more / recompute instead of re-reading). Published peaks only, like
+    :func:`mfu`."""
+    peak_flops, peak_bw, _ = published_chip(device)
     intensity = step_flops / step_bytes if step_bytes else float("inf")
     ridge = peak_flops / peak_bw
     attainable = min(peak_flops, intensity * peak_bw)
@@ -354,8 +367,12 @@ class PerfReport:
         out = dict(s)
         flops = self._cost.get("flops")
         if flops:
-            out["mfu"] = mfu(flops, s["mean_s"], self._device)
             out["achieved_tflops"] = flops / s["mean_s"] / 1e12
+        # utilizations only against a published peak: none on the CPU
+        if not has_peak(self._device):
+            return out
+        if flops:
+            out["mfu"] = mfu(flops, s["mean_s"], self._device)
         if self._cost.get("bytes"):
             out.update(roofline(self._cost.get("flops", 0.0),
                                 self._cost["bytes"], self._device))

@@ -1,6 +1,6 @@
-"""Machine-checkable byte budgets for the headline train steps (VERDICT
-r4 item 1b): the 77→~55 GB ResNet byte diagnosis and the BERT byte fixes
-must be guarded by CI that runs WITHOUT the TPU.
+"""Machine-checkable byte budgets for the headline train steps: the
+77→~55 GB ResNet byte diagnosis and the BERT byte fixes must be guarded
+by CI that runs WITHOUT the TPU.
 
 Three layers of guard, each catching what the previous can't:
 
@@ -162,9 +162,9 @@ _BERT_FLOPS_RANGE = (8.0e12, 9.8e12)     # 8.839 measured
 
 
 def _enable_cache():
-    cache = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), ".jax_cache")
-    jax.config.update("jax_compilation_cache_dir", cache)
+    from simple_tensorflow_tpu.compiler import aot
+
+    aot.enable_persistent_cache()
 
 
 # ---------------------------------------------------------------------------

@@ -1,4 +1,4 @@
-"""Static cost model vs XLA cost analysis (VERDICT r4 item 3; ref:
+"""Static cost model vs XLA cost analysis (ref:
 tensorflow/core/grappler/costs/{cost_estimator.h,op_level_cost_estimator.cc,
 graph_memory.cc}).
 
@@ -10,7 +10,7 @@ The contract on the five BASELINE bench configs:
   which approximates the *fused* program (one FusedBatchNorm node ≈ one
   fused HLO region), so the honest comparator is the measured on-chip
   bytes-accessed where it exists: ResNet-b256 77.1 GB and BERT-b24-s512
-  66 GB (artifacts/bench_measured_r3_onchip.json, TPU v5e, r3) — within
+  66 GB (an older v5e chip run whose record was removed in PR 21) — within
   2x. Where no on-chip number exists, the prediction must sit in the
   bracket [pre-fusion/16, pre-fusion]: XLA's pre-fusion analysis counts
   every decomposed elementwise op's full traffic (ResNet: 874 GB vs

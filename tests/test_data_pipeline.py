@@ -486,6 +486,7 @@ class TestCompileCacheWiring:
     def test_config_param_and_env(self, tmp_path, monkeypatch):
         import jax
 
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         try:
             cache_dir = str(tmp_path / "cc")
             cfg = stf.ConfigProto(compile_cache_dir=cache_dir)
@@ -501,4 +502,52 @@ class TestCompileCacheWiring:
         finally:
             # tmp_path is deleted after the test — don't leave the
             # process-global cache pointing into it
+            jax.config.update("jax_compilation_cache_dir", None)
+
+    def test_jax_env_var_wins_and_is_never_overwritten(
+            self, tmp_path, monkeypatch):
+        """The one rule (compiler/aot.py): with
+        JAX_COMPILATION_CACHE_DIR set, no stf path sets another
+        directory — not ConfigProto, not STF_COMPILE_CACHE, not the
+        checkout default — and the autotune verdicts follow it."""
+        import jax
+        from simple_tensorflow_tpu.compiler import aot
+        from simple_tensorflow_tpu.kernels import registry as kreg
+
+        outside = str(tmp_path / "outside")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        monkeypatch.setenv("STF_COMPILE_CACHE", str(tmp_path / "env_cc"))
+        monkeypatch.setattr(aot, "_persistent_cache_dir", None)
+        before = jax.config.jax_compilation_cache_dir
+        updates = []
+        real_update = jax.config.update
+
+        def spy(name, value):
+            updates.append(name)
+            return real_update(name, value)
+
+        monkeypatch.setattr(jax.config, "update", spy)
+        cfg = stf.ConfigProto(compile_cache_dir=str(tmp_path / "cc"))
+        with stf.Session(config=cfg):
+            pass
+        assert aot.enable_persistent_cache() == outside
+        assert not updates      # nor its thresholds: JAX's handling stands
+        assert jax.config.jax_compilation_cache_dir == before
+        assert aot.persistent_cache_dir() == outside
+        assert kreg._cache_file() == os.path.join(
+            outside, "stf_kernel_autotune.json")
+
+    def test_default_is_the_checkout_cache(self, monkeypatch):
+        import jax
+        from simple_tensorflow_tpu.compiler import aot
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setattr(aot, "_persistent_cache_dir", None)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        try:
+            assert aot.enable_persistent_cache() == os.path.join(
+                repo, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == os.path.join(
+                repo, ".jax_cache")
+        finally:
             jax.config.update("jax_compilation_cache_dir", None)

@@ -302,10 +302,35 @@ class TestPerf:
     def test_mfu_and_roofline(self):
         from simple_tensorflow_tpu.utils import perf
 
-        assert 0 < perf.mfu(1e12, 1.0) <= 1.0
-        r = perf.roofline(step_flops=1e12, step_bytes=1e9)
+        class V5e:
+            platform, device_kind = "tpu", "TPU v5 lite"
+
+        assert perf.mfu(98.5e12, 1.0, device=V5e) == pytest.approx(0.5)
+        r = perf.roofline(step_flops=1e12, step_bytes=1e9, device=V5e)
         assert r["compute_bound"] == (r["intensity_flops_per_byte"]
                                       >= r["ridge_point"])
+        assert r["ridge_point"] == pytest.approx(197e12 / 819e9)
+
+    def test_no_utilization_from_nominal_or_unknown_peaks(self):
+        """The CPU's figures are nominal planning inputs: planning maths
+        may read them, a utilization may not; an accelerator that is not
+        in the table is an error everywhere."""
+        from simple_tensorflow_tpu.utils import perf
+
+        assert perf.chip_spec() == perf._CPU_NOMINAL[:2]
+        assert perf.chip_hbm_bytes() == perf._CPU_NOMINAL[2]
+        with pytest.raises(ValueError, match="no published peak"):
+            perf.mfu(1e12, 1.0)
+        with pytest.raises(ValueError, match="no published peak"):
+            perf.roofline(1e12, 1e9)
+
+        class Unknown:
+            platform, device_kind = "tpu", "TPU v99"
+
+        for fn in (perf.chip_spec, perf.chip_hbm_bytes,
+                   perf.published_chip):
+            with pytest.raises(ValueError, match="TPU v99"):
+                fn(Unknown)
 
     def test_step_timer(self):
         from simple_tensorflow_tpu.utils import perf
@@ -331,6 +356,8 @@ class TestPerf:
         rep.step_done()
         out = rep.report()
         assert out.get("achieved_tflops", 0) >= 0
+        # the CPU has no published peak: no utilization in the report
+        assert "mfu" not in out and "roofline_fraction_of_peak" not in out
 
 
 class TestConfigProtoTransferGuard:

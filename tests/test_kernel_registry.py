@@ -350,13 +350,51 @@ class TestAutotune:
         bk = kreg.backend()
         # the CPU static gate says xla (interpret_backend); a measured
         # verdict must win anyway — auto never contradicts a measurement
-        kreg._measured[("FusedLayerNorm", key, bk)] = {
+        mkey = ("FusedLayerNorm", key, bk, kreg.device_kind())
+        kreg._measured[mkey] = {
             "verdict": "pallas", "pallas_s": 1e-6, "xla_s": 1e-3}
         try:
             assert kreg.decide("FusedLayerNorm", key, mode="auto") == (
                 "pallas", "autotune")
         finally:
-            del kreg._measured[("FusedLayerNorm", key, bk)]
+            del kreg._measured[mkey]
+
+    def test_verdict_of_another_device_kind_is_not_replayed(self):
+        key = kreg.aval_key(np.zeros((8, 48), np.float32),
+                            np.zeros((48,), np.float32),
+                            np.zeros((48,), np.float32))
+        mkey = ("FusedLayerNorm", key, kreg.backend(), "some other chip")
+        kreg._measured[mkey] = {
+            "verdict": "pallas", "pallas_s": 1e-6, "xla_s": 1e-3}
+        try:
+            assert kreg.decide("FusedLayerNorm", key, mode="auto") == (
+                "xla", "interpret_backend")
+        finally:
+            del kreg._measured[mkey]
+            kreg.clear_decisions()
+
+    def test_failing_lowering_propagates_from_autotune(self):
+        def broken(x):
+            raise ValueError("mosaic says no")
+
+        kd = kreg.register_kernel(
+            "TestKernelBroken",
+            impls={"pallas": broken, "xla": lambda x: x + x},
+            legacy="xla",
+            cost_gate=lambda key, bk: (None, "cost_model_uncertain"),
+            make_case=lambda key: ((np.ones((4,), np.float32),), {}))
+        key = kreg.aval_key(np.zeros((4,), np.float32))
+        try:
+            with pytest.raises(RuntimeError) as ei:
+                kreg.decide("TestKernelBroken", key, mode="auto")
+            msg = str(ei.value)
+            assert "TestKernelBroken" in msg and "'pallas'" in msg
+            assert isinstance(ei.value.__cause__, ValueError)
+            # nothing was recorded as a verdict
+            assert not [k for k in kreg.measured_verdicts()
+                        if k[0] == "TestKernelBroken"]
+        finally:
+            del kreg._KERNELS[kd.op_type]
 
     def test_uncertain_gate_measures_once_and_caches(self):
         calls = []
@@ -384,12 +422,12 @@ class TestAutotune:
             n1 = kreg.metric_autotune_runs.get_cell(
                 "TestKernelUncertain").value()
             assert n1 == n0 + 1  # measured exactly once, then cached
-            assert ("TestKernelUncertain", key,
-                    kreg.backend()) in kreg.measured_verdicts()
+            mkey = ("TestKernelUncertain", key, kreg.backend(),
+                    kreg.device_kind())
+            assert mkey in kreg.measured_verdicts()
         finally:
             del kreg._KERNELS["TestKernelUncertain"]
-            kreg._measured.pop(
-                ("TestKernelUncertain", key, kreg.backend()), None)
+            kreg._measured.pop(mkey, None)
 
     def test_persistence_roundtrip(self, tmp_path, monkeypatch):
         from simple_tensorflow_tpu.compiler import aot
@@ -397,7 +435,7 @@ class TestAutotune:
         monkeypatch.setattr(aot, "_persistent_cache_dir", str(tmp_path))
         monkeypatch.setattr(kreg, "_measured_loaded_from", None)
         key = kreg.aval_key(np.zeros((3, 3), np.float32), probe=True)
-        cache_key = ("FusedLayerNorm", key, "cpu")
+        cache_key = ("FusedLayerNorm", key, "cpu", "cpu")
         kreg._measured[cache_key] = {"verdict": "pallas",
                                      "pallas_s": 1e-6, "xla_s": 1e-3}
         try:
@@ -565,3 +603,63 @@ class TestRoutingReport:
         assert snap["mode"] in ("off", "auto", "force")
         for k in ("routed", "fallback", "autotune_runs", "kernels"):
             assert k in snap
+
+
+# ---------------------------------------------------------------------------
+# GSPMD cannot partition a Mosaic kernel (PR 21)
+# ---------------------------------------------------------------------------
+
+class TestMeshAutoPartitioned:
+    """Under a multi-device mesh outside shard_map the step is
+    partitioned by GSPMD, which refuses Mosaic kernels ("cannot be
+    automatically partitioned"): on a TPU the registry must take the
+    XLA lowering there, in every mode, and route normally again inside
+    a shard_map body."""
+
+    KEY = kreg.aval_key(np.zeros((8, 32), np.float32),
+                        np.zeros((32,), np.float32),
+                        np.zeros((32,), np.float32))
+
+    @pytest.mark.parametrize("mode", ["off", "auto", "force"])
+    def test_tpu_under_mesh_takes_xla(self, monkeypatch, mode):
+        monkeypatch.setattr(kreg, "backend", lambda: "tpu")
+        with kreg.activate(mode, auto_partitioned=True):
+            assert kreg.decide("FusedLayerNorm", self.KEY, count=False) \
+                == ("xla", "mesh_auto_partitioned")
+            with kreg.activate(mode):          # a shard_map body
+                impl, reason = kreg.decide("FusedLayerNorm", self.KEY,
+                                           mode="force", count=False)
+                assert (impl, reason) == ("pallas", "forced")
+            assert kreg.decide("FusedLayerNorm", self.KEY, count=False) \
+                == ("xla", "mesh_auto_partitioned")
+        kreg.clear_decisions()
+
+    def test_interpreted_kernels_partition_fine_off_tpu(self):
+        with kreg.activate("force", auto_partitioned=True):
+            assert kreg.decide("FusedLayerNorm", self.KEY, count=False) \
+                == ("pallas", "forced")
+
+    def test_session_lowering_sets_the_flag_from_the_mesh(self):
+        from simple_tensorflow_tpu import parallel
+
+        seen = []
+        real = kreg.activate.__enter__
+
+        def spy(self):
+            seen.append(self._auto)
+            return real(self)
+
+        x = stf.placeholder(stf.float32, [8, 4], "x")
+        y = stf.reduce_sum(x * 2.0)
+        try:
+            kreg.activate.__enter__ = spy
+            with stf.Session() as sess:
+                sess.run(y, {x: np.ones((8, 4), np.float32)})
+            assert seen and not any(seen)
+            del seen[:]
+            with parallel.Mesh({"dp": 4}):
+                with stf.Session() as sess:
+                    sess.run(y * 3.0, {x: np.ones((8, 4), np.float32)})
+            assert seen and all(seen)
+        finally:
+            kreg.activate.__enter__ = real

@@ -172,7 +172,7 @@ class TestMutableHashTable:
 
 class TestEndToEndTextPipeline:
     def test_vocab_to_ids_to_training_to_decoded_strings(self, tmp_path):
-        """The full journey VERDICT r3 asked for: vocab file -> string
+        """The full journey: vocab file -> string
         tokens -> ids -> embedding training step -> predicted ids ->
         decoded strings, all through stf API."""
         stf.reset_default_graph()

@@ -109,7 +109,7 @@ def test_bert_with_input_mask():
 
 def test_bert_pretrain_config_lowers_to_flash_attention():
     """The HEADLINE config — padded batches AND attention dropout — must
-    run the Pallas flash kernel, not an XLA fallback (VERDICT r2 weak #2)."""
+    run the Pallas flash kernel, not an XLA fallback."""
     from simple_tensorflow_tpu.models import bert
 
     cfg = bert.BertConfig.tiny()

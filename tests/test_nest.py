@@ -1,5 +1,5 @@
 """stf.nest conformance against reference tensorflow/python/util/nest.py
-semantics (VERDICT missing #5): flatten order, dict key sorting,
+semantics: flatten order, dict key sorting,
 namedtuple preservation, None-as-atom, error types."""
 
 import collections

@@ -1,4 +1,4 @@
-"""Op-registry conformance sweep (VERDICT r4 item 4; ref: the 175
+"""Op-registry conformance sweep (ref: the 175
 kernel_test files under tensorflow/python/kernel_tests/).
 
 Coverage is ENFORCED by enumeration: every name in the op registry must
@@ -1595,7 +1595,7 @@ def test_op_misc(op_name):
 def test_registry_fully_covered():
     """The enumeration guard: every registered op has coverage. A new op
     without a CASES entry, a MISC test, or a VERIFIED pointer to an
-    existing test fails here (VERDICT r4 item 4 'done' criterion:
+    existing test fails here (the criterion:
     0 registered ops untested)."""
     all_ops = set(op_registry.registered_ops())
     # parametric families registered lazily on first use (one concrete

@@ -1,4 +1,4 @@
-"""Plan-time graph optimizer on the Session hot path (VERDICT round-1 #5:
+"""Plan-time graph optimizer on the Session hot path (
 fold/CSE/DCE must actually run in _plan) + device-scope placement."""
 
 import numpy as np

@@ -44,9 +44,11 @@ def _two_sessions(graph):
 
 
 class TestEquivalence:
-    def test_mnist_convnet_bit_exact(self):
+    def test_mnist_convnet_fused_matches_sequential(self):
         """Convnet with dropout (stateful RNG), Adam slots, and
-        global_step: n fused steps == n sequential runs, bit for bit."""
+        global_step: n fused steps == n sequential runs — integers bit
+        for bit, floats to what one XLA program can promise of
+        another (see the tolerances below)."""
         from simple_tensorflow_tpu.models import mnist
 
         stf.set_random_seed(11)
@@ -83,11 +85,16 @@ class TestEquivalence:
                 np.testing.assert_array_equal(a, b,
                                               err_msg=f"{name} diverged")
             else:
-                # accumulated over n Adam steps: single-ULP rounding
-                # differences compound through rsqrt (measured max
-                # ~1.3e-6 absolute after 5 steps)
+                # accumulated over n Adam steps. The scan body and the
+                # single step are different XLA programs, so gradients
+                # differ in the last ULP; Adam divides by sqrt(v), which
+                # turns a last-ULP difference in a near-zero gradient
+                # into a visible fraction of one step (lr = 1e-3). On
+                # JAX 0.9 / this XLA the worst of 3.2 M elements is
+                # 8.8e-6 after 5 steps; the bound is 2 % of one step —
+                # 250x below the 5 * lr a real divergence would show.
                 np.testing.assert_allclose(
-                    a, b, rtol=1e-4, atol=5e-6,
+                    a, b, rtol=1e-4, atol=2e-5,
                     err_msg=f"variable {name} diverged")
 
     def test_lr_schedule_and_global_step(self):

@@ -28,6 +28,21 @@ class TestSaver:
             assert sess.run(v.value()).tolist() == [1.0, 2.0]
             assert float(sess.run(w.value())) == 3.0
 
+    def test_bfloat16_variable_roundtrip(self, tmp_path):
+        # npz reads bfloat16 bytes back as void: restore must recover
+        # the dtype the index recorded (bf16 serving weights, PR 21)
+        v = stf.Variable(stf.cast(stf.constant([1.5, -2.25, 3.0]),
+                                  stf.bfloat16), name="bv")
+        saver = stf.train.Saver()
+        with stf.Session() as sess:
+            sess.run(stf.global_variables_initializer())
+            path = saver.save(sess, str(tmp_path / "bf"))
+        with stf.Session() as sess2:
+            saver.restore(sess2, path)
+            out = sess2.run(v.value())
+            assert str(out.dtype) == "bfloat16"
+            assert out.astype(np.float32).tolist() == [1.5, -2.25, 3.0]
+
     def test_restore_into_fresh_session(self, tmp_path):
         v = stf.Variable(stf.constant([5.0]), name="rv")
         saver = stf.train.Saver()

@@ -1,0 +1,182 @@
+"""Described-topology compiles: every Pallas kernel of the main path,
+at the shapes BERT-base and the Transformer-big-width causal LM really
+produce, compiled by the TPU's own compiler for a v5e chip that is
+DESCRIBED, not attached (on-chip-measurement guide, section 2.3).
+
+Interpret mode hides what Mosaic refuses (the single-query decode
+kernel passed every interpret test and failed here until PR 21). These
+compile in a second or two each and guard every later PR at no chip
+time. Nothing runs: a pass is not a chip run.
+
+All in ONE file (one xdist worker loads libtpu and keeps its lock); the
+topology is described inside a module-scoped fixture, never at import.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from simple_tensorflow_tpu.ops import pallas as P
+from simple_tensorflow_tpu.ops.pallas import common
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+# BERT-base: batch 24, 12 heads x 64, seq 512, hidden 768, vocab 30522,
+# 76 masked positions per row. Causal LM at Transformer-big widths:
+# 16 heads x 64, d_model 1024, d_ff 4096, 8 live sequences of 1024.
+BERT_QKV = (24, 12, 512, 64)
+LM_QKV = (8, 16, 512, 64)
+BERT_ROWS, BERT_HIDDEN, BERT_VOCAB = 24 * 512, 768, 30522
+BERT_PARAMS = 110_000_000
+LM_CACHE = (8, 1024, 16, 64)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu / lock held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def tpu_compile(one_chip, monkeypatch):
+    """compile(fn, *(shape, dtype)) -> compiled HLO text, for the
+    described chip. Kernels lower natively (the default backend here is
+    the CPU, so use_interpret is steered from the test), and the
+    persistent cache is off: such an executable cannot be read back
+    without a chip."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(common, "use_interpret", lambda: False)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+    def compile_(fn, *avals):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in avals]
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert "tpu_custom_call" in text, "no Mosaic kernel in the HLO"
+        return text
+
+    yield compile_
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _grad(fn, argnums):
+    def loss(*a):
+        out = fn(*a)
+        out = out[0] if isinstance(out, (tuple, list)) else out
+        return jnp.sum(out.astype(F32))
+    return jax.grad(loss, argnums)
+
+
+_SEED = np.asarray([7], np.int32)
+
+
+@pytest.mark.parametrize("qkv", [BERT_QKV, LM_QKV], ids=["bert", "lm_big"])
+class TestFlashAttention:
+    def test_forward(self, tpu_compile, qkv):
+        tpu_compile(P.flash_attention, *[(qkv, BF16)] * 3)
+
+    def test_forward_bias_dropout(self, tpu_compile, qkv):
+        tpu_compile(
+            lambda q, k, v, b: P.flash_attention(
+                q, k, v, bias=b, dropout_rate=0.1, dropout_seed=_SEED),
+            *[(qkv, BF16)] * 3, ((qkv[0], qkv[2]), F32))
+
+    def test_causal_backward(self, tpu_compile, qkv):
+        tpu_compile(
+            _grad(lambda q, k, v: P.flash_attention(q, k, v, causal=True),
+                  (0, 1, 2)),
+            *[(qkv, BF16)] * 3)
+
+
+def test_flash_attention_return_lse_long(tpu_compile):
+    tpu_compile(lambda q, k, v: P.flash_attention(q, k, v, return_lse=True),
+                *[((1, 16, 2048, 64), BF16)] * 3)
+
+
+def test_layer_norm_forward_backward(tpu_compile):
+    avals = [((BERT_ROWS, BERT_HIDDEN), BF16), ((BERT_HIDDEN,), F32),
+             ((BERT_HIDDEN,), F32)]
+    tpu_compile(P.layer_norm, *avals)
+    tpu_compile(_grad(P.layer_norm, (0, 1, 2)), *avals)
+
+
+def test_softmax_xent_forward_backward(tpu_compile):
+    avals = [((24 * 76, BERT_VOCAB), BF16), ((24 * 76,), jnp.int32)]
+    tpu_compile(P.softmax_cross_entropy, *avals)
+    tpu_compile(_grad(P.softmax_cross_entropy, (0,)), *avals)
+
+
+def test_fused_adam_flat_group(tpu_compile):
+    n = (BERT_PARAMS,)
+    tpu_compile(
+        lambda p, m, v, g, a: P.adam_update(p, m, v, g, a, beta1=0.9,
+                                            beta2=0.999, eps=1e-8),
+        (n, F32), (n, F32), (n, F32), (n, F32), ((), F32))
+
+
+def test_fused_momentum_flat_group(tpu_compile):
+    n = (BERT_PARAMS,)
+    tpu_compile(P.momentum_update, (n, F32), (n, F32), (n, F32), ((), F32),
+                ((), F32))
+
+
+def test_dropout_bias_residual(tpu_compile):
+    x = ((BERT_ROWS, BERT_HIDDEN), BF16)
+    tpu_compile(
+        lambda x, r, b: P.dropout_bias_residual(x, r, b, rate=0.1,
+                                                seed=_SEED),
+        x, x, ((BERT_HIDDEN,), BF16))
+
+
+def test_quant_matmul(tpu_compile):
+    tpu_compile(P.quant_matmul, ((512, 1024), BF16), ((1024, 4096), jnp.int8),
+                ((4096,), F32))
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+class TestDecodeAttention:
+    def test_single_query(self, tpu_compile, dtype):
+        # the per-token decode shape: one query per live sequence.
+        # Mosaic refused this kernel before PR 21 (a dot_general with no
+        # non-contracting lhs dimension)
+        b, _, h, d = LM_CACHE
+        tpu_compile(P.decode_attention, ((b, h, d), dtype),
+                    (LM_CACHE, dtype), (LM_CACHE, dtype), ((b,), jnp.int32))
+
+    def test_query_block(self, tpu_compile, dtype):
+        # page-block prefill: 64 query positions, causal inside the block
+        b, _, h, d = LM_CACHE
+        tpu_compile(
+            lambda q, k, v, n: P.decode_attention(q, k, v, n,
+                                                  causal_offset=True),
+            ((b, 64, h, d), dtype), (LM_CACHE, dtype), (LM_CACHE, dtype),
+            ((b,), jnp.int32))
+
+
+def test_interpret_follows_the_lowering_platform_not_a_cached_answer(
+        monkeypatch):
+    """use_interpret is asked at every trace: a process that touched a
+    kernel while the CPU was the default is not frozen into interpret
+    mode, and interpreting on a TPU process is an error."""
+    assert common.use_interpret() is True          # CPU default backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert common.use_interpret() is False         # no cached answer
+    with jax.default_device(jax.devices("cpu")[0]):
+        with pytest.raises(RuntimeError, match="interpret mode"):
+            common.use_interpret()
