@@ -78,10 +78,6 @@ _metric_compile_seconds = monitoring.Sampler(
     monitoring.ExponentialBuckets(1e-3, 2.0, 24),
     "XLA compile seconds per new executable (on untraced first calls the "
     "sample includes the first execution — compile dominates)")
-_metric_phase_seconds = monitoring.Sampler(
-    "/stf/session/phase_seconds",
-    monitoring.ExponentialBuckets(1e-6, 4.0, 20),
-    "per-lifecycle-phase seconds of traced runs", "phase")
 _metric_deadline_exceeded = monitoring.Counter(
     "/stf/session/deadline_exceeded",
     "runs aborted by RunOptions.timeout_in_ms")
@@ -106,6 +102,7 @@ _metric_fetch_materialize = monitoring.Counter(
 # metadata for these): 0 = planning, 1 = host stages, 2 = device
 _PHASE_TRACK = {"prune": 0, "optimize": 0, "lower": 0,
                 "host_stage": 1, "post_host_stage": 1,
+                "stage_feeds": 1, "commit": 1, "fetch": 1,
                 "jit_compile": 2, "cost_analysis": 2, "device_execute": 2}
 _TRACK_NAMES = {0: "planning", 1: "host", 2: "device"}
 # traced run_steps adds a fourth track breaking the fused window down
@@ -175,8 +172,7 @@ def _attributed_device_nodes(step, window_node, min_frac=0.005,
 def _drain_spans_to_nodes(buf: "monitoring.TraceBuffer",
                           base_s: float) -> List[Dict[str, Any]]:
     """Traced-run span buffer -> step_stats ``nodes`` (chrome-trace
-    rows), feeding the per-phase seconds sampler along the way. Shared
-    by ``run`` and the fused ``run_steps`` path."""
+    rows). Shared by ``run`` and the fused ``run_steps`` path."""
     nodes: List[Dict[str, Any]] = []
     for span in sorted(buf.drain(), key=lambda s: s["start_s"]):
         phase = span["name"].split(":")[0]
@@ -189,7 +185,6 @@ def _drain_spans_to_nodes(buf: "monitoring.TraceBuffer",
         if span.get("meta"):
             node["args"] = {k: str(v) for k, v in span["meta"].items()}
         nodes.append(node)
-        _metric_phase_seconds.get_cell(phase).add(span["dur_s"])
     return nodes
 
 
@@ -955,7 +950,7 @@ class ExecutionPlan:
             rng_key = sess._ensure_base_key()
             state = dict(sess._variable_store.values)
         t0 = time.perf_counter()
-        with monitoring.traceme("aot_compile", n_feeds=len(avals)):
+        with monitoring.traceme("session/aot_compile", n_feeds=len(avals)):
             exe = aot.compile_step(step.jitted, state, avals, rng_key,
                                    np.uint32(0))
         _metric_compile_seconds.get_cell().add(time.perf_counter() - t0)
@@ -1022,16 +1017,17 @@ class ExecutionPlan:
                 "ExecutionPlan.execute: feeds must match the planned "
                 f"signature (missing: {missing}, unplanned: {extra}); "
                 "build a new plan for a different feed set")
-        values = sess._execute_plan(self._step, self._mapper.elements,
-                                    feeds, deadline=deadline,
-                                    async_fetches=as_futures)
-        dur = time.perf_counter() - t0
-        _metric_run_seconds.get_cell().add(dur)
-        if _req_tracing.current_trace_ids() is not None:
-            # request-scoped tracing: inside a serving batch's trace
-            # scope, link the executor dispatch to the riding requests
-            _req_tracing.emit_span("plan_execute", t0, dur,
-                                   n_feeds=len(feeds))
+        # request-scoped tracing: inside a serving batch's trace scope
+        # the span also links the executor dispatch to the riding
+        # requests, as the ring's ``plan_execute``
+        with (_req_tracing.span("session/run", ring="plan_execute",
+                                n_feeds=len(feeds))
+              if _req_tracing.current_trace_ids() is not None
+              else monitoring.traceme("session/run")):
+            values = sess._execute_plan(self._step, self._mapper.elements,
+                                        feeds, deadline=deadline,
+                                        async_fetches=as_futures)
+        _metric_run_seconds.get_cell().add(time.perf_counter() - t0)
         return self._mapper.rebuild(values)
 
     __call__ = execute
@@ -1442,8 +1438,11 @@ class BaseSession:
         import contextlib
 
         try:
-            with (monitoring.trace_collection(buf) if trace
-                  else contextlib.nullcontext()):
+            # the span opens outside the collection: a traced run's
+            # timeline holds the phases, not one row over all of them
+            with monitoring.traceme("session/run"), \
+                    (monitoring.trace_collection(buf) if trace
+                     else contextlib.nullcontext()):
                 mapper = _FetchMapper(self._graph, fetches)
                 feeds = self._normalize_feeds(feed_dict)
                 values = self._run_elements(mapper.elements, feeds,
@@ -1676,7 +1675,7 @@ class BaseSession:
                 raise ValueError(
                     "run_steps: tensors fed both via stacked_feeds and "
                     f"feed_iterator: {sorted(t.name for t in dup)}")
-            with monitoring.traceme("superbatch_stage", n_steps=n,
+            with monitoring.traceme("session/superbatch_stage", n_steps=n,
                                     n_feeds=len(step_feeds[0])):
                 for t in step_feeds[0]:
                     rows = [fd[t] for fd in step_feeds]
@@ -1799,7 +1798,8 @@ class BaseSession:
                         wd_token = wd.arm("fused_window", wd_deadline,
                                           n_steps=n)
                 d_t0 = time.perf_counter()
-                with monitoring.traceme("fused_device_execute", n_steps=n):
+                with monitoring.traceme("session/fused_device_execute",
+                                        n_steps=n):
                     try:
                         outs, check_flags, new_state = fused["jitted"](
                             dict(state), const_args, xs_args,
@@ -1873,7 +1873,7 @@ class BaseSession:
             host_env: Dict[Tensor, Any] = {}
             if step.post_host_plan:
                 with monitoring.traceme(
-                        "post_host_stage",
+                        "session/post_host_stage",
                         n_ops=len(step.post_host_plan)):
                     pctx = lowering_mod.LoweringContext(
                         self._variable_store.values, rng_root=None,
@@ -2219,7 +2219,8 @@ class BaseSession:
         # Host stage -------------------------------------------------------
         host_env: Dict[Tensor, Any] = {}
         if step.host_plan:
-            with monitoring.traceme("host_stage", n_ops=len(step.host_plan)):
+            with monitoring.traceme("session/host_stage",
+                                    n_ops=len(step.host_plan)):
                 hctx = lowering_mod.LoweringContext(
                     self._variable_store.values, rng_root=None,
                     feeds=dict(feeds), host=True, session=self)
@@ -2263,9 +2264,11 @@ class BaseSession:
                     for name, nbytes in step.fetch_nbytes:
                         self._transfer_guard(name, nbytes, "fetch")
                 feed_args = {}
-                for t in step.feed_tensors:
-                    val = feeds[t] if t in feeds else host_env[t]
-                    feed_args[t.name] = self._staged_feed(step, t, val)
+                with monitoring.traceme("session/stage_feeds",
+                                        n_feeds=len(step.feed_tensors)):
+                    for t in step.feed_tensors:
+                        val = feeds[t] if t in feeds else host_env[t]
+                        feed_args[t.name] = self._staged_feed(step, t, val)
                 state = self._variable_store.values
                 first_call = step.n_calls == 0
                 if collector is not None:
@@ -2273,7 +2276,7 @@ class BaseSession:
                         step, state, feed_args, rng_key, rng_ctr,
                         first_call, collector)
                 d_t0 = time.perf_counter()
-                with monitoring.traceme("device_execute"):
+                with monitoring.traceme("session/device_execute"):
                     try:
                         fetch_vals, new_state, check_flags = \
                             _call_step_executable(step, state, feed_args,
@@ -2306,9 +2309,10 @@ class BaseSession:
                                    in zip(step.check_msgs, flags_np) if f]
                             raise errors.InvalidArgumentError(
                                 None, None, "; ".join(bad))
-                    self._variable_store.values = dict(new_state)
-                    self._apply_declared_shardings(new_state.keys())
-                    self._variable_store.sync_ledger()
+                    with monitoring.traceme("session/commit"):
+                        self._variable_store.values = dict(new_state)
+                        self._apply_declared_shardings(new_state.keys())
+                        self._variable_store.sync_ledger()
                     device_results = list(fetch_vals)
                     step.n_calls += 1
                     if collector is not None or deadline is not None:
@@ -2348,7 +2352,7 @@ class BaseSession:
 
         # Post-host stage (host sinks: summaries etc.) ----------------------
         if step.post_host_plan:
-            with monitoring.traceme("post_host_stage",
+            with monitoring.traceme("session/post_host_stage",
                                     n_ops=len(step.post_host_plan)):
                 pctx = lowering_mod.LoweringContext(
                     self._variable_store.values, rng_root=None, host=True,
@@ -2379,45 +2383,48 @@ class BaseSession:
         else:
             async_on = bool(async_fetches)
         out = []
-        for e in elements:
-            if isinstance(e, Operation):
-                out.append(None)
-                continue
-            r = step.alias.get(e, e)  # CSE'd fetch -> canonical value
-            if e in feeds:
-                out.append(feeds[e])
-            elif r in dev_map and r not in host_env:
-                v = dev_map[r]
-                if e.dtype.name == "string":
-                    out.append(v)
-                elif async_on:
-                    out.append(FetchFuture(v))
-                else:
-                    out.append(np.asarray(v))
-            elif r in host_env:
-                if r.op.type == "GetSessionHandle":
-                    from ..ops.session_ops import TensorHandle, _handle_str
+        # the host first blocks on the step's results here (np.asarray of
+        # a device value), unless a post-host stage already pulled them
+        with monitoring.traceme("session/fetch"):
+            for e in elements:
+                if isinstance(e, Operation):
+                    out.append(None)
+                    continue
+                r = step.alias.get(e, e)  # CSE'd fetch -> canonical value
+                if e in feeds:
+                    out.append(feeds[e])
+                elif r in dev_map and r not in host_env:
+                    v = dev_map[r]
+                    if e.dtype.name == "string":
+                        out.append(v)
+                    elif async_on:
+                        out.append(FetchFuture(v))
+                    else:
+                        out.append(np.asarray(v))
+                elif r in host_env:
+                    if r.op.type == "GetSessionHandle":
+                        from ..ops.session_ops import TensorHandle, _handle_str
 
-                    out.append(TensorHandle(
-                        _handle_str(host_env[r]),
-                        r.op.attrs["dtype"], self))
-                else:
-                    v = host_env[r]
-                    # a raw device array can land here when the tensor
-                    # also fed a GetSessionHandle op — fetches always
-                    # return numpy (string tensors pass through)
-                    if (not isinstance(v, np.ndarray)
-                            and e.dtype.name != "string"):
-                        v = np.asarray(v)
-                    out.append(v)
-            elif r in step.const_env:  # folded at plan time
-                out.append(step.const_env[r])
-            else:  # e.g. string Const fetched directly
-                if r.op.type == "Const":
-                    out.append(r.op.attrs["value"])
-                else:
-                    raise errors.InternalError(
-                        None, e.op, f"Fetch {e.name} produced no value")
+                        out.append(TensorHandle(
+                            _handle_str(host_env[r]),
+                            r.op.attrs["dtype"], self))
+                    else:
+                        v = host_env[r]
+                        # a raw device array can land here when the tensor
+                        # also fed a GetSessionHandle op — fetches always
+                        # return numpy (string tensors pass through)
+                        if (not isinstance(v, np.ndarray)
+                                and e.dtype.name != "string"):
+                            v = np.asarray(v)
+                        out.append(v)
+                elif r in step.const_env:  # folded at plan time
+                    out.append(step.const_env[r])
+                else:  # e.g. string Const fetched directly
+                    if r.op.type == "Const":
+                        out.append(r.op.attrs["value"])
+                    else:
+                        raise errors.InternalError(
+                            None, e.op, f"Fetch {e.name} produced no value")
         return out
 
     def _observe_numerics(self, step, device_results, feed_args, state,
@@ -2628,7 +2635,7 @@ class BaseSession:
         try:
             if first_call:
                 c_t0 = time.perf_counter()
-                with monitoring.traceme("jit_compile",
+                with monitoring.traceme("session/jit_compile",
                                         n_ops=len(step.device_ops)):
                     lowered = step.jitted.lower(dict(state), feed_args,
                                                 rng_key, rng_ctr)
@@ -2650,7 +2657,7 @@ class BaseSession:
                             _memory_mod.CLASS_EXECUTABLE,
                             self._variable_store.owner)
             else:
-                with monitoring.traceme("cost_analysis"):
+                with monitoring.traceme("session/cost_analysis"):
                     lowered = step.jitted.lower(dict(state), feed_args,
                                                 rng_key, rng_ctr)
                     step.xla_cost = _executable_analysis(lowered, None)
@@ -2920,7 +2927,7 @@ class BaseSession:
                 fetch_tensors.append(e)
                 if e not in fed_set:
                     target_ops.append(e.op)
-        with monitoring.traceme("prune", n_target_ops=len(target_ops)):
+        with monitoring.traceme("session/prune", n_target_ops=len(target_ops)):
             pruned = lowering_mod.prune(target_ops, fed_set)
 
         # Plan-time graph optimizer: fold/CSE/DCE before lowering (the
@@ -2930,7 +2937,7 @@ class BaseSession:
         from ..framework import optimizer as graph_opt
 
         func_plans: Dict[Any, Any] = {}
-        with monitoring.traceme("optimize", n_pruned_ops=len(pruned)):
+        with monitoring.traceme("session/optimize", n_pruned_ops=len(pruned)):
             pruned, const_env, alias = graph_opt.optimize_pruned(
                 pruned, fed_set, fetch_tensors, func_plans=func_plans)
         step.const_env = const_env
@@ -2955,7 +2962,7 @@ class BaseSession:
         from .. import analysis
 
         a_t0 = time.perf_counter()
-        with monitoring.traceme("analysis", n_pruned_ops=len(pruned)):
+        with monitoring.traceme("session/analysis", n_pruned_ops=len(pruned)):
             pruned, plan_diags = analysis.check_plan(
                 pruned, alias, mode=self._hazard_mode())
             if self._analysis_mode != "off":
@@ -3066,9 +3073,12 @@ class BaseSession:
                 logging.warning(
                     "numerics plane: instrumentation failed, plan runs "
                     "uninstrumented: %s: %s", type(e).__name__, e)
-        # staging/partitioning timing starts AFTER the analysis block:
-        # the "lower" span must not double-count the "analysis" span
-        lower_t0 = time.perf_counter()
+        # staging/partitioning starts AFTER the analysis block: the
+        # "lower" span must not double-count the "analysis" span. Entered
+        # by hand (closed below, once the stages are counted) rather
+        # than re-indenting the whole staging pass under a ``with``.
+        lower_span = monitoring.traceme("session/lower")
+        lower_span.__enter__()
 
         def _rsv(t):  # resolve through CSE aliases
             return alias.get(t, t)
@@ -3214,11 +3224,10 @@ class BaseSession:
             and t.dtype.name != "string"]
         # staging/partitioning = the "lower" lifecycle phase (the
         # reference's placement + partitioning ahead of executor build)
-        monitoring.record_span("lower", lower_t0,
-                               time.perf_counter() - lower_t0,
-                               n_device_ops=len(device_ops),
-                               n_host_ops=len(step.host_plan),
-                               n_post_host_ops=len(post_host))
+        lower_span.set_meta(n_device_ops=len(device_ops),
+                            n_host_ops=len(step.host_plan),
+                            n_post_host_ops=len(post_host))
+        lower_span.__exit__(None, None, None)
         rec = _flight_mod.get_recorder()
         if rec.enabled:
             rec.record("plan", n_pruned=len(pruned),

@@ -2,12 +2,13 @@
 core/common_runtime/step_stats_collector.cc).
 
 The reference assembles StepStats from per-kernel timestamps; with XLA the
-per-op timeline lives in the profiler. This module provides (a) the
+per-op timeline lives in the profiler. This module provides the
 reference's Timeline class over our RunMetadata / step_stats dict —
 traced runs (``RunOptions.SOFTWARE_TRACE``) yield one track per
 lifecycle stage (planning / host / device), loadable in Perfetto or
-chrome://tracing — and (b) helpers to capture a jax.profiler trace for a
-Session.run.
+chrome://tracing. For the device's own timeline run under
+``jax.profiler.start_trace`` (or ``ProfilerHook(use_jax_profiler=True)``):
+the program's ``stf/...`` spans land in that trace beside the device ops.
 """
 
 from __future__ import annotations
@@ -121,19 +122,6 @@ class Timeline:
             events.extend(self._ledger_events())
         return json.dumps({"traceEvents": events,
                            "displayTimeUnit": "ms"})
-
-
-def trace_session_run(session, fetches, feed_dict=None, log_dir="/tmp/stf_trace"):
-    """Capture a jax.profiler trace around one Session.run; view in
-    TensorBoard / Perfetto."""
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        out = session.run(fetches, feed_dict=feed_dict)
-    finally:
-        jax.profiler.stop_trace()
-    return out
 
 
 def predicted_vs_measured(fetches, feeds=(), measured_seconds=None):

@@ -28,6 +28,8 @@ from __future__ import annotations
 
 from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
 
+import jax
+
 from . import graph as ops_mod
 from . import op_registry
 from .errors import FailedPreconditionError, InvalidArgumentError
@@ -362,7 +364,13 @@ def _execute_ops_inner(ctx: LoweringContext,
         for t in op.inputs:
             t = ctx.alias.get(t, t)
             input_vals.append(ctx.env[t] if t in ctx.env else ctx.value_of(t))
-        outputs = op.op_def.lower(ctx, op, input_vals)
+        if ctx.host:
+            outputs = op.op_def.lower(ctx, op, input_vals)
+        else:
+            # the XLA ops this traces carry the stf op that made them
+            # (HLO metadata op_name; read in a profiler trace by hand)
+            with jax.named_scope(op.name):
+                outputs = op.op_def.lower(ctx, op, input_vals)
         if len(outputs) != len(op.outputs):
             raise InternalLoweringError(
                 f"Op {op.name} ({op.type}) lowered to {len(outputs)} outputs, "

@@ -1073,7 +1073,7 @@ class PassManager:
         baseline = self._error_keys(gd) if self.verify else None
         for p in self.passes:
             t0 = time.perf_counter()
-            with monitoring.traceme(f"graph_pass:{p.name}",
+            with monitoring.traceme(f"optimizer/graph_pass:{p.name}",
                                     n_nodes=len(gd.get("node", ()))):
                 new = p.run(gd, list(keep or []))
             _metric_pass_seconds.get_cell(p.name).add(

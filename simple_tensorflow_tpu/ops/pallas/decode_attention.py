@@ -175,6 +175,7 @@ def _decode_attention_block(q, k_cache, v_cache, lengths, *, bias,
             bytes_accessed=(kk.size + vv.size + qq.size) * q.dtype.itemsize,
             transcendentals=b * kq * h * max_len),
         interpret=common.use_interpret(),
+        name=f"stf_decode_attention_q{kq}",
     )(*operands)
     return jnp.transpose(o[:, :h, :kq, :d], (0, 2, 1, 3))
 

@@ -81,6 +81,7 @@ def _fwd(x, residual, bias, seed, rate, block_rows):
             bytes_accessed=3 * rows * n * x.dtype.itemsize,
             transcendentals=0),
         interpret=common.use_interpret(),
+        name="stf_dropout_residual_fwd",
     )(*operands)
 
 

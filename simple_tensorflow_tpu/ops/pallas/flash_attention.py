@@ -200,6 +200,7 @@ def _fwd(q, k, v, bias, seed, sm_scale, causal, block_q, block_k, kv_true,
             bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize,
             transcendentals=bh * q_len * kv_true),
         interpret=common.use_interpret(),
+        name="stf_flash_attention_fwd",
     )(*operands)
     return o, lse
 
@@ -374,6 +375,7 @@ def _bwd_with_delta(sm_scale, causal, block_q, block_k, kv_true,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=common.use_interpret(),
+        name="stf_flash_attention_bwd_dkv",
     )(q, k, v, g, lse, delta, *aux_ops)
 
     dqk = functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
@@ -397,6 +399,7 @@ def _bwd_with_delta(sm_scale, causal, block_q, block_k, kv_true,
         out_shape=jax.ShapeDtypeStruct((bh, q_len, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=common.use_interpret(),
+        name="stf_flash_attention_bwd_dq",
     )(q, k, v, g, lse, delta, *aux_ops)
     grads = [dq, dk, dv]
     # bias is a constant mask under differentiation (stop_gradient'd in the

@@ -112,6 +112,7 @@ def adam_update(p, m, v, g, alpha, *, beta1, beta2, eps):
                             + 5 * m.size * m.dtype.itemsize),
             transcendentals=n),
         interpret=common.use_interpret(),
+        name="stf_fused_update_adam",
     )(p2, m2, v2, g2, alpha1)
     return (np_.reshape(-1)[:n], nm.reshape(-1)[:n], nv.reshape(-1)[:n])
 
@@ -175,5 +176,6 @@ def momentum_update(p, acc, g, lr, mu, *, use_nesterov=False):
                             + 3 * acc.size * acc.dtype.itemsize),
             transcendentals=0),
         interpret=common.use_interpret(),
+        name="stf_fused_update_momentum",
     )(p2, a2, g2, lr1, mu1)
     return (np_.reshape(-1)[:n], nacc.reshape(-1)[:n])

@@ -88,6 +88,7 @@ def _fwd(x, gamma, beta, eps, block_rows):
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
         interpret=common.use_interpret(),
+        name="stf_layer_norm_fwd",
     )(x, gamma, beta)
     return o, mean, rstd
 
@@ -128,6 +129,7 @@ def _ln_bwd_rule(eps, block_rows, res, g):
             jax.ShapeDtypeStruct((1, n), jnp.float32),
         ],
         interpret=common.use_interpret(),
+        name="stf_layer_norm_bwd",
     )(x, gamma, mean, rstd, g)
     return dx, dg[0].astype(gamma.dtype), db[0].astype(beta.dtype)
 

@@ -121,6 +121,7 @@ def quant_matmul(x, wq, w_scale, *, out_dtype=None):
                             + mp * np_ * 4),
             transcendentals=0),
         interpret=common.use_interpret(),
+        name="stf_quant_matmul_fwd",
     )(xq, wq, x_scale.astype(jnp.float32), w_scale.astype(jnp.float32))
     return out[:m, :n]
 

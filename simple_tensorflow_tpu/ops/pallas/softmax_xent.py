@@ -130,6 +130,7 @@ def _fwd(logits, labels, block_rows, block_vocab, smoothing):
             pltpu.VMEM((block_rows, 1), jnp.float32),
         ],
         interpret=common.use_interpret(),
+        name="stf_softmax_xent_fwd",
     )(logits, labels)
     return loss, lse
 
@@ -161,6 +162,7 @@ def _xent_bwd_rule(block_rows, block_vocab, smoothing, res, g):
         out_specs=pl.BlockSpec((block_rows, bv), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((rows, vocab), logits.dtype),
         interpret=common.use_interpret(),
+        name="stf_softmax_xent_bwd",
     )(logits, labels, lse, g)
     return dx, None
 

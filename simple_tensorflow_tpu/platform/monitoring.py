@@ -2,8 +2,9 @@
 (ref: tensorflow/core/lib/monitoring/{counter,gauge,sampler,
 percentile_sampler}.h, python/eager/monitoring.py).
 
-Two halves, both thread-safe and dependency-free (importable from any
-layer without cycles):
+Two halves, both thread-safe and importable from any layer without
+cycles (nothing of stf beyond ``platform.sync``; the tracing half needs
+``jax.profiler``):
 
 Metrics — a process-global registry of named metric families. Each
 family owns labeled cells, created on demand:
@@ -19,13 +20,22 @@ JSON-able), ``to_json()`` dumps it, and ``to_prometheus()`` emits the
 Prometheus text exposition format so a scrape endpoint is one
 ``web.Response(monitoring.to_prometheus())`` away.
 
-Tracing — ``traceme(name, **meta)`` is a context manager recording a
-span into every *active* per-thread trace buffer. With no buffer
-installed it costs one thread-local read (cheap enough to leave in hot
-paths, the reference's TraceMe contract). Session.run installs a buffer
-for the duration of a traced run (``RunOptions.SOFTWARE_TRACE``) and
-drains it into ``RunMetadata.step_stats`` — the source of the
-chrome-trace timeline (client/timeline.py).
+Tracing — ``traceme(name, **meta)`` is the program's one span
+primitive. It has two listeners, and is free when neither is there (one
+thread-local read and one call into the profiler's ``is_enabled``):
+
+- a running ``jax.profiler`` session gets a ``TraceAnnotation`` named
+  ``stf/<name>``, on the profiler's clock, beside the device's ops in
+  the ``.xplane.pb``. Spans at a layer boundary are named
+  ``<layer>/<phase>`` (``session/device_execute``, ``engine/decode``);
+- every *active* per-thread trace buffer gets a span dict under the
+  phase name. Session.run installs a buffer for the duration of a traced
+  run (``RunOptions.SOFTWARE_TRACE``) and drains it into
+  ``RunMetadata.step_stats`` — the source of the chrome-trace timeline
+  (client/timeline.py).
+
+``telemetry.tracing.span`` is this primitive plus one entry in the
+request-tracing ring.
 """
 
 from __future__ import annotations
@@ -36,7 +46,12 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from jax.profiler import TraceAnnotation as _Annotation
+
 from . import sync as _sync
+
+# true while a jax.profiler session is collecting host spans
+_profiling = _Annotation.is_enabled
 
 __all__ = [
     "Counter", "IntGauge", "StringGauge", "BoolGauge",
@@ -45,7 +60,6 @@ __all__ = [
     "export", "to_json", "to_prometheus",
     "get_metric", "unregister", "reset_registry",
     "traceme", "trace_collection", "TraceBuffer", "tracing_active",
-    "record_span",
     "WindowedRate",
 ]
 
@@ -640,40 +654,47 @@ class trace_collection:
         return False
 
 
-def record_span(name: str, start_s: float, dur_s: float, **meta):
-    """Manually record a span (for phases that can't wrap a ``with``
-    block). No-op when no collection is active on this thread."""
-    sinks = getattr(_trace_local, "sinks", None)
-    if sinks:
-        span = {"name": name, "start_s": start_s, "dur_s": dur_s,
-                "tid": threading.get_ident(), "meta": meta}
-        for s in sinks:
-            s.append(span)
-
-
 class traceme:
-    """Span context-manager (ref: profiler TraceMe). Free when no
-    collection is active on this thread. ``meta`` keys land in the
-    span's ``meta`` dict (rendered as chrome-trace ``args``)."""
+    """The span context-manager (ref: profiler TraceMe). Free when no
+    collection is active on this thread and no profiler session runs.
+    ``name`` is ``<layer>/<phase>`` at a layer boundary: the profiler
+    sees ``stf/<layer>/<phase>``, a collection the phase alone (its
+    timeline's rows and tracks are keyed on phases). ``meta`` keys land
+    in the span's ``meta`` dict (chrome-trace ``args``) and in the
+    annotation's stats."""
 
-    __slots__ = ("name", "meta", "_t0", "_sinks")
+    __slots__ = ("name", "meta", "_t0", "_sinks", "_ann")
 
     def __init__(self, name: str, **meta):
         self.name = name
         self.meta = meta
         self._sinks = None
+        self._ann = None
 
     def __enter__(self):
         sinks = getattr(_trace_local, "sinks", None)
         if sinks:
             self._sinks = list(sinks)
             self._t0 = time.perf_counter()
+        if _profiling():
+            self._ann = _Annotation("stf/" + self.name, **self.meta)
+            self._ann.__enter__()
         return self
 
+    def set_meta(self, **meta):
+        """Metadata known only inside the block (how many joined, the
+        bucket the model chose)."""
+        self.meta.update(meta)
+        if self._ann is not None:
+            self._ann.set_metadata(**meta)
+
     def __exit__(self, *exc):
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         if self._sinks:
             dur = time.perf_counter() - self._t0
-            span = {"name": self.name, "start_s": self._t0, "dur_s": dur,
+            span = {"name": self.name.partition("/")[2] or self.name,
+                    "start_s": self._t0, "dur_s": dur,
                     "tid": threading.get_ident(), "meta": self.meta}
             for s in self._sinks:
                 s.append(span)

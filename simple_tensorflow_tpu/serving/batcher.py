@@ -99,6 +99,23 @@ _metric_e2e_latency = monitoring.Sampler(
     "model", "outcome")
 
 
+_metric_queue_wait = monitoring.Sampler(
+    "/stf/serving/queue_wait_seconds",
+    monitoring.ExponentialBuckets(1e-4, 2.0, 22),
+    "Per-request seconds from submission to admission (the batch's "
+    "close, or the generative engine taking the request up)", "model")
+
+
+def record_queue_wait(model: str, request, now: float):
+    """The queue-wait leg of one request: the sampler and the request's
+    ``serving_queue_wait`` ring span. The one span closed after the fact
+    (it begins on the client's thread), hence ``emit_span``."""
+    wait = now - request.t_enqueue
+    _metric_queue_wait.get_cell(model).add(wait)
+    _req_tracing.emit_span("serving_queue_wait", request.t_enqueue, wait,
+                           trace_id=request.trace_id, model=model)
+
+
 class _QueueStats:
     """RingBuffer stats adapter reporting into /stf/serving/* instead of
     the /stf/data/* family (duck-typed to data.pipeline.StageStats:
@@ -142,13 +159,11 @@ class _BatchOutputs:
         if not self._fetched:
             with self._lock:
                 if not self._fetched:
-                    t0 = time.perf_counter()
-                    self._outputs = {name: np.asarray(v)
-                                     for name, v in self._outputs.items()}
-                    _req_tracing.emit_span(
-                        "serving_fetch", t0,
-                        time.perf_counter() - t0,
-                        trace_ids=self._trace_ids, model=self._model)
+                    with _req_tracing.span(
+                            "serving/fetch", ring="serving_fetch",
+                            trace_ids=self._trace_ids, model=self._model):
+                        self._outputs = {name: np.asarray(v) for name, v
+                                         in self._outputs.items()}
                     self._fetched = True
         return {name: np.asarray(v)[index]
                 for name, v in self._outputs.items()}
@@ -390,34 +405,32 @@ class ContinuousBatcher:
         # queue-wait leg of each riding request's trace (ISSUE 8): one
         # span per request, admission -> batch close
         for r in live:
-            _req_tracing.emit_span("serving_queue_wait", r.t_enqueue,
-                                   now - r.t_enqueue,
-                                   trace_id=r.trace_id, model=self.name)
-        t_asm = time.perf_counter()
-        feeds: Dict[str, np.ndarray] = {}
-        for name in live[0].inputs:
-            stacked = np.stack([r.inputs[name] for r in live])
-            if pad:
-                block = (np.repeat(stacked[-1:], pad, axis=0)
-                         if self._policy.pad_mode == "repeat" else
-                         np.zeros((pad,) + stacked.shape[1:],
-                                  dtype=stacked.dtype))
-                stacked = np.concatenate([stacked, block], axis=0)
-            feeds[name] = stacked
-        _req_tracing.emit_span("serving_batch_assemble", t_asm,
-                               time.perf_counter() - t_asm,
+            record_queue_wait(self.name, r, now)
+        with _req_tracing.span("serving/batch_assemble",
+                               ring="serving_batch_assemble",
                                trace_ids=trace_ids, model=self.name,
-                               live=k, bucket=bucket)
+                               live=k, bucket=bucket):
+            feeds: Dict[str, np.ndarray] = {}
+            for name in live[0].inputs:
+                stacked = np.stack([r.inputs[name] for r in live])
+                if pad:
+                    block = (np.repeat(stacked[-1:], pad, axis=0)
+                             if self._policy.pad_mode == "repeat" else
+                             np.zeros((pad,) + stacked.shape[1:],
+                                      dtype=stacked.dtype))
+                    stacked = np.concatenate([stacked, block], axis=0)
+                feeds[name] = stacked
         # wedge watchdog: a batch 10x past the trailing average is a
         # hang; first batches (no history) are exempt
         wd_deadline = _watchdog_mod.deadline_for(self._exec_ewma)
         wd_token = _watchdog_mod.get_watchdog().arm(
             "serving_batch", wd_deadline, model=self.name,
             live=k, bucket=bucket) if wd_deadline else None
-        t_exec = time.perf_counter()
         try:
-            with monitoring.traceme("serving_batch", model=self.name,
-                                    live=k, bucket=bucket), \
+            with _req_tracing.span("serving/batch_execute",
+                                   ring="serving_batch_execute",
+                                   trace_ids=trace_ids, model=self.name,
+                                   live=k, bucket=bucket) as sp, \
                     _req_tracing.trace_scope(trace_ids):
                 outputs = self._execute_fn(feeds, bucket)
         except BaseException as e:  # noqa: BLE001 — delivered per request
@@ -429,13 +442,10 @@ class ContinuousBatcher:
             return
         finally:
             _watchdog_mod.get_watchdog().disarm(wd_token)
-        done_t = time.perf_counter()
-        exec_dur = done_t - t_exec
+        exec_dur = sp.dur_s
+        done_t = sp.start_s + exec_dur
         self._exec_ewma = exec_dur if self._exec_ewma is None else \
             0.7 * self._exec_ewma + 0.3 * exec_dur
-        _req_tracing.emit_span("serving_batch_execute", t_exec, exec_dur,
-                               trace_ids=trace_ids, model=self.name,
-                               live=k, bucket=bucket)
         _metric_batches.get_cell(self.name).increase_by(1)
         _metric_batch_size.get_cell(self.name).add(float(k))
         _metric_batch_fill.get_cell(self.name).add(k / bucket)

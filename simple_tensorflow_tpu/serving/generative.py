@@ -72,7 +72,7 @@ from ..framework import errors
 from ..platform import monitoring
 from ..telemetry import recorder as _flight_mod
 from ..telemetry import tracing as _req_tracing
-from .batcher import _QueueStats
+from .batcher import _QueueStats, record_queue_wait
 
 # ---------------------------------------------------------------------------
 # metrics (process-global; registration is idempotent)
@@ -517,7 +517,8 @@ class GenerativeEngine:
                 hb, self._holdback = self._holdback, []
                 self._admit_batch(hb)
             if not self._active:
-                item = self._queue.get()
+                with monitoring.traceme("engine/wait"):
+                    item = self._queue.get()
                 if item is _DONE:
                     # closed AND drained: queued requests admitted before
                     # the close marker have all run to completion
@@ -540,6 +541,13 @@ class GenerativeEngine:
                     self._slots_gauge.set(0)
 
     def _admit_batch(self, items):
+        had = len(self._active)
+        with monitoring.traceme("engine/admit") as sp:
+            self._admit(items)
+            sp.set_meta(joined=len(self._active) - had,
+                        held_back=len(self._holdback))
+
+    def _admit(self, items):
         now = time.perf_counter()
         live: List[GenerateRequest] = []
         for req in items:
@@ -565,18 +573,16 @@ class GenerativeEngine:
             slot = self._pool.acquire()
             assert slot is not None, "admission exceeded free slots"
             slots.append(slot)
-            _req_tracing.emit_span("serving_queue_wait", req.t_enqueue,
-                                   now - req.t_enqueue,
-                                   trace_id=req.trace_id, model=self.name)
-        t0 = time.perf_counter()
+            record_queue_wait(self.name, req, now)
         try:
-            self._model.prefill(np.stack([r.src for r in live]),
-                                np.asarray(slots, np.int32))
-            if self._spec_enabled:
-                # the draft keeps its own caches: it needs the same
-                # prompts resident to propose from
-                self._draft.prefill(np.stack([r.src for r in live]),
+            with self._prefill_span(live, depth=1) as sp:
+                self._model.prefill(np.stack([r.src for r in live]),
                                     np.asarray(slots, np.int32))
+                if self._spec_enabled:
+                    # the draft keeps its own caches: it needs the same
+                    # prompts resident to propose from
+                    self._draft.prefill(np.stack([r.src for r in live]),
+                                        np.asarray(slots, np.int32))
         except BaseException as e:  # noqa: BLE001
             _flight_mod.get_recorder().on_error(
                 e, where="serving_decode_prefill", model=self.name)
@@ -584,18 +590,22 @@ class GenerativeEngine:
                 self._pool.release(slot)
                 self._reject(req, "error", e)
             return
-        dur = time.perf_counter() - t0
-        self._prefill_s.add(dur)
-        _req_tracing.emit_span(
-            "serving_decode_prefill", t0, dur,
-            trace_ids=[r.trace_id for r in live if r.trace_id],
-            model=self.name, joined=len(live))
+        self._prefill_s.add(sp.dur_s)
         eos = self._model.eos_id
         for req, slot in zip(live, slots):
             # decoder seeds with EOS at position 0, like beam search
             self._active.append(_Sequence(req, slot, eos,
                                           req.max_new_tokens))
         self._slots_gauge.set(len(self._active))
+
+    def _prefill_span(self, requests, depth):
+        """``engine/prefill``: the model calls that put the joining
+        prompts into the cache (``depth`` calls deep for the longest);
+        the ring knows it as ``serving_decode_prefill``."""
+        return _req_tracing.span(
+            "engine/prefill", ring="serving_decode_prefill",
+            trace_ids=[r.trace_id for r in requests if r.trace_id],
+            model=self.name, joined=len(requests), depth=depth)
 
     def _sync_prefix_metrics(self):
         pc = self._prefix
@@ -640,23 +650,14 @@ class GenerativeEngine:
                                      f"exist ({e})"))
                 continue
             admitted.append((req, slot, plan))
-            _req_tracing.emit_span("serving_queue_wait", req.t_enqueue,
-                                   now - req.t_enqueue,
-                                   trace_id=req.trace_id,
-                                   model=self.name)
+            record_queue_wait(self.name, req, now)
         if not admitted:
             self._sync_prefix_metrics()
             return
         pl = self._model.page_len
         pps = self._model.pages_per_seq
         scratch = self._model.scratch_page
-        t0 = time.perf_counter()
         try:
-            # copy-on-write first: a CoW'd tail page must be populated
-            # before any decode step reads through it
-            for _, _, plan in admitted:
-                if plan.cow_src is not None:
-                    self._model.copy_page(plan.tail_page, plan.cow_src)
             # per-sequence ordered chunk lists (append the prefilled
             # tail as the last chunk when it wasn't served by CoW)
             tables = {}
@@ -674,19 +675,23 @@ class GenerativeEngine:
                     chunks.append((plan.tail_page, row,
                                    plan.cached_len - len(plan.tail)))
                 chunk_lists[slot] = chunks
-            depth = 0
-            while True:
-                batch = [(slot, ch[depth])
-                         for slot, ch in chunk_lists.items()
-                         if depth < len(ch)]
-                if not batch:
-                    break
-                self._model.prefill_chunk(
-                    np.stack([c[1] for _, c in batch]),
-                    np.asarray([c[2] for _, c in batch], np.int32),
-                    np.stack([tables[slot] for slot, _ in batch]),
-                    np.asarray([c[0] for _, c in batch], np.int32))
-                depth += 1
+            depths = max(len(ch) for ch in chunk_lists.values())
+            with self._prefill_span([r for r, _, _ in admitted],
+                                    depth=depths) as sp:
+                # copy-on-write first: a CoW'd tail page must be
+                # populated before any decode step reads through it
+                for _, _, plan in admitted:
+                    if plan.cow_src is not None:
+                        self._model.copy_page(plan.tail_page, plan.cow_src)
+                for depth in range(depths):
+                    batch = [(slot, ch[depth])
+                             for slot, ch in chunk_lists.items()
+                             if depth < len(ch)]
+                    self._model.prefill_chunk(
+                        np.stack([c[1] for _, c in batch]),
+                        np.asarray([c[2] for _, c in batch], np.int32),
+                        np.stack([tables[slot] for slot, _ in batch]),
+                        np.asarray([c[0] for _, c in batch], np.int32))
         except BaseException as e:  # noqa: BLE001
             _flight_mod.get_recorder().on_error(
                 e, where="serving_decode_prefill", model=self.name)
@@ -698,13 +703,7 @@ class GenerativeEngine:
                 self._reject(req, "error", e)
             self._sync_prefix_metrics()
             return
-        dur = time.perf_counter() - t0
-        self._prefill_s.add(dur)
-        _req_tracing.emit_span(
-            "serving_decode_prefill", t0, dur,
-            trace_ids=[r.trace_id for r, _, _ in admitted
-                       if r.trace_id],
-            model=self.name, joined=len(admitted))
+        self._prefill_s.add(sp.dur_s)
         for req, slot, plan in admitted:
             # the first decode step feeds the LAST prompt token at
             # position plen-1 — its output is the first emitted token
@@ -723,6 +722,10 @@ class GenerativeEngine:
         self._slots_gauge.set(len(self._active))
 
     def _step(self):
+        with monitoring.traceme("engine/step"):
+            self._advance()
+
+    def _advance(self):
         # per-token deadline check: an expired sequence retires NOW —
         # it never stalls or rides another step
         now = time.perf_counter()
@@ -756,14 +759,18 @@ class GenerativeEngine:
                 tokens = tokens + [self._model.pad_id] * pad
                 positions = positions + [0] * pad
                 slots = slots + [self._scratch_slot] * pad
-        t0 = time.perf_counter()
-        next_tok, logp, bucket = self._model.decode(tokens, positions,
-                                                    slots)
-        self._finish_single_step(next_tok, logp, bucket, n, t0)
+        self._decode_step(n, tokens, positions, slots)
 
-    def _finish_single_step(self, next_tok, logp, bucket, n, t0):
-        """Shared one-token-per-sequence commit: metrics, streaming,
-        EOS/budget retirement (slot and paged steps both land here)."""
+    def _decode_step(self, n, tokens, positions, where):
+        """One decode position for the ``n`` live sequences (slot and
+        paged steps both land here), then the shared commit: metrics,
+        streaming, EOS/budget retirement. ``engine/decode`` spans what
+        ``/stf/serving/decode_step_seconds`` samples."""
+        t0 = time.perf_counter()
+        with monitoring.traceme("engine/decode", live=n) as sp:
+            next_tok, logp, bucket = self._model.decode(tokens, positions,
+                                                        where)
+            sp.set_meta(bucket=bucket)
         dur = time.perf_counter() - t0
         self._step_s.add(dur)
         self._fill.add(n / max(bucket, 1))
@@ -777,24 +784,25 @@ class GenerativeEngine:
         eos = self._model.eos_id
         max_pos = self._model.max_decode_len - 1
         still = []
-        for i, s in enumerate(self._active):
-            tok = int(next_tok[i])
-            lp = float(logp[i])
-            s.tokens.append(tok)
-            s.logps.append(lp)
-            s.pos += 1
-            s.last_tok = tok
-            if s.req.on_token is not None:
-                try:
-                    s.req.on_token(tok, lp)
-                except Exception:  # noqa: BLE001 — client cb must not kill the engine
-                    pass
-            if tok == eos:
-                self._retire(s, "eos")
-            elif len(s.tokens) >= s.budget or s.pos > max_pos:
-                self._retire(s, "length")
-            else:
-                still.append(s)
+        with monitoring.traceme("engine/deliver"):
+            for i, s in enumerate(self._active):
+                tok = int(next_tok[i])
+                lp = float(logp[i])
+                s.tokens.append(tok)
+                s.logps.append(lp)
+                s.pos += 1
+                s.last_tok = tok
+                if s.req.on_token is not None:
+                    try:
+                        s.req.on_token(tok, lp)
+                    except Exception:  # noqa: BLE001 — client cb must not kill the engine
+                        pass
+                if tok == eos:
+                    self._retire(s, "eos")
+                elif len(s.tokens) >= s.budget or s.pos > max_pos:
+                    self._retire(s, "length")
+                else:
+                    still.append(s)
         self._active = still
         self._slots_gauge.set(len(still))
 
@@ -846,13 +854,10 @@ class GenerativeEngine:
         if not self._active:
             self._slots_gauge.set(0)
             return
-        n = len(self._active)
-        t0 = time.perf_counter()
-        next_tok, logp, bucket = self._model.decode(
-            [s.last_tok for s in self._active],
-            [s.pos for s in self._active],
-            np.stack([s.pages for s in self._active]))
-        self._finish_single_step(next_tok, logp, bucket, n, t0)
+        self._decode_step(len(self._active),
+                          [s.last_tok for s in self._active],
+                          [s.pos for s in self._active],
+                          np.stack([s.pages for s in self._active]))
 
     def _step_speculative(self):
         """One speculative cycle: the draft proposes ``draft_steps``
@@ -869,10 +874,13 @@ class GenerativeEngine:
         slots = [s.slot for s in self._active]
         kd = self._draft.draft_steps
         t0 = time.perf_counter()
-        props, _ = self._draft.decode_k(tokens, positions, slots)
-        blk = np.concatenate(
-            [np.asarray(tokens, np.int32).reshape(n, 1), props], axis=1)
-        tgt, lps, bucket = self._model.verify(blk, positions, slots)
+        with monitoring.traceme("engine/decode", live=n) as sp:
+            props, _ = self._draft.decode_k(tokens, positions, slots)
+            blk = np.concatenate(
+                [np.asarray(tokens, np.int32).reshape(n, 1), props],
+                axis=1)
+            tgt, lps, bucket = self._model.verify(blk, positions, slots)
+            sp.set_meta(bucket=bucket)
         dur = time.perf_counter() - t0
         self._step_s.add(dur)
         self._fill.add(n / max(bucket, 1))
@@ -882,35 +890,36 @@ class GenerativeEngine:
         emitted_total = 0
         accepted_total = 0
         still = []
-        for i, s in enumerate(self._active):
-            a = 0
-            while a < kd and int(props[i, a]) == int(tgt[i, a]):
-                a += 1
-            accepted_total += a
-            outcome = None
-            for j in range(a + 1):
-                tok = int(tgt[i, j])
-                lp = float(lps[i, j])
-                s.tokens.append(tok)
-                s.logps.append(lp)
-                s.pos += 1
-                s.last_tok = tok
-                emitted_total += 1
-                if s.req.on_token is not None:
-                    try:
-                        s.req.on_token(tok, lp)
-                    except Exception:  # noqa: BLE001
-                        pass
-                if tok == eos:
-                    outcome = "eos"
-                    break
-                if len(s.tokens) >= s.budget or s.pos > max_pos:
-                    outcome = "length"
-                    break
-            if outcome is not None:
-                self._retire(s, outcome)
-            else:
-                still.append(s)
+        with monitoring.traceme("engine/deliver"):
+            for i, s in enumerate(self._active):
+                a = 0
+                while a < kd and int(props[i, a]) == int(tgt[i, a]):
+                    a += 1
+                accepted_total += a
+                outcome = None
+                for j in range(a + 1):
+                    tok = int(tgt[i, j])
+                    lp = float(lps[i, j])
+                    s.tokens.append(tok)
+                    s.logps.append(lp)
+                    s.pos += 1
+                    s.last_tok = tok
+                    emitted_total += 1
+                    if s.req.on_token is not None:
+                        try:
+                            s.req.on_token(tok, lp)
+                        except Exception:  # noqa: BLE001
+                            pass
+                    if tok == eos:
+                        outcome = "eos"
+                        break
+                    if len(s.tokens) >= s.budget or s.pos > max_pos:
+                        outcome = "length"
+                        break
+                if outcome is not None:
+                    self._retire(s, outcome)
+                else:
+                    still.append(s)
         self._active = still
         self._slots_gauge.set(len(still))
         self._tokens.increase_by(emitted_total)

@@ -1,17 +1,19 @@
 """Request-scoped tracing: trace ids + an always-on recent-span ring.
 
-``stf.monitoring.traceme`` spans are free unless a per-thread collection
-is installed — right for the training loop, wrong for serving, where
-the question is "what happened to THIS request" long after it finished.
-This module adds the serving-side half:
+``stf.monitoring.traceme`` spans are free unless a profiler session runs
+or a per-thread collection is installed — right for the training loop,
+wrong for serving, where the question is "what happened to THIS request"
+long after it finished. This module adds the serving-side half:
 
 - a ``trace_id`` (16 hex chars) minted at ``ModelServer.predict`` (or
   accepted from the caller, so an upstream gateway's id rides through)
   and propagated via a thread-local scope across the batcher thread,
   ``ExecutionPlan.execute``, and response materialization;
-- ``emit_span(...)``: append one closed span to a bounded process-global
-  ring (one deque append — always on) and a ``span`` event to the
-  flight recorder;
+- ``span(...)``: the ``traceme`` primitive that also appends itself, on
+  exit, to a bounded process-global ring (one deque append — always on)
+  and a ``span`` event to the flight recorder; ``emit_span(...)``
+  appends a span closed after the fact (a request's queue wait begins
+  on the client's thread and ends on the scheduler's);
 - ``chrome_trace(trace_id)``: render the ring (optionally filtered to
   one request) as a chrome-trace JSON string — queue-wait vs batch
   assembly vs device execute vs D2H fetch for a single request, ready
@@ -32,6 +34,7 @@ import uuid
 from typing import Any, Dict, List, Optional, Sequence, Union
 
 from . import recorder as _recorder_mod
+from ..platform import monitoring as _monitoring
 from ..platform import sync as _sync
 
 SPAN_RING_CAPACITY = int(os.environ.get("STF_TELEMETRY_SPANS", "4096"))
@@ -147,26 +150,32 @@ def emit_span(name: str, start_s: float, dur_s: float,
                      (trace_ids[0] if trace_ids else None)})
 
 
-class span:
-    """Context manager emitting one telemetry span on exit (always on,
-    unlike ``monitoring.traceme`` which needs an installed collection).
-    Keep it off per-op hot paths; per-request/per-batch is its grain."""
+class span(_monitoring.traceme):
+    """``monitoring.traceme`` plus one entry in the ring on exit (always
+    on, where the primitive alone needs a listener). ``name`` is what
+    the profiler and a collection see, ``ring`` the entry's name where
+    /tracez has long known the span by another. Keep it off per-op hot
+    paths; per-request/per-batch is its grain. ``dur_s`` holds the
+    span's seconds once it has closed."""
 
-    __slots__ = ("name", "trace_id", "trace_ids", "meta", "_t0")
+    __slots__ = ("ring", "trace_id", "trace_ids", "start_s", "dur_s")
 
-    def __init__(self, name: str, trace_id: Optional[str] = None,
+    def __init__(self, name: str, ring: Optional[str] = None,
+                 trace_id: Optional[str] = None,
                  trace_ids: Optional[Sequence[str]] = None, **meta):
-        self.name = name
+        super().__init__(name, **meta)
+        self.ring = ring or name
         self.trace_id = trace_id
         self.trace_ids = trace_ids
-        self.meta = meta
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
+        self.start_s = time.perf_counter()
+        return super().__enter__()
 
     def __exit__(self, *exc):
-        emit_span(self.name, self._t0, time.perf_counter() - self._t0,
+        super().__exit__(*exc)
+        self.dur_s = time.perf_counter() - self.start_s
+        emit_span(self.ring, self.start_s, self.dur_s,
                   trace_id=self.trace_id, trace_ids=self.trace_ids,
                   **self.meta)
         return False

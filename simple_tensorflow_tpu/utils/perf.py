@@ -12,7 +12,6 @@ timeline tooling; TPU-native it reads XLA cost analysis + jax.profiler).
 
 from __future__ import annotations
 
-import contextlib
 import time
 from typing import Any, Dict, List, Optional
 
@@ -325,25 +324,6 @@ def roofline(step_flops: float, step_bytes: float, device=None
             "compute_bound": intensity >= ridge,
             "attainable_flops": attainable,
             "roofline_fraction_of_peak": attainable / peak_flops}
-
-
-@contextlib.contextmanager
-def trace(logdir: str):
-    """jax.profiler trace -> TensorBoard / chrome://tracing (perfetto)."""
-    import jax
-
-    jax.profiler.start_trace(logdir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-def annotate(name: str):
-    """Named region inside a trace (ref: tracing annotations)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
 
 
 class PerfReport:

@@ -364,13 +364,18 @@ class TestTracing:
             assert len(inner) == 1
             assert len(outer) == 1
 
-    def test_record_span_manual(self):
+    def test_a_layered_name_reaches_a_collection_as_its_phase(self):
         with monitoring.trace_collection() as buf:
-            monitoring.record_span("manual", 1.0, 0.5, n=3)
-        (span,) = buf.drain()
-        assert span["name"] == "manual"
-        assert span["dur_s"] == 0.5
-        assert span["meta"] == {"n": 3}
+            with monitoring.traceme("session/device_execute", n=3) as sp:
+                sp.set_meta(late=True)
+            with monitoring.traceme("optimizer/graph_pass:a/b"):
+                pass
+        first, second = buf.drain()
+        assert first["name"] == "device_execute"
+        assert first["meta"] == {"n": 3, "late": True}
+        assert first["dur_s"] >= 0
+        # the layer is the text before the FIRST slash
+        assert second["name"] == "graph_pass:a/b"
 
     def test_tracing_active(self):
         assert not monitoring.tracing_active()

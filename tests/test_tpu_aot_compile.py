@@ -12,6 +12,8 @@ All in ONE file (one xdist worker loads libtpu and keeps its lock); the
 topology is described inside a module-scoped fixture, never at import.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -68,6 +70,15 @@ def tpu_compile(one_chip, monkeypatch):
                 for s, d in avals]
         text = jax.jit(fn).lower(*args).compile().as_text()
         assert "tpu_custom_call" in text, "no Mosaic kernel in the HLO"
+        # the name= of the pl.pallas_call names the HLO instruction
+        # (wrapped as jvp_<name>_ / transpose_jvp_<name>__ under a
+        # gradient): what a profiler trace on the chip shows, and what
+        # chipbench's kernel metrics match
+        calls = [ln for ln in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in ln]
+        assert calls and all(
+            re.match(r"\s*(ROOT )?%[\w.\-]*stf_[a-z0-9_]+(\.\d+)? = ", ln)
+            for ln in calls), [ln[:80] for ln in calls]
         return text
 
     yield compile_
