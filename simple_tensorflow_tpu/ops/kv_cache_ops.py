@@ -7,18 +7,38 @@ generative engine (stf.serving.generative) and the cached beam search
 (models/transformer.py) run on.)
 
 A cache is an entry in the Session's device-resident VariableStore —
-the SAME store that holds model weights and optimizer slots — shaped
+the SAME store that holds model weights and optimizer slots — declared
 ``(num_slots, max_len, *inner)``. Slots are PAGES: each live sequence
 owns one row, a free-list (serving/generative.py CacheSlotPool) hands
 rows to joining sequences and reclaims them at EOS, so a retiring
-sequence never compacts or copies its neighbors' cache. Because the
-store's values are donated into every step exactly like optimizer
-state, an append is an in-place HBM scatter after XLA compilation and
+sequence never compacts or copies its neighbors' cache. The store's
+values are donated into every step exactly like optimizer state, and
 the cache NEVER moves device→host between decode steps (the
 ``lint/serving-decode-cache`` rule makes a host-sink on a cache tensor
 a hard error).
 
-Three ops, registered with declared Effects so the hazard engine orders
+Stored layout. The store entry is ``(num_slots, max_len,
+prod(inner))`` whenever ``inner`` has two or more dimensions
+(:func:`stored_shape`; scalar and rank-1 inners are stored as
+declared). The ops keep the declared contract: an append takes
+``(B, P, *inner)``, a gather returns ``(B, L, *inner)``; only the
+lowerings reshape, on the way in and on the way out, and graph-level
+shapes, attrs, lint and the memory ledger see the declared shape (the
+bytes are the same). Why: an attention cache's minor dimension is
+``head_dim``, typically 64 — half a 128-lane tile. The TPU compiler
+then gives the donated parameter, and the aliased result, a layout with
+the PAGES on the lane axis, which neither the scatter nor the gather
+can use: every append paid three relayout copies of the whole pool
+(403 MB each at 3073 pages x 64 x 16 x 64 bfloat16 — 78 % of the
+serving benchmark's device time before PR 26). With heads x head_dim
+merged into one lane-dense axis the compiler leaves the parameter's
+layout alone and the scatter updates the donated buffer in place. That
+an append is in place is ASSERTED, not stated:
+``tests/test_tpu_aot_compile.py`` compiles the real decode and prefill
+programs for a described v5e chip and finds every pool aliased, no
+pool-sized ``copy`` and one pool-shaped fusion (the scatter) per append.
+
+Four ops, registered with declared Effects so the hazard engine orders
 them like any other variable access (append = read-modify-write on the
 cache resource, gather = read):
 
@@ -28,8 +48,12 @@ cache resource, gather = read):
   KVCacheAppend  write ``value (B, P, *inner)`` at rows ``slots (B,)``,
                  positions ``positions[b] + [0, P)`` — P is 1 on the
                  decode path, the prompt length on the prefill path.
-  KVCacheGather  read rows ``slots (B,)`` → ``(B, max_len, *inner)``;
-                 feeds DecodeAttention (query length 1).
+  KVCacheGather  read rows ``slots (B,)`` → ``(B, max_len, *inner)``,
+                 or through a page table ``(B, n_blocks)`` →
+                 ``(B, n_blocks * max_len, *inner)``; feeds
+                 DecodeAttention.
+  KVCachePageCopy  ``cache[dst] = cache[src]`` over whole rows: the
+                 prefix cache's copy-on-write.
 
 Ordering note: a gather has no data edge from the appends that must
 precede it; build it under ``stf.control_dependencies([append])`` (the
@@ -60,7 +84,11 @@ SHARDING_ATTR = "_cache_sharding"
 # keeps the cache whole on every device.
 HEAD_SHARD_SUFFIX = ":heads"
 # dim index of the head dim in the canonical cache layout
-# (slots, positions, heads, head_dim)
+# (slots, positions, heads, head_dim) — and in the STORED layout
+# (slots, positions, heads*head_dim), where sharding dim 2 over ``tp``
+# gives each device the same contiguous heads/tp whole heads (heads is
+# the major factor of the merged axis), so one index serves the graph's
+# specs and the store's NamedSharding alike
 HEAD_DIM = 2
 # shared-page layer markers (PR 16): PAGED_ATTR tags ops against a
 # cache whose rows are REFCOUNTED shared pages (prefix cache) — a
@@ -122,6 +150,27 @@ def cache_named_sharding(decl, rank, mesh=None):
     return mesh.named_sharding(*spec)
 
 
+def stored_shape(shape) -> Tuple[int, ...]:
+    """The shape a cache declared ``(num_slots, max_len, *inner)`` has
+    in the VariableStore: an inner shape of rank >= 2 is stored
+    flattened into ONE lane-dense minor axis (module docstring,
+    "Stored layout"); scalar and rank-1 inners are stored as declared."""
+    shape = tuple(int(d) for d in shape)
+    if len(shape) < 4:
+        return shape
+    return shape[:2] + (int(np.prod(shape[2:])),)
+
+
+def _logical_view(op, stored):
+    """``stored (..., prod(inner))`` seen with the declared inner dims
+    again: the leading inner dim is inferred, so a head shard (its own
+    ``heads/tp`` whole heads on the minor axis) reshapes the same way."""
+    inner = tuple(int(d) for d in op.attrs["shape"][2:])
+    if len(inner) < 2:
+        return stored
+    return stored.reshape(stored.shape[:-1] + (-1,) + inner[1:])
+
+
 def _hint_cache_class(ctx, op):
     """Tag the cache's store entry for the HBM ledger (trace-time
     Python side effect — stf.telemetry.memory classifies the store
@@ -139,7 +188,7 @@ def _lower_kv_alloc(ctx, op, inputs):
     import jax.numpy as jnp
 
     _hint_cache_class(ctx, op)
-    shape = tuple(int(d) for d in op.attrs["shape"])
+    shape = stored_shape(op.attrs["shape"])
     val = jnp.zeros(shape, _np_dtype(op))
     ns = None
     if not getattr(ctx, "host", False) \
@@ -166,7 +215,7 @@ def _lower_kv_alloc(ctx, op, inputs):
             except Exception:  # noqa: BLE001 — placement hint only
                 pass
     ctx.write_var(op.attrs["var_name"], val)
-    return [val]
+    return [_logical_view(op, val)]
 
 
 def _lower_kv_append(ctx, op, inputs):
@@ -177,12 +226,14 @@ def _lower_kv_append(ctx, op, inputs):
     cache = ctx.read_var(name, op)
     if value.dtype != cache.dtype:
         value = value.astype(cache.dtype)
+    # (B, P, *inner) -> the stored rank: inner dims merge into one axis
+    value = value.reshape(value.shape[:2] + cache.shape[2:])
     p = value.shape[1]
     p_idx = jnp.asarray(positions, jnp.int32)[:, None] + jnp.arange(
         p, dtype=jnp.int32)[None, :]
     new = cache.at[jnp.asarray(slots, jnp.int32)[:, None], p_idx].set(value)
     ctx.write_var(name, new)
-    return [new]
+    return [_logical_view(op, new)]
 
 
 def _lower_kv_gather(ctx, op, inputs):
@@ -190,6 +241,7 @@ def _lower_kv_gather(ctx, op, inputs):
 
     cache = ctx.read_var(op.attrs["var_name"], op)
     idx = jnp.asarray(inputs[0], jnp.int32)
+    rows = cache[idx]
     if idx.ndim == 2:
         # page-table gather: slots (B, n_blocks) -> the LOGICAL cache
         # view (B, n_blocks * page_len, *inner) — block b's pages
@@ -197,9 +249,8 @@ def _lower_kv_gather(ctx, op, inputs):
         # sees one contiguous per-sequence cache exactly like the 1-D
         # slot path (lengths mask in logical coordinates)
         b, nb = idx.shape
-        rows = cache[idx]              # (B, nb, page_len, *inner)
-        return [rows.reshape((b, nb * cache.shape[1]) + cache.shape[2:])]
-    return [cache[idx]]
+        rows = rows.reshape((b, nb * cache.shape[1]) + cache.shape[2:])
+    return [_logical_view(op, rows)]
 
 
 def _lower_kv_page_copy(ctx, op, inputs):
@@ -211,7 +262,7 @@ def _lower_kv_page_copy(ctx, op, inputs):
     rows = cache[jnp.asarray(src, jnp.int32)]
     new = cache.at[jnp.asarray(dst, jnp.int32)].set(rows)
     ctx.write_var(name, new)
-    return [new]
+    return [_logical_view(op, new)]
 
 
 op_registry.register(
@@ -265,6 +316,11 @@ class KVCache:
     @property
     def shape(self) -> Tuple[int, ...]:
         return (self.num_slots, self.max_len) + self.inner_shape
+
+    @property
+    def stored_shape(self) -> Tuple[int, ...]:
+        """Shape of the store entry (:func:`stored_shape`)."""
+        return stored_shape(self.shape)
 
     def _attrs(self):
         a = {"var_name": self.name, "shape": list(self.shape),

@@ -12,6 +12,7 @@ All in ONE file (one xdist worker loads libtpu and keeps its lock); the
 topology is described inside a module-scoped fixture, never at import.
 """
 
+import contextlib
 import re
 
 import numpy as np
@@ -51,20 +52,31 @@ def one_chip(topo):
     return jax.sharding.SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture
-def tpu_compile(one_chip, monkeypatch):
-    """compile(fn, *(shape, dtype)) -> compiled HLO text, for the
-    described chip. Kernels lower natively (the default backend here is
-    the CPU, so use_interpret is steered from the test), and the
-    persistent cache is off: such an executable cannot be read back
-    without a chip."""
+@contextlib.contextmanager
+def _lowering_for_the_described_chip():
+    """Kernels lower natively (the default backend here is the CPU, so
+    use_interpret is steered from the test), and the persistent cache
+    is off: such an executable cannot be read back without a chip.
+    Yields the MonkeyPatch for what else a case has to steer."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    monkeypatch.setattr(common, "use_interpret", lambda: False)
+    mp = pytest.MonkeyPatch()
+    mp.setattr(common, "use_interpret", lambda: False)
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    try:
+        yield mp
+    finally:
+        mp.undo()
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
 
+
+@pytest.fixture
+def tpu_compile(one_chip):
+    """compile(fn, *(shape, dtype)) -> compiled HLO text, for the
+    described chip."""
     def compile_(fn, *avals):
         args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
                 for s, d in avals]
@@ -81,9 +93,8 @@ def tpu_compile(one_chip, monkeypatch):
             for ln in calls), [ln[:80] for ln in calls]
         return text
 
-    yield compile_
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+    with _lowering_for_the_described_chip():
+        yield compile_
 
 
 def _grad(fn, argnums):
@@ -191,3 +202,101 @@ def test_interpret_follows_the_lowering_platform_not_a_cached_answer(
     with jax.default_device(jax.devices("cpu")[0]):
         with pytest.raises(RuntimeError, match="interpret mode"):
             common.use_interpret()
+
+
+# ---------------------------------------------------------------------------
+# The paged KV pool at lm-big's real sizes: a cache append must update
+# the donated pool in place. Stored (3073, 64, 16, 64) the pool's minor
+# dimension is half a lane tile, the compiler lays the donated parameter
+# out pages-minor, and every append pays three pool-sized relayout
+# copies (403 MB each; 78 % of lm-big.backlog's device time, PERF.md
+# PR 26). Stored lane-dense (ops/kv_cache_ops.stored_shape) there is
+# none: this is where "in place" is asserted.
+# ---------------------------------------------------------------------------
+
+LM_PAGES, LM_PAGE_LEN, LM_PAGES_PER_SEQ = 3072, 64, 32
+LM_POOL = (LM_PAGES + 1, LM_PAGE_LEN, 16, 64)      # + the scratch page
+_HLO_INSTR = re.compile(
+    r"\s*(?:ROOT )?%[\w.\-]+ = [a-z0-9]+\[([0-9,]*)\]\S* ([\w\-]+)\(")
+
+
+@pytest.fixture(scope="module")
+def lm_big_programs(one_chip):
+    """HLO text of the decode (bucket 96) and page-chunk prefill
+    (bucket 8) programs ``build_causal_lm_program`` emits at lm-big's
+    sizes, lowered from the Session's own donating step function for
+    the described chip. Nothing is allocated: state is avals."""
+    import simple_tensorflow_tpu as stf
+    from simple_tensorflow_tpu.kernels import registry as kreg
+    from simple_tensorflow_tpu.models import causal_lm
+    from simple_tensorflow_tpu.models.transformer import TransformerConfig
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    cfg = TransformerConfig(vocab_size=32768, d_model=1024, num_heads=16,
+                            d_ff=4096, num_layers=6, dropout=0.0,
+                            max_len=2048, layer_norm_eps=1e-6)
+    graph = stf.Graph()
+    with _lowering_for_the_described_chip() as mp, graph.as_default(), \
+            stf.Session(graph=graph) as sess:
+        mp.setattr(kreg, "backend", lambda: "tpu")
+        mp.setattr(kreg, "_mode_override", "force")
+        prog = causal_lm.build_causal_lm_program(
+            cfg, page_len=LM_PAGE_LEN, pages_per_seq=LM_PAGES_PER_SEQ,
+            num_pages=LM_PAGES, decode_bucket_sizes=(96,),
+            prefill_bucket_sizes=(8,), compute_dtype=stf.bfloat16)
+        caches = [c for pair in prog["caches"] for c in pair]
+        assert all(c.shape == LM_POOL for c in caches)
+        state = {v.var_name: aval(v.shape.as_list(),
+                                  v.dtype.base_dtype.np_dtype)
+                 for v in stf.global_variables()}
+        state.update({c.name: aval(c.stored_shape, c.dtype.np_dtype)
+                      for c in caches})
+
+        def text(fetches, feeds):
+            step = sess.plan(fetches, feeds=feeds)._step
+            feed_avals = {
+                t.name: aval(t.shape.as_list(), t.dtype.base_dtype.np_dtype)
+                for t in step.feed_tensors}
+            return step.jitted.lower(
+                dict(state), feed_avals, aval((), jax.random.key(0).dtype),
+                aval((), np.uint32)).compile().as_text()
+
+        d, p = prog["decode"][96], prog["prefill"][8]
+        texts = {
+            "decode96": text(
+                {"next_tok": d["next_tok"], "logp": d["logp"]},
+                [d["tok"], d["pos"], d["tables"], d["dst"], d["off"]]),
+            "prefill8": text(
+                {"done": p["op"]},
+                [p["tok"], p["base"], p["tables"], p["dst"]]),
+        }
+    return {"texts": texts, "n_state": len(state), "n_pools": len(caches)}
+
+
+@pytest.mark.parametrize("program", ["decode96", "prefill8"])
+def test_lm_big_cache_append_updates_the_pool_in_place(lm_big_programs,
+                                                       program):
+    text = lm_big_programs["texts"][program]
+    n_pools = lm_big_programs["n_pools"]                # 6 layers x K, V
+    assert "stf_decode_attention_" in text              # the kernel path
+    pool_elems = int(np.prod(LM_POOL))
+    pool_sized = {}       # opcode -> the entry computation's instructions
+    for ln in text[text.index("\nENTRY "):].splitlines():
+        m = _HLO_INSTR.match(ln)
+        if m and m.group(1) and m.group(2) != "parameter" \
+                and pool_elems == int(np.prod(
+                    [int(x) for x in m.group(1).split(",")])):
+            pool_sized.setdefault(m.group(2), []).append(ln.strip())
+    # (i) the donated state, every pool in it, is aliased to the outputs
+    header = text.split("\n", 1)[0]
+    assert "input_output_alias" in header
+    assert header.count("-alias)") == lm_big_programs["n_state"]
+    # (ii) no relayout of a pool: no copy with the pool's element count
+    assert not pool_sized.get("copy"), \
+        [ln[:120] for ln in pool_sized["copy"][:3]]
+    # (iii) one pool-shaped instruction per append, the scatter fusion
+    assert set(pool_sized) == {"fusion"}, sorted(pool_sized)
+    assert len(pool_sized["fusion"]) == n_pools
+    assert all("_append/scatter" in ln for ln in pool_sized["fusion"])
