@@ -1,0 +1,380 @@
+"""Runner ``serve_sparse_moe``: ``runners/serve.py``'s drive of
+``ModelServer.generate`` for a configuration whose reference is
+``chipbench/reference/sparse_moe_decoder.py``.
+
+A new reference needs a new runner: ``runners/serve.py`` and
+``harness.load_variables`` name ``reference.postln_transformer`` and
+``work.causal_lm_*``, and no file that is there is edited for a cell
+(README). Shared with ``serve.py`` by import: the request record, model
+build, server start, warm-up, the window, settling, the window's token
+count and the sample. This file's own: weights (made on the DEVICE a
+layer at a time — 4.4 B parameters through the host would cost minutes of
+``setup_s``), the check against the new reference, the planted fault, the
+calibration and the traced window's work (``work_sparse_moe.py``) — and
+the blocks of sizes in one order on every seed (``in_one_order``): one
+admission stalls every answer for up to 3.5 s here.
+"""
+
+from __future__ import annotations
+
+import bisect
+import gc
+import time
+
+import numpy as np
+
+from chipbench import harness, traffic as traffic_mod, work_sparse_moe
+from chipbench.compare import against, is_correct, serve_numbers
+from chipbench.reference import sparse_moe_decoder as ref
+from chipbench.runners.serve import (
+    _Request, _engine_row, _percentile, build, drive, sample_finished,
+    settle, start_server, tokens_in_window, warm_up)
+
+
+def load_weights(model, config, seed):
+    """The seed's weights into the served model's variables, leaf by leaf
+    as the reference makes them, in the dtype the program stores."""
+    import simple_tensorflow_tpu as stf
+
+    spec = config["reference"]["spec"]
+    with model.graph.as_default():
+        by_name = {v.name.split(":")[0]: v for v in stf.trainable_variables()}
+
+    def put(template, value, **fmt):
+        var = by_name.pop(template.format(**fmt))
+        if tuple(var.shape.as_list()) != value.shape:
+            raise RuntimeError(f"{var.name}: program shape {var.shape} != "
+                               f"reference shape {value.shape}")
+        var.load(value, model.session)
+
+    names = config["variables"]
+    top = ref.init_top(spec, seed, stored=True)
+    for leaf, value in top.items():
+        put(names[leaf], value)
+    for i in range(spec["layers"]):
+        for leaf, value in ref.init_layer(spec, seed, i, stored=True).items():
+            put(names["layers." + leaf], value, i=i)
+    if by_name:
+        raise RuntimeError("trainable variables the configuration's name "
+                           f"map does not cover: {sorted(by_name)}")
+
+
+def check(config, seed, sample, eos_id, control=None, timings=None):
+    """The reference, once over each sampled prompt with its served
+    tokens. Returns (rows per request, answers that are cut short)."""
+    if not sample:
+        return [], 1
+    missing = sum(not r.complete(eos_id) for r in sample)
+    rows = ref.served_token_gaps(
+        config["reference"]["spec"], seed, [r.prompt for r in sample],
+        [r.tokens for r in sample], control=control, timings=timings)
+    for row, r in zip(rows, sample):
+        row["served_logprob"] = np.asarray(r.logprobs, np.float64)
+    return rows, missing
+
+
+def _logprob_diffs(rows, control=False):
+    key = "control_logprob" if control else "served_logprob"
+    if not rows:
+        return np.zeros(0)
+    return np.concatenate([np.abs(r[key] - r["logprob"]) for r in rows])
+
+
+def numbers_of(rows, missing, control=False):
+    """``compare.serve_numbers`` and two numbers more, the MEDIAN and the
+    90th percentile of the distance between a served log-probability and
+    the reference's, over every checked token (``logprob_gap_median``,
+    ``logprob_gap_p90``). The widest distances of this model are set by a
+    token that changes one of its 8 experts, or one of its 2048
+    positions, on a rounding — bfloat16 and float8 alike read 0.4-0.8
+    there (PERF.md) — so the widest cannot tell the precisions apart; the
+    rounding that every token carries can, and the tenth of the tokens
+    that sit farthest can (the limits file has the readings). The tail
+    number also bounds what a fault on a minority of the tokens can hide
+    between the median and the widest."""
+    out = serve_numbers(rows, missing, control=control)
+    diffs = _logprob_diffs(rows, control)
+    p50, p90 = np.percentile(diffs, [50, 90]) if len(diffs) else (0.0, 0.0)
+    out["logprob_gap_median"], out["logprob_gap_p90"] = float(p50), float(p90)
+    return out
+
+
+def _spread(diffs):
+    """mean, then the 50th / 90th / 99th percentile of the distances."""
+    if not len(diffs):
+        return []
+    return [float(diffs.mean())] + [float(x) for x in
+                                    np.percentile(diffs, [50, 90, 99])]
+
+
+def _gap_shape(req, row):
+    """How one checked answer's distances are spread: the widest is what
+    is compared; the median and the count past a tenth tell rounding
+    (rare, isolated) from a fault (everywhere)."""
+    diff = np.abs(row["served_logprob"] - row["logprob"])
+    return {"prompt": len(req.prompt), "tokens": len(req.tokens),
+            "logprob_gap": {"max": float(diff.max()),
+                            "median": float(np.median(diff)),
+                            "over_0.1": int((diff > 0.1).sum())},
+            "logit_gap": {"max": float(row["gap"].max()),
+                          "nonzero": int((row["gap"] > 0).sum())},
+            "margin_p5": float(np.percentile(row["margin"], 5))}
+
+
+def second_best_fault(config, seed, sample, rows, eos_id):
+    """``serve.second_best_fault`` against this runner's ``check``: in each
+    sampled answer one token, at a place drawn from the seed, is replaced
+    by the token the reference puts second there, with that token's own
+    correct log-probability."""
+    rng = np.random.default_rng([int(seed), 5])
+    altered, places = [], []
+    for r in sample:
+        j = int(rng.integers(len(r.tokens)))
+        twin = _Request({"due": 0.0, "prompt": r.prompt,
+                         "max_new_tokens": r.budget})
+        twin.tokens = list(r.tokens)
+        twin.logprobs = list(r.logprobs)
+        altered.append(twin)
+        places.append(j)
+    for twin, row, j in zip(altered, rows, places):
+        twin.tokens[j] = int(row["second"][j])
+    rows2, missing = check(config, seed, altered, eos_id)
+    for row2, j in zip(rows2, places):
+        row2["served_logprob"][j] = row2["logprob"][j]
+    return (numbers_of(rows2, missing),
+            [float(row["margin"][j]) for row, j in zip(rows, places)])
+
+
+def _prefill_spans(reqs):
+    """{request: (start, end)} on the host clock for every answer that
+    began: admission runs a prompt's page chunks to completion between
+    the engine step before it (the latest token ANY answer got before
+    this answer's first; for an idle engine, the submission) and the
+    step that emits its first token."""
+    deliveries = sorted({ts for r in reqs for ts in r.times})
+    spans = {}
+    for r in reqs:
+        if r.times:
+            i = bisect.bisect_left(deliveries, r.times[0])
+            start = deliveries[i - 1] if i else r.submitted
+            spans[r] = (max(start, r.submitted), r.times[0])
+    return spans
+
+
+def _share_inside(span, t0, t1):
+    start, end = span
+    inside = min(end, t1) - max(start, t0)
+    return max(inside, 0.0) / (end - start) if end > start else 0.0
+
+
+def traced_work(spec, reqs, t0, t1):
+    """FLOPs of what was processed inside the traced window [t0, t1], by
+    ``work_sparse_moe``: every token delivered in it (one decode position
+    over its context), and of every prompt the share of its prefill
+    (``_prefill_spans``) that lay inside: a prompt takes 0.3-3.5 s of a
+    10 s stretch, so whole prompts by their first token read up to 13
+    TFLOP off either way."""
+    model_flops = prompts = 0.0
+    decode_tokens = 0
+    spans = _prefill_spans(reqs)
+    for r in reqs:
+        plen = len(r.prompt)
+        for j, ts in enumerate(r.times):
+            if t0 <= ts < t1:
+                decode_tokens += 1
+                model_flops += work_sparse_moe.decode_flops(spec, plen + j)
+        share = _share_inside(spans[r], t0, t1) if r in spans else 0.0
+        prompts += share
+        model_flops += share * work_sparse_moe.prompt_flops(spec, plen - 1)
+    return {"model_flops": model_flops, "decode_tokens": decode_tokens,
+            "prompts": prompts}
+
+
+def in_one_order(reqs, mix):
+    """The generator's requests with every block in ONE order on every
+    seed: the order is drawn from ``shape_seed`` as the sizes' pairing is
+    (the block's middle pair still heads the queue); token ids and
+    weights stay the seed's. Every answer waits out every admission's
+    whole prefill, 0.3 s for a 4.4k prompt and 3.5 s for a 30.7k one, so
+    WHICH prompts of a block a window admits, which ``traffic.requests``
+    leaves to the seed's shuffle, moved the rate by 10 % from seed to
+    seed, against 0.3-0.6 % on one seed (PERF.md)."""
+    block = mix["block"]
+    rng = np.random.default_rng([int(mix["shape_seed"]), 4])
+    out = []
+    for b in range(0, len(reqs), block):
+        by_size = sorted(reqs[b:b + block],
+                         key=lambda r: (len(r.prompt), r.budget))
+        order = rng.permutation(len(by_size))
+        if b == 0:
+            head = int(np.flatnonzero(order == len(by_size) // 2)[0])
+            order[[0, head]] = order[[head, 0]]
+        out += [by_size[j] for j in order]
+    return out
+
+
+def run(ctx):
+    config, mix, args = ctx["config"], ctx["traffic"], ctx["args"]
+    spec = config["reference"]["spec"]
+    name = ctx["model_name"]
+    clock, tracer = ctx["clock"], ctx["tracer"]
+    timings = {}
+
+    t = time.perf_counter()
+    mark = clock.mark()
+    model = build(config)
+    timings["build_and_compile_s"] = time.perf_counter() - t
+    t = time.perf_counter()
+    load_weights(model, config, args.seed)
+    timings["init_s"] = time.perf_counter() - t
+    server = start_server(model, config, name)
+    t = time.perf_counter()
+    warm_up(server, name, model, spec["vocab"])
+    timings["warm_up_s"] = time.perf_counter() - t
+    timings["setup_compiles"] = clock.since(mark)
+
+    reqs = in_one_order([_Request(r) for r in traffic_mod.requests(
+        mix, spec["vocab"], args.seed, args.seconds)], mix)
+    at_open = {}
+
+    def on_open():
+        at_open["counters"] = ctx["snapshot_counters"]()
+        at_open["depth"] = _engine_row(server, name)["queue_depth"]
+        at_open["mark"] = clock.mark()
+        at_open["setup_s"] = time.perf_counter() - ctx["t_start"]
+
+    win = drive(server, name, mix, reqs, args.seconds, tracer, on_open,
+                ctx["spans"])
+    t_open, t_close, deadline = win["t_open"], win["t_close"], win["deadline"]
+    counters1 = ctx["snapshot_counters"]()
+    row1 = _engine_row(server, name)
+    window_compiles = clock.since(at_open["mark"])
+    peak_bytes = harness.memory_peak_bytes(ctx["devices"])
+
+    settle(reqs, deadline)
+    t = time.perf_counter()
+    server.close()
+    timings["close_s"] = time.perf_counter() - t
+
+    in_window = tokens_in_window(reqs, t_open, t_close)
+    finished = [r for r in reqs if r.outcome() == "ok"]
+    # a backlog's requests are attempted once the engine takes them up;
+    # the deadline that ends the run is the harness's own
+    attempted = [r for r in reqs if r.times]
+    failed = [r for r in attempted if r.outcome() not in
+              ("ok", "DeadlineExceededError")]
+    end_to_end = {"serve_tokens_per_s": in_window / (t_close - t_open),
+                  "setup_s": at_open["setup_s"]}
+
+    facts = {"work": {}, "counters": {k: (at_open["counters"][k],
+                                          counters1[k])
+                                      for k in counters1}}
+    if tracer.t1 is not None:
+        facts["work"] = traced_work(spec, reqs, tracer.t0, tracer.t1)
+
+    first = sorted(r.times[0] - t_open for r in attempted)
+    info = {
+        "requests": {"offered": len(reqs), "taken_up": len(attempted),
+                     "finished": len(finished),
+                     "finished_in_window": sum(
+                         r.times[-1] <= t_close for r in finished),
+                     # an answer the end token cut short retires its slot
+                     # early, and the admissions after it come sooner
+                     "ended_before_budget": sum(
+                         len(r.tokens) < r.budget for r in finished),
+                     "tokens_in_window": in_window,
+                     "tokens_delivered_in_window": sum(
+                         ts <= t_close for r in reqs for ts in r.times),
+                     "prompt_tokens_taken_up": sum(
+                         len(r.prompt) for r in attempted),
+                     "window_s": t_close - t_open},
+        "generator_lateness_ms": {
+            "p95": 1000 * _percentile(win["lateness"], 95),
+            "max": 1000 * max(win["lateness"])},
+        "queue_depth": {"window_start": at_open["depth"],
+                        "window_end": row1["queue_depth"],
+                        "slots_active_end": row1["slots_active"]},
+        "prefix_cache": row1.get("prefix_cache"),
+        # when the slots first all held a started answer
+        "first_fill_s": (first[min(len(first), model.num_slots) - 1]
+                         if first else None),
+        # in the order offered: two runs part where these do
+        "first_token_s": [r.times[0] - t_open for r in attempted],
+        "timings": timings, "window_compiles": window_compiles,
+    }
+
+    # -- the reference, once the program is gone ------------------------------
+    eos_id = model.eos_id
+    del server, model
+    gc.collect()
+    t = time.perf_counter()
+    sample = sample_finished(finished, args.seed, mix["check_requests"])
+    timings["reference"] = {}
+    rows, missing = check(config, args.seed, sample, eos_id,
+                          timings=timings["reference"])
+    timings["reference_s"] = time.perf_counter() - t
+    numbers = numbers_of(rows, missing)
+    info["checked"] = {"requests": len(sample),
+                       "tokens": int(sum(len(r["gap"]) for r in rows)),
+                       "longest": (len(sample[0].prompt)
+                                   + len(sample[0].tokens)) if sample else 0,
+                       "numbers": numbers,
+                       "per_request": [_gap_shape(r, row)
+                                       for r, row in zip(sample, rows)]}
+    compared = against(numbers, ctx["limits"])
+    return {"attempted": len(attempted), "failed": len(failed),
+            "end_to_end": end_to_end, "compared": compared, "facts": facts,
+            "memory_peak_bytes": peak_bytes, "info": info}
+
+
+def calibrate(ctx, seeds, n_control, seconds):
+    """The comparison's readings over many seeds in one process (see
+    chipbench/calibrate.py): for every seed its weights, a fresh window at
+    the cell's own load, and the reference over the sample a run would
+    take; on the first ``n_control`` seeds the control's readings at the
+    same positions and the planted second-best fault's too, each also put
+    through the cell's limits (``passes``). The program and the reference
+    cannot share the chip's memory here, so every seed builds the model
+    anew and frees it before its reference runs (compiles hit the cache
+    after the first)."""
+    config, mix, limits = ctx["config"], ctx["traffic"], ctx["limits"]
+    spec, name = config["reference"]["spec"], ctx["model_name"]
+    for i, seed in enumerate(seeds):
+        t = time.perf_counter()
+        model = build(config)
+        load_weights(model, config, seed)
+        server = start_server(model, config, name)
+        warm_up(server, name, model, spec["vocab"])
+        reqs = in_one_order([_Request(r) for r in traffic_mod.requests(
+            mix, spec["vocab"], seed, seconds)], mix)
+        win = drive(server, name, mix, reqs, seconds)
+        settle(reqs, win["deadline"])
+        eos_id = model.eos_id
+        server.close()
+        del server, model
+        gc.collect()
+        finished = [r for r in reqs if r.outcome() == "ok"]
+        sample = sample_finished(finished, seed, mix["check_requests"])
+        control = config["control_precision"] if i < n_control else None
+        rows, missing = check(config, seed, sample, eos_id, control)
+        numbers = numbers_of(rows, missing)
+        record = {"seed": seed, "program": numbers,
+                  "logprob_gap_mean_p50_p90_p99": {
+                      "program": _spread(_logprob_diffs(rows))},
+                  "passes": {"program": is_correct(against(numbers, limits))},
+                  "finished": len(finished), "checked": len(sample),
+                  "tokens": int(sum(len(r["gap"]) for r in rows)),
+                  "margin_percentiles_1_5_50": [
+                      float(x) for x in np.percentile(np.concatenate(
+                          [r["margin"] for r in rows]), [1, 5, 50])]}
+        if control:
+            record["control"] = numbers_of(rows, 0, control=True)
+            record["logprob_gap_mean_p50_p90_p99"]["control"] = _spread(
+                _logprob_diffs(rows, control=True))
+            record["second_best"], record["second_best_margins"] = \
+                second_best_fault(config, seed, sample, rows, eos_id)
+            for label in ("control", "second_best"):
+                record["passes"][label] = is_correct(
+                    against(record[label], limits))
+        record["seconds"] = time.perf_counter() - t
+        harness.log(calibrate=record)

@@ -182,6 +182,7 @@ from .client.session import (Session, InteractiveSession,
 from . import compiler
 from . import nn
 from .ops import kv_cache_ops  # registers the KV-cache/decode op types
+from .ops import moe_ops, sparse_attention_ops  # noqa: F401 — RoutedFFN; sparse-attention serving ops
 from . import train
 from . import layers
 from . import losses

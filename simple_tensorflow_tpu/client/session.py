@@ -578,7 +578,11 @@ class VariableStore:
             dtype = dtypes_mod.narrowed_if_no_x64(decl).np_dtype
             if dtype != decl.np_dtype:
                 dtypes_mod.warn_64bit_narrowing_once(f"variable {name!r}")
-        arr = jnp.asarray(np.asarray(value), dtype=dtype)
+        if isinstance(value, jax.Array):
+            # already on a device: cast there, no trip through the host
+            arr = value if dtype is None else value.astype(dtype)
+        else:
+            arr = jnp.asarray(np.asarray(value), dtype=dtype)
         sh = self.shardings.get(name)
         if sh is None and variable is not None \
                 and getattr(variable, "sharding", None) is not None:

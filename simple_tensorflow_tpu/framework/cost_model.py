@@ -194,7 +194,7 @@ def _op_flops(op: Operation, grad_depth: int = 0,
             return 4.0 * b * kq * h * max_len * d
         return 2.0 * _out_elems(op)
     if t in ("KVCacheAlloc", "KVCacheAppend", "KVCacheGather",
-             "KVCachePageCopy"):
+             "KVCacheGatherRows", "KVCachePageCopy"):
         return 0.0  # pure data movement; bytes are priced in _op_bytes
     if t == "EmbeddingLookupFused":
         # row routing is data movement (the whole point vs the one-hot

@@ -122,7 +122,7 @@ class _PagedCaches:
     page is always present in the table)."""
 
     def __init__(self, caches, page_tables, dst_pages, offsets, base):
-        self._caches = caches        # [(KVCache k, KVCache v)] per layer
+        self._caches = caches        # [tuple of KVCache] per layer
         self._tables = page_tables   # (B, n_blocks) int32
         self._dst = dst_pages        # (B,) int32 physical page written
         self._off = offsets          # (B,) int32 in-page start offset
@@ -142,36 +142,116 @@ class _PagedCaches:
         kc, vc = self._caches[layer]
         return self._one(kc, k_new), self._one(vc, v_new), self._base
 
+    # a stack that keeps more than K and V per layer, or reads selected
+    # rows instead of the whole view, appends first and reads under
+    # ``after_append``'s control dependency
+    def append(self, layer, *new):
+        return [c.append(n, self._dst, self._off)
+                for c, n in zip(self._caches[layer], new)]
 
-def build_causal_lm_program(cfg: TransformerConfig, *, page_len,
-                            pages_per_seq, num_pages,
-                            decode_bucket_sizes=None,
-                            prefill_bucket_sizes=None,
+    @staticmethod
+    def after_append(appended):
+        return stf.control_dependencies([t.op for t in appended])
+
+    def gather(self, layer, which):
+        return self._caches[layer][which].gather(self._tables)
+
+    def gather_rows(self, layer, which, positions):
+        return self._caches[layer][which].gather_rows(self._tables,
+                                                      positions)
+
+    def live_rows(self):
+        """(B,) bool: the rows that write a real page. A bucket's padding
+        rows write the scratch page, the pool's last."""
+        scratch = self._caches[0][0].stored_shape[0] - 1
+        return stf.not_equal(self._dst, scratch)
+
+
+class _PostLNStack:
+    """The post-LN decoder-only stack of :func:`causal_lm_logits` as the
+    paged builder sees a block stack: which caches a layer keeps, one
+    page-aligned prompt block, one decode position with its logits."""
+
+    def __init__(self, cfg: TransformerConfig, compute_dtype, scope,
+                 int8=False, tp_axis=None):
+        self.cfg, self.scope, self.tp_axis = cfg, scope, tp_axis
+        self.compute_dtype, self.int8 = compute_dtype, int8
+        self.vocab_size, self.max_positions = cfg.vocab_size, cfg.max_len
+        self.int8_init = self._wq = self._w_scale = None
+
+    def layer_caches(self, kvc, total_pages, page_len, sharding):
+        heads = self.cfg.num_heads
+        inner = (heads, self.cfg.d_model // heads)
+        return [tuple(
+            kvc.kv_cache(f"{self.scope}_pg/l{i}_{kind}", total_pages,
+                         page_len, inner, self.compute_dtype,
+                         sharding=sharding, paged=True)
+            for kind in "kv") for i in range(self.cfg.num_layers)]
+
+    def prefill_block(self, tok, base, cache):
+        h, _ = _block_decode(tok, base, cache, None, None, None, self.cfg,
+                             self.compute_dtype, self.scope,
+                             tp_axis=self.tp_axis)
+        return h
+
+    def decode_step(self, tok, pos, cache):
+        h, emb = _incremental_decode(tok, pos, cache, None, None, None,
+                                     self.cfg, self.compute_dtype,
+                                     self.scope, tp_axis=self.tp_axis)
+        if self.int8:
+            if self.int8_init is None:
+                self._wq, self._w_scale, self.int8_init = \
+                    build_int8_logits_weights(emb, self.cfg,
+                                              scope=self.scope)
+            logits = stf.nn.quantized_matmul(h, self._wq, self._w_scale)
+        else:
+            logits = stf.matmul(h, stf.cast(emb, h.dtype.base_dtype),
+                                transpose_b=True)
+        return _tp_gather(stf.cast(logits, stf.float32), self.tp_axis), {}
+
+
+def build_causal_lm_program(cfg: TransformerConfig, *,
                             compute_dtype=stf.float32, int8=False,
-                            sampling=None, scope="causal_lm",
-                            cache_sharding=None, tp_axis=None):
-    """Build the paged-cache causal-LM serving programs.
+                            scope="causal_lm", tp_axis=None, **kw):
+    """:func:`build_paged_lm_program` over the post-LN stack
+    ``causal_lm_logits`` trains (``_block_decode`` /
+    ``_incremental_decode``, tied-embedding head, optional int8 head)."""
+    return build_paged_lm_program(
+        _PostLNStack(cfg, compute_dtype, scope, int8=int8, tp_axis=tp_axis),
+        compute_dtype=compute_dtype, scope=scope, tp_axis=tp_axis, **kw)
+
+
+def build_paged_lm_program(stack, *, page_len, pages_per_seq, num_pages,
+                           decode_bucket_sizes=None,
+                           prefill_bucket_sizes=None,
+                           compute_dtype=stf.float32, sampling=None,
+                           scope="causal_lm", cache_sharding=None,
+                           tp_axis=None):
+    """Build the paged-cache serving programs of a decoder-only block
+    ``stack`` (:class:`_PostLNStack`; ``models/sparse_moe_lm.py`` has
+    another): the caches, the bucket loops, the emit and copy-on-write
+    are here once, the layer mathematics is the stack's.
 
     Emits, in the CURRENT default graph:
 
-    - per-layer K/V caches ``(num_pages + 1, page_len, H, hd)`` with
-      ``paged=True`` (row ``num_pages`` is the scratch page bucket
+    - the stack's per-layer caches ``(num_pages + 1, page_len, *inner)``
+      with ``paged=True`` (row ``num_pages`` is the scratch page bucket
       padding writes into) + ``alloc_op``;
     - one PREFILL program per prefill bucket pb: a page-aligned BLOCK
-      of ``page_len`` prompt tokens through ``_block_decode``
-      (query-block DecodeAttention, ``causal_offset=True``), appended
-      into each row's ``dst_pages`` physical page (feeds: tok
+      of ``page_len`` prompt tokens through ``stack.prefill_block``,
+      appended into each row's ``dst_pages`` physical page (feeds: tok
       (pb, page_len), base (pb,) absolute start, page_tables
       (pb, n_blocks), dst_pages (pb,); fetches: the append group — no
       logits: the engine feeds the last prompt token through the first
       DECODE step instead, so a partial final chunk just pads);
     - one DECODE program per decode bucket sb: one position through
-      ``_incremental_decode`` (feeds: tok (sb,), pos (sb,) absolute,
+      ``stack.decode_step`` (feeds: tok (sb,), pos (sb,) absolute,
       page_tables (sb, n_blocks), dst_pages (sb,), offsets (sb,);
-      fetches next_tok/logp (sb,)) — greedy, or seeded sampling when
+      fetches next_tok/logp (sb,), and whatever else the stack returns
+      by name under ``extra``) — greedy, or seeded sampling when
       ``sampling`` is set;
     - ``cow``: the copy-on-write program — ``KVCachePageCopy`` over
-      EVERY layer cache (feeds dst (1,), src (1,)): a sequence
+      EVERY cache of every layer (feeds dst (1,), src (1,)): a sequence
       diverging inside a shared page copies it before private appends.
 
     Page tables are host-side state (the prefix-cache trie owns them);
@@ -198,12 +278,10 @@ def build_causal_lm_program(cfg: TransformerConfig, *, page_len,
     pages_per_seq = int(pages_per_seq)
     num_pages = int(num_pages)
     max_seq_len = page_len * pages_per_seq
-    if max_seq_len > cfg.max_len:
+    if max_seq_len > stack.max_positions:
         raise ValueError(
             f"page_len*pages_per_seq={max_seq_len} exceeds "
-            f"cfg.max_len={cfg.max_len} (position-encoding table)")
-    heads = cfg.num_heads
-    hd = cfg.d_model // heads
+            f"cfg.max_len={stack.max_positions} (position-encoding table)")
     total_pages = num_pages + 1          # + scratch page
     scratch_page = num_pages
     decode_buckets = sorted(set(int(x) for x in (
@@ -211,16 +289,8 @@ def build_causal_lm_program(cfg: TransformerConfig, *, page_len,
     prefill_buckets = sorted(set(int(x) for x in (
         prefill_bucket_sizes or (1,))))
 
-    caches = []
-    for i in range(cfg.num_layers):
-        caches.append((
-            kvc.kv_cache(f"{scope}_pg/l{i}_k", total_pages, page_len,
-                         (heads, hd), compute_dtype,
-                         sharding=cache_sharding, paged=True),
-            kvc.kv_cache(f"{scope}_pg/l{i}_v", total_pages, page_len,
-                         (heads, hd), compute_dtype,
-                         sharding=cache_sharding, paged=True)))
-    flat_caches = [c for pair in caches for c in pair]
+    caches = stack.layer_caches(kvc, total_pages, page_len, cache_sharding)
+    flat_caches = [c for group in caches for c in group]
     alloc_op = stf.group(*[c.alloc() for c in flat_caches],
                          name="pg_alloc")
 
@@ -230,20 +300,6 @@ def build_causal_lm_program(cfg: TransformerConfig, *, page_len,
                                    "seed"}
         if unknown:
             raise ValueError(f"unknown sampling knobs: {sorted(unknown)}")
-    state = {"int8_init": None, "wq": None, "w_scale": None}
-
-    def _logits_head(h_flat, emb):
-        if int8:
-            if state["int8_init"] is None:
-                state["wq"], state["w_scale"], state["int8_init"] = \
-                    build_int8_logits_weights(emb, cfg, scope=scope)
-            logits = stf.nn.quantized_matmul(h_flat, state["wq"],
-                                             state["w_scale"])
-        else:
-            logits = stf.matmul(h_flat,
-                                stf.cast(emb, h_flat.dtype.base_dtype),
-                                transpose_b=True)
-        return _tp_gather(stf.cast(logits, stf.float32), tp_axis)
 
     def _emit(logits):
         if sampling is not None:
@@ -254,7 +310,7 @@ def build_causal_lm_program(cfg: TransformerConfig, *, page_len,
         tok = stf.cast(stf.argmax(logits, -1, output_type=stf.int32),
                        stf.int32)
         logp = stf.reduce_sum(
-            logp_all * stf.one_hot(tok, cfg.vocab_size,
+            logp_all * stf.one_hot(tok, stack.vocab_size,
                                    dtype=stf.float32), axis=-1)
         return tok, logp
 
@@ -271,8 +327,7 @@ def build_causal_lm_program(cfg: TransformerConfig, *, page_len,
                                     f"lm_prefill{pb}_dst"))
         cache = _PagedCaches(caches, tables, dst, stf.fill([pb], 0),
                              base)
-        h, _ = _block_decode(tok, base, cache, None, None, None, cfg,
-                             compute_dtype, scope, tp_axis=tp_axis)
+        h = stack.prefill_block(tok, base, cache)
         # fetch the hidden state to anchor the whole block (appends are
         # its data deps); pad rows of a partial final chunk write
         # garbage K/V past the real length — dead rows: attention masks
@@ -293,13 +348,12 @@ def build_causal_lm_program(cfg: TransformerConfig, *, page_len,
         off = _feed(stf.placeholder(stf.int32, [sb],
                                     f"lm_decode{sb}_off"))
         cache = _PagedCaches(caches, tables, dst, off, pos)
-        h, emb = _incremental_decode(tok, pos, cache, None, None, None,
-                                     cfg, compute_dtype, scope,
-                                     tp_axis=tp_axis)
-        next_tok, logp = _emit(_logits_head(h, emb))
+        logits, extra = stack.decode_step(tok, pos, cache)
+        next_tok, logp = _emit(logits)
         decode_progs[sb] = {"tok": tok, "pos": pos, "tables": tables,
                             "dst": dst, "off": off,
-                            "next_tok": next_tok, "logp": logp}
+                            "next_tok": next_tok, "logp": logp,
+                            "logits": logits, "extra": extra}
 
     # -- copy-on-write ------------------------------------------------------
     cow_dst = _feed(stf.placeholder(stf.int32, [1], "lm_cow_dst"))
@@ -309,7 +363,7 @@ def build_causal_lm_program(cfg: TransformerConfig, *, page_len,
 
     return {
         "alloc_op": alloc_op,
-        "int8_init": state["int8_init"],
+        "int8_init": getattr(stack, "int8_init", None),
         "prefill": prefill,
         "decode": decode_progs,
         "cow": {"dst": cow_dst, "src": cow_src, "op": cow_op},
@@ -358,12 +412,8 @@ class CausalLMGenerativeModel:
         self.int8 = bool(int8)
         self.sampling = dict(sampling) if sampling else None
         self._compute_dtype = compute_dtype
-        # paged cache set == generative_cache_bytes with slots=num_pages,
-        # decode_len=page_len, no cross caches (decoder-only; all of it
-        # head-dim shardable)
         self._cache_bytes_total, self._cache_bytes_unsharded = \
-            generative_cache_bytes(cfg, 0, self.num_pages, self.page_len,
-                                   compute_dtype, cross=False)
+            self._cache_bytes()
         self.tp_choice = None
         if tp == "auto":
             from ..analysis import autoshard as _autoshard
@@ -388,14 +438,14 @@ class CausalLMGenerativeModel:
             if seed is not None:
                 stf.set_random_seed(seed)
             self.session = stf.Session(graph=self.graph, config=config)
-            prog = build_causal_lm_program(
-                cfg, page_len=page_len, pages_per_seq=pages_per_seq,
+            prog = self._build_program(
+                page_len=page_len, pages_per_seq=pages_per_seq,
                 num_pages=num_pages,
                 decode_bucket_sizes=(decode_bucket_sizes
                                      or tuple(sorted({1, max_live}))),
                 prefill_bucket_sizes=prefill_bucket_sizes,
-                compute_dtype=compute_dtype, int8=int8,
-                sampling=sampling, scope=scope, tp_axis=self.tp_axis)
+                compute_dtype=compute_dtype, sampling=sampling,
+                scope=scope, tp_axis=self.tp_axis)
             self._prog = prog
             self.scratch_page = prog["scratch_page"]
             if self.tp_axis:
@@ -419,7 +469,8 @@ class CausalLMGenerativeModel:
             self._decode_plans = {}
             for sb, p in prog["decode"].items():
                 plan = self.session.plan(
-                    {"next_tok": p["next_tok"], "logp": p["logp"]},
+                    {"next_tok": p["next_tok"], "logp": p["logp"],
+                     **p["extra"]},
                     feeds=[p["tok"], p["pos"], p["tables"], p["dst"],
                            p["off"]])
                 self._decode_plans[sb] = (plan, p)
@@ -440,6 +491,21 @@ class CausalLMGenerativeModel:
                 self._cow_plan[0].compile()
         self._decode_buckets = sorted(self._decode_plans)
         self._prefill_buckets = sorted(self._prefill_plans)
+
+    # -- what a subclass with another block stack overrides ------------------
+    def _cache_bytes(self):
+        """(total, unsharded) bytes of the paged cache set: the same as
+        ``generative_cache_bytes`` with slots=num_pages,
+        decode_len=page_len, no cross caches."""
+        return generative_cache_bytes(self.cfg, 0, self.num_pages,
+                                      self.page_len, self._compute_dtype,
+                                      cross=False)
+
+    def _build_program(self, **kw):
+        return build_causal_lm_program(self.cfg, int8=self.int8, **kw)
+
+    def _after_decode(self, out, n, positions):
+        """What a decode step fetched beside the tokens (``extra``)."""
 
     @property
     def decode_buckets(self):
@@ -535,6 +601,7 @@ class CausalLMGenerativeModel:
         out = self._run(plan, {p["tok"]: tok, p["pos"]: pos,
                                p["tables"]: tbl, p["dst"]: dst,
                                p["off"]: off.astype(np.int32)})
+        self._after_decode(out, n, positions)
         return (np.asarray(out["next_tok"])[:n],
                 np.asarray(out["logp"])[:n], sb)
 

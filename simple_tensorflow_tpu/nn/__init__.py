@@ -39,6 +39,13 @@ from ..ops.fused_ops import (
     fused_softmax_cross_entropy, quantized_matmul,
 )
 from ..ops.kv_cache_ops import decode_attention
+from ..ops.moe_ops import routed_ffn_op as routed_ffn
+from ..ops.sparse_attention_ops import (
+    indexer_topk_op as indexer_topk, rms_norm_op as rms_norm,
+    rotary_embedding_op as rotary_embedding,
+    selected_attention_op as selected_attention,
+    sparse_block_attention_op as sparse_block_attention,
+)
 from ..ops.candidate_sampling_ops import (
     uniform_candidate_sampler, log_uniform_candidate_sampler,
     learned_unigram_candidate_sampler, fixed_unigram_candidate_sampler,

@@ -38,7 +38,7 @@ an append is in place is ASSERTED, not stated:
 programs for a described v5e chip and finds every pool aliased, no
 pool-sized ``copy`` and one pool-shaped fusion (the scatter) per append.
 
-Four ops, registered with declared Effects so the hazard engine orders
+Five ops, registered with declared Effects so the hazard engine orders
 them like any other variable access (append = read-modify-write on the
 cache resource, gather = read):
 
@@ -52,6 +52,10 @@ cache resource, gather = read):
                  or through a page table ``(B, n_blocks)`` →
                  ``(B, n_blocks * max_len, *inner)``; feeds
                  DecodeAttention.
+  KVCacheGatherRows  read SELECTED token rows through a page table:
+                 ``tables (B, n_blocks)``, logical ``positions (B, K)``
+                 → ``(B, K, *inner)``. What sparse attention reads: K
+                 rows, never the sequence's whole logical view.
   KVCachePageCopy  ``cache[dst] = cache[src]`` over whole rows: the
                  prefix cache's copy-on-write.
 
@@ -101,7 +105,7 @@ VERIFY_ATTR = "_verify_plan"
 GUARD_ATTR = "_refcount_guarded"
 
 _CACHE_OP_TYPES = ("KVCacheAlloc", "KVCacheAppend", "KVCacheGather",
-                   "KVCachePageCopy")
+                   "KVCacheGatherRows", "KVCachePageCopy")
 
 
 # ---------------------------------------------------------------------------
@@ -253,6 +257,19 @@ def _lower_kv_gather(ctx, op, inputs):
     return [_logical_view(op, rows)]
 
 
+def _lower_kv_gather_rows(ctx, op, inputs):
+    import jax.numpy as jnp
+
+    cache = ctx.read_var(op.attrs["var_name"], op)
+    tables = jnp.asarray(inputs[0], jnp.int32)
+    pos = jnp.asarray(inputs[1], jnp.int32)
+    page_len = cache.shape[1]
+    # logical position -> (physical page, in-page offset); only the K
+    # selected rows move, (B, K, prod(inner)) out of the whole pool
+    pages = jnp.take_along_axis(tables, pos // page_len, axis=1)
+    return [_logical_view(op, cache[pages, pos % page_len])]
+
+
 def _lower_kv_page_copy(ctx, op, inputs):
     import jax.numpy as jnp
 
@@ -273,6 +290,9 @@ op_registry.register(
     effects=op_registry.Effects(writes=("var_name",), update="update"))
 op_registry.register(
     "KVCacheGather", lower=_lower_kv_gather,
+    effects=op_registry.Effects(reads=("var_name",)))
+op_registry.register(
+    "KVCacheGatherRows", lower=_lower_kv_gather_rows,
     effects=op_registry.Effects(reads=("var_name",)))
 op_registry.register(
     "KVCachePageCopy", lower=_lower_kv_page_copy,
@@ -384,6 +404,25 @@ class KVCache:
         op = g.create_op(
             "KVCacheGather", [slots], attrs=self._attrs(),
             name=name or f"{self.name}_gather",
+            output_specs=[(shape_mod.TensorShape(out_shape), self.dtype)])
+        return op.outputs[0]
+
+    def gather_rows(self, page_tables, positions, name=None):
+        """Read selected token rows of a paged cache: ``page_tables
+        (B, n_blocks)`` and LOGICAL ``positions (B, K)`` (token index in
+        the sequence, ``< n_blocks * max_len``) → ``(B, K, *inner)``.
+        Like :meth:`gather` it has no data edge from the appends it must
+        follow: build it under their control dependency."""
+        g = ops_mod.get_default_graph()
+        page_tables = ops_mod.convert_to_tensor(page_tables,
+                                                dtype=dtypes_mod.int32)
+        positions = ops_mod.convert_to_tensor(positions,
+                                              dtype=dtypes_mod.int32)
+        out_shape = ([positions.shape[0].value, positions.shape[1].value]
+                     + list(self.inner_shape))
+        op = g.create_op(
+            "KVCacheGatherRows", [page_tables, positions],
+            attrs=self._attrs(), name=name or f"{self.name}_gather_rows",
             output_specs=[(shape_mod.TensorShape(out_shape), self.dtype)])
         return op.outputs[0]
 
@@ -567,6 +606,7 @@ def _kv_page_copy_rule(op, in_specs, ctx):
 _shard.register_rules(_kv_alloc_rule, "KVCacheAlloc")
 _shard.register_rules(_kv_append_rule, "KVCacheAppend")
 _shard.register_rules(_kv_gather_rule, "KVCacheGather")
+_shard.register_rules(_kv_gather_rule, "KVCacheGatherRows")
 _shard.register_rules(_kv_page_copy_rule, "KVCachePageCopy")
 
 

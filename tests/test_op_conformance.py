@@ -1062,6 +1062,18 @@ COVERED_ELSEWHERE.update({
     "KVCacheAppend": ("test_generative.py", "KVCache"),
     "KVCacheGather": ("test_generative.py", "KVCache"),
     "KVCachePageCopy": ("test_decode2.py", "copy_pages"),
+    # sparse-attention routed-FFN serving ops (ISSUE 27): each against
+    # its jax-level definition, a per-expert loop, the per-position
+    # selection and the benchmark's plain reference
+    "KVCacheGatherRows": ("test_sparse_moe_lm.py", "gather_rows"),
+    "RMSNorm": ("test_sparse_moe_lm.py", "test_prefill_then_decode_logits"),
+    "RotaryEmbedding": ("test_sparse_moe_lm.py",
+                        "test_prefill_then_decode_logits"),
+    "IndexerTopK": ("test_sparse_moe_lm.py", "indexer_topk"),
+    "SelectedAttention": ("test_sparse_moe_lm.py", "selected_attention"),
+    "SparseBlockAttention": ("test_sparse_moe_lm.py",
+                             "sparse_block_attention"),
+    "RoutedFFN": ("test_sparse_moe_lm.py", "routed_ffn"),
     "DecodeAttention": ("test_generative.py", "decode_attention"),
     "BarrierIncompleteSize": ("test_data_flow_structures.py", "Barrier"),
     "BarrierInsertMany": ("test_data_flow_structures.py", "Barrier"),

@@ -300,3 +300,103 @@ def test_lm_big_cache_append_updates_the_pool_in_place(lm_big_programs,
     assert set(pool_sized) == {"fusion"}, sorted(pool_sized)
     assert len(pool_sized["fusion"]) == n_pools
     assert all("_append/scatter" in ln for ln in pool_sized["fusion"])
+
+
+# ---------------------------------------------------------------------------
+# The sparse-attention routed-FFN decoder at its benchmark sizes
+# (chipbench/configs/keye-vl2-30b-a3b.json): the decode and the page-chunk
+# prefill programs have to compile for the described chip, fit beside
+# 13.8 GB of weights and caches, keep every state leaf aliased, and leave
+# the K and V pools (761 x 512 x 512 bfloat16, 399 MB each) uncopied.
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def sparse_moe_programs(one_chip):
+    import json
+    import os
+
+    import simple_tensorflow_tpu as stf
+    from simple_tensorflow_tpu.models import causal_lm, sparse_moe_lm
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "keye-vl2-30b-a3b.json")) as f:
+        program = json.load(f)["program"]
+    cfg = sparse_moe_lm.SparseMoEConfig(**program["config_kwargs"])
+    kw = program["model_kwargs"]
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    graph = stf.Graph()
+    with _lowering_for_the_described_chip(), graph.as_default(), \
+            stf.Session(graph=graph) as sess:
+        stack = sparse_moe_lm._SparseMoEStack(
+            cfg, stf.bfloat16, "causal_lm",
+            sparse_moe_lm.attn_tile_pages(kw["pages_per_seq"])
+            * kw["page_len"])
+        prog = causal_lm.build_paged_lm_program(
+            stack, page_len=kw["page_len"],
+            pages_per_seq=kw["pages_per_seq"], num_pages=kw["num_pages"],
+            decode_bucket_sizes=(16,), prefill_bucket_sizes=(1,),
+            compute_dtype=stf.bfloat16)
+        caches = [c for group in prog["caches"] for c in group]
+        state = {v.var_name: aval(v.shape.as_list(),
+                                  v.dtype.base_dtype.np_dtype)
+                 for v in stf.global_variables()}
+        state.update({c.name: aval(c.stored_shape, c.dtype.np_dtype)
+                      for c in caches})
+
+        def compiled(fetches, feeds):
+            step = sess.plan(fetches, feeds=feeds)._step
+            feed_avals = {
+                t.name: aval(t.shape.as_list(), t.dtype.base_dtype.np_dtype)
+                for t in step.feed_tensors}
+            return step.jitted.lower(
+                dict(state), feed_avals, aval((), jax.random.key(0).dtype),
+                aval((), np.uint32)).compile()
+
+        d, p = prog["decode"][16], prog["prefill"][1]
+        programs = {
+            "decode16": compiled(
+                {"next_tok": d["next_tok"], "logp": d["logp"], **d["extra"]},
+                [d["tok"], d["pos"], d["tables"], d["dst"], d["off"]]),
+            "prefill1": compiled(
+                {"done": p["op"]},
+                [p["tok"], p["base"], p["tables"], p["dst"]]),
+        }
+    state_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                      for a in state.values())
+    return {"programs": programs, "n_state": len(state),
+            "state_bytes": state_bytes,
+            "kv_pool": caches[0].stored_shape}
+
+
+@pytest.mark.parametrize("program", ["decode16", "prefill1"])
+def test_sparse_moe_programs_compile_and_fit(sparse_moe_programs, program):
+    compiled = sparse_moe_programs["programs"][program]
+    text = compiled.as_text()
+    # weights + three caches a layer: 8.75 + 5.08 GB, all donated through
+    assert 13.5e9 < sparse_moe_programs["state_bytes"] < 14.2e9
+    header = text.split("\n", 1)[0]
+    assert header.count("-alias)") == sparse_moe_programs["n_state"]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert sparse_moe_programs["state_bytes"] + temp < 16.0e9, temp
+    # the experts run as the compiler's grouped matmul, twice a layer
+    assert text.count("ragged-dot-metadata") >= 1
+    pool = sparse_moe_programs["kv_pool"]
+    assert pool == (761, 512, 512)
+    pool_elems = int(np.prod(pool))
+    copies = []
+    for ln in text[text.index("\nENTRY "):].splitlines():
+        m = _HLO_INSTR.match(ln)
+        if m and m.group(1) and m.group(2) == "copy" and pool_elems == int(
+                np.prod([int(x) for x in m.group(1).split(",")])):
+            copies.append(ln.strip()[:120])
+    assert not copies, copies[:3]
+    if program == "decode16":
+        # the selection is a sort of (16, 33792) scores a layer, and only
+        # the selected rows are read: no (16, 33792, 512) view anywhere
+        assert " sort(" in text
+        assert "bf16[16,33792,512]" not in text
+        assert "bf16[16,2048,512]" in text
