@@ -58,6 +58,11 @@ metric_autotune_runs = monitoring.Counter(
     "/stf/kernels/autotune_runs",
     "micro-autotune measurements (both lowerings timed once per "
     "ungated (op, shape, dtype, backend) key)", "op")
+metric_flash_tiles = monitoring.Counter(
+    "/stf/kernels/flash_tiles",
+    "flash-attention kernel traces by the regime and tiles the shape "
+    "rule chose (ops/pallas/flash_attention.tiles)",
+    "regime", "block_q", "block_k", "heads_per_step")
 
 # -- mode ---------------------------------------------------------------------
 
@@ -484,6 +489,8 @@ def snapshot() -> Dict[str, Any]:
                 for labels, cell in metric_fallback.cells().items()}
     autotune = {labels[0]: cell.value()
                 for labels, cell in metric_autotune_runs.cells().items()}
+    flash_tiles = {"{}:{}x{}x{}".format(*labels): cell.value()
+                   for labels, cell in metric_flash_tiles.cells().items()}
     return {
         "mode": default_mode(),
         "backend": _backend_if_initialized(),
@@ -491,6 +498,7 @@ def snapshot() -> Dict[str, Any]:
         "routed": routed,
         "fallback": fallback,
         "autotune_runs": autotune,
+        "flash_tiles": flash_tiles,
         "measured": {f"{op}|{bk}|{kind}": v["verdict"]
                      for (op, _k, bk, kind), v in _measured.items()},
     }

@@ -4,15 +4,36 @@ Replaces the reference's attention-as-composed-matmuls path (the reference
 has no fused attention; BERT-style models there materialise the [B,H,S,S]
 score matrix through batch_matmul + softmax kernels,
 ref: tensorflow/core/kernels/{batch_matmul_op,softmax_op}.cc). On TPU the
-materialised scores blow HBM bandwidth at long sequence, so we compute
-attention with the FlashAttention-2 online-softmax recurrence, tiled to the
-MXU.
+materialised scores blow HBM bandwidth at long sequence, so scores live
+only in VMEM, a tile at a time.
 
-K/V genuinely stream: the grid's innermost dimension walks K/V blocks (TPU
-grids execute sequentially per core), the online-softmax state (m, l, acc)
-lives in VMEM scratch across those iterations, and the output block flushes
-on the last one. VMEM per program is O(block_q*d + block_k*d) independent of
-sequence length. Causally-dead blocks are predicated off with pl.when.
+Tiles are sized from the shapes (:func:`tiles`, the one place they come
+from): the largest whose grid step fits :data:`VMEM_BUDGET` by
+:func:`vmem_bytes`. A grid step costs ~0.35 us before it computes
+anything, so at 128 x 128 tiles (the fixed size until PR 28) a s512 head
+was 16 steps of 10 ns of MXU each and the kernels ran at 3 % of their
+roofline. Two regimes follow from the rule, and what each makes static is
+taken out at trace time:
+
+* **single pass** — the whole key range is ONE tile (s <= 1024 at
+  head_dim 64/128 in bfloat16). Softmax is computed outright: no running
+  max/sum, no rescale, no scratch. The backward recomputes P and dS once
+  and ONE call emits dQ, dK and dV (five matmuls, not seven). Where one
+  tile covers a whole head, a step walks as many heads of a batch row as
+  fit the budget. VMEM grows with the sequence: a (512, 512) float32
+  score tile is 1 MB, and the backward holds three.
+* **streamed** — longer sequences (ring attention's blocks, 2048-8192):
+  FlashAttention-2's online softmax. The grid's innermost dimension walks
+  K/V tiles (TPU grids execute sequentially per core), the state
+  (m, l, acc) lives in VMEM scratch across those steps, the output block
+  flushes on the last one, and the backward is two calls (dK/dV with the
+  K/V tile resident, dQ with the Q tile resident). VMEM per step is
+  O(block_q * block_k), independent of the sequence. Causally-dead tiles
+  are predicated off with pl.when.
+
+In both, the key-length mask is emitted only when the key range was
+padded and the causal mask only when asked for. Which regime and tiles a
+trace took is counted on ``/stf/kernels/flash_tiles``.
 
 Matmul policy: operands stay in the input dtype (bf16 runs the MXU at
 native rate), accumulation is f32 via preferred_element_type, and
@@ -29,7 +50,7 @@ Padded keys are masked in-kernel against the true KV length (static), so
 softmax stays NaN-free. Per-row stats (m, l, lse, delta) are kept as
 (rows, 1) tiles — Mosaic requires sublane×lane-legal block shapes.
 
-Backward follows FlashAttention-2: recompute P block-wise from (Q,K,lse),
+Backward follows FlashAttention-2: recompute P tile-wise from (Q,K,lse),
 dV = P^T dO, dP = dO V^T, dS = P * (dP - delta), dQ = dS K, dK = dS^T Q,
 with delta = rowsum(dO * O) precomputed.
 """
@@ -44,13 +65,120 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...kernels import registry as _kreg
 from . import common
-from .common import (NEG_INF, cdiv, counter_keep_mask, mix32, pad_dim,
-                     round_up)
+from .common import NEG_INF, cdiv, counter_keep_mask, pad_dim, round_up
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
 _HI = jax.lax.Precision.HIGHEST
+
+# ---------------------------------------------------------------------------
+# The tile rule
+# ---------------------------------------------------------------------------
+
+# A grid step's blocks, scratch and live score tiles are sized to this
+# much VMEM, under Mosaic's 16 MiB scoped default (v5e). The estimate is
+# an upper one (every score-sized value counted live at once), so the
+# rest is room for what it cannot see: relayouts, the transposes of the
+# backward's P and dS. Measured at (48, 12, 512, 64) bfloat16: the
+# backward at four heads a step (estimated 15.8 MiB) still compiled, at
+# six (21.3) Mosaic ran out of VMEM.
+VMEM_BUDGET = 14 * 1024 * 1024
+_LANES = 128
+# (query rows, keys) a step may hold at most, tried in order: the
+# largest sides that measured faster than the next smaller (v5e, bf16,
+# head_dim 64: s2048 forward 0.64 ms at 512x1024, 0.99 at 512x512, 2.05
+# at 256x256, 4.1 at 128x128; 1024x1024 no faster than 512x1024).
+_STREAMED = ((512, 1024), (512, 512), (256, 256), (128, 128))
+_SINGLE_PASS_MIN_Q = 256  # fewer query rows a step: the step count is back
+
+
+def _split(length, cap, align):
+    """Block size that covers ``length`` in the fewest blocks of at most
+    ``cap``, evenly sized (padding stays under one ``align`` a block)."""
+    padded = round_up(length, align)
+    return round_up(cdiv(padded, cdiv(padded, cap)), align)
+
+
+def vmem_bytes(block_q, block_k, head_dim, dtype, backward, heads=1):
+    """Upper estimate of one grid step's VMEM, from the shapes alone:
+    every pipelined block of its ``heads`` heads twice (double
+    buffering), the float32 accumulators, and the score-sized values of
+    the one head at work live at once. A minor dimension occupies whole
+    128-lane tiles, so a (rows, 1) statistics column costs as much as a
+    (rows, 128) block and head_dim 64 as much as 128."""
+    item = jnp.dtype(dtype).itemsize
+    lanes = round_up(head_dim, _LANES)
+    q_blk, k_blk = block_q * lanes * item, block_k * lanes * item
+    q_acc, k_acc = block_q * lanes * 4, block_k * lanes * 4
+    column = block_q * _LANES * 4
+    bias = 2 * 8 * block_k * 4
+    tile = block_q * block_k
+    if backward:
+        # q, dO, dQ; k, v, dK, dV; lse, delta | dK, dV, dQ accumulators |
+        # P, dP, dS in float32 and P, dS cast for their matmuls
+        return (2 * heads * (3 * q_blk + 4 * k_blk + 2 * column) + bias
+                + 2 * k_acc + q_acc + tile * (3 * 4 + 2 * item))
+    # q, o; k, v; lse | acc, m, l | S, P in float32 and P cast
+    return (2 * heads * (2 * q_blk + 2 * k_blk + column) + bias
+            + q_acc + 2 * column + tile * (2 * 4 + item))
+
+
+def _fits(block_q, block_k, head_dim, dtype, heads=1):
+    """Whether the backward step — the larger of the two — fits."""
+    return vmem_bytes(block_q, block_k, head_dim, dtype, True,
+                      heads) <= VMEM_BUDGET
+
+
+def _heads_that_fit(num_heads, block_q, block_k, head_dim, dtype):
+    """Heads a whole-head step (one tile covers a head) may hold: the
+    largest divisor of a batch row's heads (they share its bias row)
+    that fits the budget. Fewer, longer steps: at (48, 12, 512, 64) a
+    forward call measured 1.35 ms at one head a step, 1.27 at two, 1.22
+    at four; at (192, 12, 128, 64) 1.91, 1.48, 1.28 and 1.19 at twelve
+    (v5e, bf16)."""
+    return max(g for g in range(1, num_heads + 1)
+               if num_heads % g == 0
+               and (g == 1 or _fits(block_q, block_k, head_dim, dtype, g)))
+
+
+def tiles(q_len, kv_len, head_dim, dtype, causal=False, num_heads=1,
+          align=_LANES):
+    """(block_q, block_k, heads a step) for these shapes — the one place
+    tile sizes come from. The largest whose backward step fits
+    :data:`VMEM_BUDGET`:
+
+    * the whole key range in ONE tile whenever that fits with at least
+      ``_SINGLE_PASS_MIN_Q`` query rows a step (or all of them) — the
+      *single-pass* regime: softmax is computed outright, no running
+      statistics, and one backward call emits dQ, dK and dV. Where one
+      tile covers a whole head, a step takes as many heads of a batch
+      row as fit (:func:`_heads_that_fit`);
+    * else the first of ``_STREAMED`` that fits — the *streamed* regime
+      (FlashAttention-2's online softmax over key tiles), one head a
+      step.
+
+    Depends on shapes and dtype alone. ``causal`` does not change the
+    choice: at (8, 16, 512, 64) the single pass measured 0.23 ms forward
+    against 0.51 (256-wide tiles) and 0.84 (128-wide) although it
+    computes the masked half too, and streamed tiles skip dead blocks
+    whatever their size.
+    """
+    del causal
+    whole_k = round_up(kv_len, align)
+    for cap in (_STREAMED[0][0], _SINGLE_PASS_MIN_Q):
+        block_q = _split(q_len, cap, align)
+        if _fits(block_q, whole_k, head_dim, dtype):
+            heads = 1
+            if block_q >= q_len:
+                heads = _heads_that_fit(num_heads, block_q, whole_k,
+                                        head_dim, dtype)
+            return block_q, whole_k, heads
+    for cap_q, cap_k in _STREAMED:
+        block_q, block_k = _split(q_len, cap_q, align), _split(kv_len, cap_k,
+                                                               align)
+        if _fits(block_q, block_k, head_dim, dtype):
+            break
+    return block_q, block_k, 1
 
 
 def _dot(a, b, contract):
@@ -64,49 +192,109 @@ def _dot(a, b, contract):
         preferred_element_type=jnp.float32, precision=precision)
 
 
-def _score_mask(s, qi, kb, block_q, block_k, kv_true, causal):
-    """Apply KV-length and causal masking to a (block_q, block_k) score
-    tile for Q block qi / K block kb. Single source of truth for fwd+bwd."""
-    shape = (s.shape[0], s.shape[1])
-    span_q = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    span_k = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    mask = span_k < kv_true
+_NT = ((1,), (1,))   # a @ b.T
+_NN = ((1,), (0,))   # a @ b
+_TN = ((0,), (0,))   # a.T @ b
+
+
+def _score_mask(s, row0, col0, kv_limit, causal):
+    """KV-length and causal masking of a score tile whose first element
+    is global (row0, col0). Single source of truth for fwd+bwd.
+    ``kv_limit`` is None when the key range holds no padded key: with
+    ``causal`` off nothing is emitted at all."""
+    mask = None
+    if kv_limit is not None or causal:
+        cols = col0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    if kv_limit is not None:
+        mask = cols < kv_limit
     if causal:
-        mask = mask & (span_q >= span_k)
-    return jnp.where(mask, s, NEG_INF)
+        rows = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        mask = (rows >= cols) if mask is None else mask & (rows >= cols)
+    return s if mask is None else jnp.where(mask, s, NEG_INF)
 
 
-_mix32 = mix32  # moved to common.py (shared with the fused dropout kernel)
+def _scores(q, k, bias, row0, col0, *, sm_scale, kv_limit, causal):
+    """Masked float32 scores of the tile at global (row0, col0)."""
+    s = _dot(q, k, _NT) * sm_scale                     # (rows, cols) f32
+    if bias is not None:
+        s = s + bias                                   # (1, cols) f32
+    return _score_mask(s, row0, col0, kv_limit, causal)
 
 
-def _keep_mask(seed, bh, qi, kb, block_q, block_k, keep_prob):
-    """Deterministic dropout keep-mask for score tile (qi, kb) of head bh.
+def _keep_mask(seed, bh, row0, col0, shape, keep_prob):
+    """Deterministic dropout keep-mask for the score tile of head bh whose
+    first element is global (row0, col0).
 
     Counter-based on GLOBAL (row, col) score indices (common.py
     counter_keep_mask) — regenerated bit-identically in the backward
-    kernels regardless of grid order AND by the composed-XLA fallback
-    lowering (attention_xla), so swapping implementations through the
-    kernel registry preserves seeded runs exactly. No mask tensor is
-    ever materialized in HBM."""
-    shape = (block_q, block_k)
-    rows = (qi.astype(jnp.uint32) * jnp.uint32(block_q) +
+    kernels regardless of tile sizes and grid order AND by the
+    composed-XLA fallback lowering (attention_xla), so swapping
+    implementations through the kernel registry preserves seeded runs
+    exactly. No mask tensor is ever materialized in HBM."""
+    rows = (jnp.asarray(row0).astype(jnp.uint32) +
             jax.lax.broadcasted_iota(jnp.uint32, shape, 0))
-    cols = (kb.astype(jnp.uint32) * jnp.uint32(block_k) +
+    cols = (jnp.asarray(col0).astype(jnp.uint32) +
             jax.lax.broadcasted_iota(jnp.uint32, shape, 1))
     return counter_keep_mask(seed, bh, rows, cols, keep_prob)
 
 
-# ---------------------------------------------------------------------------
-# Forward kernel: grid (bh, q_blocks, k_blocks), innermost streams K/V
-# ---------------------------------------------------------------------------
+def _dropped(x, seed_ref, bh, row0, col0, dropout_rate):
+    """``x`` (a score-shaped tile at global (row0, col0)) with this
+    head's dropout applied: kept entries scaled by 1/keep_prob."""
+    if dropout_rate == 0.0:
+        return x
+    keep_prob = 1.0 - dropout_rate
+    keep = _keep_mask(seed_ref[0], bh, row0, col0, x.shape, keep_prob)
+    return jnp.where(keep, x * (1.0 / keep_prob), 0.0)
 
-def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, kv_true, num_kb,
-                has_bias, dropout_rate):
+
+def _unpack(refs, n_main, has_bias, dropout_rate):
+    """(main input refs, bias_ref, seed_ref, the rest) of a kernel's
+    positional refs: optional bias and seed follow the main inputs."""
     it = iter(refs)
-    q_ref, k_ref, v_ref = next(it), next(it), next(it)
+    main = [next(it) for _ in range(n_main)]
     bias_ref = next(it) if has_bias else None
     seed_ref = next(it) if dropout_rate > 0.0 else None
-    o_ref, lse_ref, m_scr, l_scr, acc_scr = it
+    return main, bias_ref, seed_ref, list(it)
+
+
+# ---------------------------------------------------------------------------
+# Forward kernels
+# ---------------------------------------------------------------------------
+
+def _fwd_single_kernel(*refs, sm_scale, causal, block_q, kv_limit, heads,
+                       has_bias, dropout_rate):
+    # single pass, grid (bh / heads, q_blocks): every key of a row is in
+    # this tile, so softmax is computed outright — no running max/sum,
+    # no rescale, no scratch. Blocks hold ``heads`` heads of one batch
+    # row, walked in turn.
+    (q_ref, k_ref, v_ref), bias_ref, seed_ref, (o_ref, lse_ref) = _unpack(
+        refs, 3, has_bias, dropout_rate)
+    row0 = pl.program_id(1) * block_q
+    bias = bias_ref[:] if has_bias else None
+    for g in range(heads):
+        bh = pl.program_id(0) * heads + g
+        v = v_ref[g]
+        s = _scores(q_ref[g], k_ref[g], bias, row0, 0, sm_scale=sm_scale,
+                    kv_limit=kv_limit, causal=causal)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.exp(s - m)
+        l = jnp.sum(p, axis=-1, keepdims=True)         # >= 1: exp(0) at m
+        # the denominator is the UN-dropped sum: dropout scales
+        # normalized probs, and elementwise 0/(1/keep) commutes with
+        # the per-row division by l.
+        p = _dropped(p, seed_ref, bh, row0, 0, dropout_rate)
+        o_ref[g] = (_dot(p.astype(v.dtype), v, _NN) / l).astype(o_ref.dtype)
+        lse_ref[g] = m + jnp.log(l)
+
+
+def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, kv_limit, num_kb,
+                has_bias, dropout_rate):
+    # streamed, grid (bh, q_blocks, k_blocks): the innermost dimension
+    # walks K/V tiles, the online-softmax state lives in scratch.
+    (q_ref, k_ref, v_ref), bias_ref, seed_ref, rest = _unpack(
+        refs, 3, has_bias, dropout_rate)
+    o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     bh = pl.program_id(0)
     qi = pl.program_id(1)
     kb = pl.program_id(2)
@@ -122,30 +310,20 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, kv_true, num_kb,
 
     @pl.when(live)
     def _():
-        q = q_ref[:]
-        k = k_ref[:]
+        row0, col0 = qi * block_q, kb * block_k
         v = v_ref[:]
-        s = _dot(q, k, ((1,), (1,))) * sm_scale        # (block_q, block_k)
-        if has_bias:
-            s = s + bias_ref[:]                        # (1, block_k) f32
-        s = _score_mask(s, qi, kb, block_q, block_k, kv_true, causal)
-
+        s = _scores(q_ref[:], k_ref[:], bias_ref[:] if has_bias else None,
+                    row0, col0, sm_scale=sm_scale, kv_limit=kv_limit,
+                    causal=causal)
         m_prev, l_prev = m_scr[:], l_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)                # (block_q, 1)
         m_scr[:] = m_new
-        # denominator accumulates the UN-dropped sum: dropout scales
-        # normalized probs, and elementwise 0/(1/keep) commutes with the
-        # final per-row division by l.
+        # un-dropped denominator, as in the single pass
         l_scr[:] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        if dropout_rate > 0.0:
-            keep_prob = 1.0 - dropout_rate
-            keep = _keep_mask(seed_ref[0], bh, qi, kb, block_q, block_k,
-                              keep_prob)
-            p = jnp.where(keep, p * (1.0 / keep_prob), 0.0)
-        acc_scr[:] = acc_scr[:] * alpha + _dot(
-            p.astype(v.dtype), v, ((1,), (0,)))
+        p = _dropped(p, seed_ref, bh, row0, col0, dropout_rate)
+        acc_scr[:] = acc_scr[:] * alpha + _dot(p.astype(v.dtype), v, _NN)
 
     @pl.when(kb == num_kb - 1)
     def _():
@@ -154,54 +332,76 @@ def _fwd_kernel(*refs, sm_scale, causal, block_q, block_k, kv_true, num_kb,
         lse_ref[:] = m_scr[:] + jnp.log(l_safe)
 
 
+def _aux(bias, seed, block_k, batch_of, kb_of):
+    """Specs and operands of the optional key bias and dropout seed.
+    ``batch_of`` maps a step's leading grid id to its batch row,
+    ``kb_of`` its inner grid ids to its key block."""
+    specs, ops = [], []
+    if bias is not None:
+        specs.append(pl.BlockSpec(
+            (None, 1, block_k),
+            lambda b, *ij: (batch_of(b), 0, kb_of(*ij))))
+        ops.append(bias)
+    if seed is not None:
+        specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+        ops.append(seed)
+    return specs, ops
+
+
 def _fwd(q, k, v, bias, seed, sm_scale, causal, block_q, block_k, kv_true,
-         dropout_rate, num_heads):
+         dropout_rate, num_heads, heads):
     bh, q_len, d = q.shape
     kv_pad_len = k.shape[1]
-    num_kb = cdiv(kv_pad_len, block_k)
-    has_bias = bias is not None
-    kernel = functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
-                               block_q=block_q, block_k=block_k,
-                               kv_true=kv_true, num_kb=num_kb,
-                               has_bias=has_bias, dropout_rate=dropout_rate)
-    in_specs = [
-        pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((None, block_k, d), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((None, block_k, d), lambda b, i, j: (b, j, 0)),
-    ]
-    operands = [q, k, v]
-    if has_bias:
-        in_specs.append(pl.BlockSpec(
-            (None, 1, block_k),
-            lambda b, i, j, nh=num_heads: (b // nh, 0, j)))
-        operands.append(bias)
-    if dropout_rate > 0.0:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-        operands.append(seed)
+    num_qb, num_kb = cdiv(q_len, block_q), cdiv(kv_pad_len, block_k)
+    static = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
+                  kv_limit=None if kv_true == kv_pad_len else kv_true,
+                  has_bias=bias is not None, dropout_rate=dropout_rate)
+    if num_kb == 1:
+        kernel = functools.partial(_fwd_single_kernel, heads=heads, **static)
+        grid = (bh // heads, num_qb)
+        aux_specs, aux_ops = _aux(bias, seed, block_k,
+                                  lambda b: b * heads // num_heads,
+                                  lambda i: 0)
+        lead, q_map, k_map = heads, (lambda b, i: (b, i, 0)), (
+            lambda b, i: (b, 0, 0))
+        scratch = []
+    else:
+        kernel = functools.partial(_fwd_kernel, block_k=block_k,
+                                   num_kb=num_kb, **static)
+        grid = (bh, num_qb, num_kb)
+        aux_specs, aux_ops = _aux(bias, seed, block_k,
+                                  lambda b: b // num_heads, lambda i, j: j)
+        lead, q_map, k_map = None, (lambda b, i, j: (b, i, 0)), (
+            lambda b, i, j: (b, j, 0))
+        scratch = [
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
+        ]
     o, lse = pl.pallas_call(
         kernel,
-        grid=(bh, cdiv(q_len, block_q), num_kb),
-        in_specs=in_specs,
+        grid=grid,
+        in_specs=[
+            pl.BlockSpec((lead, block_q, d), q_map),
+            pl.BlockSpec((lead, block_k, d), k_map),
+            pl.BlockSpec((lead, block_k, d), k_map),
+        ] + aux_specs,
         out_specs=[
-            pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((lead, block_q, d), q_map),
+            pl.BlockSpec((lead, block_q, 1), q_map),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, q_len, d), q.dtype),
             jax.ShapeDtypeStruct((bh, q_len, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
+        scratch_shapes=scratch,
         cost_estimate=pl.CostEstimate(
             flops=int(4 * bh * q_len * kv_true * d * (0.5 if causal else 1.0)),
             bytes_accessed=(q.size + k.size + v.size) * q.dtype.itemsize,
             transcendentals=bh * q_len * kv_true),
         interpret=common.use_interpret(),
         name="stf_flash_attention_fwd",
-    )(*operands)
+    )(q, k, v, *aux_ops)
     return o, lse
 
 
@@ -209,15 +409,74 @@ def _fwd(q, k, v, bias, seed, sm_scale, causal, block_q, block_k, kv_true,
 # Backward kernels
 # ---------------------------------------------------------------------------
 
-def _bwd_dkdv_kernel(*refs, sm_scale, causal, block_q, block_k, kv_true,
+def _bwd_tile(q, k, v, do, lse, delta, bias, seed_ref, bh, row0, col0, *,
+              sm_scale, kv_limit, causal, dropout_rate, want_p):
+    """(P as dV's matmul takes it, or None; dS) of one score tile:
+    recompute P from (Q, K, lse), dP = dO V^T, dS = P∘(dP − delta)."""
+    s = _scores(q, k, bias, row0, col0, sm_scale=sm_scale,
+                kv_limit=kv_limit, causal=causal)
+    p = jnp.exp(s - lse)                               # (rows, cols) f32
+    dp = _dropped(_dot(do, v, _NT), seed_ref, bh, row0, col0, dropout_rate)
+    ds = p * (dp - delta) * sm_scale
+    pc = None
+    if want_p:
+        pc = _dropped(p, seed_ref, bh, row0, col0,
+                      dropout_rate).astype(do.dtype)
+    return pc, ds.astype(q.dtype)
+
+
+def _bwd_single_kernel(*refs, sm_scale, causal, block_q, kv_limit, num_qb,
+                       heads, has_bias, dropout_rate):
+    # single pass, grid (bh / heads, q_blocks): the whole key range is
+    # one tile, so the tile a dK/dV step recomputes is the very tile dQ
+    # needs — one recompute of P and dS feeds all three gradients.
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), bias_ref, seed_ref, \
+        rest = _unpack(refs, 6, has_bias, dropout_rate)
+    dq_ref, dk_ref, dv_ref, *scratch = rest
+    qb = pl.program_id(1)
+    bias = bias_ref[:] if has_bias else None
+
+    def gradients(g):
+        q, k, do = q_ref[g], k_ref[g], do_ref[g]
+        pc, ds = _bwd_tile(
+            q, k, v_ref[g], do, lse_ref[g], delta_ref[g], bias, seed_ref,
+            pl.program_id(0) * heads + g, qb * block_q, 0,
+            sm_scale=sm_scale, kv_limit=kv_limit, causal=causal,
+            dropout_rate=dropout_rate, want_p=True)
+        dq_ref[g] = _dot(ds, k, _NN).astype(dq_ref.dtype)
+        return _dot(ds, q, _TN), _dot(pc, do, _TN)     # dK, dV: (keys, d)
+
+    if not scratch:            # a head is one step: nothing accumulates
+        for g in range(heads):
+            dk, dv = gradients(g)
+            dk_ref[g] = dk.astype(dk_ref.dtype)
+            dv_ref[g] = dv.astype(dv_ref.dtype)
+        return
+
+    dk_scr, dv_scr = scratch
+
+    @pl.when(qb == 0)
+    def _():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    dk, dv = gradients(0)
+    dk_scr[:] += dk
+    dv_scr[:] += dv
+
+    @pl.when(qb == num_qb - 1)
+    def _():
+        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+
+def _bwd_dkdv_kernel(*refs, sm_scale, causal, block_q, block_k, kv_limit,
                      num_qb, has_bias, dropout_rate):
-    # grid (bh, k_blocks, q_blocks): one K/V block, streaming Q/dO blocks.
-    it = iter(refs)
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = (
-        next(it), next(it), next(it), next(it), next(it), next(it))
-    bias_ref = next(it) if has_bias else None
-    seed_ref = next(it) if dropout_rate > 0.0 else None
-    dk_ref, dv_ref, dk_scr, dv_scr = it
+    # streamed, grid (bh, k_blocks, q_blocks): one K/V block, streaming
+    # Q/dO blocks.
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), bias_ref, seed_ref, \
+        rest = _unpack(refs, 6, has_bias, dropout_rate)
+    dk_ref, dv_ref, dk_scr, dv_scr = rest
     bh = pl.program_id(0)
     ki = pl.program_id(1)
     qb = pl.program_id(2)
@@ -231,31 +490,17 @@ def _bwd_dkdv_kernel(*refs, sm_scale, causal, block_q, block_k, kv_true,
 
     @pl.when(live)
     def _():
-        k = k_ref[:]
-        v = v_ref[:]
-        q = q_ref[:]
-        do = do_ref[:]
-        lse = lse_ref[:]                               # (bq, 1)
-        delta = delta_ref[:]
-        s = _dot(q, k, ((1,), (1,))) * sm_scale
-        if has_bias:
-            s = s + bias_ref[:]
-        s = _score_mask(s, qb, ki, block_q, block_k, kv_true, causal)
-        p = jnp.exp(s - lse)                           # (bq, bk) f32
-        dp = _dot(do, v, ((1,), (1,)))                 # (bq, bk)
-        if dropout_rate > 0.0:
-            keep_prob = 1.0 - dropout_rate
-            # NOTE (qb, ki) order: the mask is keyed on (q-block, k-block)
-            # exactly as in the forward, though this grid iterates k outer.
-            keep = _keep_mask(seed_ref[0], bh, qb, ki, block_q, block_k,
-                              keep_prob)
-            pc = jnp.where(keep, p * (1.0 / keep_prob), 0.0).astype(do.dtype)
-            dp = jnp.where(keep, dp * (1.0 / keep_prob), 0.0)
-        else:
-            pc = p.astype(do.dtype)
-        dv_scr[:] += _dot(pc, do, ((0,), (0,)))        # (bk, d)
-        ds = (p * (dp - delta) * sm_scale).astype(q.dtype)
-        dk_scr[:] += _dot(ds, q, ((0,), (0,)))         # (bk, d)
+        q, do = q_ref[:], do_ref[:]
+        # the mask is keyed on global (row, col) exactly as in the
+        # forward, though this grid iterates k outer.
+        pc, ds = _bwd_tile(
+            q, k_ref[:], v_ref[:], do, lse_ref[:], delta_ref[:],
+            bias_ref[:] if has_bias else None, seed_ref, bh,
+            qb * block_q, ki * block_k, sm_scale=sm_scale,
+            kv_limit=kv_limit, causal=causal, dropout_rate=dropout_rate,
+            want_p=True)
+        dv_scr[:] += _dot(pc, do, _TN)                 # (bk, d)
+        dk_scr[:] += _dot(ds, q, _TN)                  # (bk, d)
 
     @pl.when(qb == num_qb - 1)
     def _():
@@ -263,15 +508,13 @@ def _bwd_dkdv_kernel(*refs, sm_scale, causal, block_q, block_k, kv_true,
         dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, kv_true,
+def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, kv_limit,
                    num_kb, has_bias, dropout_rate):
-    # grid (bh, q_blocks, k_blocks): one Q block, streaming K/V blocks.
-    it = iter(refs)
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = (
-        next(it), next(it), next(it), next(it), next(it), next(it))
-    bias_ref = next(it) if has_bias else None
-    seed_ref = next(it) if dropout_rate > 0.0 else None
-    dq_ref, dq_scr = it
+    # streamed, grid (bh, q_blocks, k_blocks): one Q block, streaming
+    # K/V blocks.
+    (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), bias_ref, seed_ref, \
+        rest = _unpack(refs, 6, has_bias, dropout_rate)
+    dq_ref, dq_scr = rest
     bh = pl.program_id(0)
     qi = pl.program_id(1)
     kb = pl.program_id(2)
@@ -284,25 +527,14 @@ def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, kv_true,
 
     @pl.when(live)
     def _():
-        q = q_ref[:]
-        do = do_ref[:]
-        lse = lse_ref[:]
-        delta = delta_ref[:]
         k = k_ref[:]
-        v = v_ref[:]
-        s = _dot(q, k, ((1,), (1,))) * sm_scale
-        if has_bias:
-            s = s + bias_ref[:]
-        s = _score_mask(s, qi, kb, block_q, block_k, kv_true, causal)
-        p = jnp.exp(s - lse)
-        dp = _dot(do, v, ((1,), (1,)))
-        if dropout_rate > 0.0:
-            keep_prob = 1.0 - dropout_rate
-            keep = _keep_mask(seed_ref[0], bh, qi, kb, block_q, block_k,
-                              keep_prob)
-            dp = jnp.where(keep, dp * (1.0 / keep_prob), 0.0)
-        ds = (p * (dp - delta) * sm_scale).astype(k.dtype)
-        dq_scr[:] += _dot(ds, k, ((1,), (0,)))
+        _, ds = _bwd_tile(
+            q_ref[:], k, v_ref[:], do_ref[:], lse_ref[:], delta_ref[:],
+            bias_ref[:] if has_bias else None, seed_ref, bh,
+            qi * block_q, kb * block_k, sm_scale=sm_scale,
+            kv_limit=kv_limit, causal=causal, dropout_rate=dropout_rate,
+            want_p=False)
+        dq_scr[:] += _dot(ds, k, _NN)
 
     @pl.when(kb == num_kb - 1)
     def _():
@@ -310,101 +542,101 @@ def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, kv_true,
 
 
 def _bwd(sm_scale, causal, block_q, block_k, kv_true, dropout_rate,
-         num_heads, res, g):
+         num_heads, heads, res, g):
     q, k, v, bias, seed, o, lse = res
     do = g.astype(jnp.float32)
     delta = jnp.sum(do * o.astype(jnp.float32), axis=-1,
                     keepdims=True)                          # (bh, q_len, 1)
     return _bwd_with_delta(sm_scale, causal, block_q, block_k, kv_true,
-                           dropout_rate, num_heads,
+                           dropout_rate, num_heads, heads,
                            (q, k, v, bias, seed, lse), g, delta)
 
 
 def _bwd_with_delta(sm_scale, causal, block_q, block_k, kv_true,
-                    dropout_rate, num_heads, res, g, delta):
+                    dropout_rate, num_heads, heads, res, g, delta):
     """Kernel plumbing shared by the plain vjp (delta = rowsum(dO∘O)) and
     the (o, lse) vjp (delta shifted by −dlse)."""
     q, k, v, bias, seed, lse = res
     bh, q_len, d = q.shape
     kv_pad_len = k.shape[1]
-    has_bias = bias is not None
     num_qb = cdiv(q_len, block_q)
     num_kb = cdiv(kv_pad_len, block_k)
+    static = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
+                  kv_limit=None if kv_true == kv_pad_len else kv_true,
+                  has_bias=bias is not None, dropout_rate=dropout_rate)
+    operands = (q, k, v, g, lse, delta)
+    q_shape = jax.ShapeDtypeStruct((bh, q_len, d), q.dtype)
+    k_shape = jax.ShapeDtypeStruct((bh, kv_pad_len, d), k.dtype)
 
-    def aux(kb_index_map):
-        """Optional bias/seed specs+operands; kb_index_map maps grid ids to
-        the k-block index (differs between the two bwd grids)."""
-        specs, ops = [], []
-        if has_bias:
-            specs.append(pl.BlockSpec(
-                (None, 1, block_k),
-                lambda b, i, j, nh=num_heads: (b // nh, 0,
-                                               kb_index_map(i, j))))
-            ops.append(bias)
-        if dropout_rate > 0.0:
-            specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
-            ops.append(seed)
-        return specs, ops
+    def specs(lead, qb_of, kb_of):
+        """(in_specs of the six operands, a dQ-shaped and a dK-shaped
+        out_spec) for a grid whose inner ids map to (q block, k block)."""
+        def q_spec(last=d):
+            return pl.BlockSpec((lead, block_q, last),
+                                lambda b, *ij: (b, qb_of(*ij), 0))
+        k_spec = pl.BlockSpec((lead, block_k, d),
+                              lambda b, *ij: (b, kb_of(*ij), 0))
+        return ([q_spec(), k_spec, k_spec, q_spec(), q_spec(1), q_spec(1)],
+                q_spec(), k_spec)
 
-    dkdv = functools.partial(_bwd_dkdv_kernel, sm_scale=sm_scale,
-                             causal=causal, block_q=block_q, block_k=block_k,
-                             kv_true=kv_true, num_qb=num_qb,
-                             has_bias=has_bias, dropout_rate=dropout_rate)
-    aux_specs, aux_ops = aux(lambda i, j: i)  # grid (bh, kb, qb)
-    dk, dv = pl.pallas_call(
-        dkdv,
-        grid=(bh, num_kb, num_qb),
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((None, block_q, 1), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((None, block_q, 1), lambda b, i, j: (b, j, 0)),
-        ] + aux_specs,
-        out_specs=[
-            pl.BlockSpec((None, block_k, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, i, j: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, kv_pad_len, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, kv_pad_len, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
-        interpret=common.use_interpret(),
-        name="stf_flash_attention_bwd_dkv",
-    )(q, k, v, g, lse, delta, *aux_ops)
+    if num_kb == 1:
+        in_specs, dq_spec, dk_spec = specs(heads, lambda i: i, lambda i: 0)
+        aux_specs, aux_ops = _aux(bias, seed, block_k,
+                                  lambda b: b * heads // num_heads,
+                                  lambda i: 0)
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(_bwd_single_kernel, num_qb=num_qb, heads=heads,
+                              **static),
+            grid=(bh // heads, num_qb),
+            in_specs=in_specs + aux_specs,
+            out_specs=[dq_spec, dk_spec, dk_spec],
+            out_shape=[q_shape, k_shape, k_shape],
+            scratch_shapes=[] if num_qb == 1 else [
+                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, d), jnp.float32),
+            ],
+            interpret=common.use_interpret(),
+            name="stf_flash_attention_bwd",
+        )(*operands, *aux_ops)
+    else:
+        static["block_k"] = block_k
+        batch_of = lambda b: b // num_heads
+        # grid (bh, kb, qb)
+        in_specs, _, dk_spec = specs(None, lambda i, j: j, lambda i, j: i)
+        aux_specs, aux_ops = _aux(bias, seed, block_k, batch_of,
+                                  lambda i, j: i)
+        dk, dv = pl.pallas_call(
+            functools.partial(_bwd_dkdv_kernel, num_qb=num_qb, **static),
+            grid=(bh, num_kb, num_qb),
+            in_specs=in_specs + aux_specs,
+            out_specs=[dk_spec, dk_spec],
+            out_shape=[k_shape, k_shape],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, d), jnp.float32),
+                pltpu.VMEM((block_k, d), jnp.float32),
+            ],
+            interpret=common.use_interpret(),
+            name="stf_flash_attention_bwd_dkv",
+        )(*operands, *aux_ops)
 
-    dqk = functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-                            block_q=block_q, block_k=block_k,
-                            kv_true=kv_true, num_kb=num_kb,
-                            has_bias=has_bias, dropout_rate=dropout_rate)
-    aux_specs, aux_ops = aux(lambda i, j: j)  # grid (bh, qb, kb)
-    dq = pl.pallas_call(
-        dqk,
-        grid=(bh, num_qb, num_kb),
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((None, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((None, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, block_q, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, block_q, 1), lambda b, i, j: (b, i, 0)),
-        ] + aux_specs,
-        out_specs=pl.BlockSpec((None, block_q, d),
-                               lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, q_len, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=common.use_interpret(),
-        name="stf_flash_attention_bwd_dq",
-    )(q, k, v, g, lse, delta, *aux_ops)
+        # grid (bh, qb, kb)
+        in_specs, dq_spec, _ = specs(None, lambda i, j: i, lambda i, j: j)
+        aux_specs, aux_ops = _aux(bias, seed, block_k, batch_of,
+                                  lambda i, j: j)
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, num_kb=num_kb, **static),
+            grid=(bh, num_qb, num_kb),
+            in_specs=in_specs + aux_specs,
+            out_specs=dq_spec,
+            out_shape=q_shape,
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            interpret=common.use_interpret(),
+            name="stf_flash_attention_bwd_dq",
+        )(*operands, *aux_ops)
     grads = [dq, dk, dv]
     # bias is a constant mask under differentiation (stop_gradient'd in the
     # wrapper); seed is integer-typed. Both get symbolic-zero cotangents.
-    if has_bias:
+    if bias is not None:
         grads.append(jnp.zeros_like(bias))
     else:
         grads.append(None)
@@ -419,42 +651,42 @@ def _bwd_with_delta(sm_scale, causal, block_q, block_k, kv_true,
 # Public entry
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12))
 def _flash_bhsd(q, k, v, bias, seed, sm_scale, causal, block_q, block_k,
-                kv_true, dropout_rate, num_heads):
+                kv_true, dropout_rate, num_heads, heads):
     o, _ = _fwd(q, k, v, bias, seed, sm_scale, causal, block_q, block_k,
-                kv_true, dropout_rate, num_heads)
+                kv_true, dropout_rate, num_heads, heads)
     return o
 
 
 def _flash_fwd_rule(q, k, v, bias, seed, sm_scale, causal, block_q, block_k,
-                    kv_true, dropout_rate, num_heads):
+                    kv_true, dropout_rate, num_heads, heads):
     o, lse = _fwd(q, k, v, bias, seed, sm_scale, causal, block_q, block_k,
-                  kv_true, dropout_rate, num_heads)
+                  kv_true, dropout_rate, num_heads, heads)
     return o, (q, k, v, bias, seed, o, lse)
 
 
 _flash_bhsd.defvjp(_flash_fwd_rule, _bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11, 12))
 def _flash_bhsd_lse(q, k, v, bias, seed, sm_scale, causal, block_q, block_k,
-                    kv_true, dropout_rate, num_heads):
+                    kv_true, dropout_rate, num_heads, heads):
     """Variant returning (o, lse) — ring attention merges per-block
     partials through the log-sum-exp."""
     return _fwd(q, k, v, bias, seed, sm_scale, causal, block_q, block_k,
-                kv_true, dropout_rate, num_heads)
+                kv_true, dropout_rate, num_heads, heads)
 
 
 def _flash_lse_fwd_rule(q, k, v, bias, seed, sm_scale, causal, block_q,
-                        block_k, kv_true, dropout_rate, num_heads):
+                        block_k, kv_true, dropout_rate, num_heads, heads):
     o, lse = _fwd(q, k, v, bias, seed, sm_scale, causal, block_q, block_k,
-                  kv_true, dropout_rate, num_heads)
+                  kv_true, dropout_rate, num_heads, heads)
     return (o, lse), (q, k, v, bias, seed, o, lse)
 
 
 def _bwd_lse(sm_scale, causal, block_q, block_k, kv_true, dropout_rate,
-             num_heads, res, gs):
+             num_heads, heads, res, gs):
     """The lse cotangent folds into the existing kernels: with
     L = f(O, LSE), dS = P∘(dP − delta + dlse) since ∂LSE/∂S = P — i.e.
     run the standard backward with delta' = rowsum(dO∘O) − dlse."""
@@ -464,7 +696,7 @@ def _bwd_lse(sm_scale, causal, block_q, block_k, kv_true, dropout_rate,
     delta = jnp.sum(do * o.astype(jnp.float32), axis=-1, keepdims=True) \
         - g_lse.astype(jnp.float32)
     return _bwd_with_delta(sm_scale, causal, block_q, block_k, kv_true,
-                           dropout_rate, num_heads,
+                           dropout_rate, num_heads, heads,
                            (q, k, v, bias, seed, lse), g_o, delta)
 
 
@@ -473,7 +705,7 @@ _flash_bhsd_lse.defvjp(_flash_lse_fwd_rule, _bwd_lse)
 
 def flash_attention(q, k, v, *, causal=False, sm_scale=None, bias=None,
                     dropout_rate=0.0, dropout_seed=None, return_lse=False,
-                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
+                    block_q=None, block_k=None):
     """Fused attention. q,k,v: (batch, heads, seq, head_dim) (kv seq may
     differ for cross-attention; causal requires equal lengths). Returns
     (batch, heads, q_seq, head_dim) in q.dtype.
@@ -506,9 +738,21 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None, bias=None,
     if dropout_rate > 0.0 and dropout_seed is None:
         raise ValueError("flash attention dropout needs dropout_seed")
 
-    align = 8 if common.use_interpret() else 128
-    block_q = min(block_q, round_up(q_len, align))
-    block_k = min(block_k, round_up(kv_len, align))
+    align = 8 if common.use_interpret() else _LANES
+    rule_q, rule_k, heads = tiles(q_len, kv_len, d, q.dtype, causal, h, align)
+    if block_q is None and block_k is None:
+        block_q, block_k = rule_q, rule_k
+    else:
+        # the caller's tiles (tests): clipped to the sequence, and the
+        # heads a step by the same arithmetic as the rule's
+        block_q = min(block_q or rule_q, round_up(q_len, align))
+        block_k = min(block_k or rule_k, round_up(kv_len, align))
+        whole_head = block_q >= q_len and block_k >= kv_len
+        heads = _heads_that_fit(h, block_q, block_k, d,
+                                q.dtype) if whole_head else 1
+    regime = "single_pass" if block_k >= kv_len else "streamed"
+    _kreg.metric_flash_tiles.get_cell(
+        regime, str(block_q), str(block_k), str(heads)).increase_by(1)
     qp_len = round_up(q_len, block_q)
     kp_len = round_up(kv_len, block_k)
     # head_dim 64 stays unpadded: Mosaic accepts a half-tile minor dim, and
@@ -549,7 +793,7 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None, bias=None,
 
     args = (qq, kk, vv, bb, ss, float(sm_scale), bool(causal),
             int(block_q), int(block_k), int(kv_len),
-            float(dropout_rate), int(h))
+            float(dropout_rate), int(h), int(heads))
     if return_lse:
         o, lse = _flash_bhsd_lse(*args)
         o = o[:, :q_len, :d].reshape(b, h, q_len, d)
@@ -562,7 +806,7 @@ def flash_attention(q, k, v, *, causal=False, sm_scale=None, bias=None,
 
 def attention_xla(q, k, v, *, causal=False, sm_scale=None, bias=None,
                   dropout_rate=0.0, dropout_seed=None, return_lse=False,
-                  block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
+                  block_q=None, block_k=None):
     """The stock composed-XLA lowering of the FlashAttention op contract
     (batch_matmul → softmax → batch_matmul, the reference's attention
     path; ref core/kernels/{batch_matmul_op,softmax_op}.cc) — the

@@ -601,7 +601,8 @@ class TestRoutingReport:
     def test_statusz_snapshot_shape(self):
         snap = kreg.snapshot()
         assert snap["mode"] in ("off", "auto", "force")
-        for k in ("routed", "fallback", "autotune_runs", "kernels"):
+        for k in ("routed", "fallback", "autotune_runs", "flash_tiles",
+                  "kernels"):
             assert k in snap
 
 

@@ -12,7 +12,9 @@ import pytest
 
 from simple_tensorflow_tpu.ops.pallas import (
     flash_attention, layer_norm, quant_matmul, softmax_cross_entropy)
-from simple_tensorflow_tpu.ops.pallas.flash_attention import mha_reference
+from simple_tensorflow_tpu.kernels import registry as kreg
+from simple_tensorflow_tpu.ops.pallas.flash_attention import (
+    VMEM_BUDGET, attention_xla, mha_reference, tiles, vmem_bytes)
 from simple_tensorflow_tpu.ops.pallas.layer_norm import layer_norm_reference
 from simple_tensorflow_tpu.ops.pallas.quant_matmul import (
     quant_matmul_reference, quantize_colwise)
@@ -178,6 +180,192 @@ class TestFlashAttention:
                              dropout_rate=0.2, dropout_seed=5,
                              block_q=16, block_k=16)
         np.testing.assert_array_equal(np.asarray(o), np.asarray(o2))
+
+
+def _lse_reference(q, k, causal=False, bias=None):
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   precision=jax.lax.Precision.HIGHEST) / q.shape[-1] ** 0.5
+    if bias is not None:
+        s = s + jnp.asarray(bias)[:, None, None, :]
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -1e30)
+    return jax.scipy.special.logsumexp(s, axis=-1)
+
+
+# name -> (q_len, kv_len, heads, keywords of flash_attention, with a key
+# bias, with an lse cotangent, the regime and tiles the call must take).
+# In interpret mode the rule aligns to 8 rows, not to 128 lanes.
+_REGIMES = {
+    # one tile covers the head: all its heads in one step
+    "single_s128": (128, 128, 2, {}, False, False,
+                    ("single_pass", 128, 128, 2)),
+    "single_s512_bias": (512, 512, 3, {}, True, False,
+                         ("single_pass", 512, 512, 1)),
+    "single_s512_causal": (512, 512, 1, dict(causal=True), False, False,
+                           ("single_pass", 512, 512, 1)),
+    "single_cross": (32, 96, 2, {}, True, False,
+                     ("single_pass", 32, 96, 2)),
+    # 50 keys pad to 56: the length mask must NOT be elided
+    "single_pads": (50, 50, 2, {}, False, False,
+                    ("single_pass", 56, 56, 2)),
+    "single_pads_causal_bias": (50, 50, 2, dict(causal=True), True, False,
+                                ("single_pass", 56, 56, 2)),
+    # whole key range in one tile, several query blocks: the fused
+    # backward accumulates dK and dV across them
+    "single_q_blocks": (256, 96, 2, dict(block_q=64), True, False,
+                        ("single_pass", 64, 96, 1)),
+    "single_q_blocks_causal": (256, 256, 2, dict(block_q=64, causal=True),
+                               False, False, ("single_pass", 64, 256, 1)),
+    "single_lse": (128, 128, 2, dict(return_lse=True), True, True,
+                   ("single_pass", 128, 128, 2)),
+    # the rule's own streamed tiles
+    "streamed_s2048": (2048, 2048, 1, {}, False, False,
+                       ("streamed", 512, 512, 1)),
+    # small tiles forced on a short sequence
+    "streamed_causal": (256, 256, 2, dict(block_q=64, block_k=64,
+                                          causal=True), False, False,
+                        ("streamed", 64, 64, 1)),
+    "streamed_pads_bias": (200, 200, 2, dict(block_q=64, block_k=64), True,
+                           False, ("streamed", 64, 64, 1)),
+    "streamed_cross": (64, 160, 2, dict(block_q=32, block_k=32), False,
+                       False, ("streamed", 32, 32, 1)),
+    "streamed_wide_keys": (128, 256, 2, dict(block_q=32, block_k=128), True,
+                           False, ("streamed", 32, 128, 1)),
+    "streamed_lse": (128, 128, 2, dict(block_q=32, block_k=64,
+                                       return_lse=True, causal=True), False,
+                     True, ("streamed", 32, 64, 1)),
+}
+
+
+class TestFlashAttentionRegimes:
+    """Every regime the tile rule can produce, forward and all three
+    gradients against the naive reference."""
+
+    @pytest.mark.parametrize("name", list(_REGIMES))
+    def test_matches_reference(self, name):
+        sq, sk, h, kw, with_bias, with_lse, took = _REGIMES[name]
+        kw = dict(kw)
+        causal = kw.get("causal", False)
+        d = 16
+        q = rand(0, (1, h, sq, d))
+        k, v = rand(1, (1, h, sk, d)), rand(2, (1, h, sk, d))
+        bias = None
+        if with_bias:
+            bias = np.zeros((1, sk), np.float32)
+            bias[0, -(sk // 5):] = -1e9
+        w = rand(3, (1, h, sq))
+
+        def flash(q, k, v):
+            return flash_attention(q, k, v, bias=bias, **kw)
+
+        def naive(q, k, v):
+            o = mha_reference(q, k, v, causal=causal, bias=bias)
+            return (o, _lse_reference(q, k, causal, bias)) if with_lse else o
+
+        def run(attn):
+            def loss(q, k, v):
+                out = attn(q, k, v)
+                o, lse = out if with_lse else (out, jnp.zeros_like(w))
+                return jnp.sum(jnp.sin(o)) + jnp.sum(lse * w), (o, lse)
+            return jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(q, k, v)
+
+        cell = kreg.metric_flash_tiles.get_cell(*map(str, took))
+        before = cell.value()
+        (_, (o1, lse1)), g1 = run(flash)
+        assert cell.value() > before, (took, kreg.snapshot()["flash_tiles"])
+        (_, (o2, lse2)), g2 = run(naive)
+        np.testing.assert_allclose(o1, o2, atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(lse1, lse2, atol=2e-5, rtol=2e-5)
+        for a, b_ in zip(g1, g2):
+            np.testing.assert_allclose(a, b_, atol=1e-4, rtol=1e-4)
+
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_dropout_mask_is_keyed_on_position_not_on_tiles(self, causal):
+        # v = identity, so out[.., i, j] is the dropped probability of
+        # key j itself: exactly 0 where the mask drops, > 0 where it
+        # keeps. The zero pattern must be the same bit for bit whatever
+        # the tiles (single pass with two heads a step; streamed) and in
+        # the composed-XLA lowering; kept values agree to rounding.
+        h, s = 2, 64
+        q, k = rand(0, (1, h, s, s)), rand(1, (1, h, s, s))
+        v = jnp.broadcast_to(jnp.eye(s), (1, h, s, s))
+        kw = dict(causal=causal, dropout_rate=0.3, dropout_seed=11)
+        outs = [np.asarray(flash_attention(q, k, v, **kw)),
+                np.asarray(flash_attention(q, k, v, block_q=16, block_k=32,
+                                           **kw)),
+                np.asarray(flash_attention(q, k, v, block_q=32, **kw)),
+                np.asarray(attention_xla(q, k, v, **kw))]
+        live = np.tril(np.ones((s, s), bool)) if causal else np.ones(
+            (s, s), bool)
+        dropped = (outs[0] == 0.0) & live
+        assert 0.2 < dropped.sum() / (h * live.sum()) < 0.4
+        for o in outs[1:]:
+            np.testing.assert_array_equal((o == 0.0) & live, dropped)
+            np.testing.assert_allclose(o, outs[0], atol=2e-6, rtol=2e-5)
+
+    def test_dropout_gradients_agree_between_regimes(self):
+        h, s, d = 2, 64, 16
+        q, k, v = (rand(i, (1, h, s, d)) for i in range(3))
+
+        def grads(**kw):
+            return jax.grad(lambda q, k, v: jnp.sum(jnp.sin(flash_attention(
+                q, k, v, dropout_rate=0.3, dropout_seed=5, **kw))),
+                (0, 1, 2))(q, k, v)
+
+        for a, b_ in zip(grads(), grads(block_q=16, block_k=16)):
+            np.testing.assert_allclose(a, b_, atol=1e-4, rtol=1e-4)
+
+
+# (q_len, kv_len, head_dim, dtype, causal, heads of a batch row) ->
+# (block_q, block_k, heads a step), at the chip's 128-lane alignment
+_TILE_TABLE = [
+    ((512, 512, 64, jnp.bfloat16, False, 12), (512, 512, 3)),   # bert-base
+    ((512, 512, 64, jnp.bfloat16, True, 16), (512, 512, 2)),    # lm-big
+    ((128, 128, 64, jnp.bfloat16, False, 12), (128, 128, 12)),
+    ((256, 256, 64, jnp.bfloat16, False, 12), (256, 256, 6)),
+    ((500, 500, 64, jnp.bfloat16, False, 12), (512, 512, 3)),
+    ((128, 512, 64, jnp.bfloat16, False, 12), (128, 512, 6)),   # cross
+    ((1024, 1024, 64, jnp.bfloat16, False, 16), (512, 1024, 1)),
+    ((2048, 2048, 64, jnp.bfloat16, True, 16), (512, 1024, 1)),
+    ((8192, 8192, 64, jnp.bfloat16, False, 16), (512, 1024, 1)),
+    ((512, 512, 128, jnp.bfloat16, False, 8), (512, 512, 2)),
+    ((512, 512, 256, jnp.bfloat16, False, 8), (512, 512, 1)),
+    ((512, 512, 64, jnp.float32, False, 12), (512, 512, 1)),
+    ((1024, 1024, 64, jnp.float32, False, 12), (256, 1024, 1)),
+    ((2048, 2048, 128, jnp.float32, False, 12), (512, 512, 1)),
+    ((512, 512, 256, jnp.float32, False, 8), (256, 512, 1)),
+    ((2048, 2048, 256, jnp.float32, False, 8), (256, 256, 1)),
+]
+
+
+class TestFlashAttentionTileRule:
+    @pytest.mark.parametrize("shape,want", _TILE_TABLE,
+                             ids=[str(i) for i in range(len(_TILE_TABLE))])
+    def test_table(self, shape, want):
+        assert tiles(*shape) == want
+
+    @pytest.mark.parametrize("head_dim", [64, 128, 256])
+    @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+    def test_estimate_stays_under_the_budget(self, head_dim, dtype):
+        for seq in (8, 128, 384, 512, 640, 1024, 2048, 8192):
+            for kv in (seq, 128, 1536):
+                for heads in (1, 12, 16):
+                    bq, bk, g = tiles(seq, kv, head_dim, dtype, False, heads)
+                    assert heads % g == 0
+                    assert bq % 128 == 0 and bk % 128 == 0
+                    for backward in (False, True):
+                        assert vmem_bytes(bq, bk, head_dim, dtype, backward,
+                                          g) <= VMEM_BUDGET, (seq, kv, heads)
+                    # the whole key range in one tile, or tiles that
+                    # cover it with under one alignment of padding each
+                    assert bk >= kv or bk * -(-kv // bk) - kv < 128 * -(
+                        -kv // bk)
+
+    def test_causal_and_interpret_alignment(self):
+        assert tiles(512, 512, 64, jnp.bfloat16, True, 12) == tiles(
+            512, 512, 64, jnp.bfloat16, False, 12)
+        assert tiles(50, 50, 16, jnp.float32, False, 2, align=8) == (
+            56, 56, 2)
 
 
 class TestLayerNorm:

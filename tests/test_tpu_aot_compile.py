@@ -21,6 +21,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from simple_tensorflow_tpu.kernels import registry as kreg
 from simple_tensorflow_tpu.ops import pallas as P
 from simple_tensorflow_tpu.ops.pallas import common
 
@@ -129,6 +130,66 @@ class TestFlashAttention:
 def test_flash_attention_return_lse_long(tpu_compile):
     tpu_compile(lambda q, k, v: P.flash_attention(q, k, v, return_lse=True),
                 *[((1, 16, 2048, 64), BF16)] * 3)
+
+
+def _kernel_names(text):
+    return set(re.findall(r"stf_flash_attention_(?:fwd|bwd_dkv|bwd_dq|bwd)",
+                          text))
+
+
+@pytest.mark.parametrize("what", ["forward", "backward"])
+def test_flash_attention_bert_cell_single_pass(tpu_compile, what):
+    """What `bert-base.s512` really runs: batch 48, non-causal, a
+    (48, 512) float32 key-padding bias. The rule gives one (512, 512)
+    tile a head, three heads a step; the backward is ONE call."""
+    qkv = (48, 12, 512, 64)
+
+    def attn(q, k, v, b):
+        return P.flash_attention(q, k, v, bias=b)
+
+    fn = attn if what == "forward" else (
+        lambda q, k, v, b: _grad(lambda q, k, v: attn(q, k, v, b),
+                                 (0, 1, 2))(q, k, v))
+    took = kreg.metric_flash_tiles.get_cell("single_pass", "512", "512", "3")
+    before = took.value()
+    text = tpu_compile(fn, *[(qkv, BF16)] * 3, ((48, 512), F32))
+    assert took.value() > before, kreg.snapshot()["flash_tiles"]
+    assert _kernel_names(text) == {"stf_flash_attention_fwd"} | (
+        set() if what == "forward" else {"stf_flash_attention_bwd"})
+    # the operand signature chipbench's flash_attn_roofline finds the
+    # events by: rank-3 bf16 [B*H, S, D] beside a float32 [B*H, S, 1]
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert all("bf16[576,512,64]" in ln and "f32[576,512,1]" in ln
+               for ln in calls), [ln[:200] for ln in calls]
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+def test_flash_attention_streamed_8192(tpu_compile, causal):
+    """The long users (ring attention's blocks, return_lse): streamed
+    512 x 1024 tiles, forward and the two-call backward."""
+    def attn(q, k, v):
+        return P.flash_attention(q, k, v, causal=causal, return_lse=True)
+
+    avals = [((1, 16, 8192, 64), BF16)] * 3
+    assert _kernel_names(tpu_compile(attn, *avals)) == {
+        "stf_flash_attention_fwd"}
+    assert _kernel_names(tpu_compile(_grad(attn, (0, 1, 2)), *avals)) == {
+        "stf_flash_attention_fwd", "stf_flash_attention_bwd_dkv",
+        "stf_flash_attention_bwd_dq"}
+
+
+@pytest.mark.parametrize("head_dim,dtype,seq", [
+    (64, BF16, 128), (64, BF16, 1024), (128, BF16, 1024), (256, BF16, 512),
+    (64, F32, 512), (128, F32, 1024), (256, F32, 512), (256, F32, 2048)],
+    ids=lambda v: getattr(v, "__name__", str(v)))
+def test_flash_attention_rule_fits_vmem(tpu_compile, head_dim, dtype, seq):
+    """The tile rule's choice at the corners of its table: Mosaic
+    accepts the tiles and the step fits VMEM, forward and backward
+    (12 heads, so a whole-head step holds several)."""
+    avals = [((2, 12, seq, head_dim), dtype)] * 3
+    tpu_compile(_grad(lambda q, k, v: P.flash_attention(q, k, v),
+                      (0, 1, 2)), *avals)
 
 
 def test_layer_norm_forward_backward(tpu_compile):
