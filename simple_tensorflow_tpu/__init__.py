@@ -284,8 +284,8 @@ from . import telemetry
 from . import checkpoint
 
 # Pallas/XLA kernel routing tier: per-(op, shape, dtype, backend)
-# fallback registry with cost-model gating and a measured autotune
-# cache (stf.kernels; docs/PERFORMANCE.md "kernel tier")
+# fallback registry with cost-model gating (stf.kernels;
+# docs/PERFORMANCE.md "kernel tier")
 from . import kernels
 
 newaxis = None

@@ -43,8 +43,8 @@ Built-in catalog (see docs/ANALYSIS.md for the worked examples):
                          cache write that is not refcount-guarded.
                          Active only for purpose="serving" runs (ERROR)
   lint/kernel-routing    per-op Pallas/XLA routing verdicts from the
-                         stf.kernels registry (routed / fallback+reason
-                         / autotune). Active only for purpose="kernels"
+                         stf.kernels registry (routed / fallback+reason).
+                         Active only for purpose="kernels"
                          runs (``graph_lint --kernels``) (NOTE)
   lint/embedding-replicated-table
                          an embedding table at/over the byte budget
@@ -649,8 +649,7 @@ def _rule_kernel_routing(ctx):
     (active only for ``purpose="kernels"`` runs: ``graph_lint
     --kernels`` and the zoo routing gate). One NOTE per op whose type
     has a registered kernel pair, naming the verdict the registry would
-    reach offline — ``routed`` (Pallas), ``fallback`` + reason, or
-    ``autotune`` (decided by measurement on first live call). Op types
+    reach — ``routed`` (Pallas) or ``fallback`` + reason. Op types
     without a kernel are summarized by the CLI, not flagged per op."""
     if ctx.purpose != "kernels":
         return

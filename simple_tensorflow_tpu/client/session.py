@@ -1051,8 +1051,7 @@ class BaseSession:
             self._verify_graph_now(construction=True)
         # persistent executable cache (ISSUE 5): ConfigProto(
         # compile_cache_dir=...) or STF_COMPILE_CACHE makes process
-        # restarts disk-hit their compiles instead of re-paying the
-        # 13-24 s warmup_plus_compile_s (bench.py warm_start row).
+        # restarts disk-hit their compiles instead of paying them again.
         # The jax cache dir is PROCESS-GLOBAL (see ConfigProto doc):
         # once set it outlives this Session and applies to later ones.
         # Where JAX_COMPILATION_CACHE_DIR is set it wins over both
@@ -2979,8 +2978,7 @@ class BaseSession:
             # is ADVISORY — warnings/notes, never an execution gate — so
             # it runs on a worker thread overlapping lowering + XLA
             # compile instead of stretching the plan's critical path
-            # (the sharding_analysis bench row pins the blocking cost;
-            # /stf/analysis/sharding_seconds samples the full cost).
+            # (/stf/analysis/sharding_seconds samples the full cost).
             # Analyzer failures degrade to a log note, never sink a run.
             try:
                 from ..parallel import mesh as mesh_mod

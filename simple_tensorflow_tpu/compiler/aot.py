@@ -68,9 +68,7 @@ def enable_persistent_cache(cache_dir: Optional[str] = None) -> str:
 
 
 def persistent_cache_dir() -> Optional[str]:
-    """The persistent-cache directory in effect, or None. The kernel
-    registry persists its micro-autotune verdicts alongside it
-    (stf.kernels; docs/PERFORMANCE.md)."""
+    """The persistent-cache directory in effect, or None."""
     return os.environ.get(_CACHE_ENV) or _persistent_cache_dir
 
 

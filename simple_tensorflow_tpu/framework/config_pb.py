@@ -68,9 +68,9 @@ class ConfigProto:
     compile_cache_dir: directory for the persistent XLA executable
     cache (``compiler.aot.enable_persistent_cache``); a second process
     compiling the same HLO hits the disk cache instead of paying the
-    full compile again (the 13-24 s/process ``warmup_plus_compile_s``
-    in bench.py). None (default) falls back to the ``STF_COMPILE_CACHE``
-    environment variable; empty/unset leaves persistent caching off.
+    full compile again. None (default) falls back to the
+    ``STF_COMPILE_CACHE`` environment variable; empty/unset leaves
+    persistent caching off.
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, that directory is used
     and both of these are ignored.
     PROCESS-GLOBAL: the underlying jax compilation-cache directory is
@@ -86,21 +86,21 @@ class ConfigProto:
     staging overlaps step N's device execution. Default False keeps
     the eager-numpy return contract.
 
-    kernel_registry: None (process default: ``STF_PALLAS`` /
-    ``stf.kernels.set_mode``) | "off" | "auto" | "force" — the Pallas
+    kernel_registry: None (process default: ``stf.kernels.set_mode``,
+    "auto" unless set) | "off" | "auto" | "force" — the Pallas
     kernel-routing mode for programs this Session lowers
     (docs/PERFORMANCE.md "kernel tier"). "off" restores the
     pre-registry lowerings exactly; "auto" routes per (op, shape,
-    dtype, backend) through the cost-model gate + micro-autotune
-    cache; "force" pins every eligible op to the Pallas kernel
-    (interpret mode off-TPU — the tier-1 testing mode). Applies at
-    TRACE time: executables already compiled by this Session keep the
+    dtype, backend) through the cost-model gate, taking the kernel
+    on a TPU where the gate abstains; "force" pins every eligible op
+    to the Pallas kernel (interpret mode off-TPU — the tier-1 testing
+    mode). Applies at TRACE time: executables already compiled by this Session keep the
     routing they were traced with. NOTE: the fused optimizer tail is a
     GRAPH-BUILD decision — a graph built while the process default was
     not "off" already contains the fused update op and flat slot
     layout; this session-scoped "off" only picks its composed lowering.
     To restore the per-variable assign tail (and its per-variable slot
-    checkpoint layout) set STF_PALLAS=0 / stf.kernels.set_mode("off")
+    checkpoint layout) call stf.kernels.set_mode("off")
     BEFORE building the optimizer.
 
     auto_shard: False (default) | True — prescriptive sharding

@@ -7,7 +7,7 @@ GraphDef *before* running, to drive placement and scheduling decisions.
 TPU-native equivalent: predict FLOPs, HBM bytes, and peak live bytes of a
 (pruned) stf graph slice before XLA ever sees it — used by
 
-- ``bench.py`` / ``client/timeline.py`` to print predicted-vs-measured,
+- ``client/timeline.py`` to print predicted-vs-measured,
 - ``parallel.pipeline_train(n_microbatches="auto")`` /
   ``suggest_remat`` to pick microbatch count and remat granularity from
   the activation-memory estimate instead of trial-and-error OOMs.

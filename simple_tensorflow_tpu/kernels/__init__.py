@@ -10,24 +10,22 @@ fused optimizer updates.
 Quick reference (docs/PERFORMANCE.md "kernel tier"):
 
     stf.kernels.set_mode("force")          # pin Pallas everywhere
-    STF_PALLAS=0                           # kill switch: pre-registry
+    stf.kernels.set_mode("off")            # kill switch: pre-registry
                                            # lowerings exactly
     ConfigProto(kernel_registry="auto")    # per-Session mode
-    /stf/kernels/{routed,fallback,autotune_runs}   # counters
+    /stf/kernels/{routed,fallback}         # counters
 """
 
 from .registry import (MODES, activate, aval_key, backend, clear_decisions,
-                       clear_measurements, current_mode, decide,
-                       decisions_snapshot, default_mode, has_kernel,
-                       kernel_types, measured_verdicts, metric_autotune_runs,
+                       current_mode, decide, decisions_snapshot,
+                       default_mode, has_kernel, kernel_types,
                        metric_fallback, metric_routed, register_kernel,
                        roofline_gate, routing_report, select, set_mode,
                        snapshot)
 
 __all__ = [
     "MODES", "activate", "aval_key", "backend", "clear_decisions",
-    "clear_measurements", "current_mode", "decide", "decisions_snapshot",
-    "default_mode", "has_kernel", "kernel_types", "measured_verdicts",
-    "register_kernel", "roofline_gate", "routing_report", "select",
+    "current_mode", "decide", "decisions_snapshot", "default_mode",
+    "has_kernel", "kernel_types", "register_kernel", "roofline_gate", "routing_report", "select",
     "set_mode", "snapshot",
 ]

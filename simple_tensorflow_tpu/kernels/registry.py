@@ -9,38 +9,38 @@ dispatch by KernelDef priority), upgraded with the cost-model gating the
 TPU-v3 MLPerf submissions used to decide hand-tuned kernel vs compiler
 output (1909.09756 §"performance optimizations").
 
-Three modes (``STF_PALLAS`` env / ``stf.kernels.set_mode`` /
-``ConfigProto(kernel_registry=...)``):
+A decision is a pure function of (op, shapes and dtypes, backend, mesh,
+mode): it traces, compiles, times and stores nothing. The rule, whole
+(``_route``):
 
-  off    the registry is inert — every op lowers exactly as it did
-         before the registry existed (the fused graph ops keep their
-         Pallas kernels, composed ops keep their jnp lowerings, the
-         optimizer tail stays per-variable assigns).
-  auto   (default) eligibility checks, then a static cost-model gate
-         (roofline pricing of both lowerings, framework/cost_model.py
-         accounting), then — for shapes the gate cannot confidently
-         price, or always under ``STF_KERNEL_AUTOTUNE=1`` — a measured
-         micro-autotune: the first call on an ungated shape times both
-         lowerings and persists the verdict alongside the persistent
-         compile cache (compiler.aot.enable_persistent_cache). A
-         measured verdict always overrides the static gate: auto mode
-         never picks a lowering the autotune measured slower.
-  force  the Pallas implementation for every eligible op (interpret
-         mode off-TPU, so the whole tier runs under tier-1 CPU tests).
+  1. a TPU under a multi-device mesh outside shard_map -> ``xla``
+     (``mesh_auto_partitioned``: GSPMD cannot partition a Mosaic kernel),
+     in every mode;
+  2. mode ``off`` -> the kernel's ``legacy`` lowering: every op lowers
+     exactly as it did before the registry existed (the fused graph ops
+     keep their Pallas kernels, composed ops keep their jnp lowerings,
+     the optimizer tail stays per-variable assigns);
+  3. a call the kernel cannot express -> ``xla`` with its
+     ``ineligible_*`` reason;
+  4. mode ``force`` -> ``pallas`` (interpret mode off-TPU, so the whole
+     tier runs under tier-1 CPU tests);
+  5. mode ``auto`` (the default) -> the static cost gate's verdict
+     (roofline pricing of both lowerings, framework/cost_model.py
+     accounting); where the gate abstains (``cost_model_uncertain``,
+     ``unpriced``) ``pallas`` on a TPU and ``xla`` elsewhere.
+
+The mode is set process-wide by ``stf.kernels.set_mode`` and per Session
+by ``ConfigProto(kernel_registry=...)``.
 
 Every decision increments exactly one of ``/stf/kernels/routed{op}``
 (Pallas chosen) or ``/stf/kernels/fallback{op, reason}`` (XLA chosen),
-so the counters explain every non-routed call. Decisions are cached per
-(op, key, mode, backend) — a given executable always retraces to the
-same routing.
+so the counters explain every non-routed call. Decisions are recorded
+per (op, key, mode, backend) for ``decisions_snapshot()``.
 """
 
 from __future__ import annotations
 
-import json
-import os
 import threading
-import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ..platform import monitoring
@@ -54,10 +54,6 @@ metric_routed = monitoring.Counter(
 metric_fallback = monitoring.Counter(
     "/stf/kernels/fallback",
     "lowering decisions that chose the stock XLA lowering", "op", "reason")
-metric_autotune_runs = monitoring.Counter(
-    "/stf/kernels/autotune_runs",
-    "micro-autotune measurements (both lowerings timed once per "
-    "ungated (op, shape, dtype, backend) key)", "op")
 metric_flash_tiles = monitoring.Counter(
     "/stf/kernels/flash_tiles",
     "flash-attention kernel traces by the regime and tiles the shape "
@@ -67,44 +63,23 @@ metric_flash_tiles = monitoring.Counter(
 # -- mode ---------------------------------------------------------------------
 
 _state = threading.local()          # per-thread activation (Session lowering)
-_mode_override: Optional[str] = None
+_default_mode = "auto"
 _lock = _sync.RLock("kernels/registry", rank=_sync.RANK_STATE)
 
 
-def _env_mode() -> str:
-    """Resolve the process-default mode from the environment.
-
-    STF_PALLAS=0 is the documented kill switch (registry inert, pre-PR
-    lowerings); STF_PALLAS=force pins every eligible op to Pallas;
-    anything else (or unset) is auto. STF_KERNELS=off|auto|force is the
-    explicit spelling of the same knob and wins when both are set.
-    """
-    v = os.environ.get("STF_KERNELS")
-    if v in MODES:
-        return v
-    p = os.environ.get("STF_PALLAS")
-    if p is not None:
-        p = p.strip().lower()
-        if p in ("0", "off", "false", "no"):
-            return "off"
-        if p == "force":
-            return "force"
-    return "auto"
-
-
 def set_mode(mode: Optional[str]) -> None:
-    """Set the process-default routing mode (None = back to the env
-    default). Affects decisions made by FUTURE traces only: an
+    """Set the process-default routing mode (None = back to ``auto``).
+    Affects decisions made by FUTURE traces only: an
     already-compiled executable keeps the routing it was traced with."""
-    global _mode_override
+    global _default_mode
     if mode is not None and mode not in MODES:
         raise ValueError(f"kernel registry mode must be one of {MODES}, "
                          f"got {mode!r}")
-    _mode_override = mode
+    _default_mode = mode or "auto"
 
 
 def default_mode() -> str:
-    return _mode_override if _mode_override is not None else _env_mode()
+    return _default_mode
 
 
 def current_mode() -> str:
@@ -155,12 +130,6 @@ def backend() -> str:
     return jax.default_backend()
 
 
-def device_kind() -> str:
-    import jax
-
-    return jax.devices()[0].device_kind
-
-
 # -- kernel definitions -------------------------------------------------------
 
 class KernelDef:
@@ -174,23 +143,21 @@ class KernelDef:
       (``ineligible_*``). Force mode still honors ineligibility — an
       implementation that cannot express the call cannot be forced.
     cost_gate(key, backend) -> (verdict|None, reason): the static gate.
-      None verdict = uncertain, measure (auto mode).
-    make_case(key) -> (args, kwargs): representative concrete inputs
-      for the micro-autotune (never called for ineligible keys).
+      None verdict = the gate abstains; auto mode then takes the kernel
+      on a TPU and the XLA lowering elsewhere.
     """
 
     __slots__ = ("op_type", "impls", "legacy", "eligible", "cost_gate",
-                 "make_case", "graph_key", "doc")
+                 "graph_key", "doc")
 
     def __init__(self, op_type, impls, legacy, eligible=None,
-                 cost_gate=None, make_case=None, graph_key=None, doc=""):
+                 cost_gate=None, graph_key=None, doc=""):
         assert legacy in ("pallas", "xla")
         self.op_type = op_type
         self.impls = dict(impls)
         self.legacy = legacy
         self.eligible = eligible or (lambda key: None)
         self.cost_gate = cost_gate or (lambda key, backend: (None, "unpriced"))
-        self.make_case = make_case
         self.graph_key = graph_key
         self.doc = doc
 
@@ -230,169 +197,34 @@ def aval_key(*arrays, **statics) -> Tuple:
     return tuple(parts)
 
 
-# -- autotune cache -----------------------------------------------------------
-
-# (op_type, key, backend, device_kind) -> {"verdict", "pallas_s",
-# "xla_s"}: a verdict timed on one device is never replayed on another
-_measured: Dict[Tuple, Dict[str, Any]] = {}
-_measured_loaded_from: Optional[str] = None
-_AUTOTUNE_FILE = "stf_kernel_autotune.json"
-
-
-def _autotune_forced() -> bool:
-    return os.environ.get("STF_KERNEL_AUTOTUNE", "") == "1"
-
-
-def _cache_file() -> Optional[str]:
-    """Persist verdicts alongside the persistent compile cache (PR 5):
-    the same directory that makes process restarts disk-hit their XLA
-    compiles makes them skip re-measuring."""
-    from ..compiler import aot
-
-    d = aot.persistent_cache_dir()
-    if not d:
-        return None
-    return os.path.join(d, _AUTOTUNE_FILE)
-
-
-def _load_persisted() -> None:
-    global _measured_loaded_from
-    path = _cache_file()
-    if path is None or path == _measured_loaded_from:
-        return
-    _measured_loaded_from = path
-    try:
-        with open(path) as f:
-            raw = json.load(f)
-    except (OSError, ValueError):
-        return
-    def _tuplify(x):
-        if isinstance(x, list):
-            return tuple(_tuplify(v) for v in x)
-        return x
-
-    for rec in raw.get("verdicts", []):
-        try:
-            k = (rec["op"], _tuplify(rec["key"]), rec["backend"],
-                 rec["device_kind"])
-            _measured.setdefault(k, {
-                "verdict": rec["verdict"],
-                "pallas_s": rec.get("pallas_s"),
-                "xla_s": rec.get("xla_s"),
-            })
-        except (KeyError, TypeError):
-            continue
-
-
-def _persist() -> None:
-    path = _cache_file()
-    if path is None:
-        return
-    recs = []
-    for (op, key, bk, kind), v in _measured.items():
-        recs.append({"op": op, "key": _jsonable(key), "backend": bk,
-                     "device_kind": kind, "verdict": v["verdict"],
-                     "pallas_s": v.get("pallas_s"),
-                     "xla_s": v.get("xla_s")})
-    tmp = f"{path}.tmp.{os.getpid()}"
-    try:
-        with open(tmp, "w") as f:
-            json.dump({"verdicts": recs}, f)
-        os.replace(tmp, path)
-    except OSError:
-        try:
-            os.remove(tmp)
-        except OSError:
-            pass
-
-
-def _jsonable(part):
-    if isinstance(part, tuple):
-        return [_jsonable(x) for x in part]
-    return part
-
-
-def _time_thunk(fn, args, kwargs) -> float:
-    """Best-of-N wall time of ``fn(*args, **kwargs)`` under jit (the
-    first call pays trace+compile and is excluded)."""
-    import jax
-
-    jfn = jax.jit(lambda *a: fn(*a, **kwargs))
-    jax.block_until_ready(jfn(*args))  # compile + warm
-    best = float("inf")
-    for _ in range(3):
-        t0 = time.perf_counter()
-        jax.block_until_ready(jfn(*args))
-        best = min(best, time.perf_counter() - t0)
-    return best
-
-
-def _measure(kd: KernelDef, key, bk: str) -> str:
-    """Micro-autotune: time both lowerings on representative inputs,
-    persist the verdict. Called at most once per (op, key, backend,
-    device kind). A lowering that fails to compile or run here would
-    fail the same way in the plan being traced, so the failure
-    propagates, naming what was being timed — it is never a verdict."""
-    cache_key = (kd.op_type, key, bk, device_kind())
-    hit = _measured.get(cache_key)
-    if hit is not None:
-        return hit["verdict"]
-    if kd.make_case is None:
-        # nothing to measure with: defer to the static gate's lean
-        v, _ = kd.cost_gate(key, bk)
-        return v or ("xla" if bk != "tpu" else "pallas")
-    metric_autotune_runs.get_cell(kd.op_type).increase_by(1)
-    args, kwargs = kd.make_case(key)
-    times = {}
-    for impl in ("pallas", "xla"):
-        try:
-            times[impl] = _time_thunk(kd.impls[impl], args, kwargs)
-        except Exception as e:
-            raise RuntimeError(
-                f"kernel autotune: timing the {impl!r} lowering of "
-                f"{kd.op_type} failed on backend {bk!r} for key "
-                f"{key!r}") from e
-    t_p, t_x = times["pallas"], times["xla"]
-    verdict = "pallas" if t_p <= t_x else "xla"
-    _measured[cache_key] = {"verdict": verdict, "pallas_s": t_p,
-                            "xla_s": t_x}
-    _persist()
-    return verdict
-
-
-def measured_verdicts() -> Dict[Tuple, Dict[str, Any]]:
-    """The in-process autotune cache (bench/introspection)."""
-    return dict(_measured)
-
-
-def record_measurement(op_type: str, key, pallas_s: float,
-                       xla_s: float) -> str:
-    """Feed an externally-timed (pallas, xla) pair into the autotune
-    cache — the bench row records its per-kernel timings through this,
-    so auto-mode decisions afterwards follow the measurement (the
-    'never pick a lowering the autotune measured slower' contract).
-    Returns the resulting verdict. Cached decisions are invalidated for
-    this op so the next decide() re-reads the cache."""
-    verdict = "pallas" if pallas_s <= xla_s else "xla"
-    _measured[(op_type, key, backend(), device_kind())] = {
-        "verdict": verdict, "pallas_s": float(pallas_s),
-        "xla_s": float(xla_s)}
-    _persist()
-    with _lock:
-        for k in [k for k in _decisions if k[0] == op_type and k[1] == key]:
-            del _decisions[k]
-    return verdict
-
-
-def clear_measurements() -> None:
-    _measured.clear()
-
-
 # -- decisions ----------------------------------------------------------------
 
 # (op_type, key, mode, backend, auto_partitioned) -> (impl_name, reason):
-# the same trace signature always routes the same way within a process
+# what was decided in this process (decisions_snapshot)
 _decisions: Dict[Tuple, Tuple[str, str]] = {}
+
+
+def _route(kd: KernelDef, key, mode: str, bk: str,
+           auto_partitioned: bool = False) -> Tuple[str, str]:
+    """The routing rule (module docstring): a pure function of its
+    arguments. It runs the kernel's eligibility check and cost gate —
+    shape arithmetic — and never traces, compiles or runs a lowering."""
+    if auto_partitioned and bk == "tpu":
+        return ("xla", "mesh_auto_partitioned")
+    if mode == "off":
+        return (kd.legacy, "mode_off")
+    inel = kd.eligible(key)
+    if inel:
+        return ("xla", inel)
+    if mode == "force":
+        return ("pallas", "forced")
+    verdict, reason = kd.cost_gate(key, bk)
+    if verdict is None:
+        # the gate abstains: the kernel on a TPU (the side the one chip
+        # measurement of an abstention came down on, PERF.md Findings
+        # (e)), the stock lowering where Pallas is interpreted
+        verdict = "pallas" if bk == "tpu" else "xla"
+    return (verdict, reason)
 
 
 def decide(op_type: str, key, mode: Optional[str] = None,
@@ -403,20 +235,10 @@ def decide(op_type: str, key, mode: Optional[str] = None,
     kd = _KERNELS[op_type]
     mode = mode or current_mode()
     bk = backend()
-    auto = bk == "tpu" and getattr(_state, "auto_partitioned", False)
-    cache_key = (op_type, key, mode, bk, auto)
+    auto = getattr(_state, "auto_partitioned", False)
+    hit = _route(kd, key, mode, bk, auto)
     with _lock:
-        hit = _decisions.get(cache_key)
-    if hit is None:
-        # compute OUTSIDE the lock: the uncached path may run the
-        # micro-autotune (two compiles + timed executions) and must not
-        # stall every other thread's routing decisions; racing threads
-        # at worst measure redundantly, and first-publish wins so the
-        # cached decision stays stable
-        computed = (("xla", "mesh_auto_partitioned") if auto
-                    else _decide_uncached(kd, key, mode, bk))
-        with _lock:
-            hit = _decisions.setdefault(cache_key, computed)
+        _decisions[(op_type, key, mode, bk, auto)] = hit
     impl, reason = hit
     if count:
         if impl == "pallas":
@@ -424,25 +246,6 @@ def decide(op_type: str, key, mode: Optional[str] = None,
         else:
             metric_fallback.get_cell(op_type, reason).increase_by(1)
     return hit
-
-
-def _decide_uncached(kd: KernelDef, key, mode: str, bk: str):
-    if mode == "off":
-        return (kd.legacy, "mode_off")
-    inel = kd.eligible(key)
-    if inel:
-        return ("xla", inel)
-    if mode == "force":
-        return ("pallas", "forced")
-    # auto: measured verdict wins over everything else
-    _load_persisted()
-    m = _measured.get((kd.op_type, key, bk, device_kind()))
-    if m is not None:
-        return (m["verdict"], "autotune")
-    verdict, reason = kd.cost_gate(key, bk)
-    if verdict is None or _autotune_forced():
-        return (_measure(kd, key, bk), "autotune")
-    return (verdict, reason)
 
 
 def select(op_type: str, key, mode: Optional[str] = None) -> Callable:
@@ -460,8 +263,8 @@ def decisions_snapshot() -> List[Dict[str, Any]]:
 
 
 def clear_decisions() -> None:
-    """Forget cached routing decisions (tests / after set_mode). Does
-    NOT retrace already-compiled executables."""
+    """Forget the recorded routing decisions (tests). Does NOT retrace
+    already-compiled executables."""
     with _lock:
         _decisions.clear()
 
@@ -482,13 +285,11 @@ def _backend_if_initialized() -> Optional[str]:
 
 
 def snapshot() -> Dict[str, Any]:
-    """Registry state for /statusz and bench artifacts."""
+    """Registry state for /statusz and the benchmark's kernel_routing."""
     routed = {labels[0]: cell.value()
               for labels, cell in metric_routed.cells().items()}
     fallback = {f"{labels[0]}:{labels[1]}": cell.value()
                 for labels, cell in metric_fallback.cells().items()}
-    autotune = {labels[0]: cell.value()
-                for labels, cell in metric_autotune_runs.cells().items()}
     flash_tiles = {"{}:{}x{}x{}".format(*labels): cell.value()
                    for labels, cell in metric_flash_tiles.cells().items()}
     return {
@@ -497,10 +298,12 @@ def snapshot() -> Dict[str, Any]:
         "kernels": kernel_types(),
         "routed": routed,
         "fallback": fallback,
-        "autotune_runs": autotune,
+        # nothing is timed any more; the key stays, empty, for its readers
+        # chipbench/harness.kernel_routing and chipbench/tests/
+        # test_manifest.py until a benchmark PR drops it (PERF.md Open
+        # questions (q))
+        "autotune_runs": {},
         "flash_tiles": flash_tiles,
-        "measured": {f"{op}|{bk}|{kind}": v["verdict"]
-                     for (op, _k, bk, kind), v in _measured.items()},
     }
 
 
@@ -510,10 +313,9 @@ def routing_report(ops, mode: Optional[str] = None,
                    backend_name: Optional[str] = None) -> List[Dict[str, Any]]:
     """Static per-op routing verdicts for a (possibly imported) graph:
     one record per op whose type has a registered kernel —
-    ``verdict`` in {"routed", "fallback", "autotune"} — plus aggregate
-    ``no-kernel`` counts for everything else. Never measures: keys the
-    static gate cannot price report verdict "autotune" (decided on
-    first live call)."""
+    ``verdict`` in {"routed", "fallback"}, decided by the same rule as a
+    live call (``_route``) — plus aggregate ``no-kernel`` counts for
+    everything else."""
     mode = mode or current_mode()
     bk = backend_name or backend()
     records: List[Dict[str, Any]] = []
@@ -537,25 +339,7 @@ def routing_report(ops, mode: Optional[str] = None,
                             "verdict": "fallback",
                             "reason": "unknown_shape"})
             continue
-        if mode == "off":
-            impl, reason = kd.legacy, "mode_off"
-        else:
-            inel = kd.eligible(key)
-            if inel:
-                impl, reason = "xla", inel
-            elif mode == "force":
-                impl, reason = "pallas", "forced"
-            else:
-                m = _measured.get((kd.op_type, key, bk, device_kind()))
-                if m is not None:
-                    impl, reason = m["verdict"], "autotune"
-                else:
-                    impl, reason = kd.cost_gate(key, bk)
-                    if impl is None:
-                        records.append({"op": op.name, "type": op.type,
-                                        "verdict": "autotune",
-                                        "reason": "unmeasured"})
-                        continue
+        impl, reason = _route(kd, key, mode, bk)
         records.append({"op": op.name, "type": op.type,
                         "verdict": "routed" if impl == "pallas"
                         else "fallback", "reason": reason})
@@ -570,14 +354,12 @@ def roofline_gate(flops: float, pallas_bytes: float, xla_bytes: float,
                   bk: str, margin: float = 1.25) -> Tuple[Optional[str], str]:
     """Price both lowerings with the PR 1 cost-model roofline (seconds =
     max(flops/peak_flops, bytes/peak_bw), utils/perf chip numbers) and
-    pick the clearly-faster one; within ``margin`` the gate abstains and
-    the micro-autotune decides.
+    pick the clearly-faster one; within ``margin`` the gate abstains
+    (``_route`` then takes the kernel on a TPU).
 
     Off-TPU the Pallas kernels run in interpret mode — each grid program
     executes as traced jnp calls, orders of magnitude off the roofline —
-    so the gate confidently falls back (reason ``interpret_backend``);
-    a measured verdict still overrides (decide() consults the autotune
-    cache first)."""
+    so the gate confidently falls back (reason ``interpret_backend``)."""
     if bk != "tpu":
         return ("xla", "interpret_backend")
     from ..utils import perf

@@ -595,8 +595,7 @@ def beam_search_decode(src, cfg: TransformerConfig | None = None,
     see the module docstring); use_cache=True carries per-layer KV
     caches through the loop and decodes ONE position per step through
     the DecodeAttention kernel (O(L) FLOPs) — token-for-token the same
-    search (int-exact ids; scores to float round-off), bench.py's
-    ``generative`` row pins the speedup.
+    search (int-exact ids; scores to float round-off).
     """
     cfg = cfg or TransformerConfig.big()
     b = int(src.shape[0])

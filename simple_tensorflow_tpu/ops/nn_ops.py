@@ -93,7 +93,7 @@ def _sparse_xent_eligible(key):
 def _lower_sparse_xent(ctx, op, inputs):
     """nn_ops sparse softmax-xent: routed through stf.kernels — the
     large-vocab Pallas streamed kernel replaces the composed
-    log_softmax + gather lowering when the cost model/autotune gates it
+    log_softmax + gather lowering when the cost gate lets it
     in (ops/pallas/softmax_xent.py); ``off`` mode keeps the composed
     lowering exactly."""
     from ..kernels import registry as _kreg
@@ -127,22 +127,12 @@ def _register_sparse_xent_kernel():
             itm = 4
         return _kreg.roofline_gate(5.0 * n, 1.2 * n * itm, 3.0 * n * itm, bk)
 
-    def _case(key):
-        import numpy as _np
-
-        (ls, ld), (labs, labd) = key[:2]
-        rng = _np.random.RandomState(0)
-        logits = rng.randn(*ls).astype(_np.float32)
-        labels = rng.randint(0, ls[-1], size=labs).astype(_np.int32)
-        return ((logits, labels), {})
-
     _kreg.register_kernel(
         "SparseSoftmaxCrossEntropyWithLogits",
         impls={"pallas": _sparse_xent_pallas, "xla": _sparse_softmax_xent},
         legacy="xla",
         eligible=_sparse_xent_eligible,
         cost_gate=_gate,
-        make_case=_case,
         graph_key=lambda op: _sparse_xent_graph_key(op),
         doc="composed log_softmax+gather vs the Pallas streamed "
             "online-softmax xent kernel")

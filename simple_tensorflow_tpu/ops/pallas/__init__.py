@@ -56,14 +56,6 @@ def _bytes_of(aval_entry):
     return n * _np_of(dt).itemsize
 
 
-def _rand(shape, dt, seed=0):
-    rng = np.random.RandomState(seed)
-    d = _np_of(dt)
-    if d.kind in "iu":
-        return rng.randint(0, 4, size=shape).astype(d)
-    return rng.randn(*shape).astype(np.float32).astype(d)
-
-
 # ---------------------------------------------------------------------------
 # FlashAttention (+Dropout): Pallas streamed kernel vs composed matmuls
 # ---------------------------------------------------------------------------
@@ -104,26 +96,12 @@ def _flash_gate(key, bk):
                                qkv_bytes + 3.0 * b * h * sq * sk * 4, bk)
 
 
-def _flash_case(key):
-    (qs, qd), (ks, kd), (vs, vd), bias = key[:4]
-    statics = dict(key[4:])
-    args = [_rand(qs, qd, 0), _rand(ks, kd, 1), _rand(vs, vd, 2)]
-    kw = {"causal": bool(statics.get("causal", False))}
-    if bias is not None:
-        kw["bias"] = _rand(bias[0], bias[1], 3)
-    if statics.get("dropout"):
-        kw["dropout_rate"] = 0.1
-        kw["dropout_seed"] = np.asarray([7], np.int32)
-    return tuple(args), kw
-
-
 _kreg.register_kernel(
     "FlashAttention",
     impls={"pallas": flash_attention, "xla": attention_xla},
     legacy="pallas",
     eligible=_flash_eligible,
     cost_gate=_flash_gate,
-    make_case=_flash_case,
     graph_key=lambda op: _flash_graph_key(op),
     doc="streamed FlashAttention-2 kernel vs composed batch-matmul "
         "attention")
@@ -133,7 +111,6 @@ _kreg.register_kernel(
     legacy="pallas",
     eligible=_flash_eligible,
     cost_gate=_flash_gate,
-    make_case=_flash_case,
     graph_key=lambda op: _flash_graph_key(op, dropout=True),
     doc="FlashAttention with in-kernel probability dropout (counter-"
         "based mask shared with the composed fallback)")
@@ -237,18 +214,12 @@ def _ln_gate(key, bk):
     return _kreg.roofline_gate(5.0 * n, 2.0 * xb, 4.0 * xb, bk)
 
 
-def _ln_case(key):
-    (xs, xd), (gs, gd), (bs, bd) = key[:3]
-    return ((_rand(xs, xd, 0), _rand(gs, gd, 1), _rand(bs, bd, 2)), {})
-
-
 _kreg.register_kernel(
     "FusedLayerNorm",
     impls={"pallas": layer_norm, "xla": layer_norm_reference},
     legacy="pallas",
     eligible=_ln_eligible,
     cost_gate=_ln_gate,
-    make_case=_ln_case,
     graph_key=lambda op: _simple_graph_key(op),
     doc="one-pass fused layer norm vs composed mean/var/normalize")
 
@@ -293,17 +264,6 @@ def _xent_gate(key, bk):
     return _kreg.roofline_gate(5.0 * n, 1.2 * lb, 3.0 * lb, bk)
 
 
-def _xent_case(key):
-    (ls, ld), (labs, labd) = key[:2]
-    statics = dict(key[2:])
-    logits = _rand(ls, ld, 0)
-    labels = np.random.RandomState(1).randint(
-        0, ls[-1], size=labs).astype(_np_of(labd))
-    return ((logits, labels),
-            {"label_smoothing": 0.1 if statics.get("label_smoothing")
-             else 0.0})
-
-
 _kreg.register_kernel(
     "FusedSoftmaxXent",
     impls={"pallas": softmax_cross_entropy,
@@ -311,7 +271,6 @@ _kreg.register_kernel(
     legacy="pallas",
     eligible=_xent_eligible,
     cost_gate=_xent_gate,
-    make_case=_xent_case,
     graph_key=lambda op: _simple_graph_key(op),
     doc="streamed sparse softmax-xent vs composed log_softmax + gather")
 
@@ -355,22 +314,12 @@ def _qmm_gate(key, bk):
     return (None, "cost_model_uncertain")
 
 
-def _qmm_case(key):
-    (xs, xd), (ws, wd), (ss, sd) = key[:3]
-    rng = np.random.RandomState(0)
-    x = rng.randn(*xs).astype(_np_of(xd))
-    wq = rng.randint(-127, 128, size=ws).astype(np.int8)
-    scale = (rng.rand(*ss).astype(np.float32) * 0.1 + 0.01)
-    return ((x, wq, scale), {})
-
-
 _kreg.register_kernel(
     "QuantMatMul",
     impls={"pallas": quant_matmul_ste, "xla": quant_matmul_ste_reference},
     legacy="pallas",
     eligible=_qmm_eligible,
     cost_gate=_qmm_gate,
-    make_case=_qmm_case,
     graph_key=lambda op: _simple_graph_key(op),
     doc="int8 MXU quantized matmul (straight-through vjp) vs int32 dot")
 
@@ -387,8 +336,8 @@ op_registry.register("QuantMatMul", lower=_lower_quant_matmul)
 # ---------------------------------------------------------------------------
 # FusedDropoutBiasResidual: blocked elementwise kernel vs fused XLA chain.
 # XLA fuses a pure elementwise chain into one pass itself, so the static
-# gate prefers the composed lowering; the kernel is there for ``force``
-# (testability) and for measured wins via the autotune cache.
+# gate prefers the composed lowering; the kernel runs only under
+# ``force`` (ROADMAP S11 (a)).
 # ---------------------------------------------------------------------------
 
 def _dbr_eligible(key):
@@ -410,17 +359,6 @@ def _dbr_gate(key, bk):
     return ("xla", "cost_model")
 
 
-def _dbr_case(key):
-    (xs, xd), (rs, rd), bias = key[:3]
-    statics = dict(key[3:])
-    args = [_rand(xs, xd, 0), _rand(rs, rd, 1)]
-    kw = {"rate": float(statics.get("rate", 0.1)),
-          "seed": np.asarray([5], np.int32)}
-    if bias is not None:
-        kw["bias"] = _rand(bias[0], bias[1], 2)
-    return tuple(args), kw
-
-
 def _dbr_pallas(x, residual, bias=None, *, rate, seed):
     return dropout_bias_residual(x, residual, bias, rate=rate, seed=seed)
 
@@ -436,7 +374,6 @@ _kreg.register_kernel(
     legacy="xla",
     eligible=_dbr_eligible,
     cost_gate=_dbr_gate,
-    make_case=_dbr_case,
     graph_key=lambda op: _dbr_graph_key(op),
     doc="fused residual + dropout(x + bias) vs composed elementwise "
         "chain (identical counter-based mask)")
@@ -485,37 +422,10 @@ def _flat_gate(key, bk):
         return ("xla", "interpret_backend")
     n = int(dict(key).get("n", 0))
     # one guaranteed pass over the g/m/v/p streams; below ~1M elements
-    # launch overhead and XLA's own fusion make it a wash — measure
+    # launch overhead and XLA's own fusion make it a wash — abstain
     if n >= (1 << 20):
         return ("pallas", "cost_model")
     return (None, "cost_model_uncertain")
-
-
-def _adam_case(key):
-    st = dict(key)
-    n = int(st["n"])
-    pdt, udt = st["pdt"], st["udt"]
-    rng = np.random.RandomState(0)
-    p = rng.randn(n).astype(_np_of(pdt))
-    m = rng.randn(n).astype(_np_of(udt)) * 0.01
-    v = np.abs(rng.randn(n)).astype(_np_of(udt)) * 0.01
-    g = rng.randn(n).astype(_np_of(udt))
-    alpha = np.asarray(0.001, _np_of(udt))
-    return ((p, m, v, g, alpha),
-            {"beta1": 0.9, "beta2": 0.999, "eps": 1e-8})
-
-
-def _momentum_case(key):
-    st = dict(key)
-    n = int(st["n"])
-    pdt, udt = st["pdt"], st["udt"]
-    rng = np.random.RandomState(0)
-    p = rng.randn(n).astype(_np_of(pdt))
-    acc = rng.randn(n).astype(_np_of(udt)) * 0.01
-    g = rng.randn(n).astype(_np_of(udt))
-    lr = np.asarray(0.01, _np_of(udt))
-    mu = np.asarray(0.9, _np_of(udt))
-    return ((p, acc, g, lr, mu), {"use_nesterov": False})
 
 
 _kreg.register_kernel(
@@ -523,7 +433,6 @@ _kreg.register_kernel(
     impls={"pallas": adam_update, "xla": adam_update_reference},
     legacy="xla",
     cost_gate=_flat_gate,
-    make_case=_adam_case,
     graph_key=lambda op: _opt_graph_key(op),
     doc="one flat m/v/param Adam update per dtype group vs the fused "
         "XLA closure")
@@ -532,7 +441,6 @@ _kreg.register_kernel(
     impls={"pallas": momentum_update, "xla": momentum_update_reference},
     legacy="xla",
     cost_gate=_flat_gate,
-    make_case=_momentum_case,
     graph_key=lambda op: _opt_graph_key(op),
     doc="one flat accumulator/param Momentum update per dtype group vs "
         "the fused XLA closure")
@@ -595,23 +503,12 @@ def _decode_attn_gate(key, bk):
         cache_bytes + 3.0 * b * kq * h * max_len * 4, bk)
 
 
-def _decode_attn_case(key):
-    (qs, qd), (ks, kd), (vs, vd), bias = key[:4]
-    args = [_rand(qs, qd, 0), _rand(ks, kd, 1), _rand(vs, vd, 2),
-            np.full((qs[0],), ks[1] // 2 + 1, np.int32)]
-    kw = {}
-    if bias is not None:
-        kw["bias"] = _rand(bias[0], bias[1], 3)
-    return tuple(args), kw
-
-
 _kreg.register_kernel(
     "DecodeAttention",
     impls={"pallas": decode_attention, "xla": decode_attention_xla},
     legacy="xla",
     eligible=_decode_attn_eligible,
     cost_gate=_decode_attn_gate,
-    make_case=_decode_attn_case,
     graph_key=lambda op: _decode_attn_graph_key(op),
     doc="paged-cache decode attention (query length 1, heads on the "
         "sublane axis) vs composed masked softmax")

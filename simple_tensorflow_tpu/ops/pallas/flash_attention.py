@@ -811,7 +811,7 @@ def attention_xla(q, k, v, *, causal=False, sm_scale=None, bias=None,
     (batch_matmul → softmax → batch_matmul, the reference's attention
     path; ref core/kernels/{batch_matmul_op,softmax_op}.cc) — the
     registry's fallback when the Pallas kernel is ineligible or the
-    cost model/autotune prices the fused kernel slower (tiny shapes;
+    cost gate prices the fused kernel slower (tiny shapes;
     every shape off-TPU, where Pallas runs in interpret mode).
 
     Call-compatible with :func:`flash_attention` including in-kernel

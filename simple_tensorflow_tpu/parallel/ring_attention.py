@@ -234,7 +234,6 @@ def _register_ring_kernel():
         legacy="pallas",
         eligible=_p._flash_eligible,
         cost_gate=_p._flash_gate,
-        make_case=_p._flash_case,
         graph_key=_graph_key,
         doc="sequence-parallel ring attention; the per-block kernel "
             "routes like FlashAttention")

@@ -195,7 +195,7 @@ def _statusz_info() -> Dict[str, Any]:
     if kernels_mod is not None:
         try:
             # kernel tier (stf.kernels): mode, per-op routed/fallback
-            # counters, autotune verdicts (docs/PERFORMANCE.md)
+            # counters (docs/PERFORMANCE.md)
             info["kernels"] = kernels_mod.snapshot()
         except Exception as e:  # noqa: BLE001 — statusz is best-effort
             info["kernels"] = {"error": str(e)}

@@ -231,7 +231,7 @@ def main(argv=None):
                          "auto): activates the lint/kernel-routing "
                          "rule and prints a per-op-type verdict "
                          "summary (routed / fallback+reason / "
-                         "autotune / no-kernel)")
+                         "no-kernel)")
     ap.add_argument("--autoshard", action="store_true",
                     help="run the auto-sharding search "
                          "(stf.analysis.autoshard) over the graph on "

@@ -42,8 +42,7 @@ def _c(value, var):
 # Keeping m/v flat ACROSS steps is the perf point: the per-variable
 # layout would force a gather/scatter of every slot every step, and the
 # Session's state dict shrinks from O(3N) to O(N + groups) arrays —
-# which is most of the per-step tail cost at small-variable counts
-# (the bench kernel_tier row pins it).
+# which is most of the per-step tail cost at small-variable counts.
 #
 # The flat slots are ordinary Variables (saved/restored by Saver,
 # initialized by global_variables_initializer); get_slot() returns
@@ -51,8 +50,8 @@ def _c(value, var):
 # see the same shapes/values as the per-variable layout. The flat math
 # is kept op-for-op identical to the per-variable chains, so fused and
 # unfused trajectories are bit-exact (tests/test_kernel_registry.py).
-# Kill switch: kernel-registry mode "off" (STF_PALLAS=0) at
-# graph-construction time rebuilds the per-variable assigns exactly as
+# Kill switch: kernel-registry mode "off" (stf.kernels.set_mode("off"))
+# at graph-construction time rebuilds the per-variable assigns exactly as
 # before (note: the checkpoint layout of optimizer slots differs
 # between modes — resume in the mode you saved in).
 # ---------------------------------------------------------------------------

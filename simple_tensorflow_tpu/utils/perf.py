@@ -19,8 +19,8 @@ import numpy as np
 
 # Published per-chip figures, keyed by the ``device_kind`` JAX reports:
 # (peak bf16 FLOP/s, HBM bytes/s, HBM bytes). The one table of the
-# repo — bench.py and the kernel cost gate read it too. An accelerator
-# that is not in it is an error, never a default.
+# repo — the kernel cost gate reads it too. An accelerator that is not
+# in it is an error, never a default.
 #   "TPU v5 lite" (v5e): Google Cloud documentation, "TPU v5e" —
 #   197 TFLOP/s bf16, 819 GB/s and 16 GB of HBM per chip.
 CHIP_TABLE = {

@@ -264,16 +264,80 @@ def test_cond_scan_post_optimization_cost_ratchet():
     np.testing.assert_allclose(got, expected, rtol=1e-4, atol=1e-5)
 
 
+def _lowered_cost(train_op, loss, feed):
+    """Plan the session step for (train_op, loss) under `feed`, lower and
+    compile it WITHOUT running, and return XLA's cost analysis.
+
+    Kernel-registry mode must be pinned to "off" (stf.kernels) by the
+    caller AT GRAPH BUILD (the model builders run under
+    ``stf.kernels.activate("off")``): the byte budgets were calibrated
+    against the pre-registry lowerings, which "off" reproduces
+    exactly. On this CPU gate "auto" would deliberately fall back to
+    the composed XLA lowerings (materialized attention scores /
+    log-softmax — the very traffic the budgets exist to catch),
+    "force" routes EVERY kernel through interpret-mode Pallas whose
+    per-grid-step HLO inflates XLA's byte accounting, and the fused
+    optimizer tail's flat-slot slices are charged full-buffer reads by
+    XLA's (pre-fusion) cost analysis. None of those is the calibrated
+    baseline."""
+    sess = stf.Session(config=stf.ConfigProto(kernel_registry="off"))
+    sess.run(stf.global_variables_initializer())
+    feeds = sess._normalize_feeds(feed)
+    step = sess._plan([train_op, loss], feeds)
+    assert step.has_device_stage, "train step lowered to host-only?"
+    feed_args = {t.name: feeds[t] for t in step.feed_tensors}
+    state = dict(sess._variable_store.values)
+    compiled = step.jitted.lower(dict(state), feed_args,
+                                 sess._base_key, np.uint32(0)).compile()
+    cost = compiled.cost_analysis()
+    if isinstance(cost, list):
+        cost = cost[0]
+    return {
+        "flops": float(cost.get("flops", 0.0)),
+        "bytes_accessed": float(cost.get("bytes accessed", 0.0)),
+        "gbytes": round(float(cost.get("bytes accessed", 0.0)) / 1e9, 2),
+        "tflops": round(float(cost.get("flops", 0.0)) / 1e12, 3),
+    }
+
+
+def _resnet_cost(batch=256, image=224):
+    from simple_tensorflow_tpu.kernels import registry as kreg
+    from simple_tensorflow_tpu.models import resnet
+
+    stf.reset_default_graph()
+    with kreg.activate("off"):  # calibrated pre-registry lowerings
+        m = resnet.resnet50_train_model(batch_size=batch,
+                                        image_size=image,
+                                        dtype=stf.bfloat16,
+                                        learning_rate=0.1)
+    images, labels = resnet.synthetic_imagenet(batch, image)
+    feed = {m["images"]: jnp.asarray(images, stf.bfloat16.np_dtype),
+            m["labels"]: jnp.asarray(labels)}
+    return _lowered_cost(m["train_op"], m["loss"], feed)
+
+
+def _bert_cost(batch=24, seq_len=512):
+    from simple_tensorflow_tpu.kernels import registry as kreg
+    from simple_tensorflow_tpu.models import bert
+
+    stf.reset_default_graph()
+    cfg = bert.BertConfig.base()
+    max_pred = max(1, int(seq_len * 0.15))
+    with kreg.activate("off"):  # calibrated pre-registry lowerings
+        m = bert.bert_pretrain_model(
+            batch_size=batch, seq_len=seq_len, max_predictions=max_pred,
+            cfg=cfg, compute_dtype=stf.bfloat16, use_input_mask=True)
+    batch_np = bert.synthetic_pretrain_batch(batch, seq_len, max_pred,
+                                             vocab_size=cfg.vocab_size)
+    batch_np["input_mask"] = np.ones((batch, seq_len), np.int32)
+    feed = {m[k]: jnp.asarray(v) for k, v in batch_np.items()}
+    return _lowered_cost(m["train_op"], m["loss"], feed)
+
+
 @pytest.mark.skipif(not _RUN_BUDGET, reason="STF_BYTE_BUDGET=0")
 def test_resnet_train_step_byte_budget():
-    import sys
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from benchmarks import byte_budget
-
     _enable_cache()
-    cost = byte_budget.resnet_cost(batch=256)
+    cost = _resnet_cost(batch=256)
     assert cost["bytes_accessed"] <= _RESNET_BYTES_BUDGET, (
         f"ResNet-b256 step bytes regressed: {cost['gbytes']} GB > "
         f"{_RESNET_BYTES_BUDGET / 1e9} GB budget (calibrated 367 GB; a "
@@ -286,14 +350,8 @@ def test_resnet_train_step_byte_budget():
 
 @pytest.mark.skipif(not _RUN_BUDGET, reason="STF_BYTE_BUDGET=0")
 def test_bert_train_step_byte_budget():
-    import sys
-
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    from benchmarks import byte_budget
-
     _enable_cache()
-    cost = byte_budget.bert_cost(batch=24)
+    cost = _bert_cost(batch=24)
     assert cost["bytes_accessed"] <= _BERT_BYTES_BUDGET, (
         f"BERT-b24-s512 step bytes regressed: {cost['gbytes']} GB > "
         f"{_BERT_BYTES_BUDGET / 1e9} GB budget (calibrated 167.6 GB)")

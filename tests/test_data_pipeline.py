@@ -509,10 +509,9 @@ class TestCompileCacheWiring:
         """The one rule (compiler/aot.py): with
         JAX_COMPILATION_CACHE_DIR set, no stf path sets another
         directory — not ConfigProto, not STF_COMPILE_CACHE, not the
-        checkout default — and the autotune verdicts follow it."""
+        checkout default."""
         import jax
         from simple_tensorflow_tpu.compiler import aot
-        from simple_tensorflow_tpu.kernels import registry as kreg
 
         outside = str(tmp_path / "outside")
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
@@ -534,8 +533,6 @@ class TestCompileCacheWiring:
         assert not updates      # nor its thresholds: JAX's handling stands
         assert jax.config.jax_compilation_cache_dir == before
         assert aot.persistent_cache_dir() == outside
-        assert kreg._cache_file() == os.path.join(
-            outside, "stf_kernel_autotune.json")
 
     def test_default_is_the_checkout_cache(self, monkeypatch):
         import jax

@@ -587,7 +587,7 @@ class TestGoldenCommitted:
         prediction (gradient/batch-stat sync, the dominant wire cost)
         tracks XLA within 25%. (Total bytes are NOT compared here: XLA
         all-gathers the scan-stacked residuals on its dynamic-slice
-        layout choice — resnet, scan-free, pins the total in bench.py.)
+        layout choice.)
         """
         from simple_tensorflow_tpu.models import transformer as tr
 

@@ -302,7 +302,7 @@ def lm_big_programs(one_chip):
     with _lowering_for_the_described_chip() as mp, graph.as_default(), \
             stf.Session(graph=graph) as sess:
         mp.setattr(kreg, "backend", lambda: "tpu")
-        mp.setattr(kreg, "_mode_override", "force")
+        mp.setattr(kreg, "_default_mode", "force")
         prog = causal_lm.build_causal_lm_program(
             cfg, page_len=LM_PAGE_LEN, pages_per_seq=LM_PAGES_PER_SEQ,
             num_pages=LM_PAGES, decode_bucket_sizes=(96,),
