@@ -417,7 +417,8 @@ def _rule_serving_decode_cache(ctx):
     between decode steps; this rule makes both halves statically
     checkable:
 
-    - a cache op (KVCacheAlloc/Append/Gather) whose committed-sharding
+    - a cache op (KVCacheAlloc/Append/Gather, or a PagedDecodeAttention
+      reading two pools in place) whose committed-sharding
       declaration is missing would commit at whatever layout the first
       write happened to produce — resharding every subsequent step;
     - a cache tensor ESCAPING TO HOST (a host-stage op consuming a
@@ -456,9 +457,8 @@ def _rule_serving_decode_cache(ctx):
     committed_decls = {}
     for op in ctx.ops:
         if _kvc.is_cache_op(op) and op.type != "KVCachePageCopy":
-            vn = op.attrs.get("var_name")
             decl = op.attrs.get(_kvc.SHARDING_ATTR)
-            if vn is not None and decl:
+            for vn in _kvc.cache_names(op) if decl else ():
                 committed_decls.setdefault(vn, set()).add(str(decl))
 
     fetched = set()
@@ -553,15 +553,19 @@ def _rule_serving_decode_cache(ctx):
                                "per-shard instead (heads are "
                                "embarrassingly parallel)")
         paged = bool(op.attrs.get(_kvc.PAGED_ATTR))
+        # a paged attention's output is attention, not pages: what it
+        # REACHES counts, as it did through the gather that fed the
+        # kernel before the pool was read in place
+        pages_out = op.type != "PagedDecodeAttention"
         for out in op.outputs:
-            if out in fetched:
+            if pages_out and out in fetched:
                 yield (op,
                        f"cache tensor {out.name!r} is fetched — the "
                        "whole cache page set would transfer "
                        "device->host every decode step; fetch derived "
                        "values instead")
             direct_sink = False
-            for consumer in out.consumers():
+            for consumer in out.consumers() if pages_out else ():
                 if _is_host_sink(consumer):
                     direct_sink = True
                     yield (op,

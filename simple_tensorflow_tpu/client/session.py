@@ -2732,7 +2732,10 @@ class BaseSession:
                     axis = None
                 if axis is not None and axis in mesh.shape:
                     return mesh.axis_size(axis)
-            ns = shardings.get(op.attrs.get("var_name", op.name))
+            vn = op.attrs.get("var_name", op.name)
+            # a list names several store entries (a fused update, a
+            # paged attention's K and V pools): the op's output is none
+            ns = shardings.get(vn) if isinstance(vn, str) else None
             if ns is not None:
                 try:
                     shape = tuple(int(d) for d in t.shape)

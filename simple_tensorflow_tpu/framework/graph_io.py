@@ -137,6 +137,11 @@ def _build_nodes_into(target_graph, nodes, tensor_env, scope_prefix,
         if scope_prefix:
             if isinstance(attrs.get("var_name"), str):
                 attrs["var_name"] = f"{scope_prefix}/{attrs['var_name']}"
+            elif isinstance(attrs.get("var_name"), (list, tuple)):
+                # an op over several store entries (a fused update, a
+                # paged attention's K and V pools)
+                attrs["var_name"] = type(attrs["var_name"])(
+                    f"{scope_prefix}/{n}" for n in attrs["var_name"])
             if isinstance(attrs.get("var_names"), tuple):
                 attrs["var_names"] = tuple(
                     f"{scope_prefix}/{n}" for n in attrs["var_names"])

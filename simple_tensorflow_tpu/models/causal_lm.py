@@ -18,8 +18,8 @@ Two halves:
   caches by (slot, position) with one row per live sequence, the causal
   LM keys them by PAGE: each cache is ``(num_pages + 1, page_len, H,
   hd)`` with ``paged=True``, a sequence's KV state is the ordered page
-  list in its page table, and attention reads through the page-table
-  gather (``slots (B, n_blocks)`` → the concatenated logical view).
+  list in its page table, and attention reads K and V pages from the
+  pool through that table (``PagedDecodeAttention``; no logical view).
   That indirection is what the shared-prefix prompt cache
   (serving/prefix_cache.py) needs: two sequences whose prompts share a
   prefix point their leading page-table entries at the SAME physical
@@ -35,12 +35,20 @@ import numpy as np
 
 import simple_tensorflow_tpu as stf
 from simple_tensorflow_tpu.models import common
+from simple_tensorflow_tpu.platform import monitoring
 from simple_tensorflow_tpu.models.transformer import (
     TransformerConfig, _attention, _block_decode, _dense, _embed, _ffn,
     _incremental_decode, _ln, _residual, _tp_gather,
     build_int8_logits_weights, decode_tp_collective_bytes,
     decode_tp_partition_rules, generative_cache_bytes, resolve_decode_tp,
     smoothed_xent)
+
+_live_page_share = monitoring.Sampler(
+    "/stf/serving/decode_live_page_share",
+    monitoring.ExponentialBuckets(0.01, 1.5, 12),
+    "Per decode step: page-table entries that hold a live page, summed "
+    "over the step's rows, over rows x pages_per_seq: the share of the "
+    "table paged decode attention reads", "model")
 
 # the causal LM reuses TransformerConfig (decoder-side fields only:
 # d_model/num_heads/d_ff/num_layers/dropout/vocab/max_len)
@@ -114,11 +122,12 @@ class _PagedCaches:
     page-table counterpart of ``transformer._SlotCaches``.
 
     Appends land at ``(dst_pages[b], offsets[b] + j)`` — ONE physical
-    page per sequence per step/block — while the gather reads the
-    LOGICAL view through ``page_tables (B, n_blocks)``, so attention
-    sees the sequence's full history across however many (possibly
-    shared) pages it spans. The RAW between a layer's append and its
-    gather is ordered by an explicit control dependency (the appended
+    page per sequence per step/block — while attention reads the
+    sequence's full history, across however many (possibly shared)
+    pages it spans, through ``page_tables (B, n_blocks)``: K and V
+    pages straight from the stored pools (``PagedDecodeAttention``),
+    no logical view gathered. The RAW between a layer's appends and its
+    read is ordered by an explicit control dependency (the appended
     page is always present in the table)."""
 
     def __init__(self, caches, page_tables, dst_pages, offsets, base):
@@ -128,22 +137,26 @@ class _PagedCaches:
         self._off = offsets          # (B,) int32 in-page start offset
         self._base = base            # (B,) int32 committed length BEFORE
 
-    def _one(self, cache, new):
-        appended = cache.append(new, self._dst, self._off)
-        with stf.control_dependencies([appended.op]):
-            return cache.gather(self._tables)
-
-    def append_and_gather(self, layer, k_new, v_new):
+    def _attend(self, layer, q, k_new, v_new, lengths, causal_offset):
         kc, vc = self._caches[layer]
-        return (self._one(kc, k_new), self._one(vc, v_new),
-                self._base + 1)
+        with self.after_append(self.append(layer, k_new, v_new)):
+            return stf.nn.paged_decode_attention(
+                q, kc, vc, self._tables, lengths,
+                causal_offset=causal_offset)
 
-    def append_and_gather_block(self, layer, k_new, v_new):
-        kc, vc = self._caches[layer]
-        return self._one(kc, k_new), self._one(vc, v_new), self._base
+    def attend(self, layer, q, k_new, v_new):
+        """One decode position: ``q (B, H, D)`` over the history with
+        the new row appended (``transformer._incremental_decode``)."""
+        return self._attend(layer, q, k_new, v_new, self._base + 1, False)
+
+    def attend_block(self, layer, q, k_new, v_new):
+        """A page-aligned block: ``q (B, Kq, H, D)``, query j sees the
+        committed prefix plus block positions <= j
+        (``transformer._block_decode``)."""
+        return self._attend(layer, q, k_new, v_new, self._base, True)
 
     # a stack that keeps more than K and V per layer, or reads selected
-    # rows instead of the whole view, appends first and reads under
+    # rows instead of the whole history, appends first and reads under
     # ``after_append``'s control dependency
     def append(self, layer, *new):
         return [c.append(n, self._dst, self._off)
@@ -395,10 +408,13 @@ class CausalLMGenerativeModel:
                  compute_dtype=stf.float32, int8=False, sampling=None,
                  checkpoint=None, init_fresh=False, config=None,
                  scope="causal_lm", aot_warmup=True, seed=0,
-                 mesh=None, tp=None):
+                 mesh=None, tp=None, metrics_label=None):
         if checkpoint is None and not init_fresh:
             raise ValueError("pass checkpoint=... or init_fresh=True")
         self.cfg = cfg
+        # labels the model's per-step samplers: give it the name the
+        # model is served under
+        self._metrics_label = metrics_label or scope
         self.page_len = int(page_len)
         self.pages_per_seq = int(pages_per_seq)
         self.num_pages = int(num_pages)
@@ -505,7 +521,14 @@ class CausalLMGenerativeModel:
         return build_causal_lm_program(self.cfg, int8=self.int8, **kw)
 
     def _after_decode(self, out, n, positions):
-        """What a decode step fetched beside the tokens (``extra``)."""
+        """Once a decode step, with what it fetched beside the tokens
+        (``extra``). Here: the share of the step's page-table entries
+        that hold a live page, host-known — what paged decode attention
+        reads of the tables it is handed (the rest it skips)."""
+        live = -(-(np.asarray(positions[:n], np.int64) + 1)
+                 // self.page_len)
+        _live_page_share.get_cell(self._metrics_label).add(
+            float(live.sum()) / (n * self.pages_per_seq))
 
     @property
     def decode_buckets(self):
@@ -622,6 +645,10 @@ class CausalLMGenerativeModel:
                 "pages_per_seq": self.pages_per_seq,
                 "num_slots": self.num_slots, "int8": self.int8,
                 "sampling": self.sampling}
+        share = _live_page_share.cells().get((self._metrics_label,))
+        if share is not None:
+            v = share.value()
+            info["decode_live_page_share"] = v["sum"] / v["count"]
         if self.tp_degree > 1:
             info["tp"] = self.tp_info()
         return info

@@ -247,18 +247,18 @@ class SparseMoEGenerativeModel(CausalLMGenerativeModel):
     ``decode``, ``copy_page``, buckets, page geometry) is
     :class:`CausalLMGenerativeModel`'s, the block stack is this module's.
 
-    ``metrics_label`` labels the two per-step samplers
-    (``/stf/serving/moe_load_imbalance``, ``sparse_selected_share``):
-    give it the name the model is served under.
+    ``metrics_label`` (the base class's) labels the two per-step
+    samplers (``/stf/serving/moe_load_imbalance``,
+    ``sparse_selected_share``): give it the name the model is served
+    under. Its attention reads selected rows, not whole pages, so it
+    does not sample ``decode_live_page_share``.
     """
 
-    def __init__(self, cfg: SparseMoEConfig, *, metrics_label=None,
-                 pages_per_seq=4, **kw):
+    def __init__(self, cfg: SparseMoEConfig, *, pages_per_seq=4, **kw):
         for unsupported in ("int8", "mesh", "tp"):
             if kw.get(unsupported):
                 raise ValueError(f"{type(self).__name__} has no "
                                  f"{unsupported}= path")
-        self._metrics_label = metrics_label or kw.get("scope", "causal_lm")
         super().__init__(cfg, pages_per_seq=pages_per_seq, **kw)
 
     def _cache_bytes(self):

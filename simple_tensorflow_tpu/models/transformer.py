@@ -345,7 +345,26 @@ def transformer_train_model(batch_size=64, src_len=64, tgt_len=64,
 # Incremental (KV-cached) decode
 # ---------------------------------------------------------------------------
 
-class _BeamCaches:
+class _GatheredCaches:
+    """What ``_incremental_decode`` / ``_block_decode`` ask a cache
+    accessor for — the attention of one layer over its cache with the
+    new rows appended — answered by a cache that is a dense row a
+    sequence: gather it, run the gathered-view kernel. (The paged
+    accessor, models/causal_lm.py, answers with attention read straight
+    off the pool.)"""
+
+    def attend(self, layer, q, k_new, v_new):
+        k_all, v_all, lengths = self.append_and_gather(layer, k_new, v_new)
+        return stf.nn.decode_attention(q, k_all, v_all, lengths)
+
+    def attend_block(self, layer, q, k_new, v_new):
+        k_all, v_all, base = self.append_and_gather_block(layer, k_new,
+                                                          v_new)
+        return stf.nn.decode_attention(q, k_all, v_all, base,
+                                       causal_offset=True)
+
+
+class _BeamCaches(_GatheredCaches):
     """Loop-carried functional caches for the cached beam search: one
     (k, v) pair per decoder layer, each (B, L, H, hd), updated in-place
     functionally via a one-hot position mask (static shapes — the whole
@@ -370,7 +389,7 @@ class _BeamCaches:
         return k_all, v_all, lengths
 
 
-class _SlotCaches:
+class _SlotCaches(_GatheredCaches):
     """Variable-backed paged caches for the serving decode step: each
     layer's k/v live device-resident in the VariableStore
     (ops/kv_cache_ops.py); appends scatter at (slot, position) and the
@@ -440,7 +459,9 @@ def _incremental_decode(tok, pos, caches, cross_kv, cross_bias, cross_len,
     """ONE decoder position for B sequences against cached state.
 
     tok: (B,) int32 input tokens; pos: scalar or (B,) int32 position(s);
-    caches: a :class:`_BeamCaches` / :class:`_SlotCaches` accessor;
+    caches: a :class:`_BeamCaches` / :class:`_SlotCaches` accessor (or
+    the paged one of models/causal_lm.py): ``caches.attend(layer, q,
+    k_new, v_new)`` appends the new rows and returns the attention;
     cross_kv: [(ck, cv)] per layer (B, S_src, H, hd); cross_bias:
     (B, S_src) additive f32; cross_len: (B,) int32. Returns
     (h (B, d_model) in compute dtype, emb) — the caller owns the logits
@@ -486,10 +507,7 @@ def _incremental_decode(tok, pos, caches, cross_kv, cross_bias, cross_len,
                                             [b, 1, heads, hd])
                         v_new = stf.reshape(_dense(h, d, cfg, "v"),
                                             [b, 1, heads, hd])
-                        k_all, v_all, lengths = caches.append_and_gather(
-                            i, k_new, v_new)
-                        a = stf.nn.decode_attention(q, k_all, v_all,
-                                                    lengths)
+                        a = caches.attend(i, q, k_new, v_new)
                         a = _tp_gather(stf.reshape(a, [b, d]), tp_axis)
                         a = _dense(a, d, cfg, "out")
                     h = _ln(_residual(a, h, cfg, False), cfg, "ln1")
@@ -516,7 +534,7 @@ def _block_decode(tok_block, pos, caches, cross_kv, cross_bias, cross_len,
     tok_block: (B, Kq) int32 input tokens at positions
     ``pos[b]..pos[b]+Kq-1``; pos: (B,) int32 committed prefix per
     sequence BEFORE the block; caches: an accessor with
-    ``append_and_gather_block`` (:class:`_SlotCaches`, or the paged
+    ``attend_block`` (:class:`_SlotCaches`, or the paged
     variant in models/causal_lm.py); cross args as in
     :func:`_incremental_decode` (``cross_kv=None`` for decoder-only).
     Returns (h (B, Kq, d_model), emb).
@@ -556,11 +574,7 @@ def _block_decode(tok_block, pos, caches, cross_kv, cross_bias, cross_len,
                                             [b, kq, heads, hd])
                         v_new = stf.reshape(_dense(h, d, cfg, "v"),
                                             [b, kq, heads, hd])
-                        k_all, v_all, base = \
-                            caches.append_and_gather_block(i, k_new,
-                                                           v_new)
-                        a = stf.nn.decode_attention(
-                            q, k_all, v_all, base, causal_offset=True)
+                        a = caches.attend_block(i, q, k_new, v_new)
                         a = _tp_gather(stf.reshape(a, [b, kq, d]),
                                        tp_axis)
                         a = _dense(a, d, cfg, "out")

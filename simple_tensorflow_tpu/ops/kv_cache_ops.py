@@ -38,9 +38,9 @@ an append is in place is ASSERTED, not stated:
 programs for a described v5e chip and finds every pool aliased, no
 pool-sized ``copy`` and one pool-shaped fusion (the scatter) per append.
 
-Five ops, registered with declared Effects so the hazard engine orders
+Six ops, registered with declared Effects so the hazard engine orders
 them like any other variable access (append = read-modify-write on the
-cache resource, gather = read):
+cache resource, gather and paged attention = read):
 
   KVCacheAlloc   zero-fill the cache storage (engine start / slot-pool
                  reset); also the op that carries the cache's committed
@@ -58,10 +58,26 @@ cache resource, gather = read):
                  rows, never the sequence's whole logical view.
   KVCachePageCopy  ``cache[dst] = cache[src]`` over whole rows: the
                  prefix cache's copy-on-write.
+  PagedDecodeAttention  attention of ``q`` over a K and a V cache read
+                 IN PLACE through a page table (:func:`paged_decode_
+                 attention`): no view is gathered.
 
-Ordering note: a gather has no data edge from the appends that must
-precede it; build it under ``stf.control_dependencies([append])`` (the
-:class:`KVCache` helper does) — the hazard detector (mode ``raise``)
+Who reads the pool in place and who still gathers (PR 30). The paged
+programs of the dense causal LM (``models/causal_lm._PagedCaches``:
+decode step and page-chunk prefill) attend through
+``PagedDecodeAttention``, whose kernel takes pages from the stored pool
+by the table. Still gathered: the slot caches of the translation model
+and speculative verify (``transformer._SlotCaches``: ``KVCacheGather``
+of one dense row a sequence, then ``DecodeAttention``), the sparse
+model's indexer view and selected rows (``KVCacheGather`` /
+``KVCacheGatherRows``), and ``PagedDecodeAttention``'s own ``xla``
+lowering — the gathered view plus the composed softmax — which is what
+runs on the CPU, under a mesh and in mode ``off``.
+
+Ordering note: a gather or a paged attention has no data edge from the
+appends that must precede it; build it under
+``stf.control_dependencies([append])`` (the :class:`KVCache` helper and
+the models' cache accessors do) — the hazard detector (mode ``raise``)
 rejects the unordered RAW otherwise.
 """
 
@@ -105,7 +121,8 @@ VERIFY_ATTR = "_verify_plan"
 GUARD_ATTR = "_refcount_guarded"
 
 _CACHE_OP_TYPES = ("KVCacheAlloc", "KVCacheAppend", "KVCacheGather",
-                   "KVCacheGatherRows", "KVCachePageCopy")
+                   "KVCacheGatherRows", "KVCachePageCopy",
+                   "PagedDecodeAttention")
 
 
 # ---------------------------------------------------------------------------
@@ -471,6 +488,15 @@ def is_cache_op(op) -> bool:
     return op.type in _CACHE_OP_TYPES
 
 
+def cache_names(op) -> Tuple[str, ...]:
+    """Store names of the caches a cache op touches: one, or the K and
+    the V cache of a ``PagedDecodeAttention``."""
+    vn = op.attrs.get("var_name")
+    if vn is None:
+        return ()
+    return tuple(vn) if isinstance(vn, (list, tuple)) else (vn,)
+
+
 # ---------------------------------------------------------------------------
 # DecodeAttention graph op (the paged-cache decode kernel's entry)
 # ---------------------------------------------------------------------------
@@ -529,6 +555,66 @@ def _lower_decode_attention(ctx, op, input_values):
 
 
 op_registry.register("DecodeAttention", lower=_lower_decode_attention)
+
+
+# ---------------------------------------------------------------------------
+# PagedDecodeAttention graph op: attention straight off the paged pool
+# ---------------------------------------------------------------------------
+
+def paged_decode_attention(q, k_cache: KVCache, v_cache: KVCache,
+                           page_tables, lengths, *, sm_scale=None,
+                           causal_offset=False, name=None):
+    """:func:`decode_attention` of ``q`` over the logical view of two
+    PAGED caches, without the view: K and V are read from the stored
+    pools through ``page_tables (B, n_blocks)``.
+
+    q, lengths, ``causal_offset``: as :func:`decode_attention` (no key
+    bias: a paged self-attention cache has no padding inside its
+    length). The caches are named in the op's attributes and declared
+    as READS of both store entries, so the hazard engine orders the op
+    after the layer's appends exactly as it orders a ``KVCacheGather``:
+    build it under their control dependency. Routed through stf.kernels:
+    ``pallas`` is :func:`..pallas.decode_attention.paged_decode_attention`
+    (pages arrive lane-dense as stored, entries past the row's length
+    are never read), ``xla`` the gathered view and the composed softmax.
+    Inference-only: no registered gradient."""
+    if k_cache.shape != v_cache.shape or k_cache.dtype != v_cache.dtype \
+            or k_cache.sharding != v_cache.sharding \
+            or len(k_cache.inner_shape) != 2:
+        raise ValueError(
+            "paged_decode_attention wants a K and a V cache declared "
+            f"alike with inner shape (heads, head_dim); got {k_cache!r} "
+            f"and {v_cache!r}")
+    g = ops_mod.get_default_graph()
+    q = ops_mod.convert_to_tensor(q)
+    page_tables = ops_mod.convert_to_tensor(page_tables,
+                                            dtype=dtypes_mod.int32)
+    lengths = ops_mod.convert_to_tensor(lengths, dtype=dtypes_mod.int32)
+    if causal_offset and q.shape.rank != 4:
+        raise ValueError("causal_offset=True requires a query block "
+                         f"(B, Kq, H, D); got q rank {q.shape.rank}")
+    attrs = k_cache._attrs()
+    attrs.update(var_name=[k_cache.name, v_cache.name], sm_scale=sm_scale,
+                 causal_offset=bool(causal_offset))
+    op = g.create_op("PagedDecodeAttention", [q, page_tables, lengths],
+                     attrs=attrs, name=name or "paged_decode_attention",
+                     output_specs=[(q.shape, q.dtype)])
+    return op.outputs[0]
+
+
+def _lower_paged_decode_attention(ctx, op, input_values):
+    q, tables, lengths = input_values
+    k_pool, v_pool = (ctx.read_var(n, op) for n in op.attrs["var_name"])
+    fn = _kreg.select("PagedDecodeAttention",
+                      _kreg.aval_key(q, k_pool, tables))
+    return [fn(q, k_pool, v_pool, tables, lengths,
+               sm_scale=op.attrs.get("sm_scale"),
+               causal_offset=bool(op.attrs.get("causal_offset")))]
+
+
+op_registry.register(
+    "PagedDecodeAttention", lower=_lower_paged_decode_attention,
+    effects=op_registry.Effects(reads=("var_name",)))
 
 
 # ---------------------------------------------------------------------------
@@ -629,3 +715,24 @@ def _decode_attention_rule(op, in_specs, ctx):
 
 
 _shard.register_rules(_decode_attention_rule, "DecodeAttention")
+
+
+def _paged_decode_attention_rule(op, in_specs, ctx):
+    # a cache READ like the gather: local over a replicated or a
+    # head-sharded pool (each shard attends with its own heads); over a
+    # slot-sharded pool the pages the tables address move to the rows
+    # that read them, priced as the gather's all-gather of both views
+    cache = _cache_spec(op, ctx, len(op.attrs["shape"]))
+    if cache[0]:
+        shape = op.attrs["shape"]
+        page = float(np.prod(shape[1:])) * op.outputs[0].dtype.base_dtype.size
+        entries = _shard._nelems(op.inputs[1].shape) or 0
+        ctx.collective(
+            "all-gather", cache[0],
+            2.0 * entries * page / ctx.shard_factor(cache),
+            note="PagedDecodeAttention over slot-sharded caches",
+            tensor_name=op.outputs[0].name)
+    return _decode_attention_rule(op, in_specs, ctx)
+
+
+_shard.register_rules(_paged_decode_attention_rule, "PagedDecodeAttention")

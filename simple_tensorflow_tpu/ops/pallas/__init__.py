@@ -19,7 +19,10 @@ import numpy as np
 
 from ...framework import op_registry
 from ...kernels import registry as _kreg
-from .decode_attention import decode_attention, decode_attention_xla
+from .decode_attention import (decode_attention, decode_attention_xla,
+                               paged_decode_attention,
+                               paged_decode_attention_xla,
+                               paged_heads_per_group)
 from .dropout_residual import (dropout_bias_residual,
                                dropout_bias_residual_reference)
 from .flash_attention import attention_xla, flash_attention, mha_reference
@@ -525,3 +528,70 @@ def _decode_attn_graph_key(op):
         *[_Aval(*a) for a in avals],
         _Aval(*bias) if bias is not None else None,
         has_bias=len(op.inputs) > 4)
+
+
+# ---------------------------------------------------------------------------
+# PagedDecodeAttention: K and V pages read from the stored pool through
+# the page table vs the gathered logical view + composed masked softmax.
+# The graph op is registered by ops/kv_cache_ops.py; this entry owns the
+# routing.
+# ---------------------------------------------------------------------------
+
+def _paged_attn_eligible(key):
+    (qs, qd), (ps, pd), (ts, _td) = key[:3]
+    if not _is_float(qd) or str(pd) != str(qd):
+        return "ineligible_dtype"
+    # q (B, H, D) or (B, Kq, H, D); pool (pages, page_len, H*D) as
+    # stored; tables (B, n_blocks)
+    if len(qs) not in (3, 4) or len(ps) != 3 or len(ts) != 2:
+        return "ineligible_shape"
+    if ts[0] != qs[0] or ps[2] != qs[-2] * qs[-1]:
+        return "ineligible_shape"
+    return None
+
+
+def _paged_attn_gate(key, bk):
+    (qs, qd), (ps, _), (ts, _) = key[:3]
+    b, h, d = int(qs[0]), int(qs[-2]), int(qs[-1])
+    kq = int(qs[1]) if len(qs) == 4 else 1
+    page_len, n_blocks = int(ps[1]), int(ts[1])
+    itm = _np_of(qd).itemsize
+    view_len = n_blocks * page_len
+    group = paged_heads_per_group(kq, h, d)
+    # the kernel's MXU work: block-diagonal queries spend ``group``
+    # times the flops; charged to both sides, so the bytes decide
+    flops = 4.0 * group * b * kq * h * view_len * d
+    q_out = 2.0 * b * kq * h * d * itm
+    # K and V views of every table entry: the most the kernel reads
+    # (entries past a row's length are skipped at run time)
+    views = 2.0 * b * view_len * h * d * itm
+    # the composition: the gather reads and writes both views, the
+    # (B, L, H, D) relayout reads them and writes them with head_dim
+    # padded to the 128-lane tile, attention reads that, and the
+    # (B, Kq, H, L) float32 scores make three passes
+    pad = max(1.0, 128.0 / d)
+    composed = views * (2.0 + 1.0 + 2.0 * pad) \
+        + 3.0 * b * kq * h * view_len * 4
+    return _kreg.roofline_gate(flops, views + q_out, composed + q_out, bk)
+
+
+_kreg.register_kernel(
+    "PagedDecodeAttention",
+    impls={"pallas": paged_decode_attention,
+           "xla": paged_decode_attention_xla},
+    legacy="xla",
+    eligible=_paged_attn_eligible,
+    cost_gate=_paged_attn_gate,
+    graph_key=lambda op: _paged_attn_graph_key(op),
+    doc="decode attention over K/V pages read in place through the page "
+        "table vs the gathered logical view + composed masked softmax")
+
+
+def _paged_attn_graph_key(op):
+    from .. import kv_cache_ops as _kvc
+
+    q, tables = _tensor_aval(op.inputs[0]), _tensor_aval(op.inputs[1])
+    if q is None or tables is None:
+        return None
+    pool = _Aval(_kvc.stored_shape(op.attrs["shape"]), q[1])
+    return _kreg.aval_key(_Aval(*q), pool, _Aval(*tables))

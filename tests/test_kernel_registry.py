@@ -224,7 +224,26 @@ def _decode_attn_case(key):
     return tuple(args), kw
 
 
+def _paged_attn_case(key):
+    """Pools, a page table whose rows share their first page and end in
+    the scratch page (the pool's last), and lengths inside the table."""
+    (qs, qd), (ps, pd), (ts, _td) = key[:3]
+    b, nb = ts
+    rng = np.random.RandomState(4)
+    tables = np.full(ts, ps[0] - 1, np.int32)
+    lengths = rng.randint(1, nb * ps[1] - (qs[1] if len(qs) == 4 else 0)
+                          + 1, size=b).astype(np.int32)
+    for r in range(b):
+        live = -(-(int(lengths[r]) + (qs[1] if len(qs) == 4 else 0))
+                 // ps[1])
+        tables[r, :live] = rng.choice(ps[0] - 1, live, replace=False)
+        tables[r, 0] = 0
+    return ((_rand(qs, qd, 0), _rand(ps, pd, 1), _rand(ps, pd, 2), tables,
+             lengths), {"causal_offset": len(qs) == 4})
+
+
 _MAKE_CASE = {
+    "PagedDecodeAttention": _paged_attn_case,
     "FlashAttention": _flash_case,
     "FusedLayerNorm": _ln_case,
     "FusedSoftmaxXent": _xent_case,
@@ -334,7 +353,16 @@ def _assert_lowerings_agree(op_type, key, exact):
                    np.zeros((3, 24, 2, 8), np.float32),
                    np.zeros((3, 24, 2, 8), np.float32), None,
                    has_bias=False)),
-], ids=["sparse_xent", "decode_attention"])
+    ("PagedDecodeAttention",
+     kreg.aval_key(np.zeros((3, 4, 8), np.float32),
+                   np.zeros((14, 8, 32), np.float32),
+                   np.zeros((3, 4), np.int32))),
+    ("PagedDecodeAttention",
+     kreg.aval_key(np.zeros((3, 8, 4, 8), np.float32),
+                   np.zeros((14, 8, 32), np.float32),
+                   np.zeros((3, 4), np.int32))),
+], ids=["sparse_xent", "decode_attention", "paged_decode_attention",
+        "paged_block_attention"])
 def test_lowerings_the_fuzz_does_not_draw_agree(op_type, key):
     assert kreg._KERNELS[op_type].eligible(key) is None
     _assert_lowerings_agree(op_type, key, exact=False)
@@ -485,6 +513,14 @@ def _decode_key(b, length, h, d, dtype="bfloat16"):
                          has_bias=False)
 
 
+def _paged_key(b, kq, h, d, page_len, n_blocks, pages,
+               dtype="bfloat16"):
+    q = (b, h, d) if kq == 1 else (b, kq, h, d)
+    return kreg.aval_key(_aval(q, dtype),
+                         _aval((pages, page_len, h * d), dtype),
+                         _aval((b, n_blocks), "int32"))
+
+
 # one key per registered kernel type, at widths a chip would be given
 _RULE_KEYS = {
     "FlashAttention": _flash_key(8, 16, 1024, 64, causal=True),
@@ -506,6 +542,7 @@ _RULE_KEYS = {
     "FusedMomentumUpdate": flat_group_key(25_000_000, "bfloat16",
                                           "float32"),
     "DecodeAttention": _decode_key(16, 4096, 32, 128),
+    "PagedDecodeAttention": _paged_key(16, 1, 32, 128, 128, 32, 1025),
 }
 
 
@@ -573,6 +610,35 @@ class TestRule:
         assert kreg.decide("DecodeAttention", _decode_key(96, 2048, 16, 64),
                            mode="auto", count=False) == (
             "pallas", "cost_model_uncertain")
+
+    def test_lm_big_paged_attention_takes_the_kernel_by_a_gate_that_answers(
+            self, on_tpu):
+        """Every decode (Kq 1) and page-chunk prefill (Kq 64) bucket of
+        chipbench/configs/lm-big.json: the pool read in place moves a
+        seventh of the gathered, relaid view's bytes, so the gate answers
+        — no abstention, and the configuration's ``force`` pin changes
+        nothing. Where Pallas is interpreted the composition runs."""
+        import json
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(root, "chipbench", "configs",
+                               "lm-big.json")) as f:
+            kw = json.load(f)["program"]["model_kwargs"]
+        keys = [_paged_key(b, kq, 16, 64, kw["page_len"],
+                           kw["pages_per_seq"], kw["num_pages"] + 1)
+                for kq, buckets in ((1, kw["decode_bucket_sizes"]),
+                                    (kw["page_len"],
+                                     kw["prefill_bucket_sizes"]))
+                for b in buckets]
+        assert len(keys) == 13
+        for key in keys:
+            assert kreg.decide("PagedDecodeAttention", key, mode="auto",
+                               count=False) == ("pallas", "cost_model")
+            assert kreg.decide("PagedDecodeAttention", key, mode="force",
+                               count=False) == ("pallas", "forced")
+            assert kreg._route(kreg._KERNELS["PagedDecodeAttention"], key,
+                               "auto", "cpu") == ("xla",
+                                                  "interpret_backend")
 
     def test_bert_base_s512_routes_as_the_chip_printed(self, on_tpu):
         """The cell's own files build its graph (batch 48, s512, 12

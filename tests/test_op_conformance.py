@@ -1075,6 +1075,8 @@ COVERED_ELSEWHERE.update({
                              "sparse_block_attention"),
     "RoutedFFN": ("test_sparse_moe_lm.py", "routed_ffn"),
     "DecodeAttention": ("test_generative.py", "decode_attention"),
+    "PagedDecodeAttention": ("test_paged_decode_attention.py",
+                             "test_op_equals_gather_then_decode_attention"),
     "BarrierIncompleteSize": ("test_data_flow_structures.py", "Barrier"),
     "BarrierInsertMany": ("test_data_flow_structures.py", "Barrier"),
     "BarrierReadySize": ("test_data_flow_structures.py", "Barrier"),

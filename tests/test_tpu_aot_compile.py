@@ -363,6 +363,84 @@ def test_lm_big_cache_append_updates_the_pool_in_place(lm_big_programs,
     assert all("_append/scatter" in ln for ln in pool_sized["fusion"])
 
 
+def _entry_instructions(text):
+    """(shape, opcode, line) of the entry computation's instructions."""
+    for ln in text[text.index("\nENTRY "):].splitlines():
+        m = _HLO_INSTR.match(ln)
+        if m and m.group(1):
+            yield ([int(x) for x in m.group(1).split(",")], m.group(2),
+                   ln.strip())
+
+
+@pytest.mark.parametrize("program,rows", [("decode96", 96), ("prefill8", 8)])
+def test_lm_big_attention_reads_the_pool_in_place(lm_big_programs, program,
+                                                  rows):
+    """PR 30: the paged programs build no logical view. Until then a
+    decode call gathered ``bf16[3072,64,1024]`` (96 rows x 32 pages) per
+    K and V per layer and relaid it as ``bf16[96,2048,16,64]`` for the
+    kernel: 58 % of lm-big.backlog's device time."""
+    text = lm_big_programs["texts"][program]
+    view_elems = rows * LM_PAGES_PER_SEQ * LM_PAGE_LEN * 16 * 64
+    view_sized = [ln[:140] for shape, op, ln in _entry_instructions(text)
+                  if op != "parameter" and int(np.prod(shape)) == view_elems]
+    assert not view_sized, view_sized[:3]
+    assert "bf16[3072,64,1024]" not in text
+    assert "bf16[96,2048,16,64]" not in text
+    # one paged kernel a layer, named so that the benchmark's
+    # decode_attn_ms.serve pattern (stf_decode_attention_q1...) finds it
+    kq = 1 if program == "decode96" else LM_PAGE_LEN
+    calls = re.findall(
+        r"%(stf_decode_attention_q\d+_paged)[\w.\-]* = [^\n]*custom-call",
+        text)
+    # a prefill call keeps only its appends: the last layer's attention
+    # feeds nothing and the compiler drops it
+    n_calls = 6 if program == "decode96" else 5
+    assert calls == [f"stf_decode_attention_q{kq}_paged"] * n_calls, calls
+
+
+def test_paged_decode_attention_fits_vmem_at_every_lm_big_bucket():
+    import importlib
+    import json
+    import os
+
+    da = importlib.import_module(
+        "simple_tensorflow_tpu.ops.pallas.decode_attention")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench", "configs",
+                           "lm-big.json")) as f:
+        kw = json.load(f)["program"]["model_kwargs"]
+    assert (kw["page_len"], kw["pages_per_seq"]) == (LM_PAGE_LEN,
+                                                    LM_PAGES_PER_SEQ)
+    # the estimate does not depend on the bucket (the grid's first axis):
+    # one number covers every decode bucket, one every prefill bucket
+    for kq in (1, LM_PAGE_LEN):
+        est = da.paged_vmem_bytes(kq, 16, 64, LM_PAGE_LEN, BF16)
+        assert est < 14 * 2 ** 20, (kq, est)
+    assert da.paged_heads_per_group(1, 16, 64) == 16
+    assert da.paged_heads_per_group(LM_PAGE_LEN, 16, 64) == 4
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("rows,kq", [(96, 1), (8, 1), (32, LM_PAGE_LEN),
+                                     (1, LM_PAGE_LEN)])
+def test_paged_decode_attention_kernel(tpu_compile, dtype, rows, kq):
+    """The paged kernel alone at lm-big's pool, for the smallest and the
+    largest decode and prefill buckets (Mosaic refuses here what
+    interpret mode lets through)."""
+    import importlib
+
+    da = importlib.import_module(
+        "simple_tensorflow_tpu.ops.pallas.decode_attention")
+    pool = ((LM_PAGES + 1, LM_PAGE_LEN, 16 * 64), dtype)
+    q = ((rows, 16, 64) if kq == 1 else (rows, kq, 16, 64), dtype)
+    text = tpu_compile(
+        lambda q, k, v, t, n: da.paged_decode_attention(
+            q, k, v, t, n, causal_offset=kq > 1),
+        q, pool, pool, ((rows, LM_PAGES_PER_SEQ), jnp.int32),
+        ((rows,), jnp.int32))
+    assert f"stf_decode_attention_q{kq}_paged" in text
+
+
 # ---------------------------------------------------------------------------
 # The sparse-attention routed-FFN decoder at its benchmark sizes
 # (chipbench/configs/keye-vl2-30b-a3b.json): the decode and the page-chunk
