@@ -153,11 +153,9 @@ def set_kernel_mode(config):
     """The kernel-routing mode the configuration states
     (``program.kernel_mode``: off | auto | force) through the program's
     ``stf.kernels.set_mode``, before anything is traced; a configuration
-    that states none gets the program's default back. In ``auto`` a
-    decision the cost model cannot price is timed once and kept beside
-    the compile cache, so two checkouts can route the same call
-    differently (PERF.md Findings (e)): a cell whose rate hangs on such
-    a call states its mode."""
+    that states none gets the program's default back. A routing decision
+    is a pure function of op, shapes, backend, mesh and mode, so two
+    checkouts route alike in every mode."""
     import simple_tensorflow_tpu as stf
 
     stf.kernels.set_mode(config["program"].get("kernel_mode"))
@@ -165,14 +163,13 @@ def set_kernel_mode(config):
 
 def kernel_routing():
     """What the program's kernel registry decided in this process: the
-    mode, calls routed to Pallas and to XLA by reason, and how many
-    decisions were timed (each of those can fall the other way in
-    another checkout)."""
+    mode, calls routed to Pallas and to XLA by reason, and the tiles the
+    flash-attention rule chose from the shapes it was given."""
     import simple_tensorflow_tpu as stf
 
     snap = stf.kernels.snapshot()
     return {k: snap[k] for k in ("mode", "routed", "fallback",
-                                 "autotune_runs")}
+                                 "flash_tiles")}
 
 
 # -- weights ------------------------------------------------------------------
@@ -254,7 +251,9 @@ class Spans:
 class Tracer:
     """Traces ``seconds`` of the window, from ``start_after`` seconds into
     it, when asked to. The runner calls :meth:`arm` as the window opens
-    and :meth:`poll` from its loop."""
+    and :meth:`poll` when the trace is due to start (``t_open`` +
+    ``start_after``) and to stop (``t0`` + ``seconds``): a serving runner
+    sleeps in between, a training runner polls between its steps."""
 
     def __init__(self, enabled, seconds, start_after=0.0):
         self.enabled = bool(enabled)

@@ -11,7 +11,7 @@ yields empty results, and every reader then returns nothing.
 
 from chipbench import trace_reduce
 
-PREFIX = "stf/"
+PREFIX = trace_reduce.PROGRAM_PREFIX
 
 
 def program_spans(trace):

@@ -2,13 +2,13 @@
 
 params: ``per`` (the program span that counts steps), ``scope`` (regex: a
 device event that matches carries the name of the stf op that made it).
-The window's idle gaps are found as ``trace_reduce.idle_gaps`` finds them
-and labelled by the innermost ``stf/...`` span open on any host thread at
-the gap's middle; the value is the idle time that has such a label, per
-step, in ms. Logged on an earlier line: idle seconds by innermost span,
-what lies under none (``unlabelled``) and its share, and the share of
-device-busy time whose event matches ``scope``. A trace without program
-spans: nothing returned.
+The window's idle gaps, found and labelled by ``trace_reduce.idle_gaps``
+(the innermost ``stf/...`` span open on any host thread at the gap's
+middle, else a harness span, else ``unlabelled``); the value is the idle
+time under a span of the PROGRAM, per step, in ms. Logged on an earlier
+line: idle seconds by label, the share that lies under no program span,
+and the share of device-busy time whose event matches ``scope``. A trace
+without program spans: nothing returned.
 """
 
 import re
@@ -26,14 +26,11 @@ def read(params, facts):
     steps = _spans.whole(spans, params["per"], window)
     if not steps:
         return None
-    # idle_gaps labels by the harness's own prefix: hand it the program's
-    # spans under that prefix, whole names kept as the labels
-    as_harness = [(trace_reduce.SPAN_PREFIX + name, s, d, th)
-                  for evs in spans.values() for name, s, d, th in evs]
-    gaps = dict(trace_reduce.idle_gaps(trace["ops"], as_harness, window,
-                                       n=len(spans) + 1))
+    gaps = dict(trace_reduce.idle_gaps(trace["ops"], trace["host"], window,
+                                       n=None))
     idle = sum(gaps.values())
-    under_none = gaps.get("unlabelled", 0.0)
+    under_none = sum(v for label, v in gaps.items()
+                     if not label.startswith(_spans.PREFIX))
     scope = re.compile(params["scope"])
     busy = scoped = 0
     for name, _, dur, detail in trace_reduce.clip(trace["ops"], window):

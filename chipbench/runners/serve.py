@@ -3,13 +3,16 @@
 Builds the configuration's generative model through its normal entry point,
 loads the seed's weights into it, loads it into a ModelServer, warms every
 compiled shape, then offers the traffic file's backlog (everything due as
-the window opens) from this one thread. Tokens are stamped on the host clock as
-``on_token`` delivers them. After the window has closed and the server is
-gone, the reference runs once over a seeded sample of finished requests.
+the window opens, every block of sizes in one order on every seed) from
+this one thread, which then sleeps until the window closes. Tokens are
+stamped on the host clock as ``on_token`` delivers them. After the window
+has closed and the server is gone, the reference runs once over a seeded
+sample of finished requests.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import gc
 import time
@@ -118,33 +121,152 @@ def _percentile(values, q):
     return float(np.percentile(np.asarray(values, np.float64), q))
 
 
+def in_one_order(reqs, mix):
+    """The generator's requests with every block in ONE order on every
+    seed: the order is drawn from ``shape_seed`` as the sizes' pairing is
+    (the block's middle pair still heads the queue); token ids and
+    weights stay the seed's. WHICH prompts of a block a window admits,
+    and when, ``traffic.requests`` leaves to the seed's shuffle, and the
+    rate follows it: where one admission stalls every answer for a long
+    prompt's whole prefill the seeds read 10 % apart against 0.3-0.6 % on
+    one seed (``longdoc-backlog``, PR 27); on ``backlog``'s short prompts
+    three seeds' means lay 3.1 % apart where a seed repeated within 1.3 %
+    (PR 32; PERF.md)."""
+    block = mix["block"]
+    rng = np.random.default_rng([int(mix["shape_seed"]), 4])
+    out = []
+    for b in range(0, len(reqs), block):
+        by_size = sorted(reqs[b:b + block],
+                         key=lambda r: (len(r.prompt), r.budget))
+        order = rng.permutation(len(by_size))
+        if b == 0:
+            head = int(np.flatnonzero(order == len(by_size) // 2)[0])
+            order[[0, head]] = order[[head, 0]]
+        out += [by_size[j] for j in order]
+    return out
+
+
+class _Pauses:
+    """The garbage collector's pauses while it is installed, from
+    ``gc.callbacks``: (start on the host clock, seconds, generation). A
+    collection runs on whichever thread's allocation set it off and holds
+    the interpreter for its length, the engine's thread with it."""
+
+    def __init__(self):
+        self.records = []
+        self._start = None
+
+    def __call__(self, phase, info):
+        if phase == "start":
+            self._start = time.perf_counter()
+        elif self._start is not None:
+            self.records.append((self._start,
+                                 time.perf_counter() - self._start,
+                                 info["generation"]))
+            self._start = None
+
+    def __enter__(self):
+        gc.callbacks.append(self)
+        return self
+
+    def __exit__(self, *exc):
+        gc.callbacks.remove(self)
+
+
+def _sleep_until(t):
+    """One sleep as a rule (``time.sleep`` never returns early on a Python
+    that sleeps on the monotonic clock); the loop only guards the rule."""
+    while (wait := t - time.perf_counter()) > 0:
+        time.sleep(wait)
+
+
 def drive(server, name, mix, reqs, seconds, tracer=None, on_open=None,
           spans=None):
     """The window: every request is due as it opens and is offered at
-    once, from this one thread; then wait for the close. Returns the
+    once, from this one thread; then SLEEP until the close. Returns the
     window's facts; the requests carry their own times and tokens. The
-    harness's spans (``submit``, ``wait_request``) label idle gaps."""
+    harness's spans (``submit``, ``wait_request``) label idle gaps.
+
+    This thread wakes at most three times in a window: where a trace is
+    asked for, at its start and at its end, and at the close. A wake-up
+    asks the engine's thread for the interpreter, and the host sets this
+    window's pace: a poll every 2 ms was 20,000 of them (PERF.md)."""
     span = spans.span if spans is not None else (
         lambda _label: contextlib.nullcontext())
-    t_open = time.perf_counter()
-    t_close = t_open + seconds
-    deadline = t_close + mix["drain_seconds"]
-    if on_open is not None:
-        on_open()
-    if tracer is not None:
-        tracer.arm()
-    with span("submit"):
-        for r in reqs:
-            _submit(server, name, r, deadline)
-    with span("wait_request"):
-        while time.perf_counter() < t_close:
-            if tracer is not None:
-                tracer.poll()
-            time.sleep(0.002)
-    if tracer is not None:
-        tracer.stop()
+    tracing = tracer is not None and tracer.enabled
+    with _Pauses() as pauses:
+        t_open = time.perf_counter()
+        t_close = t_open + seconds
+        deadline = t_close + mix["drain_seconds"]
+        if on_open is not None:
+            on_open()
+        if tracer is not None:
+            tracer.arm()
+        with span("submit"):
+            for r in reqs:
+                _submit(server, name, r, deadline)
+        with span("wait_request"):
+            if tracing:
+                _sleep_until(min(tracer.t_open + tracer.start_after,
+                                 t_close))
+                tracer.poll()  # starts the trace, if arm() did not
+                if tracer.t0 is not None:
+                    _sleep_until(min(tracer.t0 + tracer.seconds, t_close))
+                    tracer.poll()  # stops it once its seconds are over
+            _sleep_until(t_close)
+        if tracer is not None:
+            tracer.stop()
     return {"t_open": t_open, "t_close": t_close, "deadline": deadline,
-            "lateness": [r.submitted - t_open for r in reqs]}
+            "lateness": [r.submitted - t_open for r in reqs],
+            "gc_pauses": pauses.records}
+
+
+def _decode_deliveries(reqs, t_open, t_close):
+    """When each engine step inside the window delivered its tokens: a
+    step hands every live answer one token within a fraction of a
+    millisecond, and two steps lie 10 ms or more apart, so the sorted
+    token times split into steps wherever two lie over 1 ms apart."""
+    times = np.sort(np.fromiter(
+        (ts for r in reqs for ts in r.times if t_open <= ts <= t_close),
+        np.float64))
+    if not len(times):
+        return times
+    return times[np.concatenate([[True], np.diff(times) > 1e-3])]
+
+
+def window_log(reqs, win, longest=20):
+    """For a run's ``info``: what stalled the window, if anything did.
+    The collector's pauses inside it (count by generation, their sum and
+    the longest, and every pause over 5 ms with its second of the window),
+    and the gaps between two consecutive decode deliveries (the median,
+    the longest, how many lie over 1.5x the median, and the ``longest`` of
+    those with their second of the window, in the window's order) — what
+    the training runner prints of its steps. Nothing here is a metric."""
+    t_open, t_close = win["t_open"], win["t_close"]
+    pauses = [p for p in win["gc_pauses"] if t_open <= p[0] <= t_close]
+    collector = {
+        "enabled": gc.isenabled(),
+        "collections": dict(collections.Counter(
+            str(generation) for _, _, generation in pauses)),
+        "paused_ms": 1000 * sum(d for _, d, _ in pauses),
+        "longest_ms": 1000 * max((d for _, d, _ in pauses), default=0.0),
+        "over_5ms": [[round(t - t_open, 3), round(1000 * d, 2), g]
+                     for t, d, g in pauses if d > 0.005][:longest]}
+    steps = _decode_deliveries(reqs, t_open, t_close)
+    gaps = np.diff(steps)
+    if not len(gaps):
+        return {"collector": collector, "decode_gaps_ms": None}
+    median = float(np.median(gaps))
+    over = np.flatnonzero(gaps > 1.5 * median)
+    kept = np.sort(over[np.argsort(gaps[over])[-longest:]])
+    return {"collector": collector, "decode_gaps_ms": {
+        "deliveries": len(steps), "median": 1000 * median,
+        "p95": 1000 * _percentile(gaps, 95),
+        "longest": 1000 * float(gaps.max()),
+        "over_1.5x_median": len(over),
+        "longest_over_1.5x": [[round(float(steps[i] - t_open), 3),
+                               round(1000 * float(gaps[i]), 1)]
+                              for i in kept]}}
 
 
 def settle(reqs, deadline, everything=False):
@@ -239,8 +361,8 @@ def run(ctx):
     timings["warm_up_s"] = time.perf_counter() - t
     timings["setup_compiles"] = clock.since(mark)
 
-    reqs = [_Request(r) for r in traffic_mod.requests(
-        mix, spec["vocab"], args.seed, args.seconds)]
+    reqs = in_one_order([_Request(r) for r in traffic_mod.requests(
+        mix, spec["vocab"], args.seed, args.seconds)], mix)
     at_open = {}
 
     def on_open():
@@ -302,6 +424,7 @@ def run(ctx):
             if attempted else None),
         "timings": timings, "window_compiles": window_compiles,
         "kernel_routing": harness.kernel_routing(),
+        **window_log(reqs, win),
     }
 
     # -- the reference, once the program is gone ------------------------------
@@ -368,8 +491,8 @@ def calibrate(ctx, seeds, n_control, seconds):
         # the engine is idle here: every request of the seed before has
         # ended, by its answer or by the harness's deadline
         load_weights(model, config, ref.init_params(spec, seed))
-        reqs = [_Request(r) for r in traffic_mod.requests(
-            mix, spec["vocab"], seed, seconds)]
+        reqs = in_one_order([_Request(r) for r in traffic_mod.requests(
+            mix, spec["vocab"], seed, seconds)], mix)
         win = drive(server, name, mix, reqs, seconds)
         settle(reqs, win["deadline"], everything=True)
         finished = [r for r in reqs if r.outcome() == "ok"]

@@ -6,13 +6,13 @@ A new reference needs a new runner: ``runners/serve.py`` and
 ``harness.load_variables`` name ``reference.postln_transformer`` and
 ``work.causal_lm_*``, and no file that is there is edited for a cell
 (README). Shared with ``serve.py`` by import: the request record, model
-build, server start, warm-up, the window, settling, the window's token
-count and the sample. This file's own: weights (made on the DEVICE a
-layer at a time — 4.4 B parameters through the host would cost minutes of
-``setup_s``), the check against the new reference, the planted fault, the
-calibration and the traced window's work (``work_sparse_moe.py``) — and
-the blocks of sizes in one order on every seed (``in_one_order``): one
-admission stalls every answer for up to 3.5 s here.
+build, server start, warm-up, the one order of every block
+(``in_one_order``: one admission stalls every answer for up to 3.5 s
+here), the window, settling, the window's token count and the sample.
+This file's own: weights (made on the DEVICE a layer at a time — 4.4 B
+parameters through the host would cost minutes of ``setup_s``), the check
+against the new reference, the planted fault, the calibration and the
+traced window's work (``work_sparse_moe.py``).
 """
 
 from __future__ import annotations
@@ -27,8 +27,9 @@ from chipbench import harness, traffic as traffic_mod, work_sparse_moe
 from chipbench.compare import against, is_correct, serve_numbers
 from chipbench.reference import sparse_moe_decoder as ref
 from chipbench.runners.serve import (
-    _Request, _engine_row, _percentile, build, drive, sample_finished,
-    settle, start_server, tokens_in_window, warm_up)
+    _Request, _engine_row, _percentile, build, drive, in_one_order,
+    sample_finished, settle, start_server, tokens_in_window, warm_up,
+    window_log)
 
 
 def load_weights(model, config, seed):
@@ -190,29 +191,6 @@ def traced_work(spec, reqs, t0, t1):
             "prompts": prompts}
 
 
-def in_one_order(reqs, mix):
-    """The generator's requests with every block in ONE order on every
-    seed: the order is drawn from ``shape_seed`` as the sizes' pairing is
-    (the block's middle pair still heads the queue); token ids and
-    weights stay the seed's. Every answer waits out every admission's
-    whole prefill, 0.3 s for a 4.4k prompt and 3.5 s for a 30.7k one, so
-    WHICH prompts of a block a window admits, which ``traffic.requests``
-    leaves to the seed's shuffle, moved the rate by 10 % from seed to
-    seed, against 0.3-0.6 % on one seed (PERF.md)."""
-    block = mix["block"]
-    rng = np.random.default_rng([int(mix["shape_seed"]), 4])
-    out = []
-    for b in range(0, len(reqs), block):
-        by_size = sorted(reqs[b:b + block],
-                         key=lambda r: (len(r.prompt), r.budget))
-        order = rng.permutation(len(by_size))
-        if b == 0:
-            head = int(np.flatnonzero(order == len(by_size) // 2)[0])
-            order[[0, head]] = order[[head, 0]]
-        out += [by_size[j] for j in order]
-    return out
-
-
 def run(ctx):
     config, mix, args = ctx["config"], ctx["traffic"], ctx["args"]
     spec = config["reference"]["spec"]
@@ -301,6 +279,7 @@ def run(ctx):
         # in the order offered: two runs part where these do
         "first_token_s": [r.times[0] - t_open for r in attempted],
         "timings": timings, "window_compiles": window_compiles,
+        **window_log(reqs, win),
     }
 
     # -- the reference, once the program is gone ------------------------------

@@ -142,6 +142,6 @@ def test_a_configuration_states_its_kernel_mode_or_gets_the_default():
             harness.set_kernel_mode(config)
             assert stf.kernels.default_mode() == (stated or default)
         assert set(harness.kernel_routing()) == {
-            "mode", "routed", "fallback", "autotune_runs"}
+            "mode", "routed", "fallback", "flash_tiles"}
     finally:
         stf.kernels.set_mode(None)

@@ -9,7 +9,8 @@ import os
 import pytest
 
 from chipbench import trace_reduce as tr
-from chipbench.readers import _spans, host_gap_ms, kernel_ms, span_ms
+from chipbench.readers import (_spans, host_gap_ms, kernel_ms, span_ms,
+                               trace_events)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH = os.path.dirname(HERE)
@@ -83,6 +84,20 @@ def test_span_and_kernel_readers(facts, parent_facts, metric, expected):
         pytest.approx(expected / 1e6)
     assert reader.read(spec["params"], parent_facts) is None
     assert reader.read(spec["params"], {}) is None
+
+
+def test_the_roofline_finds_its_kernels_by_their_names(facts, parent_facts):
+    """The one flash event's 2000 ns, not the decode kernels and not the
+    fusion that consumes a kernel's result; a trace whose kernels carry no
+    name of ours has nothing to read."""
+    params = metric_params("flash_attn_roofline")
+    assert params["pattern"] == metric_params("flash_attn_ms.train")["pattern"]
+    more = {"peak": {"flops_per_s_bf16": 1e12, "hbm_bytes_per_s": 1e11},
+            "work": {"flash_attention": [1e3, 10.0]}}  # least time 1 ns
+    assert trace_events.read(params, dict(facts, **more)) == \
+        pytest.approx(100 * 1 / 2000)
+    assert trace_events.read(params, dict(parent_facts, **more)) is None
+    assert trace_events.read(params, facts) is None  # no work counted
 
 
 def test_the_logged_parts_of_a_step_add_up(facts, capsys):

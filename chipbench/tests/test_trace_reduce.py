@@ -53,6 +53,40 @@ def test_gaps_are_labelled_by_the_innermost_open_span(trace):
     assert sum(gaps.values()) == pytest.approx(4000e-9)
 
 
+def test_gaps_take_the_programs_span_before_the_harnesss():
+    """Device busy 0-1000, 2000-3000, 4000-5000, 6000-7000, 8000-9000 of a
+    window 0-10000: five gaps of 1000 with middles 1500 ... 9500."""
+    ops = [("%fusion.1 = f32[8] fusion()", s, 1000, "")
+           for s in range(0, 10000, 2000)]
+    host = [
+        ("chipbench:window", 0, 10000, "main"),
+        ("chipbench:wait_request", 0, 10000, "main"),
+        # 1500: a program span on another thread, its metadata cut off,
+        # and the narrower of two that are open
+        ("stf/engine/step", 1000, 2500, "engine"),
+        ("stf/engine/admit#joined=1,held_back=0#", 1200, 700, "engine"),
+        # 3500: only the wide one is still open
+        # 5500: the harness's narrower span does not beat the program's
+        ("stf/session/run", 5000, 1000, "engine"),
+        ("chipbench:probe", 5400, 200, "main"),
+        # 7500: no program span: the harness's innermost
+        ("chipbench:probe", 7400, 200, "main"),
+        # 9500: only the harness's window-long wait
+        ("PjitFunction(step)", 9400, 200, "engine"),
+    ]
+    gaps = dict(tr.idle_gaps(ops, host, (0, 10000)))
+    assert gaps == {"stf/engine/admit": pytest.approx(1000e-9),
+                    "stf/engine/step": pytest.approx(1000e-9),
+                    "stf/session/run": pytest.approx(1000e-9),
+                    "probe": pytest.approx(1000e-9),
+                    "wait_request": pytest.approx(1000e-9)}
+    # with no span of either kind a gap stays unlabelled
+    bare = dict(tr.idle_gaps(ops, host[:1], (0, 10000)))
+    assert bare == {"unlabelled": pytest.approx(5000e-9)}
+    assert tr.idle_gaps(ops, host, (0, 10000), n=2)[0][1] == \
+        pytest.approx(1000e-9)
+
+
 def test_labels_fold_instances_and_keep_signatures(trace):
     name = trace["device"]["/device:TPU:0"][3][0]
     assert tr.op_label(name) == \

@@ -47,8 +47,6 @@ def lm(compute_dtype="bfloat16"):
         page_len=8, pages_per_seq=8, num_pages=32, max_live=4,
         decode_bucket_sizes=[1, 2, 4], prefill_bucket_sizes=[1, 2, 4])
     config["program"]["compute_dtype"] = compute_dtype
-    # off the chip a forced Pallas kernel runs in interpret mode
-    config["program"]["kernel_mode"] = "auto"
     config["reference"]["max_output"] = 16
     spec = config["reference"]["spec"]
     spec.update(hidden=32, ffn=64, layers=2, heads=2, vocab=64)

@@ -7,8 +7,9 @@ function that touches the profiler's file format.
 An event is ``(name, start_ns, dur_ns, detail)``. Device events come from
 the ``XLA Ops`` line of each ``/device:TPU:<n>`` plane (one event per
 executed HLO op or custom call); host events from every line of the
-``/host:CPU`` plane (TraceMe spans, among them the harness's own
-``chipbench:<label>`` annotations).
+``/host:CPU`` plane (TraceMe spans, among them the program's
+``stf/<layer>/<phase>`` and the harness's own ``chipbench:<label>``
+annotations).
 """
 
 from __future__ import annotations
@@ -18,6 +19,7 @@ import os
 import re
 
 SPAN_PREFIX = "chipbench:"
+PROGRAM_PREFIX = "stf/"
 WINDOW_SPAN = SPAN_PREFIX + "window"
 
 
@@ -149,8 +151,11 @@ def top_ops(events, window, n=10, operands=False, limit=120, only=None):
 
 def idle_gaps(events, host_events, window, n=10):
     """[[label, seconds]]: the window's idle time, summed by what the host
-    was doing at the middle of each gap — the innermost harness span
-    (``chipbench:<label>``) open then, else ``unlabelled``."""
+    was doing at the middle of each gap — the innermost span of the
+    program (``stf/<layer>/<phase>``, the whole name, cut at ``#``) open
+    then on any thread; where none is, the innermost harness span
+    (``chipbench:<label>``, the label alone); else ``unlabelled``. The
+    ``n`` largest, or with ``n=None`` all."""
     lo, hi = window
     busy = merged_intervals(clip(events, window))
     gaps, cursor = [], lo
@@ -160,19 +165,24 @@ def idle_gaps(events, host_events, window, n=10):
         cursor = max(cursor, e)
     if hi > cursor:
         gaps.append((cursor, hi))
-    spans = sorted(((s, s + d, name[len(SPAN_PREFIX):])
-                    for name, s, d, _ in host_events
-                    if name.startswith(SPAN_PREFIX) and name != WINDOW_SPAN),
-                   key=lambda x: x[0])
-    total = {}
-    for s, e in gaps:
+    spans = []  # (start, end, label, 0 for the program's / 1 the harness's)
+    for name, s, d, _ in host_events:
+        if name.startswith(PROGRAM_PREFIX):
+            spans.append((s, s + d, name.split("#", 1)[0], 0))
+        elif name.startswith(SPAN_PREFIX) and name != WINDOW_SPAN:
+            spans.append((s, s + d, name[len(SPAN_PREFIX):], 1))
+    spans.sort(key=lambda x: x[0])
+    total, open_now, nxt = {}, [], 0
+    for s, e in gaps:  # in time order, so spans open and close once
         mid = (s + e) // 2
-        label, width = "unlabelled", None
-        for ss, se, name in spans:
-            if ss > mid:
-                break
-            if se >= mid and (width is None or se - ss < width):
-                label, width = name, se - ss
+        while nxt < len(spans) and spans[nxt][0] <= mid:
+            open_now.append(spans[nxt])
+            nxt += 1
+        open_now = [sp for sp in open_now if sp[1] >= mid]
+        # the program's before the harness's, then the narrowest, then the
+        # one that opened first
+        label = min(open_now, key=lambda sp: (sp[3], sp[1] - sp[0]),
+                    default=(0, 0, "unlabelled"))[2]
         total[label] = total.get(label, 0) + (e - s)
     ranked = sorted(total.items(), key=lambda kv: -kv[1])[:n]
     return [[k, v / 1e9] for k, v in ranked]
