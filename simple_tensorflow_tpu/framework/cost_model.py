@@ -202,6 +202,17 @@ def _op_flops(op: Operation, grad_depth: int = 0,
         if n and ts.rank == 2 and ts.dims[1].value and len(sh) == 4:
             return 4.0 * n * int(ts.dims[1].value) * int(sh[1])
         return 2.0 * _out_elems(op)
+    if t == "PagedLatentAttention":
+        # q . row over the whole row, P . row over its value lanes:
+        # 2 * B * Kq * H * (n_blocks * page_len) * (W + value_dim)
+        qs, ts = op.inputs[0].shape, op.inputs[1].shape
+        sh = op.attrs.get("shape") or []
+        n = _nelems(qs)
+        if n and ts.rank == 2 and ts.dims[1].value and len(sh) == 3:
+            w = int(sh[2])
+            return 2.0 * (n / w) * int(ts.dims[1].value) * int(sh[1]) * (
+                w + int(op.attrs.get("value_dim", w)))
+        return 2.0 * _out_elems(op)
     if t in ("KVCacheAlloc", "KVCacheAppend", "KVCacheGather",
              "KVCacheGatherRows", "KVCachePageCopy"):
         return 0.0  # pure data movement; bytes are priced in _op_bytes
@@ -289,18 +300,20 @@ def _op_bytes_dispatch(op: Operation, fn_depth: int = 0) -> float:
         # default inputs+outputs accounting would charge a full cache
         # write per append and dominate every decode-step attribution)
         return 2.0 * sum(_tensor_bytes(t) for t in op.inputs)
-    if op.type == "PagedDecodeAttention":
-        # K and V pages are read where they lie, once: q, the tables,
-        # the output, and one K and one V page per table entry — the
-        # LIVE pages at most (entries past a row's length are skipped at
-        # run time), never a gathered (B, L, H, D) view
+    if op.type in ("PagedDecodeAttention", "PagedLatentAttention"):
+        # pages are read where they lie, once: q, the tables, the
+        # output, and per table entry one page of each pool the op reads
+        # (K and V, or the one pool of latent rows: a row is key and
+        # value) — the LIVE pages at most (entries past a row's length
+        # are skipped at run time), never a gathered (B, L, ...) view
         sh = op.attrs.get("shape") or []
         entries = _nelems(op.inputs[1].shape) or 0
+        pools = 2.0 if op.type == "PagedDecodeAttention" else 1.0
         page = 1
         for d in sh[1:]:
             page *= int(d)
         itemsize = op.outputs[0].dtype.base_dtype.size if op.outputs else 4
-        return _op_bytes(op) + 2.0 * entries * page * itemsize
+        return _op_bytes(op) + pools * entries * page * itemsize
     if op.type == "KVCachePageCopy":
         # CoW: M whole rows read + written in place (same donation
         # argument as the append) — row bytes from the cache attrs,

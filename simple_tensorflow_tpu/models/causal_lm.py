@@ -126,7 +126,8 @@ class _PagedCaches:
     sequence's full history, across however many (possibly shared)
     pages it spans, through ``page_tables (B, n_blocks)``: K and V
     pages straight from the stored pools (``PagedDecodeAttention``),
-    no logical view gathered. The RAW between a layer's appends and its
+    or the one pool of latent rows a latent-attention layer keeps
+    (``PagedLatentAttention``); no logical view gathered. The RAW between a layer's appends and its
     read is ordered by an explicit control dependency (the appended
     page is always present in the table)."""
 
@@ -154,6 +155,19 @@ class _PagedCaches:
         committed prefix plus block positions <= j
         (``transformer._block_decode``)."""
         return self._attend(layer, q, k_new, v_new, self._base, True)
+
+    def attend_latent(self, layer, q, row_new, *, value_dim, sm_scale,
+                      block=False):
+        """A layer whose ONE cache holds latent rows (``PagedLatent
+        Attention``): append ``row_new (B, P, W)``, then ``q (B, H, W)``
+        — or with ``block`` a page-aligned ``(B, Kq, H, W)`` — over the
+        history, each head against the same rows."""
+        cache, = self._caches[layer]
+        with self.after_append(self.append(layer, row_new)):
+            return stf.nn.paged_latent_attention(
+                q, cache, self._tables,
+                self._base if block else self._base + 1,
+                value_dim=value_dim, sm_scale=sm_scale, causal_offset=block)
 
     # a stack that keeps more than K and V per layer, or reads selected
     # rows instead of the whole history, appends first and reads under
@@ -223,6 +237,69 @@ class _PostLNStack:
         return _tp_gather(stf.cast(logits, stf.float32), self.tp_axis), {}
 
 
+class PreNormStack:
+    """What the pre-norm RMSNorm stacks share (``models/sparse_moe_lm.py``,
+    ``models/latent_moe_lm.py``): variables, the norm, the embedding, the
+    layer loop ``x += attention(norm(x)); x += ffn(x)`` and the untied
+    head. A stack gives ``layer_caches``, ``prefill_block``,
+    ``decode_step`` (the builder's contract) over its own
+    ``_attention(i, a, rows, lead, positions, attend)`` -> ``(rows,
+    d_model)`` and ``_ffn(i, x, row_mask)`` -> ``(y, expert counts or
+    None)``."""
+
+    def __init__(self, cfg, compute_dtype, scope):
+        self.cfg, self.scope = cfg, scope
+        self.compute_dtype = compute_dtype
+        self.vocab_size, self.max_positions = cfg.vocab_size, cfg.max_len
+
+    def _w(self, name, shape, fan_in, dtype=None):
+        return stf.get_variable(
+            name, shape, dtype=dtype or self.compute_dtype,
+            initializer=stf.random_normal_initializer(
+                stddev=fan_in ** -0.5))
+
+    def _norm(self, x, name, width, out_dtype=None, eps=None):
+        gamma = stf.get_variable(name, [width], dtype=stf.float32,
+                                 initializer=stf.ones_initializer())
+        return stf.nn.rms_norm(
+            x, gamma, eps=self.cfg.rms_norm_eps if eps is None else eps,
+            out_dtype=out_dtype)
+
+    def _embed(self, tok):
+        cfg = self.cfg
+        emb = stf.get_variable(
+            "embed", [cfg.vocab_size, cfg.d_model],
+            dtype=self.compute_dtype,
+            initializer=stf.random_normal_initializer(stddev=1.0))
+        return stf.gather(emb, tok)
+
+    def _layers(self, x, rows, lead, positions, attend, row_mask=None):
+        """The layer loop both programs share; ``attend`` is the
+        program's own cache append + attention, handed to the stack's
+        ``_attention``. Returns the hidden state and the routed layers'
+        expert counts."""
+        d = self.cfg.d_model
+        counts = []
+        with stf.variable_scope("decoder"):
+            for i in range(self.cfg.num_layers):
+                with stf.variable_scope(f"layer_{i}"):
+                    a = self._norm(x, "ln1", d)
+                    x = x + self._attention(i, a, rows, lead, positions,
+                                            attend)
+                    y, c = self._ffn(i, x, row_mask)
+                    x = x + y
+                    if c is not None:
+                        counts.append(c)
+        return x, counts
+
+    def _logits(self, x):
+        cfg = self.cfg
+        h = self._norm(x, "final_norm", cfg.d_model)
+        logits = stf.matmul(h, self._w(
+            "lm_head", [cfg.d_model, cfg.vocab_size], cfg.d_model))
+        return stf.cast(logits, stf.float32)
+
+
 def build_causal_lm_program(cfg: TransformerConfig, *,
                             compute_dtype=stf.float32, int8=False,
                             scope="causal_lm", tp_axis=None, **kw):
@@ -241,8 +318,8 @@ def build_paged_lm_program(stack, *, page_len, pages_per_seq, num_pages,
                            scope="causal_lm", cache_sharding=None,
                            tp_axis=None):
     """Build the paged-cache serving programs of a decoder-only block
-    ``stack`` (:class:`_PostLNStack`; ``models/sparse_moe_lm.py`` has
-    another): the caches, the bucket loops, the emit and copy-on-write
+    ``stack`` (:class:`_PostLNStack`; ``models/sparse_moe_lm.py`` and
+    ``models/latent_moe_lm.py`` have :class:`PreNormStack`s): the caches, the bucket loops, the emit and copy-on-write
     are here once, the layer mathematics is the stack's.
 
     Emits, in the CURRENT default graph:

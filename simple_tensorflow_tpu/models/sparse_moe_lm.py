@@ -19,6 +19,11 @@ decoders.) One layer, for a token's hidden state ``x`` at position ``t``:
   b)``; no token dropped (``ops/moe_ops.py``).
 - after the last layer RMSNorm and an UNTIED head.
 
+Variables, the norm, the embedding, the pre-norm layer loop and the head
+are ``causal_lm.PreNormStack``'s, shared with ``models/latent_moe_lm.py``;
+this module's own are the projections, the indexer and the two programs'
+cache reads. Every expert is held here (``RoutedFFN`` without ``held``).
+
 Three paged caches per layer under one page table: K, V and the indexer
 key. DECODE gathers the indexer keys of the context, picks positions
 (``IndexerTopK``) and reads only those K/V rows (``KVCacheGatherRows``);
@@ -34,8 +39,8 @@ import dataclasses
 import numpy as np
 
 import simple_tensorflow_tpu as stf
-from simple_tensorflow_tpu.models.causal_lm import CausalLMGenerativeModel
-from simple_tensorflow_tpu.models.causal_lm import build_paged_lm_program
+from simple_tensorflow_tpu.models.causal_lm import (
+    CausalLMGenerativeModel, PreNormStack, build_paged_lm_program)
 from simple_tensorflow_tpu.platform import monitoring
 
 _moe_imbalance = monitoring.Sampler(
@@ -80,19 +85,15 @@ class SparseMoEConfig:
             indexer_head_dim=8, indexer_topk=8, max_len=64)
 
 
-def _normal(fan_in):
-    return stf.random_normal_initializer(stddev=fan_in ** -0.5)
-
-
-class _SparseMoEStack:
-    """The block stack as ``build_paged_lm_program`` sees one."""
+class _SparseMoEStack(PreNormStack):
+    """The block stack as ``build_paged_lm_program`` sees one; variables,
+    norm, embedding, the layer loop and the head are
+    :class:`PreNormStack`'s."""
 
     def __init__(self, cfg: SparseMoEConfig, compute_dtype, scope,
                  attn_tile):
-        self.cfg, self.scope = cfg, scope
-        self.compute_dtype = compute_dtype
+        super().__init__(cfg, compute_dtype, scope)
         self.attn_tile = attn_tile
-        self.vocab_size, self.max_positions = cfg.vocab_size, cfg.max_len
 
     def layer_caches(self, kvc, total_pages, page_len, sharding):
         cfg = self.cfg
@@ -107,17 +108,6 @@ class _SparseMoEStack:
             for i in range(cfg.num_layers)]
 
     # -- pieces -----------------------------------------------------------
-    def _w(self, name, shape, fan_in, dtype=None):
-        return stf.get_variable(name, shape,
-                                dtype=dtype or self.compute_dtype,
-                                initializer=_normal(fan_in))
-
-    def _norm(self, x, name, width, out_dtype=None):
-        gamma = stf.get_variable(name, [width], dtype=stf.float32,
-                                 initializer=stf.ones_initializer())
-        return stf.nn.rms_norm(x, gamma, eps=self.cfg.rms_norm_eps,
-                              out_dtype=out_dtype)
-
     def _projections(self, a, lead, positions):
         """q, k, v, indexer q / k / head weights of ``a (rows, d_model)``
         laid out ``lead + (heads, dim)``, normed and rotated."""
@@ -143,9 +133,17 @@ class _SparseMoEStack:
                          stf.float32) * float((hi * di) ** -0.5)
         return q, k, v, q_idx, k_idx, stf.reshape(w_idx, lead + [hi])
 
-    def _routed_ffn(self, x, row_mask):
-        """``x (rows, d_model)`` -> (the FFN's output in the residual
-        stream's dtype, live rows per expert)."""
+    def _attention(self, i, a, rows, lead, positions, attend):
+        cfg = self.cfg
+        width = cfg.num_heads * cfg.head_dim
+        o = attend(i, *self._projections(a, lead, positions))
+        o = stf.reshape(o, [rows, width])
+        return stf.matmul(o, self._w("attn/out", [width, cfg.d_model],
+                                     width))
+
+    def _ffn(self, i, x, row_mask):
+        """``x (rows, d_model)`` -> (the routed FFN's output in the
+        residual stream's dtype, live rows per expert)."""
         cfg = self.cfg
         d, e, width = cfg.d_model, cfg.num_experts, cfg.expert_width
         b = self._norm(x, "ln2", d, out_dtype="float32")
@@ -156,35 +154,6 @@ class _SparseMoEStack:
             row_mask, top_k=cfg.experts_per_token,
             norm_topk=cfg.norm_topk_prob)
         return stf.cast(y, self.compute_dtype), counts
-
-    def _embed(self, tok):
-        cfg = self.cfg
-        emb = stf.get_variable(
-            "embed", [cfg.vocab_size, cfg.d_model],
-            dtype=self.compute_dtype,
-            initializer=stf.random_normal_initializer(stddev=1.0))
-        return stf.gather(emb, tok)
-
-    def _layers(self, x, rows, lead, positions, attend, row_mask=None):
-        """The layer loop both programs share; ``attend(i, q, k, v, q_idx,
-        k_idx, w_idx)`` is the program's own cache append + attention,
-        returning ``lead + (num_heads, head_dim)``."""
-        cfg = self.cfg
-        d = cfg.d_model
-        counts = []
-        with stf.variable_scope("decoder"):
-            for i in range(cfg.num_layers):
-                with stf.variable_scope(f"layer_{i}"):
-                    a = self._norm(x, "ln1", d)
-                    o = attend(i, *self._projections(a, lead, positions))
-                    o = stf.reshape(o, [rows, cfg.num_heads * cfg.head_dim])
-                    x = x + stf.matmul(o, self._w(
-                        "attn/out", [cfg.num_heads * cfg.head_dim, d],
-                        cfg.num_heads * cfg.head_dim))
-                    y, c = self._routed_ffn(x, row_mask)
-                    x = x + y
-                    counts.append(c)
-        return x, counts
 
     # -- the two programs -----------------------------------------------------
     def prefill_block(self, tok, base, cache):
@@ -228,11 +197,8 @@ class _SparseMoEStack:
             x, counts = self._layers(
                 self._embed(tok), b, lead, stf.reshape(pos, [b, 1]), attend,
                 row_mask=cache.live_rows())
-            h = self._norm(x, "final_norm", cfg.d_model)
-            logits = stf.matmul(h, self._w(
-                "lm_head", [cfg.d_model, cfg.vocab_size], cfg.d_model))
-        return (stf.cast(logits, stf.float32),
-                {"expert_counts": stf.stack(counts)})
+            logits = self._logits(x)
+        return logits, {"expert_counts": stf.stack(counts)}
 
 
 def attn_tile_pages(pages_per_seq):

@@ -38,7 +38,8 @@ from ..ops.fused_ops import (
     fused_attention, fused_bias_dropout_residual, fused_layer_norm,
     fused_softmax_cross_entropy, quantized_matmul,
 )
-from ..ops.kv_cache_ops import decode_attention, paged_decode_attention
+from ..ops.kv_cache_ops import (decode_attention, paged_decode_attention,
+                                paged_latent_attention)
 from ..ops.moe_ops import routed_ffn_op as routed_ffn
 from ..ops.sparse_attention_ops import (
     indexer_topk_op as indexer_topk, rms_norm_op as rms_norm,

@@ -38,7 +38,7 @@ an append is in place is ASSERTED, not stated:
 programs for a described v5e chip and finds every pool aliased, no
 pool-sized ``copy`` and one pool-shaped fusion (the scatter) per append.
 
-Six ops, registered with declared Effects so the hazard engine orders
+Seven ops, registered with declared Effects so the hazard engine orders
 them like any other variable access (append = read-modify-write on the
 cache resource, gather and paged attention = read):
 
@@ -61,13 +61,20 @@ cache resource, gather and paged attention = read):
   PagedDecodeAttention  attention of ``q`` over a K and a V cache read
                  IN PLACE through a page table (:func:`paged_decode_
                  attention`): no view is gathered.
+  PagedLatentAttention  absorbed latent attention of ``q`` over ONE cache
+                 of latent rows (inner shape ``(W,)``: a position's row
+                 is the key of every head, its first ``value_dim`` lanes
+                 their value) read in place through a page table
+                 (:func:`paged_latent_attention`).
 
 Who reads the pool in place and who still gathers (PR 30). The paged
 programs of the dense causal LM (``models/causal_lm._PagedCaches``:
 decode step and page-chunk prefill) attend through
 ``PagedDecodeAttention``, whose kernel takes pages from the stored pool
-by the table. Still gathered: the slot caches of the translation model
-and speculative verify (``transformer._SlotCaches``: ``KVCacheGather``
+by the table; the latent-attention model (``models/latent_moe_lm.py``)
+does the same over its ONE pool of latent rows a layer
+(``PagedLatentAttention``, PR 33). Still gathered: the slot caches of the
+translation model and speculative verify (``transformer._SlotCaches``: ``KVCacheGather``
 of one dense row a sequence, then ``DecodeAttention``), the sparse
 model's indexer view and selected rows (``KVCacheGather`` /
 ``KVCacheGatherRows``), and ``PagedDecodeAttention``'s own ``xla``
@@ -120,9 +127,11 @@ PAGED_ATTR = "_kv_paged"
 VERIFY_ATTR = "_verify_plan"
 GUARD_ATTR = "_refcount_guarded"
 
+# attention read IN PLACE: the output is attention, not pages
+PAGED_ATTENTION_OP_TYPES = ("PagedDecodeAttention", "PagedLatentAttention")
 _CACHE_OP_TYPES = ("KVCacheAlloc", "KVCacheAppend", "KVCacheGather",
-                   "KVCacheGatherRows", "KVCachePageCopy",
-                   "PagedDecodeAttention")
+                   "KVCacheGatherRows", "KVCachePageCopy"
+                   ) + PAGED_ATTENTION_OP_TYPES
 
 
 # ---------------------------------------------------------------------------
@@ -618,6 +627,68 @@ op_registry.register(
 
 
 # ---------------------------------------------------------------------------
+# PagedLatentAttention graph op: absorbed latent attention off the pool
+# ---------------------------------------------------------------------------
+
+def paged_latent_attention(q, cache: KVCache, page_tables, lengths, *,
+                           value_dim, sm_scale, causal_offset=False,
+                           name=None):
+    """Absorbed multi-head latent attention over ONE paged cache of
+    latent rows, read in place through ``page_tables (B, n_blocks)``.
+
+    cache: declared with inner shape ``(W,)`` — a position's row is
+    ``[c_kv ; k_rope]``, shared by every head; q: ``(B, H, W)`` or a
+    block ``(B, Kq, H, W)``, each head's query already absorbed into the
+    latent space (``[q_nope . W^K ; q_rope]``). ``score = sm_scale * q .
+    row``; the value of a position is its row's first ``value_dim``
+    lanes, so the result is ``q.shape[:-1] + (value_dim,)``, still in the
+    latent space (the caller applies ``W^V``). lengths and
+    ``causal_offset`` as :func:`decode_attention`. Ordered after the
+    layer's append like :func:`paged_decode_attention`: build it under
+    that control dependency. Routed through stf.kernels: ``pallas`` is
+    :func:`..pallas.latent_attention.paged_latent_attention`, ``xla`` the
+    gathered view and the composed softmax. Inference-only."""
+    if len(cache.inner_shape) != 1 or not 0 < value_dim <= cache.inner_shape[0]:
+        raise ValueError(
+            "paged_latent_attention wants a cache of latent rows (inner "
+            f"shape (W,)) and 0 < value_dim <= W; got {cache!r}, "
+            f"value_dim {value_dim}")
+    g = ops_mod.get_default_graph()
+    q = ops_mod.convert_to_tensor(q)
+    page_tables = ops_mod.convert_to_tensor(page_tables,
+                                            dtype=dtypes_mod.int32)
+    lengths = ops_mod.convert_to_tensor(lengths, dtype=dtypes_mod.int32)
+    if causal_offset and q.shape.rank != 4:
+        raise ValueError("causal_offset=True requires a query block "
+                         f"(B, Kq, H, W); got q rank {q.shape.rank}")
+    attrs = cache._attrs()
+    attrs.update(value_dim=int(value_dim), sm_scale=float(sm_scale),
+                 causal_offset=bool(causal_offset))
+    out_shape = q.shape.as_list()[:-1] + [int(value_dim)]
+    op = g.create_op("PagedLatentAttention", [q, page_tables, lengths],
+                     attrs=attrs, name=name or "paged_latent_attention",
+                     output_specs=[(shape_mod.TensorShape(out_shape),
+                                    q.dtype)])
+    return op.outputs[0]
+
+
+def _lower_paged_latent_attention(ctx, op, input_values):
+    q, tables, lengths = input_values
+    pool = ctx.read_var(op.attrs["var_name"], op)
+    value_dim = int(op.attrs["value_dim"])
+    fn = _kreg.select("PagedLatentAttention",
+                      _kreg.aval_key(q, pool, tables, value_dim=value_dim))
+    return [fn(q, pool, tables, lengths, value_dim=value_dim,
+               sm_scale=op.attrs["sm_scale"],
+               causal_offset=bool(op.attrs.get("causal_offset")))]
+
+
+op_registry.register(
+    "PagedLatentAttention", lower=_lower_paged_latent_attention,
+    effects=op_registry.Effects(reads=("var_name",)))
+
+
+# ---------------------------------------------------------------------------
 # sharding propagation rules (stf.analysis.sharding)
 #
 # Cache state commits at the layout declared on the cache (slot dim
@@ -717,11 +788,13 @@ def _decode_attention_rule(op, in_specs, ctx):
 _shard.register_rules(_decode_attention_rule, "DecodeAttention")
 
 
-def _paged_decode_attention_rule(op, in_specs, ctx):
+def _paged_attention_rule(op, in_specs, ctx):
     # a cache READ like the gather: local over a replicated or a
-    # head-sharded pool (each shard attends with its own heads); over a
+    # head-sharded pool (each shard attends with its own heads; a latent
+    # pool has no head dim and every head reads the same rows); over a
     # slot-sharded pool the pages the tables address move to the rows
-    # that read them, priced as the gather's all-gather of both views
+    # that read them, priced as the gather's all-gather of every pool's
+    # view (K and V, or the one pool of latent rows)
     cache = _cache_spec(op, ctx, len(op.attrs["shape"]))
     if cache[0]:
         shape = op.attrs["shape"]
@@ -729,10 +802,10 @@ def _paged_decode_attention_rule(op, in_specs, ctx):
         entries = _shard._nelems(op.inputs[1].shape) or 0
         ctx.collective(
             "all-gather", cache[0],
-            2.0 * entries * page / ctx.shard_factor(cache),
-            note="PagedDecodeAttention over slot-sharded caches",
+            len(cache_names(op)) * entries * page / ctx.shard_factor(cache),
+            note=f"{op.type} over slot-sharded caches",
             tensor_name=op.outputs[0].name)
     return _decode_attention_rule(op, in_specs, ctx)
 
 
-_shard.register_rules(_paged_decode_attention_rule, "PagedDecodeAttention")
+_shard.register_rules(_paged_attention_rule, *PAGED_ATTENTION_OP_TYPES)

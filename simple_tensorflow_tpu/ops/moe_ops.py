@@ -19,9 +19,32 @@ goes to its ``top_k`` experts, none is dropped.
   combine    rows back in pair order, weighted by the gates, summed per
              token in float32.
 
-Every expert is held by the caller: there is no code for absent experts
-or their exchange. The op also returns how many LIVE rows each expert
-got (``row_mask`` leaves a bucket's padding rows out of the count).
+The op also returns how many LIVE rows each expert got (``row_mask``
+leaves a bucket's padding rows out of the count).
+
+Attributes beyond the default block (each leaves the default lowering as
+it was when it is not given):
+
+  score      ``"softmax"`` (default) or ``"sigmoid"``: ``s = sigmoid(x .
+             Wr)``, the gates ``s_e / (sum_selected s + 1e-20)``.
+  bias       an input, ``(E,)`` float32: added to the scores to CHOOSE
+             the ``top_k`` experts and to nothing else — the gates weigh
+             with the scores as they were.
+  gate_scale a factor on the gates after normalisation.
+  held       ``(first, count)``: this chip holds experts ``[first, first
+             + count)`` of the ``E`` the router scores — ``w_gate_up`` and
+             ``w_down`` carry ``count`` experts. The router still scores
+             all ``E`` and picks ``top_k``; only the pairs whose expert is
+             held are dispatched, and ``y`` is this chip's PART of the
+             sum. ``counts`` is over the held experts.
+
+The held dispatch follows the pairs that land here, not ``T * top_k``:
+the pairs are sorted held-experts-first and taken in windows of
+``held_window(T * top_k, count, E)`` rows — twice what an even router
+sends here — one window a pass of a ``while`` loop that runs ``ceil(
+landed / window)`` times. Most calls make one pass; a call on which
+more land makes a second, none is dropped, and one on which none land
+makes none. There is no code for the absent experts or their exchange.
 """
 
 from __future__ import annotations
@@ -34,61 +57,157 @@ from ..framework import op_registry
 from . import op_util
 
 
-def route(x, w_router, *, top_k, norm_topk):
+def route(x, w_router, *, top_k, norm_topk, score="softmax", bias=None,
+          gate_scale=1.0):
     """``(experts (T, k) int32, gates (T, k) float32)``."""
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    top_p, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if score == "softmax" and bias is None:
+        top_p, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                       top_k)
+        norm = jnp.sum(top_p, axis=-1, keepdims=True)
+    else:
+        scores = (jax.nn.sigmoid(logits) if score == "sigmoid"
+                  else jax.nn.softmax(logits, axis=-1))
+        # the bias chooses, it does not weigh
+        choice = scores if bias is None else scores + bias.astype(
+            jnp.float32)
+        _, experts = jax.lax.top_k(choice, top_k)
+        top_p = jnp.take_along_axis(scores, experts, axis=-1)
+        norm = jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20
     if norm_topk:
-        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        top_p = top_p / norm
+    if gate_scale != 1.0:
+        top_p = top_p * gate_scale
     return experts.astype(jnp.int32), top_p
 
 
+def held_window(pairs, count, num_experts):
+    """Rows one pass of the held dispatch takes: twice the pairs an even
+    router sends to ``count`` of ``num_experts`` experts, a multiple of
+    8, never more than there are pairs."""
+    even = -(-pairs * count // num_experts)
+    return min(pairs, max(8, -(-2 * even // 8) * 8))
+
+
+def _experts(rows, w_gate_up, w_down, group_sizes):
+    """The two grouped matmuls around ``silu(gate) * up``, float32 out."""
+    width = w_down.shape[1]
+    h = jax.lax.ragged_dot(rows, w_gate_up, group_sizes,
+                           preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(h[:, :width]) * h[:, width:]).astype(w_down.dtype)
+    return jax.lax.ragged_dot(h, w_down, group_sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def _live_counts(one_hot, group_sizes, row_mask, top_k):
+    """Rows per expert, a bucket's padding rows (``row_mask`` False) left
+    out; ``one_hot`` is (pairs, experts)."""
+    if row_mask is None:
+        return group_sizes
+    live = jnp.repeat(row_mask.astype(jnp.int32), top_k)
+    return jnp.sum(one_hot * live[:, None], axis=0, dtype=jnp.int32)
+
+
+def _held_ffn(x, experts, gates, w_gate_up, w_down, row_mask, held,
+              num_experts):
+    """This chip's part of the routed sum (module docstring, ``held``)."""
+    t, top_k = experts.shape
+    first, count = held
+    pairs = t * top_k
+    window = held_window(pairs, count, num_experts)
+    local = experts.reshape(-1) - first                      # (T*k,)
+    local = jnp.where((local >= 0) & (local < count), local, count)
+    order = jnp.argsort(local, stable=True)      # held first, by expert
+    one_hot = local[:, None] == jnp.arange(count, dtype=jnp.int32)
+    group_sizes = jnp.sum(one_hot, axis=0, dtype=jnp.int32)
+    ends = jnp.cumsum(group_sizes)
+    starts, landed = ends - group_sizes, ends[-1]
+    # a window past the last pair reads padding, never another pass's rows
+    order = jnp.concatenate([order, jnp.zeros((window,), order.dtype)])
+    flat_gates = gates.reshape(-1)
+    lane = jnp.arange(window, dtype=jnp.int32)
+
+    def one_pass(i, y):
+        lo = i * window
+        pair = jax.lax.dynamic_slice_in_dim(order, lo, window)
+        sizes = jnp.clip(jnp.minimum(ends, lo + window)
+                         - jnp.maximum(starts, lo), 0, window)
+        token = pair // top_k
+        out = _experts(x[token].astype(w_gate_up.dtype), w_gate_up,
+                       w_down, sizes)
+        # rows past the landed pairs belong to no group: their product
+        # is not defined, so they are selected away, not multiplied
+        out = jnp.where((lo + lane < landed)[:, None],
+                        out * flat_gates[pair][:, None], 0.0)
+        return y.at[token].add(out)
+
+    y = jax.lax.fori_loop(0, -(-landed // window), one_pass,
+                          jnp.zeros((t, w_down.shape[-1]), jnp.float32))
+    return y.astype(x.dtype), _live_counts(one_hot, group_sizes, row_mask,
+                                           top_k)
+
+
 def routed_ffn(x, w_router, w_gate_up, w_down, row_mask=None, *, top_k,
-               norm_topk=True):
+               norm_topk=True, score="softmax", bias=None, gate_scale=1.0,
+               held=None):
     """``x (T, H)``; ``w_router (H, E)``; ``w_gate_up (E, H, 2*I)`` (gate
-    columns first); ``w_down (E, I, H)``. Returns ``(y (T, H)`` in
-    ``x``'s dtype, ``counts (E,)`` int32``)``."""
+    columns first); ``w_down (E, I, H)`` — with ``held = (first, count)``
+    the two carry ``count`` experts. Returns ``(y (T, H)`` in ``x``'s
+    dtype, ``counts`` int32 over the experts held``)``."""
     t, _ = x.shape
-    num_experts, width = w_down.shape[0], w_down.shape[1]
-    experts, gates = route(x, w_router, top_k=top_k, norm_topk=norm_topk)
+    experts, gates = route(x, w_router, top_k=top_k, norm_topk=norm_topk,
+                           score=score, bias=bias, gate_scale=gate_scale)
+    if held is not None:
+        return _held_ffn(x, experts, gates, w_gate_up, w_down, row_mask,
+                         tuple(int(v) for v in held), w_router.shape[1])
+    num_experts = w_down.shape[0]
     flat = experts.reshape(-1)                               # (T*k,)
     order = jnp.argsort(flat, stable=True)
     one_hot = flat[:, None] == jnp.arange(num_experts, dtype=jnp.int32)
     group_sizes = jnp.sum(one_hot, axis=0, dtype=jnp.int32)
     rows = x[order // top_k].astype(w_gate_up.dtype)
-    h = jax.lax.ragged_dot(rows, w_gate_up, group_sizes,
-                           preferred_element_type=jnp.float32)
-    h = (jax.nn.silu(h[:, :width]) * h[:, width:]).astype(w_down.dtype)
-    y = jax.lax.ragged_dot(h, w_down, group_sizes,
-                           preferred_element_type=jnp.float32)
+    y = _experts(rows, w_gate_up, w_down, group_sizes)
     y = y[jnp.argsort(order)].reshape(t, top_k, -1)          # pair order
     y = jnp.sum(y * gates[:, :, None], axis=1).astype(x.dtype)
-    if row_mask is None:
-        counts = group_sizes
-    else:
-        live = jnp.repeat(row_mask.astype(jnp.int32), top_k)
-        counts = jnp.sum(one_hot * live[:, None], axis=0, dtype=jnp.int32)
-    return y, counts
+    return y, _live_counts(one_hot, group_sizes, row_mask, top_k)
 
 
-op_registry.register_pure(
-    "RoutedFFN",
-    lambda x, w_router, w_gate_up, w_down, row_mask=None, top_k=1,
-    norm_topk=True: routed_ffn(x, w_router, w_gate_up, w_down, row_mask,
-                               top_k=top_k, norm_topk=norm_topk),
-    n_outputs=2)
+def _lower(x, w_router, w_gate_up, w_down, *rest, top_k=1, norm_topk=True,
+           score="softmax", gate_scale=1.0, held=None, has_bias=False):
+    # optional inputs, in the order ``routed_ffn_op`` appends them
+    rest = list(rest)
+    bias = rest.pop() if has_bias else None
+    row_mask = rest.pop() if rest else None
+    return routed_ffn(x, w_router, w_gate_up, w_down, row_mask, top_k=top_k,
+                      norm_topk=norm_topk, score=score, bias=bias,
+                      gate_scale=gate_scale, held=held)
+
+
+op_registry.register_pure("RoutedFFN", _lower, n_outputs=2)
 
 
 def routed_ffn_op(x, w_router, w_gate_up, w_down, row_mask=None, *, top_k,
-                  norm_topk=True, name=None):
+                  norm_topk=True, score="softmax", bias=None,
+                  gate_scale=1.0, held=None, name=None):
     """Graph op over ``x (T, H)``; see :func:`routed_ffn`. Returns
     ``(y, counts)``."""
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(f"score must be softmax or sigmoid, got {score!r}")
     inputs = [x, w_router, w_gate_up, w_down]
+    attrs = {"top_k": int(top_k), "norm_topk": bool(norm_topk)}
     if row_mask is not None:
         inputs.append(row_mask)
+    # what the default block does not use stays off its op
+    if bias is not None:
+        inputs.append(bias)
+        attrs["has_bias"] = True
+    if score != "softmax":
+        attrs["score"] = score
+    if gate_scale != 1.0:
+        attrs["gate_scale"] = float(gate_scale)
+    if held is not None:
+        attrs["held"] = (int(held[0]), int(held[1]))
     inputs = [ops_mod.convert_to_tensor(t) for t in inputs]
-    return op_util.make_op("RoutedFFN", inputs,
-                           attrs={"top_k": int(top_k),
-                                  "norm_topk": bool(norm_topk)},
+    return op_util.make_op("RoutedFFN", inputs, attrs=attrs,
                            name=name or "routed_ffn", n_out=2)

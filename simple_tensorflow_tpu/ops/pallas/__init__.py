@@ -28,6 +28,8 @@ from .dropout_residual import (dropout_bias_residual,
 from .flash_attention import attention_xla, flash_attention, mha_reference
 from .fused_update import (adam_update, adam_update_reference,
                            momentum_update, momentum_update_reference)
+from .latent_attention import (paged_latent_attention,
+                               paged_latent_attention_xla)
 from .layer_norm import layer_norm, layer_norm_reference
 from .quant_matmul import (quant_matmul, quant_matmul_reference,
                            quant_matmul_ste, quant_matmul_ste_reference,
@@ -595,3 +597,67 @@ def _paged_attn_graph_key(op):
         return None
     pool = _Aval(_kvc.stored_shape(op.attrs["shape"]), q[1])
     return _kreg.aval_key(_Aval(*q), pool, _Aval(*tables))
+
+
+# ---------------------------------------------------------------------------
+# PagedLatentAttention: absorbed latent attention over ONE pool of latent
+# rows read in place through the page table vs the gathered view +
+# composed masked softmax. The graph op is registered by
+# ops/kv_cache_ops.py; this entry owns the routing.
+# ---------------------------------------------------------------------------
+
+def _latent_attn_eligible(key):
+    (qs, qd), (ps, pd), (ts, _td) = key[:3]
+    if not _is_float(qd) or str(pd) != str(qd):
+        return "ineligible_dtype"
+    # q (B, H, W) or (B, Kq, H, W); pool (pages, page_len, W) as stored;
+    # tables (B, n_blocks)
+    if len(qs) not in (3, 4) or len(ps) != 3 or len(ts) != 2:
+        return "ineligible_shape"
+    if ts[0] != qs[0] or ps[2] != qs[-1]:
+        return "ineligible_shape"
+    return None
+
+
+def _latent_attn_gate(key, bk):
+    (qs, qd), (ps, _), (ts, _) = key[:3]
+    value_dim = dict(key[3:])["value_dim"]
+    b, h, w = int(qs[0]), int(qs[-2]), int(qs[-1])
+    kq = int(qs[1]) if len(qs) == 4 else 1
+    page_len, n_blocks = int(ps[1]), int(ts[1])
+    itm = _np_of(qd).itemsize
+    view_len = n_blocks * page_len
+    flops = 2.0 * b * kq * h * view_len * (w + value_dim)
+    q_out = b * kq * h * (w + value_dim) * itm
+    # every table entry's rows once: the most the kernel reads (entries
+    # past a row's length are skipped at run time)
+    view = 1.0 * b * view_len * w * itm
+    # the composition gathers the view (read + write), reads it for the
+    # scores and again for the values, and its (B, Kq, H, L) float32
+    # scores make three passes
+    composed = view * 4.0 + 3.0 * b * kq * h * view_len * 4
+    return _kreg.roofline_gate(flops, view + q_out, composed + q_out, bk)
+
+
+_kreg.register_kernel(
+    "PagedLatentAttention",
+    impls={"pallas": paged_latent_attention,
+           "xla": paged_latent_attention_xla},
+    legacy="xla",
+    eligible=_latent_attn_eligible,
+    cost_gate=_latent_attn_gate,
+    graph_key=lambda op: _latent_attn_graph_key(op),
+    doc="absorbed latent attention over one pool of latent rows read in "
+        "place through the page table vs the gathered view + composed "
+        "masked softmax")
+
+
+def _latent_attn_graph_key(op):
+    from .. import kv_cache_ops as _kvc
+
+    q, tables = _tensor_aval(op.inputs[0]), _tensor_aval(op.inputs[1])
+    if q is None or tables is None:
+        return None
+    pool = _Aval(_kvc.stored_shape(op.attrs["shape"]), q[1])
+    return _kreg.aval_key(_Aval(*q), pool, _Aval(*tables),
+                          value_dim=int(op.attrs["value_dim"]))

@@ -11,7 +11,8 @@ position.)
 Serving-only graph ops (no gradients), all lowered to plain XLA:
 
   RMSNorm            ``x * rsqrt(mean(x^2) + eps) * gamma`` in float32.
-  RotaryEmbedding    rotate-half RoPE from absolute positions.
+  RotaryEmbedding    rotate-half RoPE from absolute positions; with
+                     ``yarn`` YaRN's blended frequencies.
   IndexerTopK        DECODE: one query per sequence scores the gathered
                      indexer keys of its context and returns the selected
                      positions, best first, and how many are real.
@@ -36,6 +37,8 @@ operands keep their (bfloat16) dtype with float32 accumulation.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -57,13 +60,43 @@ def rms_norm(x, gamma, *, eps, out_dtype=None):
     return y.astype(out_dtype or x.dtype)
 
 
-def rotary_embedding(x, positions, *, theta):
+def yarn_inv_freq(dim, theta, *, factor, original_len, beta_fast,
+                  beta_slow):
+    """YaRN's blended frequencies for a ``dim``-wide rotation: ``f_i =
+    theta^(-2i/dim)`` where a dimension turns more than ``beta_fast``
+    times within ``original_len`` positions, ``f_i / factor`` where it
+    turns fewer than ``beta_slow`` times, a linear ramp between. Static:
+    the same frequencies at every length."""
+    def turns_dim(n):
+        return dim * math.log(original_len / (2 * math.pi * n)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(turns_dim(beta_fast)), 0)
+    high = min(math.ceil(turns_dim(beta_slow)), dim - 1)
+    freq = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return freq * (1.0 - ramp) + freq / factor * ramp
+
+
+def rotary_embedding(x, positions, *, theta, yarn=None, amplitude=1.0):
     """``x (..., H, D)`` rotated by ``positions`` (shape ``x.shape[:-2]``):
-    the rotate-half convention, angles ``pos * theta^(-2i/D)`` in float32."""
+    the rotate-half convention, angles ``pos * theta^(-2i/D)`` in float32.
+    ``yarn = (factor, original_len, beta_fast, beta_slow)`` takes
+    :func:`yarn_inv_freq`'s frequencies instead; ``amplitude`` scales cos
+    and sin (YaRN's ``mscale / mscale_all_dim``)."""
     d = x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if yarn is None:
+        inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    else:
+        factor, original_len, beta_fast, beta_slow = yarn
+        inv_freq = yarn_inv_freq(d, theta, factor=factor,
+                                 original_len=original_len,
+                                 beta_fast=beta_fast, beta_slow=beta_slow)
     ang = positions.astype(jnp.float32)[..., None, None] * inv_freq
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if amplitude != 1.0:
+        cos, sin = cos * amplitude, sin * amplitude
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., :d // 2], xf[..., d // 2:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
@@ -247,8 +280,9 @@ op_registry.register_pure(
         out_dtype=jnp.dtype(out_dtype) if out_dtype else None))
 op_registry.register_pure(
     "RotaryEmbedding",
-    lambda x, positions, theta=10000.0: rotary_embedding(
-        x, positions, theta=theta))
+    lambda x, positions, theta=10000.0, yarn=None, amplitude=1.0:
+    rotary_embedding(x, positions, theta=theta, yarn=yarn,
+                     amplitude=amplitude))
 op_registry.register_pure(
     "IndexerTopK",
     lambda q_idx, weights, k_idx, lengths, topk=0: indexer_topk(
@@ -280,9 +314,18 @@ def rms_norm_op(x, gamma, eps=1e-6, out_dtype=None, name=None):
                  name or "rms_norm")
 
 
-def rotary_embedding_op(x, positions, theta, name=None):
-    return _make("RotaryEmbedding", [x, positions],
-                 {"theta": float(theta)}, name or "rotary_embedding")
+def rotary_embedding_op(x, positions, theta, yarn=None, amplitude=1.0,
+                        name=None):
+    """``yarn`` = ``(factor, original_len, beta_fast, beta_slow)`` or
+    None for plain RoPE (:func:`rotary_embedding`)."""
+    attrs = {"theta": float(theta)}
+    # what plain RoPE does not use stays off its op
+    if yarn is not None:
+        attrs["yarn"] = tuple(float(v) for v in yarn)
+    if amplitude != 1.0:
+        attrs["amplitude"] = float(amplitude)
+    return _make("RotaryEmbedding", [x, positions], attrs,
+                 name or "rotary_embedding")
 
 
 def indexer_topk_op(q_idx, weights, k_idx, lengths, topk, name=None):

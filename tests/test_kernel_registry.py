@@ -543,6 +543,10 @@ _RULE_KEYS = {
                                           "float32"),
     "DecodeAttention": _decode_key(16, 4096, 32, 128),
     "PagedDecodeAttention": _paged_key(16, 1, 32, 128, 128, 32, 1025),
+    # 32 rows x 64 absorbed heads over a pool of 640-wide latent rows
+    "PagedLatentAttention": kreg.aval_key(
+        _aval((32, 64, 640)), _aval((1201, 512, 640)),
+        _aval((32, 37), "int32"), value_dim=512),
 }
 
 

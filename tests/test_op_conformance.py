@@ -1077,6 +1077,8 @@ COVERED_ELSEWHERE.update({
     "DecodeAttention": ("test_generative.py", "decode_attention"),
     "PagedDecodeAttention": ("test_paged_decode_attention.py",
                              "test_op_equals_gather_then_decode_attention"),
+    "PagedLatentAttention": ("test_latent_moe_lm.py",
+                             "test_prefill_then_decode_logits"),
     "BarrierIncompleteSize": ("test_data_flow_structures.py", "Barrier"),
     "BarrierInsertMany": ("test_data_flow_structures.py", "Barrier"),
     "BarrierReadySize": ("test_data_flow_structures.py", "Barrier"),

@@ -449,35 +449,38 @@ def test_paged_decode_attention_kernel(tpu_compile, dtype, rows, kq):
 # the K and V pools (761 x 512 x 512 bfloat16, 399 MB each) uncopied.
 # ---------------------------------------------------------------------------
 
-@pytest.fixture(scope="module")
-def sparse_moe_programs(one_chip):
+def _paged_serving_programs(one_chip, config_file, stack_of, decode_bucket,
+                            prefill_bucket, kernels_on_tpu=False):
+    """The decode and the page-chunk prefill program of a block stack at
+    its benchmark configuration's sizes, compiled for the described chip
+    from the Session's own donating step function (state as avals:
+    nothing is allocated). ``stack_of(cfg_kwargs, model_kwargs)`` ->
+    (config, stack)."""
     import json
     import os
 
     import simple_tensorflow_tpu as stf
-    from simple_tensorflow_tpu.models import causal_lm, sparse_moe_lm
+    from simple_tensorflow_tpu.models import causal_lm
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "chipbench", "configs",
-                           "keye-vl2-30b-a3b.json")) as f:
+    with open(os.path.join(root, "chipbench", "configs", config_file)) as f:
         program = json.load(f)["program"]
-    cfg = sparse_moe_lm.SparseMoEConfig(**program["config_kwargs"])
     kw = program["model_kwargs"]
 
     def aval(shape, dtype):
         return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
 
     graph = stf.Graph()
-    with _lowering_for_the_described_chip(), graph.as_default(), \
+    with _lowering_for_the_described_chip() as mp, graph.as_default(), \
             stf.Session(graph=graph) as sess:
-        stack = sparse_moe_lm._SparseMoEStack(
-            cfg, stf.bfloat16, "causal_lm",
-            sparse_moe_lm.attn_tile_pages(kw["pages_per_seq"])
-            * kw["page_len"])
+        if kernels_on_tpu:
+            mp.setattr(kreg, "backend", lambda: "tpu")
+        cfg, stack = stack_of(program["config_kwargs"], kw)
         prog = causal_lm.build_paged_lm_program(
             stack, page_len=kw["page_len"],
             pages_per_seq=kw["pages_per_seq"], num_pages=kw["num_pages"],
-            decode_bucket_sizes=(16,), prefill_bucket_sizes=(1,),
+            decode_bucket_sizes=(decode_bucket,),
+            prefill_bucket_sizes=(prefill_bucket,),
             compute_dtype=stf.bfloat16)
         caches = [c for group in prog["caches"] for c in group]
         state = {v.var_name: aval(v.shape.as_list(),
@@ -495,20 +498,37 @@ def sparse_moe_programs(one_chip):
                 dict(state), feed_avals, aval((), jax.random.key(0).dtype),
                 aval((), np.uint32)).compile()
 
-        d, p = prog["decode"][16], prog["prefill"][1]
+        d, p = prog["decode"][decode_bucket], prog["prefill"][prefill_bucket]
         programs = {
-            "decode16": compiled(
+            f"decode{decode_bucket}": compiled(
                 {"next_tok": d["next_tok"], "logp": d["logp"], **d["extra"]},
                 [d["tok"], d["pos"], d["tables"], d["dst"], d["off"]]),
-            "prefill1": compiled(
+            f"prefill{prefill_bucket}": compiled(
                 {"done": p["op"]},
                 [p["tok"], p["base"], p["tables"], p["dst"]]),
         }
     state_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
                       for a in state.values())
     return {"programs": programs, "n_state": len(state),
-            "state_bytes": state_bytes,
-            "kv_pool": caches[0].stored_shape}
+            "state_bytes": state_bytes, "n_pools": len(caches),
+            "pool": caches[0].stored_shape, "kw": kw, "cfg": cfg}
+
+
+@pytest.fixture(scope="module")
+def sparse_moe_programs(one_chip):
+    import simple_tensorflow_tpu as stf
+    from simple_tensorflow_tpu.models import sparse_moe_lm
+
+    def stack_of(cfg_kwargs, kw):
+        cfg = sparse_moe_lm.SparseMoEConfig(**cfg_kwargs)
+        return cfg, sparse_moe_lm._SparseMoEStack(
+            cfg, stf.bfloat16, "causal_lm",
+            sparse_moe_lm.attn_tile_pages(kw["pages_per_seq"])
+            * kw["page_len"])
+
+    got = _paged_serving_programs(one_chip, "keye-vl2-30b-a3b.json",
+                                  stack_of, 16, 1)
+    return dict(got, kv_pool=got["pool"])
 
 
 @pytest.mark.parametrize("program", ["decode16", "prefill1"])
@@ -539,3 +559,109 @@ def test_sparse_moe_programs_compile_and_fit(sparse_moe_programs, program):
         assert " sort(" in text
         assert "bf16[16,33792,512]" not in text
         assert "bf16[16,2048,512]" in text
+
+
+# ---------------------------------------------------------------------------
+# The latent-attention routed-FFN decoder at its benchmark sizes
+# (chipbench/configs/kimi-k2.7-code.json): one pool of latent rows a layer.
+# WHY THE ROW IS 640 WIDE: declared 576 wide (512 latent + 64 rope) the
+# compiler lays the pool out positions-minor and every call pays a
+# pool-sized relayout — asserted below on the kernel alone — where the
+# 640-wide pool (what the tiled layout occupies anyway) is read in place.
+# The decode and page-chunk prefill programs then have to compile for the
+# described chip, fit beside 13 GB of weights and caches, keep every state
+# leaf aliased, append in place, and hold no op of the pool's gathered-view
+# shape.
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("width,in_place", [(576, False), (640, True)])
+def test_latent_pool_layout_decides_the_row_width(tpu_compile, width,
+                                                  in_place):
+    """The decode kernel alone over a (1201, 512, width) bfloat16 pool: the
+    576-wide pool arrives positions-minor ({1,2,0}) and is copied whole
+    before the kernel; the 640-wide one arrives rows-minor and is not."""
+    import importlib
+
+    la = importlib.import_module(
+        "simple_tensorflow_tpu.ops.pallas.latent_attention")
+    pool = (1201, 512, width)
+    text = tpu_compile(
+        lambda q, p, t, n: la.paged_latent_attention(
+            q, p, t, n, value_dim=512, sm_scale=0.1447),
+        ((32, 64, width), BF16), (pool, BF16), ((32, 37), jnp.int32),
+        ((32,), jnp.int32))
+    assert "stf_latent_attention_q1_paged" in text
+    param = re.search(r"bf16\[1201,512,%d\]\{([0-9,]+)[:}]" % width
+                      + r"[^\n]* parameter\(", text).group(1)
+    copies = [ln for shape, op, ln in _entry_instructions(text)
+              if op == "copy" and shape == list(pool)]
+    assert (param == "2,1,0") is in_place, param
+    assert (not copies) is in_place, copies[:1]
+
+
+@pytest.fixture(scope="module")
+def latent_moe_programs(one_chip):
+    import simple_tensorflow_tpu as stf
+    from simple_tensorflow_tpu.models import latent_moe_lm
+
+    def stack_of(cfg_kwargs, kw):
+        cfg = latent_moe_lm.LatentMoEConfig(**cfg_kwargs)
+        return cfg, latent_moe_lm._LatentMoEStack(cfg, stf.bfloat16,
+                                                  "causal_lm")
+
+    return _paged_serving_programs(one_chip, "kimi-k2.7-code.json", stack_of,
+                                   32, 4, kernels_on_tpu=True)
+
+
+@pytest.mark.parametrize("program,rows,kq", [("decode32", 32, 1),
+                                             ("prefill4", 4, 512)])
+def test_latent_moe_programs_compile_fit_and_read_in_place(
+        latent_moe_programs, program, rows, kq):
+    got = latent_moe_programs
+    compiled = got["programs"][program]
+    text = compiled.as_text()
+    kw, cfg, pool = got["kw"], got["cfg"], got["pool"]
+    # the stored layout: one (pages + scratch, page_len, 640) pool a layer
+    assert pool == (kw["num_pages"] + 1, kw["page_len"], 640)
+    assert got["n_pools"] == cfg.num_layers
+    # weights 8.35 GB + the latent pools 4.72 GB, all donated through
+    assert 12.9e9 < got["state_bytes"] < 13.3e9, got["state_bytes"]
+    header = text.split("\n", 1)[0]
+    assert header.count("-alias)") == got["n_state"]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert got["state_bytes"] + temp < 16.0e9, temp
+    params = re.findall(r"bf16\[%d,%d,%d\]\{([0-9,]+)[:}][^\n]* parameter\("
+                        % pool, text)
+    assert len(params) >= cfg.num_layers and set(params) == {"2,1,0"}, params
+    # appends in place: no pool-sized copy, one pool-shaped scatter a layer
+    pool_elems = int(np.prod(pool))
+    pool_sized = {}
+    for shape, op, ln in _entry_instructions(text):
+        if op != "parameter" and int(np.prod(shape)) == pool_elems:
+            pool_sized.setdefault(op, []).append(ln)
+    assert not pool_sized.get("copy"), pool_sized["copy"][:1]
+    # (a bitcast moves nothing)
+    assert set(pool_sized) - {"bitcast"} == {"fusion"}, sorted(pool_sized)
+    assert len(pool_sized["fusion"]) == cfg.num_layers
+    # the scatter itself (decode), or the compiler's own in-place row
+    # update over the pool seen as (pages x page_len, 640) (a 512-row
+    # chunk: it carries no op_name but names its aliased operand)
+    assert all("_append/scatter" in ln
+               or '"aliasing_operands":{"lists":[{' in ln
+               for ln in pool_sized["fusion"])
+    # no op of the pool's gathered-view shape (rows x pages_per_seq pages)
+    view_elems = rows * kw["pages_per_seq"] * kw["page_len"] * pool[2]
+    view_sized = [ln[:140] for shape, op, ln in _entry_instructions(text)
+                  if op != "parameter" and int(np.prod(shape)) == view_elems]
+    assert not view_sized, view_sized[:3]
+    assert f"bf16[{rows * kw['pages_per_seq']},{kw['page_len']},640]" \
+        not in text
+    # one latent kernel a layer under its own name (a prefill call keeps
+    # only its appends: the last layer's attention feeds nothing)
+    calls = re.findall(
+        r"%(stf_latent_attention_q\d+_paged)[\w.\-]* = [^\n]*custom-call",
+        text)
+    n_calls = cfg.num_layers - (program == "prefill4")
+    assert calls == [f"stf_latent_attention_q{kq}_paged"] * n_calls, calls
+    # the held experts' grouped matmuls, under the compiler's own name
+    assert text.count("ragged-dot-metadata") >= 1
