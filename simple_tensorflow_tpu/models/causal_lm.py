@@ -50,6 +50,12 @@ _live_page_share = monitoring.Sampler(
     "over the step's rows, over rows x pages_per_seq: the share of the "
     "table paged decode attention reads", "model")
 
+_prefill_call_rows = monitoring.Sampler(
+    "/stf/serving/prefill_call_rows",
+    monitoring.ExponentialBuckets(1.0, 2.0, 8),
+    "Per prefill program call: the real (non-pad) page-chunk rows it "
+    "carried; count is the calls, sum / count the rows a call", "model")
+
 # the causal LM reuses TransformerConfig (decoder-side fields only:
 # d_model/num_heads/d_ff/num_layers/dropout/vocab/max_len)
 CausalLMConfig = TransformerConfig
@@ -584,6 +590,14 @@ class CausalLMGenerativeModel:
                 self._cow_plan[0].compile()
         self._decode_buckets = sorted(self._decode_plans)
         self._prefill_buckets = sorted(self._prefill_plans)
+        if aot_warmup:
+            # which buckets an admission runs follows how many rows its
+            # prompts pack into a call: no bucket's first execution is
+            # left to the traffic. Pad rows write the scratch page only
+            none = np.zeros((0,), np.int32)
+            for pb in self._prefill_buckets:
+                self._prefill_call(pb, none.reshape(0, self.page_len), none,
+                                   self._scratch_tables(0), none)
 
     # -- what a subclass with another block stack overrides ------------------
     def _cache_bytes(self):
@@ -649,12 +663,33 @@ class CausalLMGenerativeModel:
         return np.full((n, self.pages_per_seq), self.scratch_page,
                        np.int32)
 
+    def _prefill_call(self, pb, tok_chunks, bases, page_tables, dst_pages):
+        """One execution of prefill bucket ``pb``: the rows given, then
+        pad rows that read and write the scratch page alone."""
+        plan, p = self._prefill_plans[pb]
+        n = len(dst_pages)
+        tok = np.full((pb, self.page_len), self.pad_id, np.int32)
+        base = np.zeros((pb,), np.int32)
+        tbl = self._scratch_tables(pb)
+        dst = np.full((pb,), self.scratch_page, np.int32)
+        tok[:n], base[:n], tbl[:n], dst[:n] = \
+            tok_chunks, bases, page_tables, dst_pages
+        self._run(plan, {p["tok"]: tok, p["base"]: base,
+                         p["tables"]: tbl, p["dst"]: dst})
+
     def prefill_chunk(self, tok_chunks, bases, page_tables, dst_pages):
-        """Run ONE page-aligned prompt chunk for n rows: ``tok_chunks
+        """Run n page-aligned prompt chunks, one a row: ``tok_chunks
         (n, page_len)`` (pad-padded past the real tail), ``bases (n,)``
         absolute chunk start (multiple of page_len), ``page_tables
         (n, pages_per_seq)``, ``dst_pages (n,)`` the physical page each
-        row's chunk fills."""
+        row's chunk fills. Rows are independent but for the pool: a
+        layer appends every row of a call, then each row attends through
+        its table over the ``bases[i]`` positions before it, so a row
+        may read pages that rows BEFORE it in the order given fill —
+        chunks of one prompt in order of ``base`` are n rows of one
+        call. The rows are cut, in that order, into as many calls of the
+        largest prefill bucket as they fill and the remainder in the
+        smallest bucket that holds it. Returns the calls made."""
         tok_chunks = np.asarray(tok_chunks, np.int32).reshape(
             -1, self.page_len)
         bases = np.asarray(bases, np.int32)
@@ -662,23 +697,18 @@ class CausalLMGenerativeModel:
             -1, self.pages_per_seq)
         dst_pages = np.asarray(dst_pages, np.int32)
         n = len(dst_pages)
-        done = 0
+        rows_a_call = _prefill_call_rows.get_cell(self._metrics_label)
+        calls = done = 0
         while done < n:
             take = min(n - done, self._prefill_buckets[-1])
-            pb = self._bucket(self._prefill_buckets, take)
-            plan, p = self._prefill_plans[pb]
-            tok = np.full((pb, self.page_len), self.pad_id, np.int32)
-            base = np.zeros((pb,), np.int32)
-            tbl = self._scratch_tables(pb)
-            dst = np.full((pb,), self.scratch_page, np.int32)
             sl = slice(done, done + take)
-            tok[:take] = tok_chunks[sl]
-            base[:take] = bases[sl]
-            tbl[:take] = page_tables[sl]
-            dst[:take] = dst_pages[sl]
-            self._run(plan, {p["tok"]: tok, p["base"]: base,
-                             p["tables"]: tbl, p["dst"]: dst})
+            self._prefill_call(self._bucket(self._prefill_buckets, take),
+                               tok_chunks[sl], bases[sl], page_tables[sl],
+                               dst_pages[sl])
+            rows_a_call.add(take)
             done += take
+            calls += 1
+        return calls
 
     def decode(self, tokens, positions, page_tables):
         """One decode position for n live sequences; the physical write
@@ -722,10 +752,12 @@ class CausalLMGenerativeModel:
                 "pages_per_seq": self.pages_per_seq,
                 "num_slots": self.num_slots, "int8": self.int8,
                 "sampling": self.sampling}
-        share = _live_page_share.cells().get((self._metrics_label,))
-        if share is not None:
-            v = share.value()
-            info["decode_live_page_share"] = v["sum"] / v["count"]
+        for key, sampler in (("decode_live_page_share", _live_page_share),
+                             ("prefill_rows_per_call", _prefill_call_rows)):
+            cell = sampler.cells().get((self._metrics_label,))
+            v = cell.value() if cell is not None else None
+            if v and v["count"]:
+                info[key] = v["sum"] / v["count"]
         if self.tp_degree > 1:
             info["tp"] = self.tp_info()
         return info

@@ -575,7 +575,9 @@ class GenerativeEngine:
             slots.append(slot)
             record_queue_wait(self.name, req, now)
         try:
-            with self._prefill_span(live, depth=1) as sp:
+            with self._prefill_span(
+                    live, calls=2 if self._spec_enabled else 1,
+                    rows=len(live)) as sp:
                 self._model.prefill(np.stack([r.src for r in live]),
                                     np.asarray(slots, np.int32))
                 if self._spec_enabled:
@@ -598,14 +600,15 @@ class GenerativeEngine:
                                           req.max_new_tokens))
         self._slots_gauge.set(len(self._active))
 
-    def _prefill_span(self, requests, depth):
+    def _prefill_span(self, requests, **meta):
         """``engine/prefill``: the model calls that put the joining
-        prompts into the cache (``depth`` calls deep for the longest);
-        the ring knows it as ``serving_decode_prefill``."""
+        prompts into the cache (``calls`` program calls carrying
+        ``rows`` prompt rows or page chunks between them); the ring
+        knows it as ``serving_decode_prefill``."""
         return _req_tracing.span(
             "engine/prefill", ring="serving_decode_prefill",
             trace_ids=[r.trace_id for r in requests if r.trace_id],
-            model=self.name, joined=len(requests), depth=depth)
+            model=self.name, joined=len(requests), **meta)
 
     def _sync_prefix_metrics(self):
         pc = self._prefix
@@ -623,9 +626,16 @@ class GenerativeEngine:
         """Prefix-cache admission: resolve each prompt's page program
         (trie hits reuse shared pages, misses prefill fresh ones, a
         partial tail copies-on-write when a cached page extends it),
-        then batch the chunk prefills depth-by-depth so each
-        sequence's chunks run in order while different sequences
-        share plan executions."""
+        then hand the model ALL their page chunks as the rows of one
+        ``prefill_chunk``, ordered by absolute start (``base``), ties
+        by slot. A row reads pages of lower ``base`` only — its own
+        prompt's, and those of a shared prefix that another prompt of
+        this batch fills — and the model cuts the rows into program
+        calls in the order given, each layer of a call appending all
+        its rows before any attends: every page a row reads is written
+        by its own call or an earlier one. Rows of like ``base`` share
+        a call, so a call's furthest row is no further than a
+        lock-step fill's."""
         from .prefix_cache import PagesExhaustedError
 
         admitted = []          # (req, slot, plan)
@@ -658,40 +668,35 @@ class GenerativeEngine:
         pps = self._model.pages_per_seq
         scratch = self._model.scratch_page
         try:
-            # per-sequence ordered chunk lists (append the prefilled
-            # tail as the last chunk when it wasn't served by CoW)
+            # one row a page chunk: (base, slot, page, tokens); the
+            # prefilled tail is a prompt's last chunk when it wasn't
+            # served by CoW
             tables = {}
-            chunk_lists = {}
+            rows = []
             for req, slot, plan in admitted:
                 table = np.full((pps,), scratch, np.int32)
                 pages = plan.pages
                 table[:len(pages)] = pages
                 tables[slot] = table
-                chunks = list(plan.fill)
+                rows += [(base, slot, page, tok)
+                         for page, tok, base in plan.fill]
                 if len(plan.tail) and plan.cow_src is None and \
                         not plan.tail_ready:
-                    row = np.full((pl,), self._model.pad_id, np.int32)
-                    row[:len(plan.tail)] = plan.tail
-                    chunks.append((plan.tail_page, row,
-                                   plan.cached_len - len(plan.tail)))
-                chunk_lists[slot] = chunks
-            depths = max(len(ch) for ch in chunk_lists.values())
+                    tok = np.full((pl,), self._model.pad_id, np.int32)
+                    tok[:len(plan.tail)] = plan.tail
+                    rows.append((plan.cached_len - len(plan.tail), slot,
+                                 plan.tail_page, tok))
+            rows.sort(key=lambda r: r[:2])
             with self._prefill_span([r for r, _, _ in admitted],
-                                    depth=depths) as sp:
+                                    rows=len(rows)) as sp:
                 # copy-on-write first: a CoW'd tail page must be
                 # populated before any decode step reads through it
                 for _, _, plan in admitted:
                     if plan.cow_src is not None:
                         self._model.copy_page(plan.tail_page, plan.cow_src)
-                for depth in range(depths):
-                    batch = [(slot, ch[depth])
-                             for slot, ch in chunk_lists.items()
-                             if depth < len(ch)]
-                    self._model.prefill_chunk(
-                        np.stack([c[1] for _, c in batch]),
-                        np.asarray([c[2] for _, c in batch], np.int32),
-                        np.stack([tables[slot] for slot, _ in batch]),
-                        np.asarray([c[0] for _, c in batch], np.int32))
+                sp.set_meta(calls=self._model.prefill_chunk(
+                    [r[3] for r in rows], [r[0] for r in rows],
+                    [tables[r[1]] for r in rows], [r[2] for r in rows]))
         except BaseException as e:  # noqa: BLE001
             _flight_mod.get_recorder().on_error(
                 e, where="serving_decode_prefill", model=self.name)

@@ -7,8 +7,10 @@ query-block decode-attention parity, the paged causal-LM serving path,
 the new serving-decode-cache lint branches, and the new
 /stf/serving/{prefix_cache_*,spec_*} metrics."""
 
+import dataclasses
 import os
 import tempfile
+import threading
 
 import numpy as np
 import pytest
@@ -650,6 +652,190 @@ class TestPagedCausalLM:
             assert name in exported, name
         hits = exported["/stf/serving/prefix_cache_hits"]["cells"]
         assert any(v > 0 for v in hits.values())
+
+
+# ---------------------------------------------------------------------------
+# Admission hands a prompt's page chunks to the model as the ROWS of one
+# prefill_chunk: calls by the model's prefill buckets, rows by base
+# ---------------------------------------------------------------------------
+
+def _call_rows(label):
+    v = monitoring.get_metric("/stf/serving/prefill_call_rows") \
+        .get_cell(label).value()
+    return v["count"], v["sum"]
+
+
+class TestAdmissionPacksRows:
+    PAGES = 12
+
+    def _model(self, label, buckets, **kw):
+        cfg = dataclasses.replace(tr.TransformerConfig.tiny(),
+                                  max_len=PAGE_LEN * self.PAGES)
+        kw.setdefault("aot_warmup", False)
+        return cfg, clm.CausalLMGenerativeModel(
+            cfg, page_len=PAGE_LEN, pages_per_seq=self.PAGES, num_pages=40,
+            max_live=MAX_LIVE, prefill_bucket_sizes=buckets,
+            init_fresh=True, seed=11, metrics_label=label, **kw)
+
+    def _engine(self, name, model, steps):
+        return serving.GenerativeEngine(name, model, serving.DecodePolicy(
+            num_slots=MAX_LIVE, max_decode_len=model.max_seq_len,
+            bucket_sizes=[1, MAX_LIVE], max_new_tokens=steps))
+
+    def _prompt(self, rng, cfg, chunks, head=()):
+        """``chunks`` page chunks of cached span, the last one partial."""
+        n = PAGE_LEN * (chunks - 1) + 2 + 1 - len(head)
+        return list(head) + list(rng.randint(2, cfg.vocab_size, n))
+
+    def _assert_naive(self, cfg, model, prompts, results, steps):
+        ckpt = _save_ckpt(model, tempfile.mkdtemp(prefix="stf_rows_"))
+        nsess, ids, logits = TestPagedCausalLM()._naive_handles(
+            cfg, ckpt, model.max_seq_len)
+        try:
+            for p, r in zip(prompts, results):
+                naive = _naive_causal_greedy(nsess, ids, logits, p, steps,
+                                             cfg.pad_id)
+                got = list(r["tokens"])
+                assert got == naive[:len(got)]
+                assert r["outcome"] == "eos" or got == naive
+        finally:
+            nsess.close()
+
+    def _one_batch(self, model, name, prompts, steps=3):
+        """Serve ``prompts`` admitted in ONE batch: a first answer's
+        ``on_token`` holds the engine's thread while they are queued,
+        so the next step's joiners are all of them. Returns the answers,
+        every ``prefill_chunk`` the batch made as (bases, tables), the
+        program calls and rows ``/stf/serving/prefill_call_rows{name}``
+        counted for them, and the trie's drift once all have retired."""
+        seen, entered, gate = [], threading.Event(), threading.Event()
+        chunk = model.prefill_chunk
+
+        def recording(tok, bases, tables, dst):
+            seen.append((list(bases), np.asarray(tables)))
+            return chunk(tok, bases, tables, dst)
+
+        def hold(tok, lp):
+            entered.set()
+            assert gate.wait(60)
+
+        model.prefill_chunk = recording
+        with self._engine(name, model, steps) as eng:
+            first = eng.generate([5, 6, 7], max_new_tokens=2, on_token=hold)
+            assert entered.wait(60)
+            del seen[:]
+            before = _call_rows(name)
+            futs = [eng.generate(p, max_new_tokens=steps) for p in prompts]
+            gate.set()
+            results = [f.result(timeout=120) for f in futs]
+            first.result(timeout=120)
+            drift = eng._prefix.reconcile([])
+            stats = eng.statusz_info()
+        counted = tuple(np.subtract(_call_rows(name), before))
+        return results, seen, counted, drift, stats
+
+    @pytest.mark.parametrize("chunks, calls", [(7, 1), (11, 2)])
+    def test_a_prompt_is_the_rows_of_one_call(self, chunks, calls):
+        label = f"rows_{chunks}"
+        cfg, model = self._model(label, [1, 2, 4, 8])
+        prompt = self._prompt(np.random.RandomState(chunks), cfg, chunks)
+        with self._engine(label, model, 4) as eng:
+            result = eng.generate(prompt, max_new_tokens=4).result(120)
+            row = model.statusz_info()
+        assert _call_rows(label) == (calls, chunks)
+        assert row["prefill_rows_per_call"] == chunks / calls
+        self._assert_naive(cfg, model, [prompt], [result], 4)
+        model.close()
+
+    @pytest.mark.parametrize("chunks", [6, 11],
+                             ids=["one-call", "two-calls"])
+    def test_chunks_as_rows_equal_one_call_a_chunk(self, chunks):
+        """Rows j = 0..n-1 of one prefill call may be chunks j of ONE
+        prompt: a layer appends every row before any attends, so row j
+        reads pages 0..j as n chained bucket-1 calls do. Bucket 8 holds
+        6 rows and two pad rows; 11 rows are a call of 8 and a call of
+        3 in bucket 4. Bit-equal decode logits."""
+        cfg, model = self._model(f"rows_eq_{chunks}", [1, 4, 8])
+        body = np.random.RandomState(chunks).randint(
+            2, cfg.vocab_size, (chunks, PAGE_LEN)).astype(np.int32)
+        bases = PAGE_LEN * np.arange(chunks)
+        logits = []
+        for pages, one_call in ((np.arange(chunks), False),
+                                (20 + np.arange(chunks), True)):
+            table = model._scratch_tables(1)
+            # one page more: the decode position's
+            table[0, :chunks + 1] = list(pages) + [pages[-1] + 1]
+            tables = np.repeat(table, chunks, axis=0)
+            if one_call:
+                assert model.prefill_chunk(body, bases, tables, pages) == \
+                    -(-chunks // 8)
+            else:
+                for j in range(chunks):
+                    model.prefill_chunk(body[j:j + 1], bases[j:j + 1],
+                                        table, pages[j:j + 1])
+            _, p = model._decode_plans[1]
+            pos = chunks * PAGE_LEN
+            logits.append(model.session.run(p["logits"], {
+                p["tok"]: [7], p["pos"]: [pos], p["tables"]: table,
+                p["dst"]: table[:, pos // PAGE_LEN], p["off"]: [0]}))
+        model.close()
+        np.testing.assert_array_equal(logits[1], logits[0])
+
+    def test_shared_prefix_in_one_batch_splits_over_calls(self):
+        """B reuses the two pages A fills in the SAME batch; with the
+        largest bucket 2 the six rows take three calls, and B's rows
+        read A's pages from an earlier call of the same admission."""
+        cfg, model = self._model("rows_shared", [1, 2])
+        rng = np.random.RandomState(3)
+        shared = list(rng.randint(2, cfg.vocab_size, 2 * PAGE_LEN))
+        prompts = [self._prompt(rng, cfg, 4, shared),
+                   self._prompt(rng, cfg, 4, shared)]
+        results, seen, counted, drift, stats = self._one_batch(
+            model, "rows_shared", prompts)
+        assert len(seen) == 1 and len(seen[0][0]) == 6     # 4 + 2 rows
+        assert counted == (3, 6)
+        assert drift == 0
+        assert stats["prefix_cache"]["hit_pages"] >= 2
+        self._assert_naive(cfg, model, prompts, results, 3)
+        model.close()
+
+    def test_rows_reach_the_model_ordered_by_base(self):
+        cfg, model = self._model("rows_order", [1, 2, 4, 8])
+        rng = np.random.RandomState(5)
+        prompts = [self._prompt(rng, cfg, c) for c in (3, 5, 2)]
+        results, seen, counted, drift, _ = self._one_batch(
+            model, "rows_order", prompts)
+        (bases, tables), = seen
+        assert counted == (2, 10)                          # 8 + 2 rows
+        assert bases == sorted(bases) and len(bases) == 10
+        assert bases[:4] == [0, 0, 0, PAGE_LEN]
+        # a prompt's rows carry its own table: three tables at base 0
+        assert len({t.tobytes() for t in tables[:3]}) == 3
+        assert drift == 0
+        self._assert_naive(cfg, model, prompts, results, 3)
+        model.close()
+
+    def test_aot_warmup_runs_every_prefill_bucket_on_scratch_rows(
+            self, monkeypatch):
+        ran = []
+        call = clm.CausalLMGenerativeModel._prefill_call
+
+        def recording(self, pb, tok, bases, tables, dst):
+            ran.append((pb, len(dst)))
+            return call(self, pb, tok, bases, tables, dst)
+
+        monkeypatch.setattr(clm.CausalLMGenerativeModel, "_prefill_call",
+                            recording)
+        _, model = self._model("rows_warm", [1, 2, 4], aot_warmup=True)
+        assert ran == [(1, 0), (2, 0), (4, 0)]
+        assert _call_rows("rows_warm") == (0, 0)
+        values = model.session._variable_store.values
+        for group in model._prog["caches"]:
+            for cache in group:
+                pool = np.asarray(values[cache.name])
+                assert not pool[:model.scratch_page].any()
+                assert pool[model.scratch_page].any()
+        model.close()
 
 
 # ---------------------------------------------------------------------------

@@ -178,7 +178,7 @@ def test_engine_spans_nest_on_the_engines_thread(tmp_path):
     assert spanned_s == pytest.approx(after["sum"] - sampled["sum"],
                                       rel=0.1)
     prefill = next(ev for ev in events if ev[0] == "stf/engine/prefill")
-    assert prefill[3]["depth"] == 1
+    assert (prefill[3]["calls"], prefill[3]["rows"]) == (1, 1)
     # the queue ran empty between the first request and its joiners, or
     # at the end: the wait is a span of its own, never a parent of work
     for wait in (ev for ev in events if ev[0] == "stf/engine/wait"):
@@ -219,7 +219,7 @@ def test_the_ring_keeps_the_serving_spans_with_no_profiler():
                                          "serving_decode_prefill"]
     prefill = mine[1]
     assert prefill["meta"] == {"model": "span_ring", "joined": 1,
-                               "depth": 1}
+                               "calls": 1, "rows": 1}
     assert prefill["thread"].startswith("stf_serving_decode_")
     assert prefill["dur_s"] >= 0
     # the engine's step spans are the primitive alone: none reach the ring

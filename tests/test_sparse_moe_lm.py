@@ -53,16 +53,25 @@ def served():
     model.close()
 
 
-def _prefill(model, prompt, pages):
+def _prefill(model, prompt, pages, one_call=False):
     """Every page chunk of ``prompt[:-1]`` (the engine's split: the last
-    prompt token goes through the first decode step)."""
+    prompt token goes through the first decode step), a ``prefill_chunk``
+    a chunk — or with ``one_call`` as the ROWS of one, as the engine's
+    admission hands them over: ordered by base, every row under the
+    prompt's one table."""
     tables = np.full((1, PAGES_PER_SEQ), model.scratch_page, np.int32)
     tables[0, :len(pages)] = pages
-    body = prompt[:-1]
-    for c in range(0, len(body), PAGE):
-        chunk = np.full((PAGE,), model.pad_id, np.int32)
-        chunk[:len(body[c:c + PAGE])] = body[c:c + PAGE]
-        model.prefill_chunk(chunk[None], [c], tables, [tables[0, c // PAGE]])
+    n = -(-(len(prompt) - 1) // PAGE)
+    body = np.full((n * PAGE,), model.pad_id, np.int32)
+    body[:len(prompt) - 1] = prompt[:-1]
+    body, bases = body.reshape(n, PAGE), PAGE * np.arange(n)
+    if one_call:
+        model.prefill_chunk(body, bases, np.repeat(tables, n, axis=0),
+                            tables[0, :n])
+    else:
+        for c in range(n):
+            model.prefill_chunk(body[c:c + 1], bases[c:c + 1], tables,
+                                tables[0, c:c + 1])
     return tables
 
 
@@ -76,6 +85,26 @@ def _decode_logits(model, tok, pos, tables):
             p["off"]: np.asarray([pos % PAGE], np.int32)}
     logits, nxt = model.session.run([p["logits"], p["next_tok"]], feed)
     return logits[0], int(nxt[0])
+
+
+@pytest.mark.parametrize("chunks", [3, 5], ids=["one-call", "two-calls"])
+def test_a_prompts_chunks_as_rows_equal_one_call_a_chunk(chunks):
+    """Rows j = 0..n-1 of one prefill call may be chunks j of ONE prompt:
+    a layer appends every row before any attends, so row j reads pages
+    0..j as n chained calls do. Bucket 4 holds 3 rows and a pad row; 5
+    rows are a call of 4 and a call of 1. Bit-equal decode logits."""
+    model, _, cfg = _model(prefill_bucket_sizes=[1, 4], aot_warmup=False)
+    rng = np.random.default_rng(chunks)
+    prompt = rng.integers(2, cfg.vocab_size,
+                          size=PAGE * (chunks - 1) + 4).astype(np.int32)
+    chained = _prefill(model, prompt, [3, 7, 11, 2, 9, 5][:chunks + 1])
+    rows = _prefill(model, prompt, [14, 4, 21, 8, 1, 17][:chunks + 1],
+                    one_call=True)
+    tok, pos = int(prompt[-1]), len(prompt) - 1
+    want, _ = _decode_logits(model, tok, pos, chained)
+    got, _ = _decode_logits(model, tok, pos, rows)
+    model.close()
+    np.testing.assert_array_equal(got, want)
 
 
 class TestProgramAgainstReference:
