@@ -417,8 +417,9 @@ def _rule_serving_decode_cache(ctx):
     between decode steps; this rule makes both halves statically
     checkable:
 
-    - a cache op (KVCacheAlloc/Append/Gather, or a paged attention
-      reading its pools in place) whose committed-sharding
+    - a cache op (KVCacheAlloc/Append/Gather, a paged attention
+      reading its pools in place, or a state pool's alloc and its
+      in-place updates) whose committed-sharding
       declaration is missing would commit at whatever layout the first
       write happened to produce — resharding every subsequent step;
     - a cache tensor ESCAPING TO HOST (a host-stage op consuming a
@@ -553,10 +554,11 @@ def _rule_serving_decode_cache(ctx):
                                "per-shard instead (heads are "
                                "embarrassingly parallel)")
         paged = bool(op.attrs.get(_kvc.PAGED_ATTR))
-        # a paged attention's output is attention, not pages: what it
+        # a paged attention's output is attention, not pages — and a
+        # state-pool update's is its layer's, not the pool: what it
         # REACHES counts, as it did through the gather that fed the
         # kernel before the pool was read in place
-        pages_out = op.type not in _kvc.PAGED_ATTENTION_OP_TYPES
+        pages_out = op.type not in _kvc.IN_PLACE_OP_TYPES
         for out in op.outputs:
             if pages_out and out in fetched:
                 yield (op,

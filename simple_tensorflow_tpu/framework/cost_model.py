@@ -35,6 +35,7 @@ import math
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from . import dtypes as dtypes_mod
 from . import graph as ops_mod
 from . import lowering as lowering_mod
 
@@ -213,8 +214,23 @@ def _op_flops(op: Operation, grad_depth: int = 0,
             return 2.0 * (n / w) * int(ts.dims[1].value) * int(sh[1]) * (
                 w + int(op.attrs.get("value_dim", w)))
         return 2.0 * _out_elems(op)
+    if t in ("SSMStateUpdate", "SSMChunkScan"):
+        # the recurrence at 6 flops a state element and token (decay
+        # multiply, outer-product multiply-add, h.C multiply-add): tokens
+        # = the leading dims of x (B, H, P) or (R, L, H, P), a row's
+        # state from the pool's declared shape
+        xs, sh = op.inputs[0].shape, op.attrs.get("shape") or []
+        n = _nelems(xs)
+        if n and len(sh) == 4 and xs.rank >= 3:
+            tokens = n / (int(xs.dims[-1].value) * int(xs.dims[-2].value))
+            return 6.0 * tokens * int(sh[1]) * int(sh[2]) * int(sh[3])
+        return 2.0 * _out_elems(op)
+    if t == "CausalConv1D":
+        # one multiply-add a tap and output element
+        taps = op.inputs[1].shape.dims[0].value or 1
+        return 2.0 * int(taps) * _out_elems(op)
     if t in ("KVCacheAlloc", "KVCacheAppend", "KVCacheGather",
-             "KVCacheGatherRows", "KVCachePageCopy"):
+             "KVCacheGatherRows", "KVCachePageCopy", "StatePoolAlloc"):
         return 0.0  # pure data movement; bytes are priced in _op_bytes
     if t == "EmbeddingLookupFused":
         # row routing is data movement (the whole point vs the one-hot
@@ -314,6 +330,17 @@ def _op_bytes_dispatch(op: Operation, fn_depth: int = 0) -> float:
             page *= int(d)
         itemsize = op.outputs[0].dtype.base_dtype.size if op.outputs else 4
         return _op_bytes(op) + pools * entries * page * itemsize
+    if op.type in ("SSMStateUpdate", "SSMChunkScan", "CausalConv1D"):
+        # a state pool's rows advanced in place: the op's own inputs and
+        # its output (the layer's, not the pool), and per ROW of the call
+        # one slot's state read and written — never the whole pool
+        sh = op.attrs.get("shape") or []
+        rows = op.inputs[0].shape.dims[0].value or 0
+        row = 1
+        for d in sh[1:]:
+            row *= int(d)
+        itemsize = dtypes_mod.as_dtype(op.attrs["dtype"]).size
+        return _op_bytes(op) + 2.0 * int(rows) * row * itemsize
     if op.type == "KVCachePageCopy":
         # CoW: M whole rows read + written in place (same donation
         # argument as the append) — row bytes from the cache attrs,

@@ -50,6 +50,22 @@ _live_page_share = monitoring.Sampler(
     "over the step's rows, over rows x pages_per_seq: the share of the "
     "table paged decode attention reads", "model")
 
+_prefill_pad_tokens = monitoring.Counter(
+    "/stf/serving/prefill_pad_tokens",
+    "Pad tokens the prefill programs carried behind the real tokens of "
+    "their page-chunk rows (a prompt's last chunk is padded to the page): "
+    "what a model with state outside its pages masks by ``lens``", "model")
+_prefill_real_tokens = monitoring.Counter(
+    "/stf/serving/prefill_real_tokens",
+    "Real prompt tokens the prefill programs carried in their page-chunk "
+    "rows (beside /stf/serving/prefill_pad_tokens)", "model")
+_prefill_pad_share = monitoring.Sampler(
+    "/stf/serving/prefill_pad_share",
+    monitoring.ExponentialBuckets(0.001, 2.0, 11),
+    "Per prefill_chunk of a model with state outside its pages: the pad "
+    "tokens over all the tokens of the page-chunk rows it was handed",
+    "model")
+
 _prefill_call_rows = monitoring.Sampler(
     "/stf/serving/prefill_call_rows",
     monitoring.ExponentialBuckets(1.0, 2.0, 8),
@@ -135,14 +151,24 @@ class _PagedCaches:
     or the one pool of latent rows a latent-attention layer keeps
     (``PagedLatentAttention``); no logical view gathered. The RAW between a layer's appends and its
     read is ordered by an explicit control dependency (the appended
-    page is always present in the table)."""
+    page is always present in the table).
 
-    def __init__(self, caches, page_tables, dst_pages, offsets, base):
-        self._caches = caches        # [tuple of KVCache] per layer
+    A layer's pools are of either kind: paged ``KVCache``s, read through
+    the table, or ``StatePool``s addressed by SLOT (``state``): what a
+    recurrent layer carries a sequence. A stack that keeps any gets
+    ``slots (B,)``, and in prefill ``lens (B,)``, the real tokens of each
+    row's chunk; ``fresh`` says which rows start a sequence (position 0):
+    their state is zero whatever the slot last held."""
+
+    def __init__(self, caches, page_tables, dst_pages, offsets, base,
+                 slots=None, lens=None):
+        self._caches = caches        # [tuple of KVCache | StatePool] per layer
         self._tables = page_tables   # (B, n_blocks) int32
         self._dst = dst_pages        # (B,) int32 physical page written
         self._off = offsets          # (B,) int32 in-page start offset
         self._base = base            # (B,) int32 committed length BEFORE
+        self.slots = slots           # (B,) int32 state-pool rows, or None
+        self.lens = lens             # (B,) int32 real tokens a row (prefill)
 
     def _attend(self, layer, q, k_new, v_new, lengths, causal_offset):
         kc, vc = self._caches[layer]
@@ -193,11 +219,22 @@ class _PagedCaches:
         return self._caches[layer][which].gather_rows(self._tables,
                                                       positions)
 
+    def state(self, layer):
+        """The ``StatePool``s of a layer that keeps state by slot."""
+        return self._caches[layer]
+
+    def fresh(self):
+        """(B,) bool: the rows at position 0, whose sequence starts."""
+        return stf.equal(self._base, 0)
+
     def live_rows(self):
         """(B,) bool: the rows that write a real page. A bucket's padding
         rows write the scratch page, the pool's last."""
-        scratch = self._caches[0][0].stored_shape[0] - 1
-        return stf.not_equal(self._dst, scratch)
+        flat = [c for group in self._caches for c in group]
+        paged = next((c for c in flat if c.paged), None)
+        if paged is None:       # a stack of state pools alone: by slot
+            return stf.not_equal(self.slots, flat[0].scratch_slot)
+        return stf.not_equal(self._dst, paged.stored_shape[0] - 1)
 
 
 class _PostLNStack:
@@ -245,13 +282,15 @@ class _PostLNStack:
 
 class PreNormStack:
     """What the pre-norm RMSNorm stacks share (``models/sparse_moe_lm.py``,
-    ``models/latent_moe_lm.py``): variables, the norm, the embedding, the
-    layer loop ``x += attention(norm(x)); x += ffn(x)`` and the untied
-    head. A stack gives ``layer_caches``, ``prefill_block``,
-    ``decode_step`` (the builder's contract) over its own
-    ``_attention(i, a, rows, lead, positions, attend)`` -> ``(rows,
-    d_model)`` and ``_ffn(i, x, row_mask)`` -> ``(y, expert counts or
-    None)``."""
+    ``models/latent_moe_lm.py``, ``models/state_space_moe_lm.py``):
+    variables, the norm, the embedding, a loop over the layers' KINDS and
+    the untied head. A stack gives ``layer_caches``, ``prefill_block``,
+    ``decode_step`` (the builder's contract). ``layer_kinds`` names each
+    layer's kind; the default, ``"block"``, is ``x += attention(norm(x));
+    x += ffn(x)`` over the stack's own ``_attention(i, a, rows, lead,
+    positions, attend)`` -> ``(rows, d_model)`` and ``_ffn(i, x,
+    row_mask)`` -> ``(y, expert counts or None)``; a stack with other
+    kinds (one mixer a layer) gives its own ``_layer``."""
 
     def __init__(self, cfg, compute_dtype, scope):
         self.cfg, self.scope = cfg, scope
@@ -279,21 +318,32 @@ class PreNormStack:
             initializer=stf.random_normal_initializer(stddev=1.0))
         return stf.gather(emb, tok)
 
+    @property
+    def layer_kinds(self):
+        return getattr(self.cfg, "layer_kinds", None) or (
+            ("block",) * self.cfg.num_layers)
+
+    def _layer(self, kind, i, x, rows, lead, positions, attend, row_mask):
+        """Layer ``i`` of ``kind``: ``(x, expert counts or None)``."""
+        if kind != "block":
+            raise ValueError(f"{type(self).__name__} has no layer kind "
+                             f"{kind!r}")
+        a = self._norm(x, "ln1", self.cfg.d_model)
+        x = x + self._attention(i, a, rows, lead, positions, attend)
+        y, c = self._ffn(i, x, row_mask)
+        return x + y, c
+
     def _layers(self, x, rows, lead, positions, attend, row_mask=None):
-        """The layer loop both programs share; ``attend`` is the
-        program's own cache append + attention, handed to the stack's
-        ``_attention``. Returns the hidden state and the routed layers'
-        expert counts."""
-        d = self.cfg.d_model
+        """The layer loop both programs share, over ``layer_kinds``;
+        ``attend`` is the program's own cache append + attention, handed
+        to the stack's ``_attention``. Returns the hidden state and the
+        routed layers' expert counts."""
         counts = []
         with stf.variable_scope("decoder"):
-            for i in range(self.cfg.num_layers):
+            for i, kind in enumerate(self.layer_kinds):
                 with stf.variable_scope(f"layer_{i}"):
-                    a = self._norm(x, "ln1", d)
-                    x = x + self._attention(i, a, rows, lead, positions,
-                                            attend)
-                    y, c = self._ffn(i, x, row_mask)
-                    x = x + y
+                    x, c = self._layer(kind, i, x, rows, lead, positions,
+                                       attend, row_mask)
                     if c is not None:
                         counts.append(c)
         return x, counts
@@ -318,7 +368,7 @@ def build_causal_lm_program(cfg: TransformerConfig, *,
 
 
 def build_paged_lm_program(stack, *, page_len, pages_per_seq, num_pages,
-                           decode_bucket_sizes=None,
+                           max_live=None, decode_bucket_sizes=None,
                            prefill_bucket_sizes=None,
                            compute_dtype=stf.float32, sampling=None,
                            scope="causal_lm", cache_sharding=None,
@@ -347,8 +397,20 @@ def build_paged_lm_program(stack, *, page_len, pages_per_seq, num_pages,
       by name under ``extra``) — greedy, or seeded sampling when
       ``sampling`` is set;
     - ``cow``: the copy-on-write program — ``KVCachePageCopy`` over
-      EVERY cache of every layer (feeds dst (1,), src (1,)): a sequence
-      diverging inside a shared page copies it before private appends.
+      EVERY paged cache of every layer (feeds dst (1,), src (1,)): a
+      sequence diverging inside a shared page copies it before private
+      appends.
+
+    A stack whose ``layer_caches`` names a ``StatePool`` — state addressed
+    by SLOT beside the pages, ``(max_live + 1, *inner)`` with the last row
+    the scratch slot of padding rows (``stack.layer_caches`` is then
+    called with ``state_slots=max_live + 1``) — gets two more feeds, and
+    only such a stack does: ``slots (rows,)`` in both programs, each
+    row's sequence's slot, and ``lens (pb,)`` in prefill, the real tokens
+    of each row's chunk. Rows of one prefill call are walked IN THE ORDER
+    GIVEN by the layers that keep state (``ops/ssm_ops.py``). A state
+    pool is allocated under ``alloc_op`` with the pages and is in no
+    ``cow``. ``state_pools`` in the result says whether there are any.
 
     Page tables are host-side state (the prefix-cache trie owns them);
     the device only ever sees the resolved (page_tables, dst, offset)
@@ -385,8 +447,15 @@ def build_paged_lm_program(stack, *, page_len, pages_per_seq, num_pages,
     prefill_buckets = sorted(set(int(x) for x in (
         prefill_bucket_sizes or (1,))))
 
-    caches = stack.layer_caches(kvc, total_pages, page_len, cache_sharding)
+    if getattr(stack, "keeps_state", False):
+        caches = stack.layer_caches(kvc, total_pages, page_len,
+                                    cache_sharding,
+                                    state_slots=int(max_live) + 1)
+    else:
+        caches = stack.layer_caches(kvc, total_pages, page_len,
+                                    cache_sharding)
     flat_caches = [c for group in caches for c in group]
+    state_pools = any(not c.paged for c in flat_caches)
     alloc_op = stf.group(*[c.alloc() for c in flat_caches],
                          name="pg_alloc")
 
@@ -421,15 +490,22 @@ def build_paged_lm_program(stack, *, page_len, pages_per_seq, num_pages,
                                        f"lm_prefill{pb}_tables"))
         dst = _feed(stf.placeholder(stf.int32, [pb],
                                     f"lm_prefill{pb}_dst"))
+        by_slot = {}
+        if state_pools:
+            by_slot = {
+                "slots": _feed(stf.placeholder(stf.int32, [pb],
+                                               f"lm_prefill{pb}_slots")),
+                "lens": _feed(stf.placeholder(stf.int32, [pb],
+                                              f"lm_prefill{pb}_lens"))}
         cache = _PagedCaches(caches, tables, dst, stf.fill([pb], 0),
-                             base)
+                             base, **by_slot)
         h = stack.prefill_block(tok, base, cache)
         # fetch the hidden state to anchor the whole block (appends are
         # its data deps); pad rows of a partial final chunk write
         # garbage K/V past the real length — dead rows: attention masks
         # by committed length and the next append overwrites in place
         prefill[pb] = {"tok": tok, "base": base, "tables": tables,
-                       "dst": dst,
+                       "dst": dst, **by_slot,
                        "op": stf.group(h, name=f"lm_prefill{pb}")}
 
     # -- decode: one position -----------------------------------------------
@@ -443,11 +519,15 @@ def build_paged_lm_program(stack, *, page_len, pages_per_seq, num_pages,
                                     f"lm_decode{sb}_dst"))
         off = _feed(stf.placeholder(stf.int32, [sb],
                                     f"lm_decode{sb}_off"))
-        cache = _PagedCaches(caches, tables, dst, off, pos)
+        by_slot = {}
+        if state_pools:
+            by_slot = {"slots": _feed(stf.placeholder(
+                stf.int32, [sb], f"lm_decode{sb}_slots"))}
+        cache = _PagedCaches(caches, tables, dst, off, pos, **by_slot)
         logits, extra = stack.decode_step(tok, pos, cache)
         next_tok, logp = _emit(logits)
         decode_progs[sb] = {"tok": tok, "pos": pos, "tables": tables,
-                            "dst": dst, "off": off,
+                            "dst": dst, "off": off, **by_slot,
                             "next_tok": next_tok, "logp": logp,
                             "logits": logits, "extra": extra}
 
@@ -455,7 +535,7 @@ def build_paged_lm_program(stack, *, page_len, pages_per_seq, num_pages,
     cow_dst = _feed(stf.placeholder(stf.int32, [1], "lm_cow_dst"))
     cow_src = _feed(stf.placeholder(stf.int32, [1], "lm_cow_src"))
     cow_op = stf.group(*[c.copy_pages(cow_dst, cow_src)
-                         for c in flat_caches], name="lm_cow")
+                         for c in flat_caches if c.paged], name="lm_cow")
 
     return {
         "alloc_op": alloc_op,
@@ -467,6 +547,7 @@ def build_paged_lm_program(stack, *, page_len, pages_per_seq, num_pages,
         "prefill_buckets": prefill_buckets,
         "scratch_page": scratch_page,
         "caches": caches,
+        "state_pools": state_pools,
         "cache_sharding": cache_sharding,
         "tp_axis": tp_axis,
     }
@@ -547,6 +628,11 @@ class CausalLMGenerativeModel:
                 scope=scope, tp_axis=self.tp_axis)
             self._prog = prog
             self.scratch_page = prog["scratch_page"]
+            # state addressed by SLOT beside the pages (a recurrent
+            # layer's): the engine then hands over each row's slot and
+            # real length, shares no prefix and takes no draft
+            self.state_outside_pages = prog["state_pools"]
+            self.scratch_slot_row = self.num_slots
             if self.tp_axis:
                 # commit the TP weight layout BEFORE restore/init so
                 # the Session places (checkpoint-restored or fresh)
@@ -571,7 +657,7 @@ class CausalLMGenerativeModel:
                     {"next_tok": p["next_tok"], "logp": p["logp"],
                      **p["extra"]},
                     feeds=[p["tok"], p["pos"], p["tables"], p["dst"],
-                           p["off"]])
+                           p["off"]] + [p[k] for k in ("slots",) if k in p])
                 self._decode_plans[sb] = (plan, p)
                 if aot_warmup:
                     plan.compile()
@@ -579,7 +665,8 @@ class CausalLMGenerativeModel:
             for pb, p in prog["prefill"].items():
                 plan = self.session.plan(
                     {"done": p["op"]},
-                    feeds=[p["tok"], p["base"], p["tables"], p["dst"]])
+                    feeds=[p["tok"], p["base"], p["tables"], p["dst"]]
+                    + [p[k] for k in ("slots", "lens") if k in p])
                 self._prefill_plans[pb] = (plan, p)
                 if aot_warmup:
                     plan.compile()
@@ -663,9 +750,11 @@ class CausalLMGenerativeModel:
         return np.full((n, self.pages_per_seq), self.scratch_page,
                        np.int32)
 
-    def _prefill_call(self, pb, tok_chunks, bases, page_tables, dst_pages):
+    def _prefill_call(self, pb, tok_chunks, bases, page_tables, dst_pages,
+                      slots=(), lens=()):
         """One execution of prefill bucket ``pb``: the rows given, then
-        pad rows that read and write the scratch page alone."""
+        pad rows that read and write the scratch page (and the scratch
+        slot) alone."""
         plan, p = self._prefill_plans[pb]
         n = len(dst_pages)
         tok = np.full((pb, self.page_len), self.pad_id, np.int32)
@@ -674,10 +763,17 @@ class CausalLMGenerativeModel:
         dst = np.full((pb,), self.scratch_page, np.int32)
         tok[:n], base[:n], tbl[:n], dst[:n] = \
             tok_chunks, bases, page_tables, dst_pages
-        self._run(plan, {p["tok"]: tok, p["base"]: base,
-                         p["tables"]: tbl, p["dst"]: dst})
+        feed = {p["tok"]: tok, p["base"]: base, p["tables"]: tbl,
+                p["dst"]: dst}
+        if self.state_outside_pages:
+            slot = np.full((pb,), self.scratch_slot_row, np.int32)
+            real = np.zeros((pb,), np.int32)
+            slot[:n], real[:n] = slots, lens
+            feed.update({p["slots"]: slot, p["lens"]: real})
+        self._run(plan, feed)
 
-    def prefill_chunk(self, tok_chunks, bases, page_tables, dst_pages):
+    def prefill_chunk(self, tok_chunks, bases, page_tables, dst_pages,
+                      slots=None, lens=None):
         """Run n page-aligned prompt chunks, one a row: ``tok_chunks
         (n, page_len)`` (pad-padded past the real tail), ``bases (n,)``
         absolute chunk start (multiple of page_len), ``page_tables
@@ -689,7 +785,14 @@ class CausalLMGenerativeModel:
         chunks of one prompt in order of ``base`` are n rows of one
         call. The rows are cut, in that order, into as many calls of the
         largest prefill bucket as they fill and the remainder in the
-        smallest bucket that holds it. Returns the calls made."""
+        smallest bucket that holds it. Returns the calls made.
+
+        A model with state outside its pages (``state_outside_pages``)
+        also takes ``slots (n,)``, each row's sequence's slot, and ``lens
+        (n,)``, the real tokens of each row's chunk: its recurrent layers
+        walk a call's rows in the order given — a slot's rows in order of
+        ``base`` — start a row of ``base`` 0 from zero state, and stop a
+        row's recurrence at its last real token."""
         tok_chunks = np.asarray(tok_chunks, np.int32).reshape(
             -1, self.page_len)
         bases = np.asarray(bases, np.int32)
@@ -697,6 +800,22 @@ class CausalLMGenerativeModel:
             -1, self.pages_per_seq)
         dst_pages = np.asarray(dst_pages, np.int32)
         n = len(dst_pages)
+        by_slot = ()
+        if self.state_outside_pages:
+            if slots is None or lens is None:
+                raise ValueError(
+                    f"{type(self).__name__} keeps state outside its pages: "
+                    "prefill_chunk needs each row's slot and real length")
+            by_slot = (np.asarray(slots, np.int32),
+                       np.asarray(lens, np.int32))
+            real = int(by_slot[1].sum())
+            _prefill_real_tokens.get_cell(self._metrics_label).increase_by(
+                real)
+            _prefill_pad_tokens.get_cell(self._metrics_label).increase_by(
+                n * self.page_len - real)
+            if n:
+                _prefill_pad_share.get_cell(self._metrics_label).add(
+                    1.0 - real / (n * self.page_len))
         rows_a_call = _prefill_call_rows.get_cell(self._metrics_label)
         calls = done = 0
         while done < n:
@@ -704,17 +823,19 @@ class CausalLMGenerativeModel:
             sl = slice(done, done + take)
             self._prefill_call(self._bucket(self._prefill_buckets, take),
                                tok_chunks[sl], bases[sl], page_tables[sl],
-                               dst_pages[sl])
+                               dst_pages[sl], *(a[sl] for a in by_slot))
             rows_a_call.add(take)
             done += take
             calls += 1
         return calls
 
-    def decode(self, tokens, positions, page_tables):
+    def decode(self, tokens, positions, page_tables, slots=None):
         """One decode position for n live sequences; the physical write
         target is resolved host-side from each row's page table:
         ``dst = page_tables[i, pos // page_len]``, ``off = pos %
-        page_len``. Returns (next_tok (n,), logp (n,), bucket)."""
+        page_len``. A model with state outside its pages also takes each
+        sequence's ``slots (n,)``. Returns (next_tok (n,), logp (n,),
+        bucket)."""
         tokens = np.asarray(tokens, np.int32)
         positions = np.asarray(positions, np.int32)
         page_tables = np.asarray(page_tables, np.int32).reshape(
@@ -728,9 +849,17 @@ class CausalLMGenerativeModel:
         tok[:n], pos[:n], tbl[:n] = tokens, positions, page_tables
         dst = tbl[np.arange(sb), pos // self.page_len]
         off = pos % self.page_len
-        out = self._run(plan, {p["tok"]: tok, p["pos"]: pos,
-                               p["tables"]: tbl, p["dst"]: dst,
-                               p["off"]: off.astype(np.int32)})
+        feed = {p["tok"]: tok, p["pos"]: pos, p["tables"]: tbl,
+                p["dst"]: dst, p["off"]: off.astype(np.int32)}
+        if self.state_outside_pages:
+            if slots is None:
+                raise ValueError(
+                    f"{type(self).__name__} keeps state outside its pages: "
+                    "decode needs each sequence's slot")
+            slot = np.full((sb,), self.scratch_slot_row, np.int32)
+            slot[:n] = slots
+            feed[p["slots"]] = slot
+        out = self._run(plan, feed)
         self._after_decode(out, n, positions)
         return (np.asarray(out["next_tok"])[:n],
                 np.asarray(out["logp"])[:n], sb)

@@ -79,6 +79,19 @@ _local_pair_share = monitoring.Sampler(
     "layers: held / num_experts when routing is even", "model")
 
 
+def sample_held_expert_counts(label, counts, rows, experts_per_token):
+    """Once a decode step, from the (routed layers x held experts)
+    histogram of live rows: ``moe_load_imbalance`` over the held experts
+    and ``moe_local_pair_share``."""
+    counts = np.asarray(counts, np.float64)
+    mean = counts.mean(axis=-1)
+    if mean.all():
+        _moe_imbalance.get_cell(label).add(
+            float((counts.max(axis=-1) / mean).mean()))
+    _local_pair_share.get_cell(label).add(
+        float(counts.sum() / (len(counts) * rows * experts_per_token)))
+
+
 def yarn_mscale(factor, mscale):
     return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
@@ -308,12 +321,5 @@ class LatentMoEGenerativeModel(CausalLMGenerativeModel):
 
     def _after_decode(self, out, n, positions):
         super()._after_decode(out, n, positions)       # live page share
-        cfg = self.cfg
-        counts = np.asarray(out["expert_counts"], np.float64)
-        mean = counts.mean(axis=-1)
-        if mean.all():
-            _moe_imbalance.get_cell(self._metrics_label).add(
-                float((counts.max(axis=-1) / mean).mean()))
-        _local_pair_share.get_cell(self._metrics_label).add(
-            float(counts.sum()
-                  / (len(counts) * n * cfg.experts_per_token)))
+        sample_held_expert_counts(self._metrics_label, out["expert_counts"],
+                                  n, self.cfg.experts_per_token)

@@ -41,6 +41,11 @@ from ..ops.fused_ops import (
 from ..ops.kv_cache_ops import (decode_attention, paged_decode_attention,
                                 paged_latent_attention)
 from ..ops.moe_ops import routed_ffn_op as routed_ffn
+from ..ops.ssm_ops import (
+    causal_conv1d_op as causal_conv1d, gated_rms_norm_op as gated_rms_norm,
+    ssm_chunk_scan_op as ssm_chunk_scan,
+    ssm_state_update_op as ssm_state_update,
+)
 from ..ops.sparse_attention_ops import (
     indexer_topk_op as indexer_topk, rms_norm_op as rms_norm,
     rotary_embedding_op as rotary_embedding,

@@ -38,7 +38,7 @@ an append is in place is ASSERTED, not stated:
 programs for a described v5e chip and finds every pool aliased, no
 pool-sized ``copy`` and one pool-shaped fusion (the scatter) per append.
 
-Seven ops, registered with declared Effects so the hazard engine orders
+Seven ops over paged pools, registered with declared Effects so the hazard engine orders
 them like any other variable access (append = read-modify-write on the
 cache resource, gather and paged attention = read):
 
@@ -66,6 +66,13 @@ cache resource, gather and paged attention = read):
                  is the key of every head, its first ``value_dim`` lanes
                  their value) read in place through a page table
                  (:func:`paged_latent_attention`).
+
+A second KIND of pool, :class:`StatePool` ``(num_slots, *inner)``, is
+addressed by SLOT, not by page and position: what a recurrent layer
+carries a sequence (``StatePoolAlloc`` here; the ops that advance a row
+in place are ``ops/ssm_ops.py``'s ``CausalConv1D``, ``SSMChunkScan`` and
+``SSMStateUpdate``). It is allocated, donated and linted with the paged
+pools and is in no copy-on-write: a sequence's state is never shared.
 
 Who reads the pool in place and who still gathers (PR 30). The paged
 programs of the dense causal LM (``models/causal_lm._PagedCaches``:
@@ -127,11 +134,20 @@ PAGED_ATTR = "_kv_paged"
 VERIFY_ATTR = "_verify_plan"
 GUARD_ATTR = "_refcount_guarded"
 
+# a pool ADDRESSED BY SLOT (:class:`StatePool`): state that no position
+# addresses — a recurrence's — beside the paged pools
+STATE_ATTR = "_state_pool"
+
 # attention read IN PLACE: the output is attention, not pages
 PAGED_ATTENTION_OP_TYPES = ("PagedDecodeAttention", "PagedLatentAttention")
+# a state pool's rows read, advanced and written back by ONE op
+# (ops/ssm_ops.py): the output is the layer's, not the pool
+STATE_UPDATE_OP_TYPES = ("CausalConv1D", "SSMChunkScan", "SSMStateUpdate")
+# ops whose output is computed FROM a pool and is not the pool's rows
+IN_PLACE_OP_TYPES = PAGED_ATTENTION_OP_TYPES + STATE_UPDATE_OP_TYPES
 _CACHE_OP_TYPES = ("KVCacheAlloc", "KVCacheAppend", "KVCacheGather",
-                   "KVCacheGatherRows", "KVCachePageCopy"
-                   ) + PAGED_ATTENTION_OP_TYPES
+                   "KVCacheGatherRows", "KVCachePageCopy", "StatePoolAlloc"
+                   ) + IN_PLACE_OP_TYPES
 
 
 # ---------------------------------------------------------------------------
@@ -493,6 +509,82 @@ def kv_cache(name, num_slots, max_len, inner_shape, dtype,
                    sharding=sharding, paged=paged)
 
 
+class StatePool:
+    """Handle to one pool of per-sequence state ADDRESSED BY SLOT:
+    ``(num_slots, *inner)`` in the VariableStore, stored as declared.
+
+    What a paged cache cannot hold: state that is not a row a position —
+    a state-space layer's ``h`` and the last rows its convolution saw. A
+    live sequence's state is the row of its SLOT (the engine's
+    ``CacheSlotPool`` id); the last row is the scratch slot a bucket's
+    padding rows use. Allocated with the paged pools, donated into every
+    step and updated in place like them; never shared between sequences
+    (no ``PAGED_ATTR``) and never copied on write. The ops that advance
+    it (``ops/ssm_ops.py``) read and write a row in ONE op, declared as a
+    read-modify-write of the store entry."""
+
+    def __init__(self, name: str, num_slots: int,
+                 inner_shape: Sequence[int], dtype,
+                 sharding: Optional[str] = None):
+        self.name = name
+        self.num_slots = int(num_slots)
+        self.inner_shape = tuple(int(d) for d in inner_shape)
+        self.dtype = dtypes_mod.as_dtype(dtype)
+        self.sharding = sharding or "replicated"
+        if self.sharding != "replicated":
+            raise ValueError("a state pool is kept whole on every device; "
+                             f"got sharding {sharding!r}")
+        self.paged = False
+
+    @property
+    def shape(self) -> Tuple[int, ...]:
+        return (self.num_slots,) + self.inner_shape
+
+    stored_shape = shape
+
+    @property
+    def scratch_slot(self) -> int:
+        return self.num_slots - 1
+
+    def _attrs(self):
+        return {"var_name": self.name, "shape": list(self.shape),
+                "dtype": self.dtype.name, CACHE_ATTR: True,
+                STATE_ATTR: True, SHARDING_ATTR: self.sharding}
+
+    def alloc(self, name=None):
+        """Zero-fill the pool (fetch the op, not the tensor)."""
+        g = ops_mod.get_default_graph()
+        op = g.create_op(
+            "StatePoolAlloc", [], attrs=self._attrs(),
+            name=name or f"{self.name}_alloc",
+            output_specs=[(shape_mod.TensorShape(list(self.shape)),
+                           self.dtype)])
+        return op.outputs[0]
+
+    def __repr__(self):
+        return (f"StatePool({self.name!r}, slots={self.num_slots}, "
+                f"inner={self.inner_shape}, dtype={self.dtype.name})")
+
+
+def state_pool(name, num_slots, inner_shape, dtype) -> StatePool:
+    """Declare one pool of per-sequence state addressed by slot."""
+    return StatePool(name, num_slots, inner_shape, dtype)
+
+
+def _lower_state_alloc(ctx, op, inputs):
+    import jax.numpy as jnp
+
+    _hint_cache_class(ctx, op)
+    val = jnp.zeros(tuple(op.attrs["shape"]), _np_dtype(op))
+    ctx.write_var(op.attrs["var_name"], val)
+    return [val]
+
+
+op_registry.register(
+    "StatePoolAlloc", lower=_lower_state_alloc,
+    effects=op_registry.Effects(writes=("var_name",)))
+
+
 def is_cache_op(op) -> bool:
     return op.type in _CACHE_OP_TYPES
 
@@ -579,7 +671,9 @@ def paged_decode_attention(q, k_cache: KVCache, v_cache: KVCache,
 
     q, lengths, ``causal_offset``: as :func:`decode_attention` (no key
     bias: a paged self-attention cache has no padding inside its
-    length). The caches are named in the op's attributes and declared
+    length). GROUPED QUERIES: ``q`` may carry more heads than the caches'
+    inner shape ``(H_kv, D)``, ``H % H_kv == 0``: query head ``h`` reads
+    key-value head ``h // (H / H_kv)``, in both lowerings. The caches are named in the op's attributes and declared
     as READS of both store entries, so the hazard engine orders the op
     after the layer's appends exactly as it orders a ``KVCacheGather``:
     build it under their control dependency. Routed through stf.kernels:
@@ -602,6 +696,12 @@ def paged_decode_attention(q, k_cache: KVCache, v_cache: KVCache,
     if causal_offset and q.shape.rank != 4:
         raise ValueError("causal_offset=True requires a query block "
                          f"(B, Kq, H, D); got q rank {q.shape.rank}")
+    h_kv, d = k_cache.inner_shape
+    if q.shape[-1].value != d or (q.shape[-2].value or 0) % h_kv:
+        raise ValueError(
+            f"q {q.shape} does not go with caches of inner shape "
+            f"{k_cache.inner_shape}: head_dim alike and the query heads a "
+            "multiple of the key-value heads")
     attrs = k_cache._attrs()
     attrs.update(var_name=[k_cache.name, v_cache.name], sm_scale=sm_scale,
                  causal_offset=bool(causal_offset))

@@ -36,6 +36,7 @@ from .quant_matmul import (quant_matmul, quant_matmul_reference,
                            quantize_colwise, quantize_rowwise)
 from .softmax_xent import (softmax_cross_entropy,
                            softmax_cross_entropy_reference)
+from .ssm_state_update import ssm_state_update, ssm_state_update_xla
 
 
 def _np_of(dt):
@@ -543,11 +544,16 @@ def _paged_attn_eligible(key):
     (qs, qd), (ps, pd), (ts, _td) = key[:3]
     if not _is_float(qd) or str(pd) != str(qd):
         return "ineligible_dtype"
-    # q (B, H, D) or (B, Kq, H, D); pool (pages, page_len, H*D) as
+    # q (B, H, D) or (B, Kq, H, D); pool (pages, page_len, H_kv*D) as
     # stored; tables (B, n_blocks)
     if len(qs) not in (3, 4) or len(ps) != 3 or len(ts) != 2:
         return "ineligible_shape"
-    if ts[0] != qs[0] or ps[2] != qs[-2] * qs[-1]:
+    if ts[0] != qs[0] or ps[2] % qs[-1] \
+            or (qs[-2] * qs[-1]) % ps[2]:
+        return "ineligible_shape"
+    # grouped queries (H > H_kv): a key-value head's lanes are sliced
+    # out of the page, so they are whole 128-lane tiles
+    if ps[2] != qs[-2] * qs[-1] and qs[-1] % 128:
         return "ineligible_shape"
     return None
 
@@ -559,14 +565,17 @@ def _paged_attn_gate(key, bk):
     page_len, n_blocks = int(ps[1]), int(ts[1])
     itm = _np_of(qd).itemsize
     view_len = n_blocks * page_len
-    group = paged_heads_per_group(kq, h, d)
+    h_kv = int(ps[2]) // d
     # the kernel's MXU work: block-diagonal queries spend ``group``
-    # times the flops; charged to both sides, so the bytes decide
+    # times the flops (grouped queries none: a key-value head's query
+    # heads are rows of its own tile); charged to both sides, so the
+    # bytes decide
+    group = paged_heads_per_group(kq, h, d) if h_kv == h else 1
     flops = 4.0 * group * b * kq * h * view_len * d
     q_out = 2.0 * b * kq * h * d * itm
     # K and V views of every table entry: the most the kernel reads
     # (entries past a row's length are skipped at run time)
-    views = 2.0 * b * view_len * h * d * itm
+    views = 2.0 * b * view_len * h_kv * d * itm
     # the composition: the gather reads and writes both views, the
     # (B, L, H, D) relayout reads them and writes them with head_dim
     # padded to the 128-lane tile, attention reads that, and the
@@ -661,3 +670,53 @@ def _latent_attn_graph_key(op):
     pool = _Aval(_kvc.stored_shape(op.attrs["shape"]), q[1])
     return _kreg.aval_key(_Aval(*q), pool, _Aval(*tables),
                           value_dim=int(op.attrs["value_dim"]))
+
+
+# ---------------------------------------------------------------------------
+# SSMStateUpdate: one decode token of a state-space layer, every row's
+# state read and written in place in the slot pool vs gather, update and
+# scatter. The graph op is registered by ops/ssm_ops.py; this entry owns
+# the routing.
+# ---------------------------------------------------------------------------
+
+def _ssm_update_eligible(key):
+    (xs, xd), (ps, pd), (bs, _bd) = key[:3]
+    if not _is_float(xd) or str(pd) != "float32":
+        return "ineligible_dtype"
+    # x (B, H, P); pool (slots, H / pack, N, pack * P); B (B, G, N)
+    if len(xs) != 3 or len(ps) != 4 or len(bs) != 3:
+        return "ineligible_shape"
+    if ps[3] % xs[2] or ps[1] * (ps[3] // xs[2]) != xs[1] \
+            or ps[2] != bs[2] or xs[1] % bs[1]:
+        return "ineligible_shape"
+    return None
+
+
+def _ssm_update_gate(key, bk):
+    (xs, _xd), (ps, _pd) = key[:2]
+    rows = float(xs[0])
+    state = 4.0 * ps[1] * ps[2] * ps[3]
+    # the kernel reads and writes a row's state once; the composition
+    # gathers it (read + write), updates it (read + write) and scatters
+    # it (read + write)
+    return _kreg.roofline_gate(6.0 * rows * state / 4.0, 2.0 * rows * state,
+                               6.0 * rows * state, bk)
+
+
+_kreg.register_kernel(
+    "SSMStateUpdate",
+    impls={"pallas": ssm_state_update, "xla": ssm_state_update_xla},
+    legacy="xla",
+    eligible=_ssm_update_eligible,
+    cost_gate=_ssm_update_gate,
+    graph_key=lambda op: _ssm_update_graph_key(op),
+    doc="one decode token of a state-space layer, the rows' states "
+        "updated in place in the slot pool vs gather, update and scatter")
+
+
+def _ssm_update_graph_key(op):
+    x, bm = _tensor_aval(op.inputs[0]), _tensor_aval(op.inputs[3])
+    if x is None or bm is None:
+        return None
+    return _kreg.aval_key(_Aval(*x), _Aval(tuple(op.attrs["shape"]),
+                                           "float32"), _Aval(*bm))

@@ -53,6 +53,15 @@ Two kernels, by what they read (PR 30):
   :func:`paged_decode_attention_xla` — the gathered view plus
   :func:`decode_attention_xla` — is its composition for the CPU, a mesh
   and mode ``off``.
+
+GROUPED QUERIES (PR 35). Both paged lowerings take ``q`` with MORE heads
+than the pools hold, ``H % H_kv == 0``: query head ``h`` reads key-value
+head ``h // (H / H_kv)``. The kernel then needs no block-diagonal
+lay-out: a key-value head's ``H / H_kv`` query heads are ROWS of one query
+tile over that head's own lanes (``_paged_grouped_kernel``; head_dim a
+whole number of 128-lane tiles; its call site is named
+``stf_decode_attention_q<Kq>_paged_gqa``). At ``H == H_kv`` the kernel is
+the one above, tile for tile.
 """
 
 from __future__ import annotations
@@ -380,13 +389,149 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
             o_ref[:, lanes] = o.astype(o_ref.dtype)
 
 
+# the most query rows one tile of the grouped kernel holds: a (rows,
+# page_len) float32 score tile and its exponentials are the step's
+# largest temporaries (1 MB each at 1024 x 256)
+_GROUPED_MAX_ROWS = 1024
+
+
+def grouped_heads_per_tile(kq, rep):
+    """Query heads of ONE key-value head whose ``kq`` queries share a
+    tile of the grouped kernel, from the shapes alone: the largest
+    divisor of ``rep = H / H_kv`` that keeps a tile within
+    ``_GROUPED_MAX_ROWS`` rows — all 16 at a decode step (16 rows), 4 at
+    a 256-query prefill block (1024 rows, four tiles a key-value head)."""
+    return max(r for r in range(1, rep + 1)
+               if rep % r == 0 and (r * kq <= _GROUPED_MAX_ROWS or r == 1))
+
+
+def _paged_grouped_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+                          m_scr, l_scr, acc_scr, *, sm_scale, page_len,
+                          n_blocks, kq, causal_offset):
+    """:func:`_paged_kernel` for grouped queries: grid (row, tile of
+    query heads, table entry); ``q_ref (H_kv, rows, D)`` holds, a
+    key-value head, ``rows / Kq`` of its query heads' queries — head-major
+    — which meet that head's own lanes of the page. No zeros are
+    multiplied and the output leaves in the tile's own lay-out."""
+    del tbl_ref
+    b, page = pl.program_id(0), pl.program_id(2)
+    n_kv, rows, d = q_ref.shape
+    hi = _HI if q_ref.dtype == jnp.float32 else None
+
+    @pl.when(page == 0)
+    def _():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    length = len_ref[b]
+
+    @pl.when(page < _live_pages(length, kq, page_len, n_blocks,
+                                causal_offset))
+    def _():
+        span = page * page_len + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, page_len), 1)
+        if causal_offset:
+            jrow = jax.lax.broadcasted_iota(
+                jnp.int32, (rows, page_len), 0) % kq
+            allowed = length + jrow + 1
+        else:
+            allowed = length
+        visible = span < allowed
+        for g in range(n_kv):
+            lanes = slice(g * d, (g + 1) * d)
+            k = k_ref[:, lanes]                        # (page_len, D)
+            v = v_ref[:, lanes]
+            s = jax.lax.dot_general(
+                q_ref[g], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=hi) * sm_scale               # (rows, page_len)
+            s = jnp.where(visible, s, NEG_INF)
+            m_prev = m_scr[g]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            m_scr[g] = m_new
+            l_scr[g] = alpha * l_scr[g] + jnp.sum(p, axis=-1,
+                                                  keepdims=True)
+            acc_scr[g] = acc_scr[g] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=hi)
+
+    @pl.when(page == pl.num_programs(2) - 1)
+    def _():
+        for g in range(n_kv):
+            l = l_scr[g]
+            o_ref[g] = (acc_scr[g] / jnp.where(l == 0.0, 1.0, l)).astype(
+                o_ref.dtype)
+
+
+def _paged_grouped_call(q, k_pool, v_pool, page_tables, lengths, *,
+                        sm_scale, causal_offset, interpret):
+    b, kq, h, d = q.shape
+    _, page_len, hd = k_pool.shape
+    n_blocks = page_tables.shape[1]
+    n_kv = hd // d
+    rep = h // n_kv
+    r_blk = grouped_heads_per_tile(kq, rep)
+    n_tiles = rep // r_blk
+    real = r_blk * kq
+    rows = real if interpret else round_up(real, 8)
+
+    # (B, H_kv, tiles, r_blk * Kq, D): a tile's query heads head-major
+    qt = jnp.transpose(q.reshape(b, kq, n_kv, n_tiles, r_blk, d),
+                       (0, 2, 3, 4, 1, 5)).reshape(b, n_kv, n_tiles, real, d)
+    qt = pad_dim(qt, 3, rows).astype(k_pool.dtype)
+
+    def page_of(bi, ti, page, tbl, lens):
+        last = _live_pages(lens[bi], kq, page_len, n_blocks,
+                           causal_offset) - 1
+        entry = jnp.maximum(jnp.minimum(page, last), 0)
+        return (tbl[bi * n_blocks + entry], 0, 0)
+
+    page_spec = pl.BlockSpec((None, page_len, hd), page_of)
+    tile_spec = pl.BlockSpec((None, n_kv, None, rows, d),
+                             lambda bi, ti, page, tbl, lens: (bi, 0, ti, 0, 0))
+    kernel = functools.partial(
+        _paged_grouped_kernel, sm_scale=float(sm_scale), page_len=page_len,
+        n_blocks=n_blocks, kq=kq, causal_offset=causal_offset)
+    itm = jnp.dtype(k_pool.dtype).itemsize
+    o = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, n_tiles, n_blocks),
+            in_specs=[tile_spec, page_spec, page_spec],
+            out_specs=tile_spec,
+            scratch_shapes=[
+                pltpu.VMEM((n_kv, rows, 1), jnp.float32),
+                pltpu.VMEM((n_kv, rows, 1), jnp.float32),
+                pltpu.VMEM((n_kv, rows, d), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, n_kv, n_tiles, rows, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        cost_estimate=pl.CostEstimate(
+            flops=int(4 * b * kq * h * n_blocks * page_len * d),
+            bytes_accessed=int(2 * b * n_tiles * n_blocks * page_len * hd
+                               * itm),
+            transcendentals=int(b * kq * h * n_blocks * page_len)),
+        interpret=interpret,
+        name=f"stf_decode_attention_q{kq}_paged_gqa",
+    )(jnp.asarray(page_tables, jnp.int32).reshape(-1),
+      jnp.asarray(lengths, jnp.int32), qt, k_pool, v_pool)
+    o = o[:, :, :, :real].reshape(b, n_kv, n_tiles, r_blk, kq, d)
+    return jnp.transpose(o, (0, 4, 1, 2, 3, 5)).reshape(b, kq, h, d)
+
+
 def paged_decode_attention(q, k_pool, v_pool, page_tables, lengths, *,
                            sm_scale=None, causal_offset=False):
     """Decode attention that reads the paged pool IN PLACE.
 
     q: (B, H, D) — one query a sequence — or a (B, Kq, H, D) block;
-    k_pool/v_pool: the caches as STORED, ``(pages, page_len, H*D)``
-    (:func:`..kv_cache_ops.stored_shape`); page_tables: (B, n_blocks)
+    k_pool/v_pool: the caches as STORED, ``(pages, page_len, H_kv*D)``
+    (:func:`..kv_cache_ops.stored_shape`), ``H % H_kv == 0`` (module
+    docstring, "GROUPED QUERIES"); page_tables: (B, n_blocks)
     int32 physical pages in logical order; lengths and ``causal_offset``
     as :func:`decode_attention`. Same float32 online softmax, same
     masks, same result as :func:`decode_attention` over the gathered
@@ -418,10 +563,15 @@ def _paged_call(q, k_pool, v_pool, page_tables, lengths, *, sm_scale,
     b, kq, h, d = q.shape
     _, page_len, hd = k_pool.shape
     n_blocks = page_tables.shape[1]
-    assert hd == h * d and v_pool.shape == k_pool.shape, (
-        q.shape, k_pool.shape, v_pool.shape)
+    assert hd % d == 0 and (h * d) % hd == 0 \
+        and v_pool.shape == k_pool.shape, (q.shape, k_pool.shape,
+                                           v_pool.shape)
     if sm_scale is None:
         sm_scale = 1.0 / (d ** 0.5)
+    if hd != h * d:
+        return _paged_grouped_call(
+            q, k_pool, v_pool, page_tables, lengths, sm_scale=sm_scale,
+            causal_offset=causal_offset, interpret=interpret)
     g = paged_heads_per_group(kq, h, d)
     n_g, w = h // g, g * d
     rows = g * kq if interpret else round_up(g * kq, 8)
@@ -487,7 +637,10 @@ def paged_decode_attention_xla(q, k_pool, v_pool, page_tables, lengths, *,
         rows = pool[tables].reshape((b, nb * pool.shape[1], -1))
         # the leading inner dim is inferred, so a head shard (its own
         # heads/tp whole heads on the minor axis) reshapes the same way
-        return rows.reshape(rows.shape[:-1] + (-1, q.shape[-1]))
+        rows = rows.reshape(rows.shape[:-1] + (-1, q.shape[-1]))
+        # grouped queries: query head h reads key-value head h // rep
+        rep = q.shape[-2] // rows.shape[-2]
+        return rows if rep == 1 else jnp.repeat(rows, rep, axis=2)
 
     return decode_attention_xla(q, view(k_pool), view(v_pool), lengths,
                                 sm_scale=sm_scale,

@@ -54,6 +54,19 @@ Two decode-throughput extensions ride the same scheduler loop:
   at refs 0 until LRU eviction). Admissions that run out of pages
   hold back and retry after the next retirement.
 
+- STATE OUTSIDE THE PAGES (a paged model that declares
+  ``state_outside_pages``, e.g.
+  ``models.state_space_moe_lm.StateSpaceMoEGenerativeModel``): beside
+  its K/V pages a sequence has recurrent state in pools addressed by
+  SLOT. The engine has a slot for every sequence and tells such a model:
+  ``prefill_chunk(..., slots=, lens=)`` — each page-chunk row's slot and
+  REAL token count (pad tokens must not run a recurrence on) — and
+  ``decode(..., slots=)``. Rows go in order of ``(base, slot)`` as for
+  every paged model, so a slot's rows come in order of ``base`` and the
+  model walks them in the order given. Such a model gets no prefix hit
+  (``PrefixCache(share=False)``: fresh pages, freed at retirement) and
+  no ``draft=``. The model's declaration decides: there is no knob.
+
 Metrics: the ``/stf/serving/decode_*`` / ``prefix_cache_*`` /
 ``spec_*`` families (docs/OBSERVABILITY.md).
 """
@@ -289,12 +302,21 @@ class GenerativeEngine:
         # paged models (page_len attr) route through the shared-prefix
         # prompt cache; slot models through per-sequence cache rows
         self._paged = getattr(model, "page_len", None) is not None
+        # recurrent state in pools addressed by slot, beside the pages
+        self._by_slot = bool(getattr(model, "state_outside_pages", False))
+        if draft is not None and self._by_slot:
+            raise ValueError(
+                f"model {name!r} keeps recurrent state outside its cache "
+                "pages: a draft's rejected proposals would have advanced "
+                "that state and it cannot be rolled back, so speculative "
+                "decoding (draft=) is not supported for it")
         self._prefix = None
         self._holdback: List[GenerateRequest] = []
         if self._paged and getattr(policy, "use_prefix_cache", True):
             from .prefix_cache import PrefixCache
 
-            self._prefix = PrefixCache(model.num_pages, model.page_len)
+            self._prefix = PrefixCache(model.num_pages, model.page_len,
+                                       share=not self._by_slot)
         elif self._paged:
             raise ValueError(
                 "paged models require the prefix cache "
@@ -668,9 +690,9 @@ class GenerativeEngine:
         pps = self._model.pages_per_seq
         scratch = self._model.scratch_page
         try:
-            # one row a page chunk: (base, slot, page, tokens); the
-            # prefilled tail is a prompt's last chunk when it wasn't
-            # served by CoW
+            # one row a page chunk: (base, slot, page, tokens, real
+            # tokens); the prefilled tail is a prompt's last chunk when
+            # it wasn't served by CoW
             tables = {}
             rows = []
             for req, slot, plan in admitted:
@@ -678,15 +700,19 @@ class GenerativeEngine:
                 pages = plan.pages
                 table[:len(pages)] = pages
                 tables[slot] = table
-                rows += [(base, slot, page, tok)
+                rows += [(base, slot, page, tok, pl)
                          for page, tok, base in plan.fill]
                 if len(plan.tail) and plan.cow_src is None and \
                         not plan.tail_ready:
                     tok = np.full((pl,), self._model.pad_id, np.int32)
                     tok[:len(plan.tail)] = plan.tail
                     rows.append((plan.cached_len - len(plan.tail), slot,
-                                 plan.tail_page, tok))
+                                 plan.tail_page, tok, len(plan.tail)))
             rows.sort(key=lambda r: r[:2])
+            # a model with state outside its pages is told each row's
+            # slot and how many of its tokens are real
+            by_slot = {"slots": [r[1] for r in rows],
+                       "lens": [r[4] for r in rows]} if self._by_slot else {}
             with self._prefill_span([r for r, _, _ in admitted],
                                     rows=len(rows)) as sp:
                 # copy-on-write first: a CoW'd tail page must be
@@ -696,7 +722,8 @@ class GenerativeEngine:
                         self._model.copy_page(plan.tail_page, plan.cow_src)
                 sp.set_meta(calls=self._model.prefill_chunk(
                     [r[3] for r in rows], [r[0] for r in rows],
-                    [tables[r[1]] for r in rows], [r[2] for r in rows]))
+                    [tables[r[1]] for r in rows], [r[2] for r in rows],
+                    **by_slot))
         except BaseException as e:  # noqa: BLE001
             _flight_mod.get_recorder().on_error(
                 e, where="serving_decode_prefill", model=self.name)
@@ -704,6 +731,9 @@ class GenerativeEngine:
                 # the tail page (when any) is trie-resident: release of
                 # the node chain covers it, nothing to free directly
                 self._prefix.release(plan.node)
+                if plan.node is None:       # unshared: the plan's own pages
+                    for pg in plan.pages:
+                        self._prefix.free_page(pg)
                 self._pool.release(slot)
                 self._reject(req, "error", e)
             self._sync_prefix_metrics()
@@ -717,10 +747,14 @@ class GenerativeEngine:
             s.pos = len(req.src) - 1
             s.pages = tables[slot]
             s.node = plan.node
+            if plan.node is None:
+                # unshared pages (a model with state outside its pages):
+                # the sequence's own from the start, freed when it retires
+                s.private = list(plan.pages)
             # the tail page is trie-owned (shared): the sequence owns
             # no private pages yet — its first decode append into the
             # tail block copies-on-write (see _step_paged)
-            if len(plan.tail):
+            elif len(plan.tail):
                 s.cow_blk = plan.cached_len // pl
             self._active.append(s)
         self._sync_prefix_metrics()
@@ -766,15 +800,17 @@ class GenerativeEngine:
                 slots = slots + [self._scratch_slot] * pad
         self._decode_step(n, tokens, positions, slots)
 
-    def _decode_step(self, n, tokens, positions, where):
+    def _decode_step(self, n, tokens, positions, where, **by_slot):
         """One decode position for the ``n`` live sequences (slot and
         paged steps both land here), then the shared commit: metrics,
         streaming, EOS/budget retirement. ``engine/decode`` spans what
-        ``/stf/serving/decode_step_seconds`` samples."""
+        ``/stf/serving/decode_step_seconds`` samples. ``by_slot``: the
+        sequences' ``slots`` beside their page tables, for a paged model
+        with state outside its pages."""
         t0 = time.perf_counter()
         with monitoring.traceme("engine/decode", live=n) as sp:
             next_tok, logp, bucket = self._model.decode(tokens, positions,
-                                                        where)
+                                                        where, **by_slot)
             sp.set_meta(bucket=bucket)
         dur = time.perf_counter() - t0
         self._step_s.add(dur)
@@ -859,10 +895,13 @@ class GenerativeEngine:
         if not self._active:
             self._slots_gauge.set(0)
             return
+        by_slot = ({"slots": [s.slot for s in self._active]}
+                   if self._by_slot else {})
         self._decode_step(len(self._active),
                           [s.last_tok for s in self._active],
                           [s.pos for s in self._active],
-                          np.stack([s.pages for s in self._active]))
+                          np.stack([s.pages for s in self._active]),
+                          **by_slot)
 
     def _step_speculative(self):
         """One speculative cycle: the draft proposes ``draft_steps``

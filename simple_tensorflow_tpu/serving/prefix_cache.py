@@ -35,6 +35,15 @@ dedups it at PAGE granularity:
   least-recently-touched refs-0 LEAF (leaf-first keeps the trie
   consistent: an inner node's page can't outlive its children's).
 
+A model with STATE OUTSIDE ITS PAGES (a recurrent layer's, addressed by
+slot: ``models/state_space_moe_lm.py``) cannot take a trie hit: the hit
+hands over pages of K/V whose recurrent state nobody kept. For such a
+model the engine builds the cache with ``share=False``: ``acquire``
+matches nothing, inserts nothing and hands out fresh pages that the
+sequence owns and frees at retirement (``AdmitPlan.node`` is None); the
+pool, the free list and ``reconcile`` are the same. Prefix reuse by state
+snapshot is open (ROADMAP X6).
+
 Single-threaded by design: the engine's scheduler thread owns the
 instance (same ownership contract as ``generative.CacheSlotPool``).
 :meth:`PrefixCache.reconcile` cross-checks the three page populations
@@ -105,9 +114,12 @@ class AdmitPlan:
 class PrefixCache:
     """Refcounted page pool + shared-prefix trie (module docstring)."""
 
-    def __init__(self, num_pages: int, page_len: int):
+    def __init__(self, num_pages: int, page_len: int, share: bool = True):
         self.num_pages = int(num_pages)
         self.page_len = int(page_len)
+        # False: every admission gets fresh pages of its own (module
+        # docstring, "state outside its pages")
+        self.share = bool(share)
         self._free: List[int] = list(range(self.num_pages))[::-1]
         self._root = _TrieNode(None, None, None)
         self._tick = 0
@@ -184,6 +196,8 @@ class PrefixCache:
         pl = self.page_len
         n_full = len(toks) // pl
         tail = np.asarray(toks[n_full * pl:], np.int32)
+        if not self.share:
+            return self._acquire_unshared(toks, n_full, tail)
 
         node = self._root
         reused: List[int] = []
@@ -276,7 +290,25 @@ class PrefixCache:
         return AdmitPlan(reused, fill, tail, tail_page, cow_src, node,
                          len(toks), tail_ready=tail_ready)
 
-    def release(self, node: _TrieNode):
+    def _acquire_unshared(self, toks, n_full, tail) -> AdmitPlan:
+        """Fresh pages for every chunk and the tail, in no trie: the
+        sequence owns them (``node`` None) and frees them when it
+        retires."""
+        pl = self.page_len
+        pages: List[int] = []
+        try:
+            for _ in range(n_full + bool(len(tail))):
+                pages.append(self.alloc_page())
+        except PagesExhaustedError:
+            self._free.extend(pages)
+            raise
+        fill = [(pages[i], np.asarray(toks[i * pl:(i + 1) * pl], np.int32),
+                 i * pl) for i in range(n_full)]
+        self.miss_pages += len(pages)
+        return AdmitPlan([], fill, tail, pages[n_full] if len(tail) else None,
+                         None, None, len(toks))
+
+    def release(self, node: Optional[_TrieNode]):
         """Retire one sequence's hold on its trie chain (deepest node
         first; pages stay cached at refs 0 until evicted)."""
         while node is not None and node is not self._root:

@@ -547,6 +547,10 @@ _RULE_KEYS = {
     "PagedLatentAttention": kreg.aval_key(
         _aval((32, 64, 640)), _aval((1201, 512, 640)),
         _aval((32, 37), "int32"), value_dim=512),
+    # 256 rows x 64 heads x 64 over a float32 slot pool of 2.1 MB states
+    "SSMStateUpdate": kreg.aval_key(
+        _aval((256, 64, 64)), _aval((257, 32, 128, 128), "float32"),
+        _aval((256, 8, 128))),
 }
 
 

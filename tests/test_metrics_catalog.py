@@ -29,6 +29,7 @@ def _registered_names():
     # a worker that an earlier test file already made import it
     import simple_tensorflow_tpu.models.sparse_moe_lm  # noqa: F401
     import simple_tensorflow_tpu.models.latent_moe_lm  # noqa: F401
+    import simple_tensorflow_tpu.models.state_space_moe_lm  # noqa: F401
     from simple_tensorflow_tpu.platform import monitoring
 
     return {n for n in monitoring._registry if n.startswith("/stf/")}

@@ -1079,6 +1079,15 @@ COVERED_ELSEWHERE.update({
                              "test_op_equals_gather_then_decode_attention"),
     "PagedLatentAttention": ("test_latent_moe_lm.py",
                              "test_prefill_then_decode_logits"),
+    "StatePoolAlloc": ("test_state_space_moe_lm.py", "test_two_kinds_of_pool"),
+    "CausalConv1D": ("test_state_space_moe_lm.py",
+                     "test_conv_chunk_equals_one_step"),
+    "SSMChunkScan": ("test_state_space_moe_lm.py",
+                     "test_chunk_scan_at_chunk_128"),
+    "SSMStateUpdate": ("test_state_space_moe_lm.py",
+                       "test_state_update_kernel_equals_composition"),
+    "GatedRMSNorm": ("test_state_space_moe_lm.py",
+                     "test_gated_group_norm_gate_first"),
     "BarrierIncompleteSize": ("test_data_flow_structures.py", "Barrier"),
     "BarrierInsertMany": ("test_data_flow_structures.py", "Barrier"),
     "BarrierReadySize": ("test_data_flow_structures.py", "Barrier"),

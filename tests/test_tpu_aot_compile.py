@@ -439,6 +439,31 @@ def test_paged_decode_attention_kernel(tpu_compile, dtype, rows, kq):
         q, pool, pool, ((rows, LM_PAGES_PER_SEQ), jnp.int32),
         ((rows,), jnp.int32))
     assert f"stf_decode_attention_q{kq}_paged" in text
+    # multi-head: the block-diagonal tiles it has had since PR 30
+    g = da.paged_heads_per_group(kq, 16, 64)
+    assert f"bf16[{rows},{16 // g},{max(g * kq, 8)},{g * 64}]" in text \
+        or dtype != BF16
+
+
+@pytest.mark.parametrize("rows,kq", [(256, 1), (1, 1), (8, 256), (1, 256)])
+def test_paged_decode_attention_kernel_grouped(tpu_compile, rows, kq):
+    """Grouped queries, 32 query over 2 key-value heads x 128 at the
+    state-space cell's K/V pool (3,361 pages x 256 x 256 lanes): a
+    key-value head's 16 query heads are rows of its own tile — 16 rows at
+    a decode step, four tiles of 1024 at a 256-query prefill block."""
+    import importlib
+
+    da = importlib.import_module(
+        "simple_tensorflow_tpu.ops.pallas.decode_attention")
+    pool = ((3361, 256, 2 * 128), BF16)
+    q = ((rows, 32, 128) if kq == 1 else (rows, kq, 32, 128), BF16)
+    text = tpu_compile(
+        lambda q, k, v, t, n: da.paged_decode_attention(
+            q, k, v, t, n, causal_offset=kq > 1),
+        q, pool, pool, ((rows, 13), jnp.int32), ((rows,), jnp.int32))
+    assert f"stf_decode_attention_q{kq}_paged" in text
+    r_blk = da.grouped_heads_per_tile(kq, 16)
+    assert f"bf16[{rows},2,{16 // r_blk},{r_blk * kq},128]" in text
 
 
 # ---------------------------------------------------------------------------
@@ -476,12 +501,15 @@ def _paged_serving_programs(one_chip, config_file, stack_of, decode_bucket,
         if kernels_on_tpu:
             mp.setattr(kreg, "backend", lambda: "tpu")
         cfg, stack = stack_of(program["config_kwargs"], kw)
+        # a stack with state by slot sizes its pools from max_live
+        by_slot = ({"max_live": kw["max_live"]}
+                   if getattr(stack, "keeps_state", False) else {})
         prog = causal_lm.build_paged_lm_program(
             stack, page_len=kw["page_len"],
             pages_per_seq=kw["pages_per_seq"], num_pages=kw["num_pages"],
             decode_bucket_sizes=(decode_bucket,),
             prefill_bucket_sizes=(prefill_bucket,),
-            compute_dtype=stf.bfloat16)
+            compute_dtype=stf.bfloat16, **by_slot)
         caches = [c for group in prog["caches"] for c in group]
         state = {v.var_name: aval(v.shape.as_list(),
                                   v.dtype.base_dtype.np_dtype)
@@ -502,14 +530,16 @@ def _paged_serving_programs(one_chip, config_file, stack_of, decode_bucket,
         programs = {
             f"decode{decode_bucket}": compiled(
                 {"next_tok": d["next_tok"], "logp": d["logp"], **d["extra"]},
-                [d["tok"], d["pos"], d["tables"], d["dst"], d["off"]]),
+                [d["tok"], d["pos"], d["tables"], d["dst"], d["off"]]
+                + [d[k] for k in ("slots",) if k in d]),
             f"prefill{prefill_bucket}": compiled(
                 {"done": p["op"]},
-                [p["tok"], p["base"], p["tables"], p["dst"]]),
+                [p["tok"], p["base"], p["tables"], p["dst"]]
+                + [p[k] for k in ("slots", "lens") if k in p]),
         }
     state_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
                       for a in state.values())
-    return {"programs": programs, "n_state": len(state),
+    return {"programs": programs, "n_state": len(state), "caches": caches,
             "state_bytes": state_bytes, "n_pools": len(caches),
             "pool": caches[0].stored_shape, "kw": kw, "cfg": cfg}
 
@@ -665,3 +695,88 @@ def test_latent_moe_programs_compile_fit_and_read_in_place(
     assert calls == [f"stf_latent_attention_q{kq}_paged"] * n_calls, calls
     # the held experts' grouped matmuls, under the compiler's own name
     assert text.count("ragged-dot-metadata") >= 1
+
+
+# ---------------------------------------------------------------------------
+# The hybrid state-space routed-FFN decoder at its benchmark sizes
+# (chipbench/configs/nemotron-3-nano-30b-a3b.json): the decode-256 and the
+# largest prefill program have to compile for the described chip, fit
+# beside 13.1 GB of weights, pages and state, keep every state leaf aliased
+# and UPDATE THE STATE POOLS IN PLACE: 257 slots x 2.1 MB a layer, 539 MB a
+# pool, six of them.
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def state_space_moe_programs(one_chip):
+    import simple_tensorflow_tpu as stf
+    from simple_tensorflow_tpu.models import state_space_moe_lm
+
+    def stack_of(cfg_kwargs, kw):
+        cfg = state_space_moe_lm.StateSpaceMoEConfig(**cfg_kwargs)
+        return cfg, state_space_moe_lm._StateSpaceMoEStack(
+            cfg, stf.bfloat16, "causal_lm")
+
+    return _paged_serving_programs(
+        one_chip, "nemotron-3-nano-30b-a3b.json", stack_of, 256, 8,
+        kernels_on_tpu=True)
+
+
+@pytest.mark.parametrize("program,rows,kq", [("decode256", 256, 1),
+                                             ("prefill8", 8, 256)])
+def test_state_space_moe_programs_compile_fit_and_update_in_place(
+        state_space_moe_programs, program, rows, kq):
+    got = state_space_moe_programs
+    compiled = got["programs"][program]
+    text = compiled.as_text()
+    kw, cfg = got["kw"], got["cfg"]
+    h_pools = [c for c in got["caches"] if not c.paged
+               and c.dtype.name == "float32"]
+    conv_pools = [c for c in got["caches"] if not c.paged
+                  and c.dtype.name != "float32"]
+    kv_pools = [c for c in got["caches"] if c.paged]
+    assert (len(h_pools), len(conv_pools), len(kv_pools)) == (6, 6, 4)
+    assert h_pools[0].stored_shape == (257, 32, 128, 128)
+    assert conv_pools[0].stored_shape == (257, 3 * 6144)
+    assert kv_pools[0].stored_shape == (3361, 256, 256)
+    # weights 8.07 GB as stored (7.85 GB and the experts' zero columns) +
+    # K/V pages 1.76 GB + state 3.29 GB, donated through
+    assert 13.0e9 < got["state_bytes"] < 13.3e9, got["state_bytes"]
+    header = text.split("\n", 1)[0]
+    assert header.count("-alias)") == got["n_state"]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert got["state_bytes"] + temp < 16.0e9, temp
+    # in place: nothing in the entry computation of a pool's size but the
+    # parameters, the update itself and bitcasts — no copy of a
+    # (257, 32, 128, 128) float32 buffer, nor of a K/V or window pool
+    for pool in (h_pools[0], conv_pools[0], kv_pools[0]):
+        elems = int(np.prod(pool.stored_shape))
+        sized = {}
+        for shape, op, ln in _entry_instructions(text):
+            if op != "parameter" and int(np.prod(shape)) == elems:
+                sized.setdefault(op, []).append(ln)
+        copies = sized.get("copy", [])
+        if pool is conv_pools[0]:
+            # the 9.5 MB window pool: the compiler may stage it through
+            # its fast memory (layout ...S(1)) around the gather and the
+            # scatter; it is never copied within HBM
+            copies = [ln for ln in copies if "S(1)}" not in ln]
+        assert not copies, (pool, copies[:1])
+    if program == "decode256":
+        # one state-update kernel a state-space layer, by its own name,
+        # and the grouped attention kernel once an attention layer
+        calls = re.findall(
+            r"%(stf_ssm_state_update_b\d+)[\w.\-]* = [^\n]*custom-call",
+            text)
+        assert calls == ["stf_ssm_state_update_b256"] * 6, calls
+    attn = re.findall(
+        r"%(stf_decode_attention_q\d+_paged)[\w.\-]* = [^\n]*custom-call",
+        text)
+    # (a prefill call keeps only its appends and states: the last layer
+    # of the cut is an attention layer, whose output feeds nothing)
+    assert attn == [f"stf_decode_attention_q{kq}_paged"] * (
+        2 - (program == "prefill8")), attn
+    assert "_paged_gqa" in text
+    # the held experts: the grouped matmul at 2048 rows, every held expert
+    # over every row at 256 (ops/moe_ops.py, "THE DENSE FORM")
+    assert (text.count("ragged-dot-metadata") >= 1) == (
+        program == "prefill8")
