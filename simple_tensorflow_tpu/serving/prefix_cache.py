@@ -34,6 +34,22 @@ dedups it at PAGE granularity:
   list runs dry, then :meth:`PrefixCache._evict_one` reclaims the
   least-recently-touched refs-0 LEAF (leaf-first keeps the trie
   consistent: an inner node's page can't outlive its children's).
+- the next victim is KEPT, not searched: a binary heap of
+  ``(last_use, n, node)`` holds every evictable node (refs 0, no
+  children, resident), pushed at the only places a node becomes
+  evictable — :meth:`PrefixCache.release` (a leaf's count reaching 0),
+  ``_evict_one`` (the victim's parent left a refs-0 leaf) and
+  ``acquire``'s roll-back. Nothing is deleted from the middle: a later
+  hit, child or eviction leaves the entry STALE, and an entry is
+  checked when popped (still resident, refs 0, childless, ``last_use``
+  the entry's). ``last_use`` ticks are unique, so the first valid
+  unpinned entry IS the node a walk of the whole trie would choose. A
+  pinned victim is skipped and put back. Where nothing is evicted (a
+  hot prefix hit and released with free pages left) stale entries are
+  swept once the heap holds more than twice the resident nodes, so it
+  stays bounded; ``shared_pages`` is a count kept at insert and
+  remove. No call of the engine's step path iterates the trie
+  (``_iter_nodes`` is ``reconcile``'s and the tests').
 
 A model with STATE OUTSIDE ITS PAGES (a recurrent layer's, addressed by
 slot: ``models/state_space_moe_lm.py``) cannot take a trie hit: the hit
@@ -54,6 +70,7 @@ asserts drift stays 0.
 
 from __future__ import annotations
 
+import heapq
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -123,6 +140,12 @@ class PrefixCache:
         self._free: List[int] = list(range(self.num_pages))[::-1]
         self._root = _TrieNode(None, None, None)
         self._tick = 0
+        self._resident = 0        # trie nodes (a page each)
+        # evictable nodes by last_use, entries checked when popped
+        # (module docstring); a removed node's parent is None
+        self._victims: List[Tuple[int, int, _TrieNode]] = []
+        self._pushes = 0
+        self.stale_discarded = 0
         # counters the engine maps into /stf/serving/prefix_cache_*
         self.hit_pages = 0        # full chunks served with zero prefill
         self.cow_hits = 0         # tails served by page copy, not prefill
@@ -137,7 +160,7 @@ class PrefixCache:
     @property
     def shared_pages(self) -> int:
         """Trie-resident page count (refs > 0 or cached at refs 0)."""
-        return sum(1 for _ in self._iter_nodes())
+        return self._resident
 
     def _iter_nodes(self):
         stack = list(self._root.children.values())
@@ -162,19 +185,72 @@ class PrefixCache:
     def free_page(self, page: int):
         self._free.append(page)
 
-    def _evict_one(self, pin: set):
+    def _insert(self, chunk, page: int, parent: _TrieNode) -> _TrieNode:
+        node = _TrieNode(chunk, page, parent)
+        node.refs = 1
+        self._touch(node)
+        parent.children[chunk] = node
+        self._resident += 1
+        return node
+
+    def _remove(self, node: _TrieNode):
+        del node.parent.children[node.chunk]
+        node.parent = None
+        self._resident -= 1
+
+    @staticmethod
+    def _evictable(node: _TrieNode) -> bool:
+        return (node.parent is not None and node.refs == 0
+                and not node.children)
+
+    def _stale(self, entry) -> bool:
+        """The entry's node was hit again, got a child or left the trie
+        since it was queued."""
+        return entry[2].last_use != entry[0] or \
+            not self._evictable(entry[2])
+
+    def _offer(self, node: _TrieNode):
+        """``node`` may have become evictable: queue it if so."""
+        if not self._evictable(node):       # the root's parent is None
+            return
+        heap = self._victims
+        if len(heap) > 2 * self._resident + 16:
+            # more stale than live (hits and releases with nothing
+            # evicted): sweep, so the heap is bounded by the trie
+            live = [e for e in heap if not self._stale(e)]
+            self.stale_discarded += len(heap) - len(live)
+            heap[:] = live
+            heapq.heapify(heap)
+        self._pushes += 1
+        heapq.heappush(heap, (node.last_use, self._pushes, node))
+
+    def _evict_one(self, pin: set) -> _TrieNode:
+        """Evict the least-recently-touched refs-0 leaf whose page is
+        not in ``pin``; returns it."""
+        heap = self._victims
+        pinned = []
         victim = None
-        for n in self._iter_nodes():
-            if n.refs == 0 and not n.children and n.page not in pin:
-                if victim is None or n.last_use < victim.last_use:
-                    victim = n
+        while heap:
+            entry = heapq.heappop(heap)
+            if self._stale(entry):
+                self.stale_discarded += 1
+            elif entry[2].page in pin:
+                pinned.append(entry)
+            else:
+                victim = entry[2]
+                break
+        for entry in pinned:
+            heapq.heappush(heap, entry)
         if victim is None:
             raise PagesExhaustedError(
                 f"all {self.num_pages} pages live (no refs-0 leaf to "
                 "evict)")
-        del victim.parent.children[victim.chunk]
+        parent = victim.parent
+        self._remove(victim)
+        self._offer(parent)
         self._free.append(victim.page)
         self.evictions += 1
+        return victim
 
     # -- admission / retirement ----------------------------------------------
     def acquire(self, cached_tokens: Sequence[int]) -> AdmitPlan:
@@ -222,10 +298,11 @@ class PrefixCache:
         pin = set(reused)
 
         def _rollback():
+            for nd in inserted:
+                self._remove(nd)
             for m in matched:
                 m.refs -= 1
-            for nd in inserted:
-                del nd.parent.children[nd.chunk]
+                self._offer(m)
             for pg in allocated:
                 self._free.append(pg)
 
@@ -235,10 +312,7 @@ class PrefixCache:
                 pg = self.alloc_page(pin)
                 allocated.append(pg)
                 pin.add(pg)
-                child = _TrieNode(chunk, pg, node)
-                child.refs = 1
-                self._touch(child)
-                node.children[chunk] = child
+                child = self._insert(chunk, pg, node)
                 inserted.append(child)
                 fill.append((pg, np.asarray(chunk, np.int32), i * pl))
                 self.miss_pages += 1
@@ -274,10 +348,7 @@ class PrefixCache:
                         pin.add(cow_src)
                     tail_page = self.alloc_page(pin)
                     allocated.append(tail_page)
-                    tail_node = _TrieNode(tkey, tail_page, node)
-                    tail_node.refs = 1
-                    self._touch(tail_node)
-                    node.children[tkey] = tail_node
+                    tail_node = self._insert(tkey, tail_page, node)
                     inserted.append(tail_node)
                     if cow_src is not None:
                         self.cow_hits += 1
@@ -314,6 +385,7 @@ class PrefixCache:
         while node is not None and node is not self._root:
             node.refs -= 1
             assert node.refs >= 0, "prefix-cache refcount underflow"
+            self._offer(node)
             node = node.parent
 
     # -- invariant check -----------------------------------------------------
@@ -338,4 +410,6 @@ class PrefixCache:
                 "shared_pages": self.shared_pages,
                 "hit_pages": self.hit_pages, "cow_hits": self.cow_hits,
                 "miss_pages": self.miss_pages,
-                "evictions": self.evictions}
+                "evictions": self.evictions,
+                "victim_entries": len(self._victims),
+                "stale_discarded": self.stale_discarded}

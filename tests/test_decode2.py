@@ -249,6 +249,251 @@ class TestPrefixChurnFuzz:
 
 
 # ---------------------------------------------------------------------------
+# The victim order is KEPT (a heap), the policy is the walk's: an oracle
+# names the victim from the whole trie at every allocation from a dry
+# pool; and the step path never iterates the trie
+# ---------------------------------------------------------------------------
+
+def _walk_victim(pc, pin=()):
+    """The policy as a walk of the whole trie: the least-recently-touched
+    unpinned refs-0 leaf, None when every page is live."""
+    victim = None
+    for n in pc._iter_nodes():
+        if n.refs == 0 and not n.children and n.page not in pin:
+            if victim is None or n.last_use < victim.last_use:
+                victim = n
+    return victim
+
+
+def _refs_by_node(pc):
+    return {n: n.refs for n in pc._iter_nodes()}
+
+
+def _walked_count(pc):
+    return sum(1 for _ in pc._iter_nodes())
+
+
+def _private(live):
+    return [p for _, priv in live for p in priv]
+
+
+def _retire(pc, node, priv):
+    pc.release(node)
+    for pg in priv:
+        pc.free_page(pg)
+
+
+def _no_walk(*_):
+    raise AssertionError("the step path walked the trie")
+
+
+class _OracleCache(PrefixCache):
+    """Holds every eviction to the walk, and the bookkeeping to the trie
+    as walked, at the moment a page is wanted from a dry pool."""
+
+    def __init__(self, *a, **kw):
+        super().__init__(*a, **kw)
+        self.live = []          # (node, private pages), the churn's
+        self.evicted = []
+        self.refused = 0
+        self.pin_decided = 0
+
+    def _evict_one(self, pin):
+        assert self.reconcile(_private(self.live)) == 0
+        assert self.shared_pages == _walked_count(self)
+        want = _walk_victim(self, pin)
+        if want is None:
+            self.refused += 1
+            return super()._evict_one(pin)      # raises, as the walk did
+        if want is not _walk_victim(self):
+            self.pin_decided += 1
+        got = super()._evict_one(pin)
+        assert got is want
+        self.evicted.append(got)
+        return got
+
+
+def _churn(pc, rng, steps, heads, suffix, max_faults):
+    """Shared heads (each cut at one of its four places, inside a page
+    more often than at its end, so that tails find copy-on-write sources
+    and a retired tail is hit again), decode page faults with and
+    without a pinned page, releases, and admissions until the pool
+    refuses: every eviction goes through ``_OracleCache._evict_one``.
+    Admits only until the pool first refuses, then ``steps`` steps in
+    stretches of 20, mostly retirements and mostly admissions in turn:
+    what a stretch retires is resident at refs 0 for the next to hit."""
+    live = pc.live
+    rolled_back = 0
+    while steps:
+        if rolled_back:
+            steps -= 1
+        if live and rolled_back and \
+                rng.rand() < (0.75 if steps // 20 % 2 else 0.3):
+            _retire(pc, *live.pop(rng.randint(len(live))))
+            continue
+        head, cuts = heads[rng.randint(len(heads))]
+        toks = list(head[:cuts[rng.randint(len(cuts))]])
+        if rng.rand() < 0.7:
+            toks += list(rng.randint(2, 1000, rng.randint(*suffix)))
+        before = _refs_by_node(pc)
+        n_evicted = len(pc.evicted)
+        try:
+            plan = pc.acquire(toks)
+        except PagesExhaustedError:
+            # roll-back: the trie is what it was, less what was evicted
+            rolled_back += 1
+            for nd in pc.evicted[n_evicted:]:
+                assert before.pop(nd) == 0
+            assert _refs_by_node(pc) == before
+            continue
+        priv = []
+        live.append((plan.node, priv))
+        try:
+            if len(plan.tail) and rng.rand() < 0.6:
+                # first decode append into the shared tail page
+                priv.append(pc.alloc_page({plan.tail_page}))
+            for _ in range(rng.randint(0, max_faults + 1)):
+                priv.append(pc.alloc_page())
+        except PagesExhaustedError:
+            pass
+    assert pc.reconcile(_private(live)) == 0
+    assert pc.shared_pages == _walked_count(pc)
+    return rolled_back
+
+
+_GEOMETRIES = {
+    # pool, page_len, steps, heads (count, tokens), suffix tokens, faults
+    "small": (24, 4, 400, (5, (2, 13)), (0, 6), 2),
+    "cell": (3072, 64, 320, (40, (200, 700)), (300, 1200), 40),
+}
+
+
+class TestVictimOrderIsTheWalks:
+    @pytest.mark.parametrize("seed", range(8))
+    @pytest.mark.parametrize("geometry", sorted(_GEOMETRIES))
+    def test_every_eviction_is_the_oracles(self, geometry, seed):
+        pool, pl, steps, (n_heads, head_len), suffix, faults = \
+            _GEOMETRIES[geometry]
+        rng = np.random.RandomState(3600 + seed)
+        pc = _OracleCache(num_pages=pool, page_len=pl)
+        heads = [list(rng.randint(2, 1000, rng.randint(*head_len)))
+                 for _ in range(n_heads)]
+        heads = [(h, rng.randint(1, len(h) + 1, 4)) for h in heads]
+        rolled_back = _churn(pc, rng, steps, heads, suffix, faults)
+        assert len(pc.evicted) == pc.evictions > 0
+        assert pc.refused > 0 and rolled_back > 0
+        assert pc.hit_pages > 0 and pc.cow_hits > 0
+        for node, priv in pc.live:
+            _retire(pc, node, priv)
+        assert pc.reconcile([]) == 0
+
+    def test_pinned_victim_is_skipped_and_put_back(self):
+        pc = _OracleCache(num_pages=3, page_len=2)
+        old = pc.acquire([1, 2])
+        mid = pc.acquire([3, 4])
+        new = pc.acquire([5, 6])
+        for plan in (old, mid, new):
+            pc.release(plan.node)
+        # the oldest leaf is pinned: the next oldest goes, and the
+        # pinned one is still the first to go once nothing pins it
+        private = []
+        pc.live.append((None, private))
+        private.append(pc.alloc_page({old.pages[0]}))
+        assert private == mid.pages and pc.pin_decided == 1
+        private.append(pc.alloc_page())
+        assert private == mid.pages + old.pages
+        with pytest.raises(PagesExhaustedError):
+            pc.alloc_page(set(new.pages))
+        private.append(pc.alloc_page())
+        with pytest.raises(PagesExhaustedError):
+            pc.alloc_page()
+        assert pc.reconcile(private) == 0 and pc.shared_pages == 0
+
+    def test_roll_back_keeps_a_matched_leaf_evictable(self):
+        pc = _OracleCache(num_pages=3, page_len=2)
+        cached = pc.acquire([1, 2])
+        pc.release(cached.node)
+        held = pc.acquire([7, 8, 9, 10])
+        # hits the cached leaf (its queued entry goes stale), then finds
+        # no page for the rest: rolled back, the leaf is refs 0 again
+        with pytest.raises(PagesExhaustedError):
+            pc.acquire([1, 2, 3, 4])
+        assert cached.node.refs == 0 and pc.shared_pages == 3
+        assert pc.alloc_page() == cached.pages[0]
+        assert pc.stale_discarded == 1
+        assert pc.reconcile(cached.pages) == 0 and held.node.refs == 1
+
+    def test_only_pinned_leaves_left_raises_and_keeps_them(self):
+        pc = _OracleCache(num_pages=1, page_len=2)
+        only = pc.acquire([1, 2])
+        pc.release(only.node)
+        with pytest.raises(PagesExhaustedError):
+            pc.alloc_page({only.pages[0]})
+        assert pc.statusz_info()["victim_entries"] == 1
+        assert pc.alloc_page() == only.pages[0]
+
+
+def _cell_prompt(rng):
+    """`lm-big.backlog`'s prompts: log-uniform 600-1728, none shared."""
+    n = int(np.exp(rng.uniform(np.log(600), np.log(1728))))
+    return rng.randint(2, 32000, n)
+
+
+class TestStepPathNeverWalksTheTrie:
+    def test_dry_pool_admissions_and_retirements_never_iterate(
+            self, monkeypatch):
+        rng = np.random.RandomState(36)
+        pc = PrefixCache(num_pages=3072, page_len=64)
+        live = []
+
+        def _admit():
+            plan = pc.acquire(_cell_prompt(rng)[:-1])
+            priv = [pc.alloc_page({plan.tail_page})] if len(plan.tail) \
+                else []
+            live.append((plan.node, priv))
+
+        def _retire_one():
+            _retire(pc, *live.pop(rng.randint(len(live))))
+
+        while pc.evictions < 100:            # fill until the pool is dry
+            _admit()
+            if len(live) > 96:
+                _retire_one()
+        monkeypatch.setattr(pc, "_iter_nodes", _no_walk)
+        before = pc.evictions
+        for _ in range(300):
+            _admit()
+            live[-1][1].append(pc.alloc_page())      # a decode page fault
+            _retire_one()
+            info = pc.statusz_info()
+            assert info["shared_pages"] == pc.shared_pages > 2000
+        # every page handed out was evicted from the trie, and the
+        # structure that names the victim holds only what is evictable
+        assert pc.evictions - before > 3000
+        assert info["victim_entries"] <= info["shared_pages"]
+        monkeypatch.undo()
+        assert pc.shared_pages == _walked_count(pc)
+        assert pc.reconcile(_private(live)) == 0
+
+    def test_hits_without_evictions_leave_the_structure_bounded(self):
+        # a hot shared prefix hit and released with free pages left:
+        # nothing is ever evicted, every hit strands an entry
+        pc = PrefixCache(num_pages=64, page_len=4)
+        prompts = [list(range(100 * k, 100 * k + 10)) for k in range(3)]
+        for i in range(5000):
+            pc.release(pc.acquire(prompts[i % 3]).node)
+            info = pc.statusz_info()
+            assert info["victim_entries"] <= 2 * info["shared_pages"] + 17
+        assert pc.evictions == 0 and pc.free_count == 64 - 9
+        assert info["shared_pages"] == 9
+        assert info["stale_discarded"] > 4000
+        # and the three tails are still the evictable ones, oldest first
+        oldest = _walk_victim(pc)
+        assert oldest.chunk == (208, 209)
+        assert pc._evict_one(set()) is oldest
+
+
+# ---------------------------------------------------------------------------
 # Speculative decoding: greedy token-exact through a checkpoint
 # ---------------------------------------------------------------------------
 
@@ -640,6 +885,35 @@ class TestPagedCausalLM:
         assert all(r["outcome"] in ("eos", "length") for r in results)
         assert all(len(r["tokens"]) >= 1 for r in results)
         assert stats["prefix_cache"]["hit_pages"] > 0
+
+    def test_engine_step_path_never_walks_the_trie(self, monkeypatch):
+        # admissions, page faults, retirements and /statusz over a pool
+        # that runs dry, with the trie's iterator taken away
+        cfg = tr.TransformerConfig.tiny()
+        model = _clm_model(cfg)
+        L = model.max_seq_len
+        rng = np.random.RandomState(36)
+        pol = serving.DecodePolicy(num_slots=MAX_LIVE, max_decode_len=L,
+                                   bucket_sizes=[1, MAX_LIVE],
+                                   max_new_tokens=3)
+        with serving.GenerativeEngine("nowalk_eng", model, pol) as eng:
+            monkeypatch.setattr(PrefixCache, "_iter_nodes", _no_walk)
+            prompts = [list(rng.randint(2, cfg.vocab_size, 6 + (i % 4)))
+                       for i in range(12)]
+            futs = [eng.generate(p, max_new_tokens=3) for p in prompts]
+            results = [f.result(timeout=240) for f in futs]
+            stats = eng.statusz_info()["prefix_cache"]
+            monkeypatch.undo()
+            walked = _walked_count(eng._prefix)
+            drift = eng._prefix.reconcile([])
+        model.close()
+        assert all(r["outcome"] in ("eos", "length") for r in results)
+        assert stats["evictions"] > 0 and drift == 0
+        assert stats["shared_pages"] == walked
+        assert stats["victim_entries"] <= 2 * walked + 17
+        gauge = monitoring.export()[
+            "/stf/serving/prefix_cache_shared_pages"]["cells"]
+        assert walked in [v for k, v in gauge.items() if "nowalk_eng" in k]
 
     def test_prefix_and_spec_metrics_exported(self):
         exported = monitoring.export()
