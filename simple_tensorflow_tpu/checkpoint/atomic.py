@@ -22,6 +22,7 @@ from __future__ import annotations
 import hashlib
 import json
 import os
+import threading
 from typing import Callable, Optional
 
 # ordered commit points per file write; fault hooks receive
@@ -72,9 +73,13 @@ def atomic_write_bytes(path: str, data: bytes, fsync: bool = True,
     """
     label = label if label is not None else os.path.basename(path)
     d = os.path.dirname(path) or "."
-    # dotfile temp name: directory listings / GC / ckpt_inspect ignore it
+    # dotfile temp name: directory listings / GC / ckpt_inspect ignore
+    # it; one a thread, or two writers of one path in one process (the
+    # preemption handler's save and the saver hook's) would commit each
+    # other's temp file and one of them find its own gone
     tmp = os.path.join(
-        d, f".{os.path.basename(path)}.tmp.{os.getpid()}")
+        d, f".{os.path.basename(path)}.tmp.{os.getpid()}."
+           f"{threading.get_ident()}")
     fd = os.open(tmp, os.O_WRONLY | os.O_CREAT | os.O_TRUNC, 0o644)
     try:
         _fault(f"{label}:open_tmp")

@@ -73,6 +73,11 @@ _metric_run_seconds = monitoring.Sampler(
     "/stf/session/run_seconds",
     monitoring.ExponentialBuckets(1e-5, 2.0, 30),
     "wall seconds per Session.run")
+_metric_await_device_seconds = monitoring.Sampler(
+    "/stf/session/await_device_seconds",
+    monitoring.ExponentialBuckets(1e-6, 2.0, 30),
+    "seconds a run waited for the device results it fetches to be ready "
+    "(run_seconds minus this is the host's share of a call)")
 _metric_compile_seconds = monitoring.Sampler(
     "/stf/session/jit_compile_seconds",
     monitoring.ExponentialBuckets(1e-3, 2.0, 24),
@@ -102,7 +107,8 @@ _metric_fetch_materialize = monitoring.Counter(
 # metadata for these): 0 = planning, 1 = host stages, 2 = device
 _PHASE_TRACK = {"prune": 0, "optimize": 0, "lower": 0,
                 "host_stage": 1, "post_host_stage": 1,
-                "stage_feeds": 1, "commit": 1, "fetch": 1,
+                "prepare": 1, "stage_feeds": 1, "commit": 1, "assemble": 1,
+                "fetch": 1, "await_device": 1, "copy_to_host": 1,
                 "jit_compile": 2, "cost_analysis": 2, "device_execute": 2}
 _TRACK_NAMES = {0: "planning", 1: "host", 2: "device"}
 # traced run_steps adds a fourth track breaking the fused window down
@@ -1031,8 +1037,10 @@ class ExecutionPlan:
             values = sess._execute_plan(self._step, self._mapper.elements,
                                         feeds, deadline=deadline,
                                         async_fetches=as_futures)
+            with monitoring.traceme("session/assemble"):
+                out = self._mapper.rebuild(values)
         _metric_run_seconds.get_cell().add(time.perf_counter() - t0)
-        return self._mapper.rebuild(values)
+        return out
 
     __call__ = execute
 
@@ -1451,6 +1459,8 @@ class BaseSession:
                 values = self._run_elements(mapper.elements, feeds,
                                             collector=collector,
                                             deadline=deadline)
+                with monitoring.traceme("session/assemble"):
+                    out = mapper.rebuild(values)
         except Exception as e:
             # flight recorder (docs/OBSERVABILITY.md): the event is the
             # forensics breadcrumb; device-stage failures additionally
@@ -1459,7 +1469,6 @@ class BaseSession:
                 "error", where="session_run",
                 error_type=type(e).__name__, message=str(e)[:500])
             raise
-        out = mapper.rebuild(values)
         wall = time.perf_counter() - t0
         _metric_run_seconds.get_cell().add(wall)
         rec = _flight_mod.get_recorder()
@@ -2250,22 +2259,28 @@ class BaseSession:
             # stay concurrent: a blocked queue dequeue must not
             # deadlock the producer thread that would fill it.
             with self._lock:
-                rng_key, rng_ctr = self._rng_args(consume=step.uses_rng)
-                guard_on = (self._config is not None and
-                            getattr(self._config, "transfer_guard", "allow")
-                            != "allow" and step.n_calls >= 2)
-                if guard_on:
-                    # guards run BEFORE execution so a "disallow" raise can
-                    # never land after the variable updates commit. Feeds: a
-                    # big host-numpy feed is an H2D transfer EVERY step.
-                    # Fetches: sizes precomputed from static shapes at plan
-                    # time (dynamic-shaped fetches are unguarded by design).
-                    for t in step.feed_tensors:
-                        val = feeds[t] if t in feeds else host_env[t]
-                        if isinstance(val, np.ndarray):
-                            self._transfer_guard(t.name, val.nbytes, "feed")
-                    for name, nbytes in step.fetch_nbytes:
-                        self._transfer_guard(name, nbytes, "fetch")
+                # the executor's own code ahead of the feeds: rng,
+                # transfer guards (the wait for the lock is session/run's)
+                with monitoring.traceme("session/prepare"):
+                    rng_key, rng_ctr = self._rng_args(consume=step.uses_rng)
+                    guard_on = (self._config is not None and
+                                getattr(self._config, "transfer_guard",
+                                        "allow") != "allow"
+                                and step.n_calls >= 2)
+                    if guard_on:
+                        # guards run BEFORE execution so a "disallow" raise
+                        # can never land after the variable updates commit.
+                        # Feeds: a big host-numpy feed is an H2D transfer
+                        # EVERY step. Fetches: sizes precomputed from static
+                        # shapes at plan time (dynamic-shaped fetches are
+                        # unguarded by design).
+                        for t in step.feed_tensors:
+                            val = feeds[t] if t in feeds else host_env[t]
+                            if isinstance(val, np.ndarray):
+                                self._transfer_guard(t.name, val.nbytes,
+                                                     "feed")
+                        for name, nbytes in step.fetch_nbytes:
+                            self._transfer_guard(name, nbytes, "fetch")
                 feed_args = {}
                 with monitoring.traceme("session/stage_feeds",
                                         n_feeds=len(step.feed_tensors)):
@@ -2340,18 +2355,31 @@ class BaseSession:
                     rep = step.join_sharding()
                     if rep is not None:
                         collector["sharding_report"] = rep
+
+        # the executor's own code between the enqueue and the fetch
+        with monitoring.traceme("session/assemble"):
             # numerics plane: inspect the packed health tensor AFTER
             # the commit (outside the lock — forensics must not block
             # concurrent steps). State through this step is already
             # committed; "raise" tells the user to restore a
             # checkpoint, "dump" re-executes from the retained
             # pre-step state to localize the first bad op.
-            if (step.numerics is not None
+            if (step.has_device_stage and step.numerics is not None
                     and step.numerics["index"] is not None):
                 self._observe_numerics(step, device_results, feed_args,
                                        state, rng_key, rng_ctr)
-
-        dev_map = dict(zip(step.device_fetches, device_results))
+            dev_map = dict(zip(step.device_fetches, device_results))
+            # the donated state and the staged feeds die here, under a
+            # name, and not with the frame (a hundred arrays: ~0.1 ms)
+            state = new_state = fetch_vals = feed_args = None
+            # async_fetches: device-produced fetches leave as lazy
+            # FetchFutures riding jax async dispatch; the host transfer
+            # happens at materialization (docs/PERFORMANCE.md)
+            if async_fetches is None:
+                async_on = (self._config is not None
+                            and getattr(self._config, "async_fetches", False))
+            else:
+                async_on = bool(async_fetches)
 
         # Post-host stage (host sinks: summaries etc.) ----------------------
         if step.post_host_plan:
@@ -2376,18 +2404,10 @@ class BaseSession:
                 host_env = pctx.env
             _check_deadline(deadline, "the post-host stage")
 
-        # Assemble ---------------------------------------------------------
-        # async_fetches: device-produced fetches leave as lazy
-        # FetchFutures riding jax async dispatch; the host transfer
-        # happens at materialization (docs/PERFORMANCE.md)
-        if async_fetches is None:
-            async_on = (self._config is not None
-                        and getattr(self._config, "async_fetches", False))
-        else:
-            async_on = bool(async_fetches)
+        # Fetch ------------------------------------------------------------
         out = []
-        # the host first blocks on the step's results here (np.asarray of
-        # a device value), unless a post-host stage already pulled them
+        # device values to materialize: their places in ``out``
+        pending: List[int] = []
         with monitoring.traceme("session/fetch"):
             for e in elements:
                 if isinstance(e, Operation):
@@ -2403,7 +2423,8 @@ class BaseSession:
                     elif async_on:
                         out.append(FetchFuture(v))
                     else:
-                        out.append(np.asarray(v))
+                        pending.append(len(out))
+                        out.append(v)
                 elif r in host_env:
                     if r.op.type == "GetSessionHandle":
                         from ..ops.session_ops import TensorHandle, _handle_str
@@ -2428,6 +2449,19 @@ class BaseSession:
                     else:
                         raise errors.InternalError(
                             None, e.op, f"Fetch {e.name} produced no value")
+            if pending:
+                # the host first blocks on the step's results HERE (unless
+                # a post-host stage or a deadline already did): one
+                # explicit wait, the one np.asarray would make, so that
+                # the wait and the copy are told apart — traced or not
+                with monitoring.traceme("session/await_device"):
+                    t0 = time.perf_counter()
+                    _block_with_deadline([out[i] for i in pending], None)
+                    waited = time.perf_counter() - t0
+                _metric_await_device_seconds.get_cell().add(waited)
+                with monitoring.traceme("session/copy_to_host"):
+                    for i in pending:
+                        out[i] = np.asarray(out[i])
         return out
 
     def _observe_numerics(self, step, device_results, feed_args, state,

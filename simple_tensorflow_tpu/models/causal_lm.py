@@ -756,20 +756,21 @@ class CausalLMGenerativeModel:
         pad rows that read and write the scratch page (and the scratch
         slot) alone."""
         plan, p = self._prefill_plans[pb]
-        n = len(dst_pages)
-        tok = np.full((pb, self.page_len), self.pad_id, np.int32)
-        base = np.zeros((pb,), np.int32)
-        tbl = self._scratch_tables(pb)
-        dst = np.full((pb,), self.scratch_page, np.int32)
-        tok[:n], base[:n], tbl[:n], dst[:n] = \
-            tok_chunks, bases, page_tables, dst_pages
-        feed = {p["tok"]: tok, p["base"]: base, p["tables"]: tbl,
-                p["dst"]: dst}
-        if self.state_outside_pages:
-            slot = np.full((pb,), self.scratch_slot_row, np.int32)
-            real = np.zeros((pb,), np.int32)
-            slot[:n], real[:n] = slots, lens
-            feed.update({p["slots"]: slot, p["lens"]: real})
+        with monitoring.traceme("model/prefill_feeds"):
+            n = len(dst_pages)
+            tok = np.full((pb, self.page_len), self.pad_id, np.int32)
+            base = np.zeros((pb,), np.int32)
+            tbl = self._scratch_tables(pb)
+            dst = np.full((pb,), self.scratch_page, np.int32)
+            tok[:n], base[:n], tbl[:n], dst[:n] = \
+                tok_chunks, bases, page_tables, dst_pages
+            feed = {p["tok"]: tok, p["base"]: base, p["tables"]: tbl,
+                    p["dst"]: dst}
+            if self.state_outside_pages:
+                slot = np.full((pb,), self.scratch_slot_row, np.int32)
+                real = np.zeros((pb,), np.int32)
+                slot[:n], real[:n] = slots, lens
+                feed.update({p["slots"]: slot, p["lens"]: real})
         self._run(plan, feed)
 
     def prefill_chunk(self, tok_chunks, bases, page_tables, dst_pages,
@@ -793,30 +794,33 @@ class CausalLMGenerativeModel:
         walk a call's rows in the order given — a slot's rows in order of
         ``base`` — start a row of ``base`` 0 from zero state, and stop a
         row's recurrence at its last real token."""
-        tok_chunks = np.asarray(tok_chunks, np.int32).reshape(
-            -1, self.page_len)
-        bases = np.asarray(bases, np.int32)
-        page_tables = np.asarray(page_tables, np.int32).reshape(
-            -1, self.pages_per_seq)
-        dst_pages = np.asarray(dst_pages, np.int32)
-        n = len(dst_pages)
-        by_slot = ()
-        if self.state_outside_pages:
-            if slots is None or lens is None:
-                raise ValueError(
-                    f"{type(self).__name__} keeps state outside its pages: "
-                    "prefill_chunk needs each row's slot and real length")
-            by_slot = (np.asarray(slots, np.int32),
-                       np.asarray(lens, np.int32))
-            real = int(by_slot[1].sum())
-            _prefill_real_tokens.get_cell(self._metrics_label).increase_by(
-                real)
-            _prefill_pad_tokens.get_cell(self._metrics_label).increase_by(
-                n * self.page_len - real)
-            if n:
-                _prefill_pad_share.get_cell(self._metrics_label).add(
-                    1.0 - real / (n * self.page_len))
-        rows_a_call = _prefill_call_rows.get_cell(self._metrics_label)
+        with monitoring.traceme("model/prefill_feeds"):
+            tok_chunks = np.asarray(tok_chunks, np.int32).reshape(
+                -1, self.page_len)
+            bases = np.asarray(bases, np.int32)
+            page_tables = np.asarray(page_tables, np.int32).reshape(
+                -1, self.pages_per_seq)
+            dst_pages = np.asarray(dst_pages, np.int32)
+            n = len(dst_pages)
+            by_slot = ()
+            if self.state_outside_pages:
+                if slots is None or lens is None:
+                    raise ValueError(
+                        f"{type(self).__name__} keeps state outside its "
+                        "pages: prefill_chunk needs each row's slot and "
+                        "real length")
+                by_slot = (np.asarray(slots, np.int32),
+                           np.asarray(lens, np.int32))
+                real = int(by_slot[1].sum())
+                _prefill_real_tokens.get_cell(
+                    self._metrics_label).increase_by(real)
+                _prefill_pad_tokens.get_cell(
+                    self._metrics_label).increase_by(
+                        n * self.page_len - real)
+                if n:
+                    _prefill_pad_share.get_cell(self._metrics_label).add(
+                        1.0 - real / (n * self.page_len))
+            rows_a_call = _prefill_call_rows.get_cell(self._metrics_label)
         calls = done = 0
         while done < n:
             take = min(n - done, self._prefill_buckets[-1])
@@ -836,33 +840,35 @@ class CausalLMGenerativeModel:
         page_len``. A model with state outside its pages also takes each
         sequence's ``slots (n,)``. Returns (next_tok (n,), logp (n,),
         bucket)."""
-        tokens = np.asarray(tokens, np.int32)
-        positions = np.asarray(positions, np.int32)
-        page_tables = np.asarray(page_tables, np.int32).reshape(
-            -1, self.pages_per_seq)
-        n = len(tokens)
-        sb = self._bucket(self._decode_buckets, n)
-        plan, p = self._decode_plans[sb]
-        tok = np.full((sb,), self.pad_id, np.int32)
-        pos = np.zeros((sb,), np.int32)
-        tbl = self._scratch_tables(sb)
-        tok[:n], pos[:n], tbl[:n] = tokens, positions, page_tables
-        dst = tbl[np.arange(sb), pos // self.page_len]
-        off = pos % self.page_len
-        feed = {p["tok"]: tok, p["pos"]: pos, p["tables"]: tbl,
-                p["dst"]: dst, p["off"]: off.astype(np.int32)}
-        if self.state_outside_pages:
-            if slots is None:
-                raise ValueError(
-                    f"{type(self).__name__} keeps state outside its pages: "
-                    "decode needs each sequence's slot")
-            slot = np.full((sb,), self.scratch_slot_row, np.int32)
-            slot[:n] = slots
-            feed[p["slots"]] = slot
+        with monitoring.traceme("model/decode_feeds"):
+            tokens = np.asarray(tokens, np.int32)
+            positions = np.asarray(positions, np.int32)
+            page_tables = np.asarray(page_tables, np.int32).reshape(
+                -1, self.pages_per_seq)
+            n = len(tokens)
+            sb = self._bucket(self._decode_buckets, n)
+            plan, p = self._decode_plans[sb]
+            tok = np.full((sb,), self.pad_id, np.int32)
+            pos = np.zeros((sb,), np.int32)
+            tbl = self._scratch_tables(sb)
+            tok[:n], pos[:n], tbl[:n] = tokens, positions, page_tables
+            dst = tbl[np.arange(sb), pos // self.page_len]
+            off = pos % self.page_len
+            feed = {p["tok"]: tok, p["pos"]: pos, p["tables"]: tbl,
+                    p["dst"]: dst, p["off"]: off.astype(np.int32)}
+            if self.state_outside_pages:
+                if slots is None:
+                    raise ValueError(
+                        f"{type(self).__name__} keeps state outside its "
+                        "pages: decode needs each sequence's slot")
+                slot = np.full((sb,), self.scratch_slot_row, np.int32)
+                slot[:n] = slots
+                feed[p["slots"]] = slot
         out = self._run(plan, feed)
-        self._after_decode(out, n, positions)
-        return (np.asarray(out["next_tok"])[:n],
-                np.asarray(out["logp"])[:n], sb)
+        with monitoring.traceme("model/after_decode"):
+            self._after_decode(out, n, positions)
+            return (np.asarray(out["next_tok"])[:n],
+                    np.asarray(out["logp"])[:n], sb)
 
     def copy_page(self, dst, src):
         """Copy-on-write: duplicate physical page ``src`` into ``dst``

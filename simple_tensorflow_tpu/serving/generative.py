@@ -853,55 +853,56 @@ class GenerativeEngine:
         lazily, page-fault style), then run the page-table decode."""
         from .prefix_cache import PagesExhaustedError
 
-        pl = self._model.page_len
-        scratch = self._model.scratch_page
-        still = []
-        for s in self._active:
-            blk = s.pos // pl
-            if s.pages[blk] == scratch:
-                try:
-                    pg = self._prefix.alloc_page()
-                except PagesExhaustedError as e:
-                    # every page is held by live sequences: this one
-                    # cannot advance — fail it rather than stall all
-                    self._retire(s, "error",
-                                 exc=errors.ResourceExhaustedError(
-                                     None, None,
-                                     f"model {self.name!r}: out of "
-                                     f"cache pages mid-decode ({e})"))
-                    continue
-                s.pages[blk] = pg
-                s.private.append(pg)
-            elif s.cow_blk is not None and blk == s.cow_blk:
-                # first decode append into the trie-resident tail page:
-                # copy-on-write so the shared rows stay pristine for
-                # the next exact-tail hit
-                shared = int(s.pages[blk])
-                try:
-                    pg = self._prefix.alloc_page({shared})
-                except PagesExhaustedError as e:
-                    self._retire(s, "error",
-                                 exc=errors.ResourceExhaustedError(
-                                     None, None,
-                                     f"model {self.name!r}: out of "
-                                     f"cache pages mid-decode ({e})"))
-                    continue
-                self._model.copy_page(pg, shared)
-                s.pages[blk] = pg
-                s.private.append(pg)
-                s.cow_blk = None
-            still.append(s)
-        self._active = still
-        if not self._active:
-            self._slots_gauge.set(0)
-            return
-        by_slot = ({"slots": [s.slot for s in self._active]}
-                   if self._by_slot else {})
-        self._decode_step(len(self._active),
-                          [s.last_tok for s in self._active],
-                          [s.pos for s in self._active],
-                          np.stack([s.pages for s in self._active]),
-                          **by_slot)
+        with monitoring.traceme("engine/page_faults"):
+            pl = self._model.page_len
+            scratch = self._model.scratch_page
+            still = []
+            for s in self._active:
+                blk = s.pos // pl
+                if s.pages[blk] == scratch:
+                    try:
+                        pg = self._prefix.alloc_page()
+                    except PagesExhaustedError as e:
+                        # every page is held by live sequences: this one
+                        # cannot advance — fail it rather than stall all
+                        self._retire(s, "error",
+                                     exc=errors.ResourceExhaustedError(
+                                         None, None,
+                                         f"model {self.name!r}: out of "
+                                         f"cache pages mid-decode ({e})"))
+                        continue
+                    s.pages[blk] = pg
+                    s.private.append(pg)
+                elif s.cow_blk is not None and blk == s.cow_blk:
+                    # first decode append into the trie-resident tail page:
+                    # copy-on-write so the shared rows stay pristine for
+                    # the next exact-tail hit
+                    shared = int(s.pages[blk])
+                    try:
+                        pg = self._prefix.alloc_page({shared})
+                    except PagesExhaustedError as e:
+                        self._retire(s, "error",
+                                     exc=errors.ResourceExhaustedError(
+                                         None, None,
+                                         f"model {self.name!r}: out of "
+                                         f"cache pages mid-decode ({e})"))
+                        continue
+                    self._model.copy_page(pg, shared)
+                    s.pages[blk] = pg
+                    s.private.append(pg)
+                    s.cow_blk = None
+                still.append(s)
+            self._active = still
+            if not self._active:
+                self._slots_gauge.set(0)
+                return
+            by_slot = ({"slots": [s.slot for s in self._active]}
+                       if self._by_slot else {})
+            rows = (len(self._active),
+                    [s.last_tok for s in self._active],
+                    [s.pos for s in self._active],
+                    np.stack([s.pages for s in self._active]))
+        self._decode_step(*rows, **by_slot)
 
     def _step_speculative(self):
         """One speculative cycle: the draft proposes ``draft_steps``

@@ -76,6 +76,36 @@ class TestAtomicCommit:
         atomic.set_fault_hook(None)
         assert os.listdir(tmp_path) == []
 
+    def test_two_threads_commit_one_path(self, tmp_path):
+        """Two writers of one path in one process (the preemption
+        handler's save and the saver hook's, each updating the
+        ``checkpoint`` state file) never commit each other's temp file:
+        every commit lands, the file is always one writer's whole."""
+        import threading
+
+        path = str(tmp_path / "state.json")
+        errors = []
+
+        def writer(tag):
+            try:
+                for i in range(300):
+                    atomic.atomic_write_json(path, {"by": tag, "n": i},
+                                             fsync=False)
+            except BaseException as e:  # noqa: BLE001 — reported below
+                errors.append(e)
+
+        threads = [threading.Thread(target=writer, args=(t,),
+                                    name=f"stf_test_commit_{t}")
+                   for t in ("a", "b", "c")]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+            assert not t.is_alive()
+        assert errors == []
+        assert json.load(open(path))["n"] == 299
+        assert os.listdir(tmp_path) == ["state.json"]
+
     def test_checksum_detects_flip(self, tmp_path):
         data = os.urandom(4096)
         path = str(tmp_path / "c.bin")

@@ -107,18 +107,85 @@ def test_session_run_spans_nest_in_the_profile(tmp_path):
     assert len(runs) == 2
     for run in runs:
         inside = _children(events, run)
-        assert inside == ["stf/session/stage_feeds",
-                          "stf/session/device_execute",
-                          "stf/session/commit", "stf/session/fetch"]
-    # the commit is the device stage's child; meta arrives as stats
+        assert inside == ["stf/session/" + phase for phase in (
+            "prepare", "stage_feeds", "device_execute", "commit",
+            "assemble", "fetch", "await_device", "copy_to_host",
+            "assemble")]
+    # the commit is the device stage's child, the wait and the copy the
+    # fetch's, in that order; meta arrives as stats
     execs = [ev for ev in events if ev[0] == "stf/session/device_execute"]
     assert _children(events, execs[0]) == ["stf/session/commit"]
+    for fetch in (ev for ev in events if ev[0] == "stf/session/fetch"):
+        assert _children(events, fetch) == ["stf/session/await_device",
+                                            "stf/session/copy_to_host"]
+    for name in ("prepare", "assemble", "await_device", "copy_to_host"):
+        for ev in events:
+            if ev[0] == "stf/session/" + name:
+                assert _children(events, ev) == []
     stage = next(ev for ev in events if ev[0] == "stf/session/stage_feeds")
     assert stage[3].get("n_feeds") == 1
     # nothing was planned or compiled inside the trace: a recompile would
     # show as a jit_compile / prune span at its step
     assert not any(ev[0].endswith(("/prune", "/jit_compile"))
                    for ev in events)
+
+
+def _await_device_cell():
+    return monitoring.get_metric(
+        "/stf/session/await_device_seconds").get_cell()
+
+
+def test_the_wait_on_the_device_is_sampled_where_its_span_is(tmp_path):
+    """One sample of ``/stf/session/await_device_seconds`` a run that
+    fetches a device value, from two clock reads inside the span; none
+    for a run that fetches nothing, whose fetch holds neither child."""
+    with stf.Graph().as_default():
+        sess, (y, step), feed = _toy_session()
+        plan = sess.plan({"y": y}, feeds=list(feed))
+        plan.execute(feed)
+        before = _await_device_cell().value()
+        lines = _profile(tmp_path, lambda: (sess.run(y, feed),
+                                            sess.run(step.op, feed),
+                                            plan.execute(feed)))
+        after = _await_device_cell().value()
+        sess.close()
+    (events,) = lines.values()
+    waits = [ev for ev in events if ev[0] == "stf/session/await_device"]
+    fetches = [ev for ev in events if ev[0] == "stf/session/fetch"]
+    assert len(fetches) == 3 and len(waits) == 2
+    assert after["count"] - before["count"] == 2
+    assert [_children(events, f) for f in fetches] == [
+        ["stf/session/await_device", "stf/session/copy_to_host"], [],
+        ["stf/session/await_device", "stf/session/copy_to_host"]]
+    sampled_ns = (after["sum"] - before["sum"]) * 1e9
+    spanned_ns = sum(ev[2] - ev[1] for ev in waits)
+    assert 0 <= sampled_ns <= spanned_ns
+    # an ExecutionPlan's run closes with the rebuild, inside session/run
+    last = [ev for ev in events if ev[0] == "stf/session/run"][-1]
+    assert _children(events, last)[-1] == "stf/session/assemble"
+
+
+def test_a_run_with_async_fetches_waits_for_nothing(tmp_path):
+    """Lazy fetches stay lazy: no wait is added, no ``await_device`` span
+    is opened and nothing is sampled until the future is asked."""
+    with stf.Graph().as_default():
+        sess, (y, _), feed = _toy_session()
+        plan = sess.plan(y, feeds=list(feed))
+        plan.execute(feed)
+        before = _await_device_cell().value()["count"]
+        got = []
+        lines = _profile(tmp_path, lambda: got.append(
+            plan.execute(feed, as_futures=True)))
+        assert _await_device_cell().value()["count"] == before
+        assert isinstance(got[0], stf.FetchFuture)
+        np.testing.assert_array_equal(np.asarray(got[0]),
+                                      plan.execute(feed))
+        sess.close()
+    (events,) = lines.values()
+    names = [ev[0] for ev in events]
+    assert "stf/session/fetch" in names
+    assert "stf/session/await_device" not in names
+    assert "stf/session/copy_to_host" not in names
 
 
 def test_a_new_plan_inside_the_trace_shows_its_planning_spans(tmp_path):
@@ -185,6 +252,56 @@ def test_engine_spans_nest_on_the_engines_thread(tmp_path):
         assert _children(events, wait) == []
 
 
+def test_a_paged_step_nests_the_model_and_the_session(tmp_path):
+    """The engine's thread, a paged causal LM: ``engine/step`` holds the
+    page faults, the decode call and the delivery; ``engine/decode`` the
+    model's feed building, its one ``session/run`` and what it does with
+    the results; ``engine/prefill`` the model's row building and its
+    call."""
+    from simple_tensorflow_tpu.models import causal_lm, transformer
+
+    model = causal_lm.CausalLMGenerativeModel(
+        transformer.TransformerConfig.tiny(), page_len=4, pages_per_seq=4,
+        num_pages=12, max_live=3, prefill_bucket_sizes=(1, 2),
+        aot_warmup=False, init_fresh=True, seed=11,
+        metrics_label="span_plane_paged")
+    pol = serving.DecodePolicy(num_slots=3, max_decode_len=model.max_seq_len,
+                               bucket_sizes=[1, 3], max_new_tokens=4)
+    prompt = np.arange(2, 8, dtype=np.int32)
+    with serving.GenerativeEngine("span_plane_paged", model, pol) as eng:
+        eng.generate(prompt).result(120)    # every program compiled
+        lines = _profile(
+            tmp_path, lambda: eng.generate(prompt + 1).result(120))
+    (events,) = [evs for evs in lines.values()
+                 if any(ev[0] == "stf/engine/step" for ev in evs)]
+    steps = [ev for ev in events if ev[0] == "stf/engine/step"]
+    assert 2 <= len(steps) <= 4     # an end token may cut the answer short
+    for step in steps:
+        direct = [name for name in _children(events, step)
+                  if name.startswith("stf/engine/")]
+        assert direct == ["stf/engine/page_faults", "stf/engine/decode",
+                          "stf/engine/deliver"]
+    run = ["stf/session/" + phase for phase in (
+        "run", "prepare", "stage_feeds", "device_execute", "commit",
+        "assemble", "fetch", "await_device", "copy_to_host", "assemble")]
+    for decode in (ev for ev in events if ev[0] == "stf/engine/decode"):
+        assert _children(events, decode) == [
+            "stf/model/decode_feeds", *run, "stf/model/after_decode"]
+    # a page fault that copies-on-write runs the model's copy_page: the
+    # span is the parent of that session/run, the decode span's is not in it
+    faults = [ev for ev in events if ev[0] == "stf/engine/page_faults"]
+    assert not any("stf/model/decode_feeds" in _children(events, ev)
+                   for ev in faults)
+    # the prompt's page chunks: the row building, then per program call
+    # the padding to its bucket and the call, which fetches nothing
+    (prefill,) = [ev for ev in events if ev[0] == "stf/engine/prefill"]
+    inside = _children(events, prefill)
+    assert inside[:3] == ["stf/model/prefill_feeds",
+                          "stf/model/prefill_feeds", "stf/session/run"]
+    assert "stf/session/await_device" not in inside
+    model.close()
+
+
 def test_without_a_listener_the_primitive_records_nothing():
     assert not monitoring.tracing_active()
     assert not jax.profiler.TraceAnnotation.is_enabled()
@@ -192,6 +309,25 @@ def test_without_a_listener_the_primitive_records_nothing():
         sp.set_meta(j=2)
         assert sp._ann is None and sp._sinks is None
     assert sp._ann is None and sp.meta == {"k": 1, "j": 2}
+    # the same holds of every span a run opens: a collection on ANOTHER
+    # thread hears nothing of this thread's run
+    import threading
+
+    with stf.Graph().as_default():
+        sess, fetches, feed = _toy_session()
+        with monitoring.trace_collection() as elsewhere:
+            worker = threading.Thread(
+                target=lambda: sess.run(fetches, feed), name="stf_test_run")
+            worker.start()
+            worker.join()
+        assert elsewhere.drain() == []
+        # and on this thread it hears the new phases by their names
+        with monitoring.trace_collection() as here:
+            sess.run(fetches, feed)
+        sess.close()
+    assert [span["name"] for span in here.drain()] == [
+        "prepare", "stage_feeds", "commit", "device_execute", "assemble",
+        "await_device", "copy_to_host", "fetch", "assemble", "run"]
     # a collection hears the phase name; the profiler would hear
     # stf/session/heard
     with monitoring.trace_collection() as buf:
