@@ -225,6 +225,17 @@ def _op_flops(op: Operation, grad_depth: int = 0,
             tokens = n / (int(xs.dims[-1].value) * int(xs.dims[-2].value))
             return 6.0 * tokens * int(sh[1]) * int(sh[2]) * int(sh[3])
         return 2.0 * _out_elems(op)
+    if t in ("KDAStateUpdate", "KDAChunkScan"):
+        # the delta rule at 7 flops a state element and token (decay
+        # multiply, S^T k, k u^T and S^T q multiply-adds): tokens = the
+        # leading dims of q (B, H, K) or (R, L, H, K), a row's state
+        # (H, K, V) from the pool's declared shape
+        qs, sh = op.inputs[0].shape, op.attrs.get("shape") or []
+        n = _nelems(qs)
+        if n and len(sh) == 4 and qs.rank >= 3:
+            tokens = n / (int(qs.dims[-1].value) * int(qs.dims[-2].value))
+            return 7.0 * tokens * int(sh[1]) * int(sh[2]) * int(sh[3])
+        return 2.0 * _out_elems(op)
     if t == "CausalConv1D":
         # one multiply-add a tap and output element
         taps = op.inputs[1].shape.dims[0].value or 1
@@ -330,7 +341,8 @@ def _op_bytes_dispatch(op: Operation, fn_depth: int = 0) -> float:
             page *= int(d)
         itemsize = op.outputs[0].dtype.base_dtype.size if op.outputs else 4
         return _op_bytes(op) + pools * entries * page * itemsize
-    if op.type in ("SSMStateUpdate", "SSMChunkScan", "CausalConv1D"):
+    if op.type in ("SSMStateUpdate", "SSMChunkScan", "CausalConv1D",
+                   "KDAStateUpdate", "KDAChunkScan"):
         # a state pool's rows advanced in place: the op's own inputs and
         # its output (the layer's, not the pool), and per ROW of the call
         # one slot's state read and written — never the whole pool

@@ -208,6 +208,23 @@ class _PagedCaches:
         return [c.append(n, self._dst, self._off)
                 for c, n in zip(self._caches[layer], new)]
 
+    def append_one(self, layer, which, new, offsets=None):
+        """One cache of the layer's group, at ``offsets`` into each row's
+        page — a cache whose page holds fewer rows than positions (one a
+        block of them) — or the positions' own."""
+        return self._caches[layer][which].append(
+            new, self._dst, self._off if offsets is None else offsets)
+
+    @property
+    def offsets(self):
+        """(B,) int32: where each row's appends start in its page."""
+        return self._off
+
+    @property
+    def base(self):
+        """(B,) int32: each row's committed length before this call."""
+        return self._base
+
     @staticmethod
     def after_append(appended):
         return stf.control_dependencies([t.op for t in appended])
@@ -347,6 +364,35 @@ class PreNormStack:
                     if c is not None:
                         counts.append(c)
         return x, counts
+
+    # -- a hyper-connected residual (mHC), for a stack whose cfg asks for
+    # it with ``hc_mult`` streams (ops/hyper_connection_ops.py) ----------
+    def _streams(self, x):
+        """``x (rows, d_model)`` -> the residual's ``(rows, n, d_model)``,
+        the embedding in every stream."""
+        return stf.tile(stf.expand_dims(x, 1), [1, self.cfg.hc_mult, 1])
+
+    def _hyper(self, x, name, fn):
+        """``X <- H_res X + H_post^T fn(H_pre X)`` around one sublayer,
+        its coefficients under ``name``."""
+        cfg = self.cfg
+        n, width = cfg.hc_mult, cfg.hc_mult * cfg.d_model
+        coef = 2 * n + n * n
+        h, post, res = stf.nn.hyper_connection_pre(
+            x, self._w(f"{name}/phi", [width, coef], width,
+                       dtype=stf.float32),
+            stf.get_variable(f"{name}/scale", [3], dtype=stf.float32,
+                             initializer=stf.ones_initializer()),
+            stf.get_variable(f"{name}/bias", [coef], dtype=stf.float32,
+                             initializer=stf.zeros_initializer()),
+            iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps,
+            norm_eps=cfg.rms_norm_eps)
+        return stf.nn.hyper_connection_post(x, fn(h), post, res)
+
+    def _merged(self, x):
+        """The streams summed, in float32, for the head."""
+        return stf.cast(stf.reduce_sum(stf.cast(x, stf.float32), axis=1),
+                        self.compute_dtype)
 
     def _logits(self, x):
         cfg = self.cfg

@@ -51,6 +51,16 @@ from ..ops.sparse_attention_ops import (
     rotary_embedding_op as rotary_embedding,
     selected_attention_op as selected_attention,
     sparse_block_attention_op as sparse_block_attention,
+    pooled_block_choice_op as pooled_block_choice,
+    sparse_latent_block_attention_op as sparse_latent_block_attention,
+    selected_latent_attention_op as selected_latent_attention,
+)
+from ..ops.linear_attention_ops import (
+    kda_chunk_scan_op as kda_chunk_scan,
+    kda_state_update_op as kda_state_update,
+)
+from ..ops.hyper_connection_ops import (
+    hc_pre_op as hyper_connection_pre, hc_post_op as hyper_connection_post,
 )
 from ..ops.candidate_sampling_ops import (
     uniform_candidate_sampler, log_uniform_candidate_sampler,

@@ -71,7 +71,8 @@ A second KIND of pool, :class:`StatePool` ``(num_slots, *inner)``, is
 addressed by SLOT, not by page and position: what a recurrent layer
 carries a sequence (``StatePoolAlloc`` here; the ops that advance a row
 in place are ``ops/ssm_ops.py``'s ``CausalConv1D``, ``SSMChunkScan`` and
-``SSMStateUpdate``). It is allocated, donated and linted with the paged
+``SSMStateUpdate``, and ``ops/linear_attention_ops.py``'s ``KDAChunkScan``
+and ``KDAStateUpdate``). It is allocated, donated and linted with the paged
 pools and is in no copy-on-write: a sequence's state is never shared.
 
 Who reads the pool in place and who still gathers (PR 30). The paged
@@ -141,8 +142,10 @@ STATE_ATTR = "_state_pool"
 # attention read IN PLACE: the output is attention, not pages
 PAGED_ATTENTION_OP_TYPES = ("PagedDecodeAttention", "PagedLatentAttention")
 # a state pool's rows read, advanced and written back by ONE op
-# (ops/ssm_ops.py): the output is the layer's, not the pool
-STATE_UPDATE_OP_TYPES = ("CausalConv1D", "SSMChunkScan", "SSMStateUpdate")
+# (ops/ssm_ops.py, ops/linear_attention_ops.py): the output is the
+# layer's, not the pool
+STATE_UPDATE_OP_TYPES = ("CausalConv1D", "SSMChunkScan", "SSMStateUpdate",
+                         "KDAChunkScan", "KDAStateUpdate")
 # ops whose output is computed FROM a pool and is not the pool's rows
 IN_PLACE_OP_TYPES = PAGED_ATTENTION_OP_TYPES + STATE_UPDATE_OP_TYPES
 _CACHE_OP_TYPES = ("KVCacheAlloc", "KVCacheAppend", "KVCacheGather",

@@ -40,6 +40,8 @@ it was when it is not given):
   activation ``"swiglu"`` (default) or ``"relu2"``: an expert is
              ``relu(x . w_up)^2 . w_down`` — NO gate matrix: the third
              input is ``w_up (E, H, I)``, not ``(E, H, 2*I)``.
+  swiglu_limit  a SwiGLU expert's clamp: ``gate <- min(gate, limit)``,
+             ``up <- clip(up, -limit, limit)`` before ``silu(gate) * up``.
 
 The held dispatch follows the pairs that land here, not ``T * top_k``:
 the pairs are sorted held-experts-first and taken in windows of
@@ -111,7 +113,17 @@ def held_window(pairs, count, num_experts):
     return min(-(-pairs // 8) * 8, max(8, -(-2 * even // 8) * 8))
 
 
-def _experts(rows, w_gate_up, w_down, group_sizes, activation="swiglu"):
+def swiglu(gate, up, limit=None):
+    """``silu(gate) * up``; with ``limit`` the gate clamped from above and
+    the up projection to ``[-limit, limit]`` first."""
+    if limit is not None:
+        gate = jnp.minimum(gate, limit)
+        up = jnp.clip(up, -limit, limit)
+    return jax.nn.silu(gate) * up
+
+
+def _experts(rows, w_gate_up, w_down, group_sizes, activation="swiglu",
+             limit=None):
     """The two grouped matmuls around ``silu(gate) * up`` — or, with
     ``relu2``, around ``relu(up)^2`` — float32 out."""
     width = w_down.shape[1]
@@ -120,7 +132,7 @@ def _experts(rows, w_gate_up, w_down, group_sizes, activation="swiglu"):
     if activation == "relu2":
         h = jnp.square(jax.nn.relu(h)).astype(w_down.dtype)
     else:
-        h = (jax.nn.silu(h[:, :width]) * h[:, width:]).astype(w_down.dtype)
+        h = swiglu(h[:, :width], h[:, width:], limit).astype(w_down.dtype)
     return jax.lax.ragged_dot(h, w_down, group_sizes,
                               preferred_element_type=jnp.float32)
 
@@ -160,7 +172,7 @@ def _held_dense(x, experts, gates, w_up, w_down, row_mask, held):
 
 
 def _held_ffn(x, experts, gates, w_gate_up, w_down, row_mask, held,
-              num_experts, activation="swiglu"):
+              num_experts, activation="swiglu", limit=None):
     """This chip's part of the routed sum (module docstring, ``held``)."""
     t, top_k = experts.shape
     first, count = held
@@ -188,7 +200,7 @@ def _held_ffn(x, experts, gates, w_gate_up, w_down, row_mask, held,
                          - jnp.maximum(starts, lo), 0, window)
         token = pair // top_k
         out = _experts(x[token].astype(w_gate_up.dtype), w_gate_up,
-                       w_down, sizes, activation)
+                       w_down, sizes, activation, limit)
         # rows past the landed pairs belong to no group: their product
         # is not defined, so they are selected away, not multiplied
         out = jnp.where((lo + lane < landed)[:, None],
@@ -203,7 +215,7 @@ def _held_ffn(x, experts, gates, w_gate_up, w_down, row_mask, held,
 
 def routed_ffn(x, w_router, w_gate_up, w_down, row_mask=None, *, top_k,
                norm_topk=True, score="softmax", bias=None, gate_scale=1.0,
-               held=None, activation="swiglu"):
+               held=None, activation="swiglu", swiglu_limit=None):
     """``x (T, H)``; ``w_router (H, E)``; ``w_gate_up (E, H, 2*I)`` (gate
     columns first; ``(E, H, I)``, the up projection alone, with
     ``activation="relu2"``); ``w_down (E, I, H)`` — with ``held = (first,
@@ -215,14 +227,15 @@ def routed_ffn(x, w_router, w_gate_up, w_down, row_mask=None, *, top_k,
     if held is not None:
         return _held_ffn(x, experts, gates, w_gate_up, w_down, row_mask,
                          tuple(int(v) for v in held), w_router.shape[1],
-                         activation)
+                         activation, swiglu_limit)
     num_experts = w_down.shape[0]
     flat = experts.reshape(-1)                               # (T*k,)
     order = jnp.argsort(flat, stable=True)
     one_hot = flat[:, None] == jnp.arange(num_experts, dtype=jnp.int32)
     group_sizes = jnp.sum(one_hot, axis=0, dtype=jnp.int32)
     rows = x[order // top_k].astype(w_gate_up.dtype)
-    y = _experts(rows, w_gate_up, w_down, group_sizes, activation)
+    y = _experts(rows, w_gate_up, w_down, group_sizes, activation,
+                 swiglu_limit)
     y = y[jnp.argsort(order)].reshape(t, top_k, -1)          # pair order
     y = jnp.sum(y * gates[:, :, None], axis=1).astype(x.dtype)
     return y, _live_counts(one_hot, group_sizes, row_mask, top_k)
@@ -230,14 +243,15 @@ def routed_ffn(x, w_router, w_gate_up, w_down, row_mask=None, *, top_k,
 
 def _lower(x, w_router, w_gate_up, w_down, *rest, top_k=1, norm_topk=True,
            score="softmax", gate_scale=1.0, held=None, has_bias=False,
-           activation="swiglu"):
+           activation="swiglu", swiglu_limit=None):
     # optional inputs, in the order ``routed_ffn_op`` appends them
     rest = list(rest)
     bias = rest.pop() if has_bias else None
     row_mask = rest.pop() if rest else None
     return routed_ffn(x, w_router, w_gate_up, w_down, row_mask, top_k=top_k,
                       norm_topk=norm_topk, score=score, bias=bias,
-                      gate_scale=gate_scale, held=held, activation=activation)
+                      gate_scale=gate_scale, held=held, activation=activation,
+                      swiglu_limit=swiglu_limit)
 
 
 op_registry.register_pure("RoutedFFN", _lower, n_outputs=2)
@@ -245,7 +259,8 @@ op_registry.register_pure("RoutedFFN", _lower, n_outputs=2)
 
 def routed_ffn_op(x, w_router, w_gate_up, w_down, row_mask=None, *, top_k,
                   norm_topk=True, score="softmax", bias=None,
-                  gate_scale=1.0, held=None, activation="swiglu", name=None):
+                  gate_scale=1.0, held=None, activation="swiglu",
+                  swiglu_limit=None, name=None):
     """Graph op over ``x (T, H)``; see :func:`routed_ffn`. Returns
     ``(y, counts)``."""
     if score not in ("softmax", "sigmoid"):
@@ -269,6 +284,8 @@ def routed_ffn_op(x, w_router, w_gate_up, w_down, row_mask=None, *, top_k,
         attrs["held"] = (int(held[0]), int(held[1]))
     if activation != "swiglu":
         attrs["activation"] = activation
+    if swiglu_limit is not None:
+        attrs["swiglu_limit"] = float(swiglu_limit)
     inputs = [ops_mod.convert_to_tensor(t) for t in inputs]
     return op_util.make_op("RoutedFFN", inputs, attrs=attrs,
                            name=name or "routed_ffn", n_out=2)

@@ -26,6 +26,7 @@ from .decode_attention import (decode_attention, decode_attention_xla,
 from .dropout_residual import (dropout_bias_residual,
                                dropout_bias_residual_reference)
 from .flash_attention import attention_xla, flash_attention, mha_reference
+from .kda_state_update import kda_state_update, kda_state_update_xla
 from .fused_update import (adam_update, adam_update_reference,
                            momentum_update, momentum_update_reference)
 from .latent_attention import (paged_latent_attention,
@@ -721,3 +722,53 @@ def _ssm_update_graph_key(op):
         return None
     return _kreg.aval_key(_Aval(*x), _Aval(tuple(op.attrs["shape"]),
                                            "float32"), _Aval(*bm))
+
+
+# ---------------------------------------------------------------------------
+# KDAStateUpdate: one decode token of a gated delta-rule linear-attention
+# layer, every row's matrix state read and written in place in the slot
+# pool vs gather, update and scatter. The graph op is registered by
+# ops/linear_attention_ops.py; this entry owns the routing.
+# ---------------------------------------------------------------------------
+
+def _kda_update_eligible(key):
+    (qs, qd), (ps, pd), (vs, _vd) = key[:3]
+    if not _is_float(qd) or str(pd) != "float32":
+        return "ineligible_dtype"
+    # q (B, H, K); pool (slots, H, K, V); v (B, H, V)
+    if len(qs) != 3 or len(ps) != 4 or len(vs) != 3:
+        return "ineligible_shape"
+    if tuple(ps[1:]) != (qs[1], qs[2], vs[2]) or vs[:2] != qs[:2]:
+        return "ineligible_shape"
+    return None
+
+
+def _kda_update_gate(key, bk):
+    (qs, _qd), (ps, _pd) = key[:2]
+    rows = float(qs[0])
+    state = 4.0 * ps[1] * ps[2] * ps[3]
+    # the kernel reads and writes a row's state once; the composition
+    # gathers it (read + write), updates it (read + write) and scatters
+    # it (read + write)
+    return _kreg.roofline_gate(7.0 * rows * state / 4.0, 2.0 * rows * state,
+                               6.0 * rows * state, bk)
+
+
+_kreg.register_kernel(
+    "KDAStateUpdate",
+    impls={"pallas": kda_state_update, "xla": kda_state_update_xla},
+    legacy="xla",
+    eligible=_kda_update_eligible,
+    cost_gate=_kda_update_gate,
+    graph_key=lambda op: _kda_update_graph_key(op),
+    doc="one decode token of a gated delta-rule linear-attention layer, "
+        "the rows' matrix states updated in place in the slot pool vs "
+        "gather, update and scatter")
+
+
+def _kda_update_graph_key(op):
+    q, v = _tensor_aval(op.inputs[0]), _tensor_aval(op.inputs[2])
+    if q is None or v is None:
+        return None
+    return _kreg.aval_key(_Aval(*q), _Aval(tuple(op.attrs["shape"]),
+                                           "float32"), _Aval(*v))

@@ -79,12 +79,14 @@ def yarn_inv_freq(dim, theta, *, factor, original_len, beta_fast,
     return freq * (1.0 - ramp) + freq / factor * ramp
 
 
-def rotary_embedding(x, positions, *, theta, yarn=None, amplitude=1.0):
+def rotary_embedding(x, positions, *, theta, yarn=None, amplitude=1.0,
+                     interleave=False):
     """``x (..., H, D)`` rotated by ``positions`` (shape ``x.shape[:-2]``):
     the rotate-half convention, angles ``pos * theta^(-2i/D)`` in float32.
     ``yarn = (factor, original_len, beta_fast, beta_slow)`` takes
     :func:`yarn_inv_freq`'s frequencies instead; ``amplitude`` scales cos
-    and sin (YaRN's ``mscale / mscale_all_dim``)."""
+    and sin (YaRN's ``mscale / mscale_all_dim``); ``interleave`` rotates
+    the pairs ``(x[2i], x[2i + 1])`` instead of ``(x[i], x[i + D/2])``."""
     d = x.shape[-1]
     if yarn is None:
         inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
@@ -98,6 +100,11 @@ def rotary_embedding(x, positions, *, theta, yarn=None, amplitude=1.0):
     if amplitude != 1.0:
         cos, sin = cos * amplitude, sin * amplitude
     xf = x.astype(jnp.float32)
+    if interleave:
+        pairs = xf.reshape(xf.shape[:-1] + (d // 2, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+        return out.reshape(x.shape).astype(x.dtype)
     x1, x2 = xf[..., :d // 2], xf[..., d // 2:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
     return out.astype(x.dtype)
@@ -270,6 +277,175 @@ def sparse_block_attention(q, q_idx, weights, k_view, v_view, k_idx_view,
 
 
 # ---------------------------------------------------------------------------
+# POOLED selection over a latent cache (DeepSeek sparse attention with a
+# pooled indexer): one indexer key a block of ``pool`` positions, the mean
+# of the block's keys; query t attends its own block's positions <= t (the
+# TAIL, always) and every position of the ``topk / pool - 1`` complete
+# blocks before the tail of largest score (a tie to the lower block) — all
+# of them while there are no more. Scores are float32 at the highest
+# matmul precision: they choose, and a rounding must not.
+# ---------------------------------------------------------------------------
+
+def _pooled_scores(q_idx, weights, k_pool):
+    """``I[..., b] = sum_j w_j relu(q_j . k_b)``: ``q_idx (B, ..., Hi,
+    Di)``, ``weights (B, ..., Hi)``, ``k_pool (B, T, Di)`` -> ``(B, ...,
+    T)`` float32."""
+    f32 = jnp.float32
+    s = jnp.einsum("b...hd,btd->b...ht", q_idx.astype(f32),
+                   k_pool.astype(f32), precision=jax.lax.Precision.HIGHEST)
+    return jnp.einsum("b...ht,b...h->b...t", jax.nn.relu(s),
+                      weights.astype(f32),
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def indexer_topk_pooled(q_idx, weights, k_pool, lengths, *, topk, pool):
+    """DECODE selection over POOLED keys. ``q_idx (B, Hi, Di)``, ``weights
+    (B, Hi)``, ``k_pool (B, Lp, Di)`` the gathered pooled keys (a block's
+    key is complete once its ``pool`` positions are), ``lengths (B,)`` the
+    live context, this position ``t = lengths - 1`` included. Returns
+    ``positions (B, K)`` int32 — the tail's positions ``<= t`` and the
+    chosen blocks' positions, the real ones first (dead entries 0) — and
+    ``n_valid (B,)``; ``K = pool * (1 + min(topk / pool - 1, Lp))``."""
+    b, lp, _ = k_pool.shape
+    t = jnp.asarray(lengths, jnp.int32) - 1
+    tail = t // pool                          # also the candidates' count
+    kb = min(int(topk) // pool - 1, lp)
+    scores = _pooled_scores(q_idx, weights, k_pool)          # (B, Lp)
+    cand = jnp.arange(lp, dtype=jnp.int32)[None, :] < tail[:, None]
+    _, blocks = jax.lax.top_k(jnp.where(cand, scores, _NEG_INF), kb)
+    chosen = jnp.arange(kb, dtype=jnp.int32)[None, :] < jnp.minimum(
+        tail, kb)[:, None]
+    lane = jnp.arange(pool, dtype=jnp.int32)
+    tail_pos = tail[:, None] * pool + lane[None, :]
+    pos = jnp.concatenate(
+        [tail_pos, (blocks[..., None] * pool + lane).reshape(b, -1)], 1)
+    valid = jnp.concatenate([tail_pos <= t[:, None],
+                             jnp.repeat(chosen, pool, axis=1)], 1)
+    order = jnp.argsort(~valid, axis=1, stable=True)
+    valid = jnp.take_along_axis(valid, order, axis=1)
+    pos = jnp.where(valid, jnp.take_along_axis(pos, order, axis=1), 0)
+    return (pos.astype(jnp.int32),
+            jnp.sum(valid, axis=1, dtype=jnp.int32))
+
+
+def largest_divisor(n, most=512):
+    """The largest divisor of ``n`` up to ``most``: pooled keys a step of
+    prefill scoring takes, rows a page of the selected rows holds."""
+    return max(d for d in range(1, min(n, most) + 1) if n % d == 0)
+
+
+def pooled_block_choice(q_idx, weights, k_pool_view, base, *, topk, pool):
+    """PREFILL selection. ``q_idx (B, S, Hi, Di)`` and ``weights (B, S,
+    Hi)`` of a page-aligned block at positions ``base[b] + [0, S)``,
+    ``k_pool_view (B, Lp, Di)`` the gathered pooled keys (the block's own
+    appended). Returns ``(B, S, Lp)`` bool: the complete blocks before
+    each query's tail that it reads (:func:`indexer_topk_pooled`'s choice,
+    as a mask)."""
+    b, s_len = q_idx.shape[:2]
+    lp = k_pool_view.shape[1]
+    t_pos = (jnp.asarray(base, jnp.int32)[:, None]
+             + jnp.arange(s_len, dtype=jnp.int32)[None, :])
+    tail = t_pos // pool
+    kb = int(topk) // pool - 1
+    step = largest_divisor(lp)
+    needed = (jnp.max(tail) + step - 1) // step
+    seen = jnp.arange(step, dtype=jnp.int32)
+
+    # scores a tile of pooled keys at a time: (B, S, Hi, Lp) at once
+    # would be 0.8 GB at 4 rows of 512 queries and 3,200 blocks
+    def score(j, buf):
+        k_t = jax.lax.dynamic_slice_in_dim(k_pool_view, j * step, step, 1)
+        sc = _pooled_scores(q_idx, weights, k_t)           # (B, S, step)
+        cand = (j * step + seen)[None, None, :] < tail[:, :, None]
+        return jax.lax.dynamic_update_slice_in_dim(
+            buf, jnp.where(cand, sc, _NEG_INF), j * step, 2)
+
+    scores = jax.lax.fori_loop(
+        0, needed, score, jnp.full((b, s_len, lp), _NEG_INF, jnp.float32))
+    finite = scores > _NEG_INF
+    if kb >= lp:
+        return finite
+    bits, kth = kth_largest_bits(scores.reshape(b * s_len, lp), kb)
+    bits = bits.reshape(b, s_len, lp)
+    kth = kth.reshape(b, s_len, 1)
+    above = bits > kth
+    equal = bits == kth
+    take_equal = kb - jnp.sum(above, axis=-1, keepdims=True)
+    rank = jnp.cumsum(equal.astype(jnp.int32), axis=-1)
+    return (above | (equal & (rank <= take_equal))) & finite
+
+
+def sparse_latent_block_attention(q, chosen, view, base, *, pool, sm_scale,
+                                  value_dim, tile):
+    """PREFILL attention over a latent cache under a pooled choice. ``q
+    (B, S, H, W)`` absorbed queries at positions ``base[b] + [0, S)``;
+    ``chosen (B, S, Lp)`` (:func:`pooled_block_choice`); ``view (B, L, W)``
+    the gathered latent rows (the block's own appended), a row the key of
+    every head and its first ``value_dim`` lanes their value. Query t
+    reads its tail's positions ``<= t`` and the chosen blocks' positions;
+    float32 online softmax over key tiles of ``tile`` positions, walked up
+    to the last any query of the block sees. Returns ``(B, S, H,
+    value_dim)`` in q's dtype."""
+    b, s_len, h, _ = q.shape
+    big_l = view.shape[1]
+    if big_l % tile or tile % pool:
+        raise ValueError(f"tile {tile} must divide the view {big_l} and be "
+                         f"a multiple of the pool {pool}")
+    t_pos = (jnp.asarray(base, jnp.int32)[:, None]
+             + jnp.arange(s_len, dtype=jnp.int32)[None, :])
+    tail = (t_pos // pool)[:, :, None]
+    needed = (jnp.max(t_pos) + tile) // tile
+    lane = jnp.arange(tile, dtype=jnp.int32)
+
+    def attend_tile(j, carry):
+        m, l, acc = carry
+        rows = jax.lax.dynamic_slice_in_dim(view, j * tile, tile, 1)
+        blk = jax.lax.dynamic_slice_in_dim(chosen, j * (tile // pool),
+                                           tile // pool, 2)
+        key = j * tile + lane
+        allowed = (key[None, None, :] <= t_pos[:, :, None]) & (
+            jnp.repeat(blk, pool, axis=2) | (key // pool == tail))
+        logits = jnp.einsum("bshw,btw->bhst", q, rows,
+                            preferred_element_type=jnp.float32) * sm_scale
+        logits = jnp.where(allowed[:, None], logits, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
+        shift = jnp.where(m_new > _NEG_INF, m_new, 0.0)
+        p = jnp.exp(logits - shift[..., None])
+        alpha = jnp.exp(jnp.where(m > _NEG_INF, m - shift, _NEG_INF))
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bhst,btv->bhsv", p.astype(rows.dtype), rows[..., :value_dim],
+            preferred_element_type=jnp.float32)
+        return m_new, l * alpha + jnp.sum(p, axis=-1), acc
+
+    stat = (b, h, s_len)
+    _, l, acc = jax.lax.fori_loop(
+        0, needed, attend_tile,
+        (jnp.full(stat, _NEG_INF, jnp.float32), jnp.zeros(stat, jnp.float32),
+         jnp.zeros(stat + (value_dim,), jnp.float32)))
+    out = acc / l[..., None]                    # every query sees itself
+    return jnp.transpose(out, (0, 2, 1, 3)).astype(q.dtype)
+
+
+def selected_latent_attention(q, rows, n_valid, *, sm_scale):
+    """DECODE. ``q (B, H, W)`` absorbed queries over ``rows (B, K, W)``
+    selected latent rows, the first ``n_valid[b]`` real: the whole row is
+    the key, the value too. The selected rows ARE a paged pool of their
+    own — row b's pages one after another, its length ``n_valid`` — so the
+    paged latent kernel (``pallas/latent_attention.py``) reads them as it
+    reads the cache: routed through stf.kernels as
+    ``PagedLatentAttention``."""
+    from ..kernels import registry as kreg
+
+    b, k, w = rows.shape
+    page = largest_divisor(k)
+    pool = rows.reshape(b * (k // page), page, w)
+    tables = jnp.arange(b * (k // page), dtype=jnp.int32).reshape(b, -1)
+    fn = kreg.select("PagedLatentAttention",
+                     kreg.aval_key(q, pool, tables, value_dim=w))
+    return fn(q, pool, tables, n_valid, value_dim=w, sm_scale=sm_scale)
+
+
+# ---------------------------------------------------------------------------
 # graph ops
 # ---------------------------------------------------------------------------
 
@@ -280,14 +456,35 @@ op_registry.register_pure(
         out_dtype=jnp.dtype(out_dtype) if out_dtype else None))
 op_registry.register_pure(
     "RotaryEmbedding",
-    lambda x, positions, theta=10000.0, yarn=None, amplitude=1.0:
-    rotary_embedding(x, positions, theta=theta, yarn=yarn,
-                     amplitude=amplitude))
+    lambda x, positions, theta=10000.0, yarn=None, amplitude=1.0,
+    interleave=False: rotary_embedding(x, positions, theta=theta, yarn=yarn,
+                                       amplitude=amplitude,
+                                       interleave=interleave))
+
+
+def _indexer_topk(q_idx, weights, k_idx, lengths, topk=0, pool=1):
+    if pool > 1:
+        return indexer_topk_pooled(q_idx, weights, k_idx, lengths,
+                                   topk=topk, pool=pool)
+    return indexer_topk(q_idx, weights, k_idx, lengths, topk=topk)
+
+
+op_registry.register_pure("IndexerTopK", _indexer_topk, n_outputs=2)
 op_registry.register_pure(
-    "IndexerTopK",
-    lambda q_idx, weights, k_idx, lengths, topk=0: indexer_topk(
-        q_idx, weights, k_idx, lengths, topk=topk),
-    n_outputs=2)
+    "PooledBlockChoice",
+    lambda q_idx, weights, k_pool_view, base, topk=0, pool=1:
+    pooled_block_choice(q_idx, weights, k_pool_view, base, topk=topk,
+                        pool=pool))
+op_registry.register_pure(
+    "SparseLatentBlockAttention",
+    lambda q, chosen, view, base, pool=1, sm_scale=1.0, value_dim=0,
+    tile=0: sparse_latent_block_attention(
+        q, chosen, view, base, pool=pool, sm_scale=sm_scale,
+        value_dim=value_dim, tile=tile))
+op_registry.register_pure(
+    "SelectedLatentAttention",
+    lambda q, rows, n_valid, sm_scale=1.0: selected_latent_attention(
+        q, rows, n_valid, sm_scale=sm_scale))
 op_registry.register_pure(
     "SelectedAttention",
     selected_attention)
@@ -315,7 +512,7 @@ def rms_norm_op(x, gamma, eps=1e-6, out_dtype=None, name=None):
 
 
 def rotary_embedding_op(x, positions, theta, yarn=None, amplitude=1.0,
-                        name=None):
+                        interleave=False, name=None):
     """``yarn`` = ``(factor, original_len, beta_fast, beta_slow)`` or
     None for plain RoPE (:func:`rotary_embedding`)."""
     attrs = {"theta": float(theta)}
@@ -324,13 +521,42 @@ def rotary_embedding_op(x, positions, theta, yarn=None, amplitude=1.0,
         attrs["yarn"] = tuple(float(v) for v in yarn)
     if amplitude != 1.0:
         attrs["amplitude"] = float(amplitude)
+    if interleave:
+        attrs["interleave"] = True
     return _make("RotaryEmbedding", [x, positions], attrs,
                  name or "rotary_embedding")
 
 
-def indexer_topk_op(q_idx, weights, k_idx, lengths, topk, name=None):
-    return _make("IndexerTopK", [q_idx, weights, k_idx, lengths],
-                 {"topk": int(topk)}, name or "indexer_topk", n_out=2)
+def indexer_topk_op(q_idx, weights, k_idx, lengths, topk, pool=1,
+                    name=None):
+    """``pool > 1``: ``k_idx`` holds one POOLED key a block of ``pool``
+    positions and the selection is :func:`indexer_topk_pooled`'s."""
+    attrs = {"topk": int(topk)}
+    if pool > 1:
+        attrs["pool"] = int(pool)
+    return _make("IndexerTopK", [q_idx, weights, k_idx, lengths], attrs,
+                 name or "indexer_topk", n_out=2)
+
+
+def pooled_block_choice_op(q_idx, weights, k_pool_view, base, *, topk, pool,
+                           name=None):
+    return _make("PooledBlockChoice", [q_idx, weights, k_pool_view, base],
+                 {"topk": int(topk), "pool": int(pool)},
+                 name or "pooled_block_choice")
+
+
+def sparse_latent_block_attention_op(q, chosen, view, base, *, pool,
+                                     sm_scale, value_dim, tile, name=None):
+    return _make("SparseLatentBlockAttention", [q, chosen, view, base],
+                 {"pool": int(pool), "sm_scale": float(sm_scale),
+                  "value_dim": int(value_dim), "tile": int(tile)},
+                 name or "sparse_latent_block_attention")
+
+
+def selected_latent_attention_op(q, rows, n_valid, *, sm_scale, name=None):
+    return _make("SelectedLatentAttention", [q, rows, n_valid],
+                 {"sm_scale": float(sm_scale)},
+                 name or "selected_latent_attention")
 
 
 def selected_attention_op(q, k_sel, v_sel, n_valid, name=None):

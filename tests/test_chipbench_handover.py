@@ -27,7 +27,8 @@ assert {n for n in dir(_cases) if n.startswith("test_")} <= set(globals())
 
 SERVING = ["lm-big.backlog", "keye-vl2-30b-a3b.longdoc-backlog",
            "kimi-k2.7-code.repo-backlog",
-           "nemotron-3-nano-30b-a3b.reasoning-backlog"]
+           "nemotron-3-nano-30b-a3b.reasoning-backlog",
+           "glm-5.3-flash.longthink-backlog"]
 
 
 @pytest.mark.parametrize("name, layer, source, reader, cells", [

@@ -551,6 +551,11 @@ _RULE_KEYS = {
     "SSMStateUpdate": kreg.aval_key(
         _aval((256, 64, 64)), _aval((257, 32, 128, 128), "float32"),
         _aval((256, 8, 128))),
+    # 128 rows x 64 heads of a 128 x 128 float32 matrix state (4.2 MB)
+    "KDAStateUpdate": kreg.aval_key(
+        _aval((128, 64, 128), "float32"),
+        _aval((129, 64, 128, 128), "float32"),
+        _aval((128, 64, 128), "float32")),
 }
 
 

@@ -796,3 +796,67 @@ def test_state_space_moe_programs_compile_fit_and_update_in_place(
     # over every row at 256 (ops/moe_ops.py, "THE DENSE FORM")
     assert (text.count("ragged-dot-metadata") >= 1) == (
         program == "prefill8")
+
+
+# ---------------------------------------------------------------------------
+# glm-5.3-flash (models/hybrid_linear_moe_lm.py): the decode program of the
+# cell's 128 rows and the page-chunk prefill of 4 rows compile for the
+# described chip beside 13.6 GB of weights, latent pages, pooled keys and
+# KDA state, keep every state leaf aliased and UPDATE THE KDA STATE POOLS IN
+# PLACE: 129 slots x 4.2 MB a layer, 541 MB a pool, four of them.
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def hybrid_linear_moe_programs(one_chip):
+    import simple_tensorflow_tpu as stf
+    from simple_tensorflow_tpu.models import hybrid_linear_moe_lm
+
+    def stack_of(cfg_kwargs, kw):
+        cfg = hybrid_linear_moe_lm.HybridLinearMoEConfig(**cfg_kwargs)
+        return cfg, hybrid_linear_moe_lm._HybridLinearMoEStack(
+            cfg, stf.bfloat16, "causal_lm", kw["page_len"])
+
+    return _paged_serving_programs(
+        one_chip, "glm-5.3-flash.json", stack_of, 128, 4,
+        kernels_on_tpu=True)
+
+
+@pytest.mark.parametrize("program", ["decode128", "prefill4"])
+def test_hybrid_linear_moe_programs_compile_fit_and_update_in_place(
+        hybrid_linear_moe_programs, program):
+    got = hybrid_linear_moe_programs
+    compiled = got["programs"][program]
+    text = compiled.as_text()
+    s_pools = [c for c in got["caches"] if not c.paged
+               and c.dtype.name == "float32"]
+    conv_pools = [c for c in got["caches"] if not c.paged
+                  and c.dtype.name != "float32"]
+    latent = [c for c in got["caches"] if c.paged
+              and c.dtype.name != "float32"]
+    pooled = [c for c in got["caches"] if c.paged
+              and c.dtype.name == "float32"]
+    assert [len(p) for p in (s_pools, conv_pools, latent, pooled)] == [
+        4, 4, 1, 1]
+    assert s_pools[0].stored_shape == (129, 64, 128, 128)
+    assert conv_pools[0].stored_shape == (129, 3 * 3 * 8192)
+    assert latent[0].stored_shape == (3233, 512, 512)
+    assert pooled[0].stored_shape == (3233, 128, 128)
+    print(program, "state", got["state_bytes"],
+          "temp", compiled.memory_analysis().temp_size_in_bytes)
+    header = text.split("\n", 1)[0]
+    assert header.count("-alias)") == got["n_state"]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert got["state_bytes"] + temp < 16.5e9, temp
+    for pool in (s_pools[0], latent[0]):
+        elems = int(np.prod(pool.stored_shape))
+        copies = [ln for shape, op, ln in _entry_instructions(text)
+                  if op == "copy" and int(np.prod(shape)) == elems]
+        assert not copies, (pool, copies[:1])
+    if program == "decode128":
+        calls = re.findall(
+            r"%(stf_kda_state_update_b\d+)[\w.\-]* = [^\n]*custom-call",
+            text)
+        assert calls == ["stf_kda_state_update_b128"] * 4, calls
+        assert re.findall(
+            r"%(stf_latent_attention_q1_paged)[\w.\-]* = [^\n]*custom-call",
+            text) == ["stf_latent_attention_q1_paged"]
