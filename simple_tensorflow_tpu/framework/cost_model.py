@@ -240,6 +240,11 @@ def _op_flops(op: Operation, grad_depth: int = 0,
         # one multiply-add a tap and output element
         taps = op.inputs[1].shape.dims[0].value or 1
         return 2.0 * int(taps) * _out_elems(op)
+    if t == "ShortConvUpdate":
+        # B x~ and C v (one multiply each) and a multiply-add a tap, an
+        # output element
+        taps = op.inputs[1].shape.dims[0].value or 1
+        return (2.0 * int(taps) + 1.0) * _out_elems(op)
     if t in ("KVCacheAlloc", "KVCacheAppend", "KVCacheGather",
              "KVCacheGatherRows", "KVCachePageCopy", "StatePoolAlloc"):
         return 0.0  # pure data movement; bytes are priced in _op_bytes
@@ -342,7 +347,7 @@ def _op_bytes_dispatch(op: Operation, fn_depth: int = 0) -> float:
         itemsize = op.outputs[0].dtype.base_dtype.size if op.outputs else 4
         return _op_bytes(op) + pools * entries * page * itemsize
     if op.type in ("SSMStateUpdate", "SSMChunkScan", "CausalConv1D",
-                   "KDAStateUpdate", "KDAChunkScan"):
+                   "KDAStateUpdate", "KDAChunkScan", "ShortConvUpdate"):
         # a state pool's rows advanced in place: the op's own inputs and
         # its output (the layer's, not the pool), and per ROW of the call
         # one slot's state read and written — never the whole pool

@@ -299,10 +299,12 @@ class _PostLNStack:
 
 class PreNormStack:
     """What the pre-norm RMSNorm stacks share (``models/sparse_moe_lm.py``,
-    ``models/latent_moe_lm.py``, ``models/state_space_moe_lm.py``):
+    ``models/latent_moe_lm.py``, ``models/state_space_moe_lm.py``,
+    ``models/hybrid_linear_moe_lm.py``, ``models/short_conv_moe_lm.py``):
     variables, the norm, the embedding, a loop over the layers' KINDS and
-    the untied head. A stack gives ``layer_caches``, ``prefill_block``,
-    ``decode_step`` (the builder's contract). ``layer_kinds`` names each
+    the head, untied or tied to the embedding. A stack gives
+    ``layer_caches``, ``prefill_block``, ``decode_step`` (the builder's
+    contract). ``layer_kinds`` names each
     layer's kind; the default, ``"block"``, is ``x += attention(norm(x));
     x += ffn(x)`` over the stack's own ``_attention(i, a, rows, lead,
     positions, attend)`` -> ``(rows, d_model)`` and ``_ffn(i, x,
@@ -327,13 +329,15 @@ class PreNormStack:
             x, gamma, eps=self.cfg.rms_norm_eps if eps is None else eps,
             out_dtype=out_dtype)
 
-    def _embed(self, tok):
+    def _embedding(self):
         cfg = self.cfg
-        emb = stf.get_variable(
+        return stf.get_variable(
             "embed", [cfg.vocab_size, cfg.d_model],
             dtype=self.compute_dtype,
             initializer=stf.random_normal_initializer(stddev=1.0))
-        return stf.gather(emb, tok)
+
+    def _embed(self, tok):
+        return stf.gather(self._embedding(), tok)
 
     @property
     def layer_kinds(self):
@@ -394,11 +398,16 @@ class PreNormStack:
         return stf.cast(stf.reduce_sum(stf.cast(x, stf.float32), axis=1),
                         self.compute_dtype)
 
-    def _logits(self, x):
+    def _logits(self, x, tied=False):
+        """The final norm and the head: an untied ``lm_head``, or with
+        ``tied`` the embedding's own rows."""
         cfg = self.cfg
         h = self._norm(x, "final_norm", cfg.d_model)
-        logits = stf.matmul(h, self._w(
-            "lm_head", [cfg.d_model, cfg.vocab_size], cfg.d_model))
+        if tied:
+            logits = stf.matmul(h, self._embedding(), transpose_b=True)
+        else:
+            logits = stf.matmul(h, self._w(
+                "lm_head", [cfg.d_model, cfg.vocab_size], cfg.d_model))
         return stf.cast(logits, stf.float32)
 
 

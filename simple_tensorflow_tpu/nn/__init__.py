@@ -43,6 +43,7 @@ from ..ops.kv_cache_ops import (decode_attention, paged_decode_attention,
 from ..ops.moe_ops import routed_ffn_op as routed_ffn
 from ..ops.ssm_ops import (
     causal_conv1d_op as causal_conv1d, gated_rms_norm_op as gated_rms_norm,
+    short_conv_update_op as short_conv_update,
     ssm_chunk_scan_op as ssm_chunk_scan,
     ssm_state_update_op as ssm_state_update,
 )

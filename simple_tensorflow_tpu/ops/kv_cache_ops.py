@@ -145,7 +145,7 @@ PAGED_ATTENTION_OP_TYPES = ("PagedDecodeAttention", "PagedLatentAttention")
 # (ops/ssm_ops.py, ops/linear_attention_ops.py): the output is the
 # layer's, not the pool
 STATE_UPDATE_OP_TYPES = ("CausalConv1D", "SSMChunkScan", "SSMStateUpdate",
-                         "KDAChunkScan", "KDAStateUpdate")
+                         "KDAChunkScan", "KDAStateUpdate", "ShortConvUpdate")
 # ops whose output is computed FROM a pool and is not the pool's rows
 IN_PLACE_OP_TYPES = PAGED_ATTENTION_OP_TYPES + STATE_UPDATE_OP_TYPES
 _CACHE_OP_TYPES = ("KVCacheAlloc", "KVCacheAppend", "KVCacheGather",

@@ -26,7 +26,9 @@ Attributes beyond the default block (each leaves the default lowering as
 it was when it is not given):
 
   score      ``"softmax"`` (default) or ``"sigmoid"``: ``s = sigmoid(x .
-             Wr)``, the gates ``s_e / (sum_selected s + 1e-20)``.
+             Wr)``, the gates ``s_e / (sum_selected s + gate_norm_eps)``.
+  gate_norm_eps  the ``eps`` of that sum (default 1e-20); a family whose
+             code adds another (LFM2's 1e-6) names it.
   bias       an input, ``(E,)`` float32: added to the scores to CHOOSE
              the ``top_k`` experts and to nothing else — the gates weigh
              with the scores as they were.
@@ -77,8 +79,13 @@ from ..framework import op_registry
 from . import op_util
 
 
+# the default ``gate_norm_eps``: the sum of the selected sigmoid scores
+# (or of biased softmax probabilities) is never zero
+GATE_NORM_EPS = 1e-20
+
+
 def route(x, w_router, *, top_k, norm_topk, score="softmax", bias=None,
-          gate_scale=1.0):
+          gate_scale=1.0, gate_norm_eps=GATE_NORM_EPS):
     """``(experts (T, k) int32, gates (T, k) float32)``."""
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
@@ -94,7 +101,7 @@ def route(x, w_router, *, top_k, norm_topk, score="softmax", bias=None,
             jnp.float32)
         _, experts = jax.lax.top_k(choice, top_k)
         top_p = jnp.take_along_axis(scores, experts, axis=-1)
-        norm = jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20
+        norm = jnp.sum(top_p, axis=-1, keepdims=True) + gate_norm_eps
     if norm_topk:
         top_p = top_p / norm
     if gate_scale != 1.0:
@@ -215,7 +222,8 @@ def _held_ffn(x, experts, gates, w_gate_up, w_down, row_mask, held,
 
 def routed_ffn(x, w_router, w_gate_up, w_down, row_mask=None, *, top_k,
                norm_topk=True, score="softmax", bias=None, gate_scale=1.0,
-               held=None, activation="swiglu", swiglu_limit=None):
+               held=None, activation="swiglu", swiglu_limit=None,
+               gate_norm_eps=GATE_NORM_EPS):
     """``x (T, H)``; ``w_router (H, E)``; ``w_gate_up (E, H, 2*I)`` (gate
     columns first; ``(E, H, I)``, the up projection alone, with
     ``activation="relu2"``); ``w_down (E, I, H)`` — with ``held = (first,
@@ -223,7 +231,8 @@ def routed_ffn(x, w_router, w_gate_up, w_down, row_mask=None, *, top_k,
     ``x``'s dtype, ``counts`` int32 over the experts held``)``."""
     t, _ = x.shape
     experts, gates = route(x, w_router, top_k=top_k, norm_topk=norm_topk,
-                           score=score, bias=bias, gate_scale=gate_scale)
+                           score=score, bias=bias, gate_scale=gate_scale,
+                           gate_norm_eps=gate_norm_eps)
     if held is not None:
         return _held_ffn(x, experts, gates, w_gate_up, w_down, row_mask,
                          tuple(int(v) for v in held), w_router.shape[1],
@@ -243,7 +252,8 @@ def routed_ffn(x, w_router, w_gate_up, w_down, row_mask=None, *, top_k,
 
 def _lower(x, w_router, w_gate_up, w_down, *rest, top_k=1, norm_topk=True,
            score="softmax", gate_scale=1.0, held=None, has_bias=False,
-           activation="swiglu", swiglu_limit=None):
+           activation="swiglu", swiglu_limit=None,
+           gate_norm_eps=GATE_NORM_EPS):
     # optional inputs, in the order ``routed_ffn_op`` appends them
     rest = list(rest)
     bias = rest.pop() if has_bias else None
@@ -251,7 +261,7 @@ def _lower(x, w_router, w_gate_up, w_down, *rest, top_k=1, norm_topk=True,
     return routed_ffn(x, w_router, w_gate_up, w_down, row_mask, top_k=top_k,
                       norm_topk=norm_topk, score=score, bias=bias,
                       gate_scale=gate_scale, held=held, activation=activation,
-                      swiglu_limit=swiglu_limit)
+                      swiglu_limit=swiglu_limit, gate_norm_eps=gate_norm_eps)
 
 
 op_registry.register_pure("RoutedFFN", _lower, n_outputs=2)
@@ -260,7 +270,7 @@ op_registry.register_pure("RoutedFFN", _lower, n_outputs=2)
 def routed_ffn_op(x, w_router, w_gate_up, w_down, row_mask=None, *, top_k,
                   norm_topk=True, score="softmax", bias=None,
                   gate_scale=1.0, held=None, activation="swiglu",
-                  swiglu_limit=None, name=None):
+                  swiglu_limit=None, gate_norm_eps=GATE_NORM_EPS, name=None):
     """Graph op over ``x (T, H)``; see :func:`routed_ffn`. Returns
     ``(y, counts)``."""
     if score not in ("softmax", "sigmoid"):
@@ -286,6 +296,8 @@ def routed_ffn_op(x, w_router, w_gate_up, w_down, row_mask=None, *, top_k,
         attrs["activation"] = activation
     if swiglu_limit is not None:
         attrs["swiglu_limit"] = float(swiglu_limit)
+    if gate_norm_eps != GATE_NORM_EPS:
+        attrs["gate_norm_eps"] = float(gate_norm_eps)
     inputs = [ops_mod.convert_to_tensor(t) for t in inputs]
     return op_util.make_op("RoutedFFN", inputs, attrs=attrs,
                            name=name or "routed_ffn", n_out=2)

@@ -22,7 +22,7 @@ from ...kernels import registry as _kreg
 from .decode_attention import (decode_attention, decode_attention_xla,
                                paged_decode_attention,
                                paged_decode_attention_xla,
-                               paged_heads_per_group)
+                               paged_heads_per_group, kv_heads_per_lane_tile)
 from .dropout_residual import (dropout_bias_residual,
                                dropout_bias_residual_reference)
 from .flash_attention import attention_xla, flash_attention, mha_reference
@@ -35,6 +35,7 @@ from .layer_norm import layer_norm, layer_norm_reference
 from .quant_matmul import (quant_matmul, quant_matmul_reference,
                            quant_matmul_ste, quant_matmul_ste_reference,
                            quantize_colwise, quantize_rowwise)
+from .short_conv_update import short_conv_update, short_conv_update_xla
 from .softmax_xent import (softmax_cross_entropy,
                            softmax_cross_entropy_reference)
 from .ssm_state_update import ssm_state_update, ssm_state_update_xla
@@ -553,8 +554,10 @@ def _paged_attn_eligible(key):
             or (qs[-2] * qs[-1]) % ps[2]:
         return "ineligible_shape"
     # grouped queries (H > H_kv): a key-value head's lanes are sliced
-    # out of the page, so they are whole 128-lane tiles
-    if ps[2] != qs[-2] * qs[-1] and qs[-1] % 128:
+    # out of the page, so they are whole 128-lane tiles, or a head_dim
+    # below one whose heads fill whole tiles together
+    d = qs[-1]
+    if ps[2] != qs[-2] * d and d * kv_heads_per_lane_tile(d, ps[2] // d) % 128:
         return "ineligible_shape"
     return None
 
@@ -568,11 +571,12 @@ def _paged_attn_gate(key, bk):
     view_len = n_blocks * page_len
     h_kv = int(ps[2]) // d
     # the kernel's MXU work: block-diagonal queries spend ``group``
-    # times the flops (grouped queries none: a key-value head's query
-    # heads are rows of its own tile); a block of several pages a grid
-    # step changes the dots' widths, not their flops; charged to both
-    # sides, so the bytes decide
-    group = paged_heads_per_group(kq, h, d) if h_kv == h else 1
+    # times the flops (grouped queries the key-value heads that share a
+    # lane tile: a key-value head's query heads are rows of its own
+    # tile); a block of several pages a grid step changes the dots'
+    # widths, not their flops; charged to both sides, so the bytes decide
+    group = (paged_heads_per_group(kq, h, d) if h_kv == h
+             else kv_heads_per_lane_tile(d, h_kv))
     flops = 4.0 * group * b * kq * h * view_len * d
     q_out = 2.0 * b * kq * h * d * itm
     # K and V views of every table entry: the most the kernel reads
@@ -772,3 +776,58 @@ def _kda_update_graph_key(op):
         return None
     return _kreg.aval_key(_Aval(*q), _Aval(tuple(op.attrs["shape"]),
                                            "float32"), _Aval(*v))
+
+
+# ---------------------------------------------------------------------------
+# ShortConvUpdate: one decode token of a gated short-convolution layer,
+# every row's window read and written in place in the slot pool vs gather,
+# update and scatter. The graph op is registered by ops/ssm_ops.py; this
+# entry owns the routing.
+# ---------------------------------------------------------------------------
+
+def _short_conv_eligible(key):
+    (bs, bd), (ps, pd), (ws, _wd) = key[:3]
+    if not _is_float(bd) or str(pd) != str(bd):
+        return "ineligible_dtype"
+    # bcx (B, 3W); pool (slots, (taps - 1) W / 128, 128); w (taps, W)
+    if len(bs) != 2 or len(ps) != 3 or len(ws) != 2:
+        return "ineligible_shape"
+    taps, width = ws
+    if bs[1] != 3 * width or width % 128 or ps[2] != 128 \
+            or ps[1] * 128 != (taps - 1) * width:
+        return "ineligible_shape"
+    return None
+
+
+def _short_conv_gate(key, bk):
+    (bs, bd), (ps, _pd), (ws, _wd) = key[:3]
+    rows, (taps, width) = float(bs[0]), ws
+    itm = _np_of(bd).itemsize
+    window = float(ps[1] * ps[2] * itm)
+    rows_io = rows * 4.0 * width * itm          # [B | C | x~] in, y out
+    # the kernel reads and writes a row's window once; the composition
+    # gathers it (read + write), reads it into the step and scatters the
+    # shifted one (read + write)
+    return _kreg.roofline_gate(rows * width * (2.0 * taps + 1.0),
+                               2.0 * rows * window + rows_io,
+                               5.0 * rows * window + rows_io, bk)
+
+
+_kreg.register_kernel(
+    "ShortConvUpdate",
+    impls={"pallas": short_conv_update, "xla": short_conv_update_xla},
+    legacy="xla",
+    eligible=_short_conv_eligible,
+    cost_gate=_short_conv_gate,
+    graph_key=lambda op: _short_conv_graph_key(op),
+    doc="one decode token of a gated short-convolution layer, the rows' "
+        "windows updated in place in the slot pool vs gather, update and "
+        "scatter")
+
+
+def _short_conv_graph_key(op):
+    bcx, w = _tensor_aval(op.inputs[0]), _tensor_aval(op.inputs[1])
+    if bcx is None or w is None:
+        return None
+    return _kreg.aval_key(_Aval(*bcx), _Aval(tuple(op.attrs["shape"]),
+                                             op.attrs["dtype"]), _Aval(*w))

@@ -65,10 +65,14 @@ GROUPED QUERIES (PR 35). Both paged lowerings take ``q`` with MORE heads
 than the pools hold, ``H % H_kv == 0``: query head ``h`` reads key-value
 head ``h // (H / H_kv)``. The kernel then needs no block-diagonal
 lay-out: a key-value head's ``H / H_kv`` query heads are ROWS of one query
-tile over that head's own lanes (``_paged_grouped_kernel``; head_dim a
-whole number of 128-lane tiles; its call site is named
-``stf_decode_attention_q<Kq>_paged_gqa``). At ``H == H_kv`` the kernel is
-the one above, tile for tile.
+tile over that head's own lanes (``_paged_grouped_kernel``; its call site
+is named ``stf_decode_attention_q<Kq>_paged_gqa``). At ``H == H_kv`` the
+kernel is the one above, tile for tile. A head_dim below one lane tile
+(64) puts ``128 / head_dim`` key-value heads in a tile
+(:func:`kv_heads_per_lane_tile`): their query rows are stacked in one
+query tile, each row over its own head's lanes and zero on the others', so
+the kernel still slices whole tiles and row-block ``j`` of the result keeps
+head ``j``'s lanes (taken out after the call).
 """
 
 from __future__ import annotations
@@ -515,14 +519,25 @@ def _paged_kernel(tbl_ref, len_ref, plan_ref, q_ref, k_hbm, v_hbm, o_ref,
 _GROUPED_MAX_ROWS = 1024
 
 
-def grouped_heads_per_tile(kq, rep):
+def grouped_heads_per_tile(kq, rep, pack=1):
     """Query heads of ONE key-value head whose ``kq`` queries share a
     tile of the grouped kernel, from the shapes alone: the largest
-    divisor of ``rep = H / H_kv`` that keeps a tile within
-    ``_GROUPED_MAX_ROWS`` rows — all 16 at a decode step (16 rows), 4 at
-    a 256-query prefill block (1024 rows, four tiles a key-value head)."""
+    divisor of ``rep = H / H_kv`` that keeps a tile — ``pack`` key-value
+    heads' rows at a head_dim below a lane tile — within
+    ``_GROUPED_MAX_ROWS`` rows: all 16 at a decode step (16 rows), 4 at a
+    256-query prefill block (1024 rows, four tiles a key-value head)."""
     return max(r for r in range(1, rep + 1)
-               if rep % r == 0 and (r * kq <= _GROUPED_MAX_ROWS or r == 1))
+               if rep % r == 0
+               and (pack * r * kq <= _GROUPED_MAX_ROWS or r == 1))
+
+
+def kv_heads_per_lane_tile(head_dim, kv_heads):
+    """Key-value heads whose lanes share one 128-lane tile in the grouped
+    kernel: ``128 / head_dim`` below a tile (2 at 64) where the heads fill
+    whole tiles, else 1."""
+    d = int(head_dim)
+    pack = 128 // d if d < 128 and 128 % d == 0 else 1
+    return pack if int(kv_heads) % pack == 0 else 1
 
 
 def _paged_grouped_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -593,14 +608,29 @@ def _paged_grouped_call(q, k_pool, v_pool, page_tables, lengths, *,
     n_blocks = page_tables.shape[1]
     n_kv = hd // d
     rep = h // n_kv
-    r_blk = grouped_heads_per_tile(kq, rep)
+    pack = kv_heads_per_lane_tile(d, n_kv)
+    r_blk = grouped_heads_per_tile(kq, rep, pack)
     n_tiles = rep // r_blk
     real = r_blk * kq
-    rows = real if interpret else round_up(real, 8)
 
-    # (B, H_kv, tiles, r_blk * Kq, D): a tile's query heads head-major
-    qt = jnp.transpose(q.reshape(b, kq, n_kv, n_tiles, r_blk, d),
-                       (0, 2, 3, 4, 1, 5)).reshape(b, n_kv, n_tiles, real, d)
+    if pack == 1:
+        rows = real if interpret else round_up(real, 8)
+        # (B, H_kv, tiles, r_blk * Kq, D): a tile's query heads head-major
+        qt = jnp.transpose(q.reshape(b, kq, n_kv, n_tiles, r_blk, d),
+                           (0, 2, 3, 4, 1, 5)).reshape(b, n_kv, n_tiles,
+                                                       real, d)
+    else:
+        # (B, H_kv / pack, tiles, pack * r_blk * Kq, pack * D): key-value
+        # head j of a lane tile in rows [j * real, (j + 1) * real) and
+        # lanes [j * D, (j + 1) * D), zeros elsewhere
+        n_kv, d = n_kv // pack, pack * d
+        rows = pack * real if interpret else round_up(pack * real, 8)
+        qg = jnp.transpose(
+            q.reshape(b, kq, n_kv, pack, n_tiles, r_blk, d // pack),
+            (0, 2, 4, 3, 5, 1, 6)).reshape(b, n_kv, n_tiles, pack, real,
+                                           1, d // pack)
+        eye = jnp.eye(pack, dtype=q.dtype)[:, None, :, None]
+        qt = (qg * eye).reshape(b, n_kv, n_tiles, pack * real, d)
     qt = pad_dim(qt, 3, rows).astype(k_pool.dtype)
 
     def page_of(bi, ti, page, tbl, lens):
@@ -640,6 +670,14 @@ def _paged_grouped_call(q, k_pool, v_pool, page_tables, lengths, *,
         name=f"stf_decode_attention_q{kq}_paged_gqa",
     )(jnp.asarray(page_tables, jnp.int32).reshape(-1),
       jnp.asarray(lengths, jnp.int32), qt, k_pool, v_pool)
+    if pack > 1:
+        # row-block j keeps key-value head j's lanes
+        d //= pack
+        o = o[:, :, :, :pack * real].reshape(b, n_kv, n_tiles, pack, real,
+                                             pack, d)
+        n_kv *= pack
+        o = jnp.stack([o[:, :, :, j, :, j] for j in range(pack)],
+                      2).reshape(b, n_kv, n_tiles, real, d)
     o = o[:, :, :, :real].reshape(b, n_kv, n_tiles, r_blk, kq, d)
     return jnp.transpose(o, (0, 4, 1, 2, 3, 5)).reshape(b, kq, h, d)
 

@@ -30,6 +30,12 @@ place.
                  gather-update-scatter composition).
   GatedRMSNorm   ``RMSNorm_groups(y * silu(z)) * gamma``: the gate first,
                  then the norm, over groups of the last axis.
+  ShortConvUpdate  DECODE of a gated short convolution (LFM2's): ``y = C *
+                 conv(window, B * x~)`` from the in-projection's ``[B | C |
+                 x~]``, one token a row; routed through stf.kernels
+                 (``ops/pallas/short_conv_update.py`` in place, or the
+                 gather-update-scatter composition). Its prefill is
+                 ``CausalConv1D`` over ``B * x~``.
 
 ROWS OF ONE CALL. A prefill call's rows are page chunks in the order the
 engine gives them — by ``(base, slot)``, so a slot's rows come in order of
@@ -110,7 +116,9 @@ def causal_conv1d(pool, x, w, bias, slots, fresh, lens=None):
     """Depthwise causal convolution with the window carried in ``pool
     (slots, (taps - 1) * C)`` — a slot's last ``taps - 1`` inputs, oldest
     first, flat on one lane-dense axis (a ``(slots, 3, C)`` pool is relaid
-    by the TPU compiler at every call: 3 rows fill no sublane tile). ``w
+    by the TPU compiler at every call: 3 rows fill no sublane tile), or the
+    same values in whole ``(rows, 128)`` tiles a slot
+    (``pallas/short_conv_update.window_shape``). ``w
     (taps, C)``: ``out[t] = bias + sum_k w[k] in[t - (taps - 1) + k]`` (the
     last tap meets the current token). ``x (rows, L, C)`` with ``lens
     (rows,)`` real tokens a row, or one token a row ``x (rows, C)``.
@@ -125,7 +133,7 @@ def causal_conv1d(pool, x, w, bias, slots, fresh, lens=None):
         ext = jnp.concatenate([window, x[:, None].astype(pool.dtype)], 1)
         out = bf + jnp.einsum("bkc,kc->bc", ext.astype(jnp.float32), wf)
         return out.astype(x.dtype), pool.at[slots].set(
-            ext[:, 1:].reshape(-1, (taps - 1) * c))
+            ext[:, 1:].reshape((-1,) + pool.shape[1:]))
     rows, length, _ = x.shape
     lens = jnp.asarray(lens, jnp.int32)
     xs = x.astype(pool.dtype)
@@ -138,7 +146,8 @@ def causal_conv1d(pool, x, w, bias, slots, fresh, lens=None):
         entering.append(window)
         leaving.append(jax.lax.dynamic_slice_in_dim(
             jnp.concatenate([window, xs[i]], 0), lens[i], taps - 1, 0))
-    pool = _write_back(pool, slots, jnp.stack(leaving).reshape(rows, -1))
+    pool = _write_back(pool, slots, jnp.stack(leaving).reshape(
+        (rows,) + pool.shape[1:]))
     ext = jnp.concatenate([jnp.stack(entering), xs], 1).astype(jnp.float32)
     out = bf + sum(wf[k] * ext[:, k:k + length] for k in range(taps))
     return out.astype(x.dtype), pool
@@ -275,6 +284,30 @@ def _lower_causal_conv1d(ctx, op, inputs):
     return [out]
 
 
+def short_conv_update_op(bcx, w, pool, slots, fresh, name=None):
+    """One token a row of a gated short convolution: ``bcx (B, 3 W)`` the
+    in-projection ``[B | C | x~]``, ``w (taps, W)``, against the window
+    pool ``pool`` (inner shape ``pallas/short_conv_update.window_shape``),
+    updated in place. Returns ``y (B, W)``. Routed through stf.kernels."""
+    bcx = ops_mod.convert_to_tensor(bcx)
+    rows, width = bcx.shape.as_list()
+    op = _pool_update_op("ShortConvUpdate", pool,
+                         [bcx, w, _ints(slots), _flags(fresh)],
+                         ([rows, width // 3], bcx.dtype),
+                         name or "short_conv_update")
+    return op.outputs[0]
+
+
+def _lower_short_conv_update(ctx, op, inputs):
+    name = op.attrs["var_name"]
+    pool = ctx.read_var(name, op)
+    bcx, w = inputs[:2]
+    fn = _kreg.select("ShortConvUpdate", _kreg.aval_key(bcx, pool, w))
+    y, pool = fn(pool, *inputs)
+    ctx.write_var(name, pool)
+    return [y]
+
+
 def ssm_chunk_scan_op(x, dt, a, bm, cm, d, pool, slots, fresh, lens, *,
                       chunk, name=None):
     """:func:`ssm_chunk_scan` against the state pool ``pool``."""
@@ -320,7 +353,8 @@ def _lower_ssm_state_update(ctx, op, inputs):
 
 for _type, _lower in (("CausalConv1D", _lower_causal_conv1d),
                       ("SSMChunkScan", _lower_ssm_chunk_scan),
-                      ("SSMStateUpdate", _lower_ssm_state_update)):
+                      ("SSMStateUpdate", _lower_ssm_state_update),
+                      ("ShortConvUpdate", _lower_short_conv_update)):
     op_registry.register(
         _type, lower=_lower,
         effects=op_registry.Effects(writes=("var_name",), update="update"))

@@ -28,7 +28,7 @@ assert {n for n in dir(_cases) if n.startswith("test_")} <= set(globals())
 SERVING = ["lm-big.backlog", "keye-vl2-30b-a3b.longdoc-backlog",
            "kimi-k2.7-code.repo-backlog",
            "nemotron-3-nano-30b-a3b.reasoning-backlog",
-           "glm-5.3-flash.longthink-backlog"]
+           "glm-5.3-flash.longthink-backlog", "lfm2-8b-a1b.gen-backlog"]
 
 
 @pytest.mark.parametrize("name, layer, source, reader, cells", [

@@ -556,6 +556,9 @@ _RULE_KEYS = {
         _aval((128, 64, 128), "float32"),
         _aval((129, 64, 128, 128), "float32"),
         _aval((128, 64, 128), "float32")),
+    # 256 rows of [B | C | x~] 3 x 2048 over a pool of 2 x 2048 windows
+    "ShortConvUpdate": kreg.aval_key(
+        _aval((256, 6144)), _aval((257, 32, 128)), _aval((3, 2048))),
 }
 
 

@@ -860,3 +860,64 @@ def test_hybrid_linear_moe_programs_compile_fit_and_update_in_place(
         assert re.findall(
             r"%(stf_latent_attention_q1_paged)[\w.\-]* = [^\n]*custom-call",
             text) == ["stf_latent_attention_q1_paged"]
+
+
+# ---------------------------------------------------------------------------
+# lfm2-8b-a1b (models/short_conv_moe_lm.py): the decode program of the cell's
+# 256 rows and the page-chunk prefill of 8 rows compile for the described
+# chip beside 14.5 GB of weights, K/V pages and windows, keep every state
+# leaf aliased, update the windows in place by the short-conv kernel and read
+# K/V through the grouped kernel at head_dim 64.
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def short_conv_moe_programs(one_chip):
+    import simple_tensorflow_tpu as stf
+    from simple_tensorflow_tpu.models import short_conv_moe_lm
+
+    def stack_of(cfg_kwargs, kw):
+        cfg = short_conv_moe_lm.ShortConvMoEConfig(**cfg_kwargs)
+        return cfg, short_conv_moe_lm._ShortConvMoEStack(
+            cfg, stf.bfloat16, "causal_lm")
+
+    return _paged_serving_programs(
+        one_chip, "lfm2-8b-a1b.json", stack_of, 256, 8,
+        kernels_on_tpu=True)
+
+
+@pytest.mark.parametrize("program,kq", [("decode256", 1), ("prefill8", 256)])
+def test_short_conv_moe_programs_compile_fit_and_update_in_place(
+        short_conv_moe_programs, program, kq):
+    got = short_conv_moe_programs
+    compiled = got["programs"][program]
+    text = compiled.as_text()
+    windows = [c for c in got["caches"] if not c.paged]
+    kv_pools = [c for c in got["caches"] if c.paged]
+    assert (len(windows), len(kv_pools)) == (10, 6)
+    # a slot's two rows of u as whole (16, 128) bfloat16 tiles
+    assert windows[0].stored_shape == (257, 32, 128)
+    assert kv_pools[0].stored_shape == (3361, 256, 8 * 64)
+    # weights 9.21 GB + K/V pages 5.29 GB + windows 21 MB, donated through
+    assert 14.4e9 < got["state_bytes"] < 14.6e9, got["state_bytes"]
+    header = text.split("\n", 1)[0]
+    assert header.count("-alias)") == got["n_state"]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(program, "state", got["state_bytes"], "temp", temp)
+    assert got["state_bytes"] + temp < 16.0e9, temp
+    for pool in (windows[0], kv_pools[0]):
+        elems = int(np.prod(pool.stored_shape))
+        copies = [ln for shape, op, ln in _entry_instructions(text)
+                  if op == "copy" and int(np.prod(shape)) == elems]
+        assert not copies, (pool, copies[:1])
+    calls = re.findall(
+        r"%(stf_short_conv_update_b\d+)[\w.\-]* = [^\n]*custom-call", text)
+    if program == "decode256":
+        # one window update a conv layer, and no loop over the rows
+        assert calls == ["stf_short_conv_update_b256"] * 10, calls
+        assert not re.search(r"\bwhile\(", text.split("ENTRY", 1)[1])
+    else:
+        assert not calls
+    attn = re.findall(
+        r"%(stf_decode_attention_q\d+_paged_gqa)[\w.\-]* = [^\n]*custom-call",
+        text)
+    assert attn == [f"stf_decode_attention_q{kq}_paged_gqa"] * 3, attn
