@@ -437,6 +437,34 @@ class FetchFuture:
                f"dtype={self.dtype}>"
 
 
+def _await_device(values):
+    """One explicit wait for device ``values``, the one ``np.asarray``
+    would make, so that the wait and the copy are told apart — traced or
+    not: ``session/await_device``, sampled by
+    ``/stf/session/await_device_seconds``."""
+    with monitoring.traceme("session/await_device"):
+        t0 = time.perf_counter()
+        _block_with_deadline(values, None)
+        waited = time.perf_counter() - t0
+    _metric_await_device_seconds.get_cell().add(waited)
+
+
+def materialize(fetched: Dict[Any, Any]) -> Dict[Any, Any]:
+    """Host values of a run's fetches ``{key: value}`` whose device values
+    came back as :class:`FetchFuture` (``ExecutionPlan.execute(...,
+    as_futures=True)``): one wait for all of them, then their copies,
+    under ``session/fetch`` — what the run that enqueued them does when it
+    fetches at once. A device error of that run raises here."""
+    with monitoring.traceme("session/fetch"):
+        pending = [f.device_value() for f in fetched.values()
+                   if isinstance(f, FetchFuture) and not f.materialized]
+        if pending:
+            _await_device(pending)
+        with monitoring.traceme("session/copy_to_host"):
+            return {k: f.result() if isinstance(f, FetchFuture) else f
+                    for k, f in fetched.items()}
+
+
 def get_default_session():
     stack = getattr(_default_session_stack, "stack", None)
     return stack[-1] if stack else None
@@ -2451,14 +2479,8 @@ class BaseSession:
                             None, e.op, f"Fetch {e.name} produced no value")
             if pending:
                 # the host first blocks on the step's results HERE (unless
-                # a post-host stage or a deadline already did): one
-                # explicit wait, the one np.asarray would make, so that
-                # the wait and the copy are told apart — traced or not
-                with monitoring.traceme("session/await_device"):
-                    t0 = time.perf_counter()
-                    _block_with_deadline([out[i] for i in pending], None)
-                    waited = time.perf_counter() - t0
-                _metric_await_device_seconds.get_cell().add(waited)
+                # a post-host stage or a deadline already did)
+                _await_device([out[i] for i in pending])
                 with monitoring.traceme("session/copy_to_host"):
                     for i in pending:
                         out[i] = np.asarray(out[i])

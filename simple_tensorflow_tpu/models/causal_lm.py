@@ -34,6 +34,7 @@ import contextlib
 import numpy as np
 
 import simple_tensorflow_tpu as stf
+from simple_tensorflow_tpu.client.session import materialize
 from simple_tensorflow_tpu.models import common
 from simple_tensorflow_tpu.platform import monitoring
 from simple_tensorflow_tpu.models.transformer import (
@@ -447,10 +448,19 @@ def build_paged_lm_program(stack, *, page_len, pages_per_seq, num_pages,
       DECODE step instead, so a partial final chunk just pads);
     - one DECODE program per decode bucket sb: one position through
       ``stack.decode_step`` (feeds: tok (sb,), pos (sb,) absolute,
-      page_tables (sb, n_blocks), dst_pages (sb,), offsets (sb,);
-      fetches next_tok/logp (sb,), and whatever else the stack returns
-      by name under ``extra``) — greedy, or seeded sampling when
+      page_tables (sb, n_blocks), dst_pages (sb,), offsets (sb,), slots
+      (sb,); fetches next_tok/logp (sb,), and whatever else the stack
+      returns by name under ``extra``) — greedy, or seeded sampling when
       ``sampling`` is set;
+    - ``last_tok``: the device's own record of each sequence's last
+      token, ``(max_live + 1,)`` int32 addressed by SLOT (the last row is
+      the scratch slot of padding rows; allocated under ``alloc_op``).
+      A decode program takes a row's token from it where the fed token
+      is negative — ``tok = where(tok >= 0, tok, last_tok[slot])`` — and
+      writes the row's ``next_tok`` back into it. So the host can
+      enqueue step k + 1 before it has fetched step k's tokens: it feeds
+      -1 for a continuing sequence and a token only for a sequence's
+      first step;
     - ``cow``: the copy-on-write program — ``KVCachePageCopy`` over
       EVERY paged cache of every layer (feeds dst (1,), src (1,)): a
       sequence diverging inside a shared page copies it before private
@@ -459,10 +469,11 @@ def build_paged_lm_program(stack, *, page_len, pages_per_seq, num_pages,
     A stack whose ``layer_caches`` names a ``StatePool`` — state addressed
     by SLOT beside the pages, ``(max_live + 1, *inner)`` with the last row
     the scratch slot of padding rows (``stack.layer_caches`` is then
-    called with ``state_slots=max_live + 1``) — gets two more feeds, and
-    only such a stack does: ``slots (rows,)`` in both programs, each
-    row's sequence's slot, and ``lens (pb,)`` in prefill, the real tokens
-    of each row's chunk. Rows of one prefill call are walked IN THE ORDER
+    called with ``state_slots=max_live + 1``) — gets two more feeds in
+    prefill, and only such a stack does: ``slots (pb,)``, each row's
+    sequence's slot, and ``lens (pb,)``, the real tokens of each row's
+    chunk (``max_live`` defaults to the largest decode bucket). Rows of
+    one prefill call are walked IN THE ORDER
     GIVEN by the layers that keep state (``ops/ssm_ops.py``). A state
     pool is allocated under ``alloc_op`` with the pages and is in no
     ``cow``. ``state_pools`` in the result says whether there are any.
@@ -502,6 +513,8 @@ def build_paged_lm_program(stack, *, page_len, pages_per_seq, num_pages,
     prefill_buckets = sorted(set(int(x) for x in (
         prefill_bucket_sizes or (1,))))
 
+    if max_live is None:
+        max_live = decode_buckets[-1]
     if getattr(stack, "keeps_state", False):
         caches = stack.layer_caches(kvc, total_pages, page_len,
                                     cache_sharding,
@@ -511,7 +524,11 @@ def build_paged_lm_program(stack, *, page_len, pages_per_seq, num_pages,
                                     cache_sharding)
     flat_caches = [c for group in caches for c in group]
     state_pools = any(not c.paged for c in flat_caches)
-    alloc_op = stf.group(*[c.alloc() for c in flat_caches],
+    # a (slots, 1) int32 cache read and written by slot at position 0:
+    # the one value a sequence keeps there is its last token
+    last_tok = kvc.kv_cache(f"{scope}_pg/last_tok", int(max_live) + 1, 1,
+                            (), stf.int32)
+    alloc_op = stf.group(*[c.alloc() for c in flat_caches + [last_tok]],
                          name="pg_alloc")
 
     if sampling is not None:
@@ -574,15 +591,20 @@ def build_paged_lm_program(stack, *, page_len, pages_per_seq, num_pages,
                                     f"lm_decode{sb}_dst"))
         off = _feed(stf.placeholder(stf.int32, [sb],
                                     f"lm_decode{sb}_off"))
-        by_slot = {}
-        if state_pools:
-            by_slot = {"slots": _feed(stf.placeholder(
-                stf.int32, [sb], f"lm_decode{sb}_slots"))}
+        slots = _feed(stf.placeholder(stf.int32, [sb],
+                                      f"lm_decode{sb}_slots"))
+        by_slot = {"slots": slots} if state_pools else {}
         cache = _PagedCaches(caches, tables, dst, off, pos, **by_slot)
-        logits, extra = stack.decode_step(tok, pos, cache)
+        held = stf.reshape(last_tok.gather(slots), [sb])
+        logits, extra = stack.decode_step(
+            stf.where(stf.greater_equal(tok, 0), tok, held), pos, cache)
         next_tok, logp = _emit(logits)
+        wrote = last_tok.append(stf.reshape(next_tok, [sb, 1]), slots,
+                                np.zeros((sb,), np.int32))
+        with stf.control_dependencies([wrote.op]):
+            next_tok = stf.identity(next_tok)
         decode_progs[sb] = {"tok": tok, "pos": pos, "tables": tables,
-                            "dst": dst, "off": off, **by_slot,
+                            "dst": dst, "off": off, "slots": slots,
                             "next_tok": next_tok, "logp": logp,
                             "logits": logits, "extra": extra}
 
@@ -602,10 +624,22 @@ def build_paged_lm_program(stack, *, page_len, pages_per_seq, num_pages,
         "prefill_buckets": prefill_buckets,
         "scratch_page": scratch_page,
         "caches": caches,
+        "last_tok": last_tok,
         "state_pools": state_pools,
         "cache_sharding": cache_sharding,
         "tp_axis": tp_axis,
     }
+
+
+class _DecodeCall:
+    """A decode step enqueued and not yet collected: the plan's fetches
+    as futures, the live rows, their positions and the bucket."""
+
+    __slots__ = ("out", "n", "positions", "bucket")
+
+    def __init__(self, out, n, positions, bucket):
+        self.out, self.n = out, n
+        self.positions, self.bucket = positions, bucket
 
 
 class CausalLMGenerativeModel:
@@ -615,8 +649,9 @@ class CausalLMGenerativeModel:
     The engine (serving/generative.py) owns the page-table bookkeeping
     through :class:`~..serving.prefix_cache.PrefixCache`; this model
     exposes the device half: ``prefill_chunk`` (one page-aligned block
-    per live row), ``decode`` (one position; physical page/offset
-    resolved from the page table HERE, host-side), ``copy_page`` (CoW),
+    per live row), ``decode_async`` / ``collect`` (one position, enqueued,
+    then fetched; physical page/offset resolved from the page table HERE,
+    host-side; ``decode`` is the two at once), ``copy_page`` (CoW),
     and the ``page_len / num_pages / pages_per_seq / scratch_page``
     geometry the pool is sized against.
     """
@@ -675,7 +710,7 @@ class CausalLMGenerativeModel:
             self.session = stf.Session(graph=self.graph, config=config)
             prog = self._build_program(
                 page_len=page_len, pages_per_seq=pages_per_seq,
-                num_pages=num_pages,
+                num_pages=num_pages, max_live=self.num_slots,
                 decode_bucket_sizes=(decode_bucket_sizes
                                      or tuple(sorted({1, max_live}))),
                 prefill_bucket_sizes=prefill_bucket_sizes,
@@ -712,7 +747,7 @@ class CausalLMGenerativeModel:
                     {"next_tok": p["next_tok"], "logp": p["logp"],
                      **p["extra"]},
                     feeds=[p["tok"], p["pos"], p["tables"], p["dst"],
-                           p["off"]] + [p[k] for k in ("slots",) if k in p])
+                           p["off"], p["slots"]])
                 self._decode_plans[sb] = (plan, p)
                 if aot_warmup:
                     plan.compile()
@@ -778,14 +813,14 @@ class CausalLMGenerativeModel:
         raise ValueError(f"{n} rows exceed the largest bucket "
                          f"{buckets[-1]}")
 
-    def _run(self, plan, feed):
+    def _run(self, plan, feed, as_futures=None):
         """Execute under the model's mesh scope (thread-local; the
         engine's scheduler thread is not inside the construction-time
         ``with mesh:``)."""
         if self._mesh is None:
-            return plan.execute(feed)
+            return plan.execute(feed, as_futures=as_futures)
         with self._mesh:
-            return plan.execute(feed)
+            return plan.execute(feed, as_futures=as_futures)
 
     def tp_info(self):
         """Decode-TP facts for telemetry (/stf/serving/tp_*)."""
@@ -888,42 +923,60 @@ class CausalLMGenerativeModel:
             calls += 1
         return calls
 
-    def decode(self, tokens, positions, page_tables, slots=None):
-        """One decode position for n live sequences; the physical write
-        target is resolved host-side from each row's page table:
-        ``dst = page_tables[i, pos // page_len]``, ``off = pos %
-        page_len``. A model with state outside its pages also takes each
-        sequence's ``slots (n,)``. Returns (next_tok (n,), logp (n,),
-        bucket)."""
+    def decode_async(self, tokens, positions, page_tables, slots=None):
+        """Enqueue one decode position for n live sequences and return
+        at once with a handle for :meth:`collect`. The physical write
+        target is resolved host-side from each row's page table: ``dst =
+        page_tables[i, pos // page_len]``, ``off = pos % page_len``.
+        ``slots (n,)`` are the sequences' slots: a row whose token is
+        negative takes the token that the step before left for its slot
+        on the device (``last_tok`` of :func:`build_paged_lm_program`),
+        and every row writes its own there. Without ``slots`` every row
+        uses the scratch slot, so each token must be given; a model with
+        state outside its pages always needs them."""
         with monitoring.traceme("model/decode_feeds"):
             tokens = np.asarray(tokens, np.int32)
             positions = np.asarray(positions, np.int32)
             page_tables = np.asarray(page_tables, np.int32).reshape(
                 -1, self.pages_per_seq)
             n = len(tokens)
+            if slots is None and self.state_outside_pages:
+                raise ValueError(
+                    f"{type(self).__name__} keeps state outside its "
+                    "pages: decode needs each sequence's slot")
             sb = self._bucket(self._decode_buckets, n)
             plan, p = self._decode_plans[sb]
             tok = np.full((sb,), self.pad_id, np.int32)
             pos = np.zeros((sb,), np.int32)
             tbl = self._scratch_tables(sb)
+            slot = np.full((sb,), self.scratch_slot_row, np.int32)
             tok[:n], pos[:n], tbl[:n] = tokens, positions, page_tables
+            if slots is not None:
+                slot[:n] = slots
             dst = tbl[np.arange(sb), pos // self.page_len]
             off = pos % self.page_len
             feed = {p["tok"]: tok, p["pos"]: pos, p["tables"]: tbl,
-                    p["dst"]: dst, p["off"]: off.astype(np.int32)}
-            if self.state_outside_pages:
-                if slots is None:
-                    raise ValueError(
-                        f"{type(self).__name__} keeps state outside its "
-                        "pages: decode needs each sequence's slot")
-                slot = np.full((sb,), self.scratch_slot_row, np.int32)
-                slot[:n] = slots
-                feed[p["slots"]] = slot
-        out = self._run(plan, feed)
+                    p["dst"]: dst, p["off"]: off.astype(np.int32),
+                    p["slots"]: slot}
+        return _DecodeCall(self._run(plan, feed, as_futures=True), n,
+                           positions, sb)
+
+    def collect(self, call):
+        """Wait for a step :meth:`decode_async` enqueued and fetch what it
+        computed: ``(next_tok (n,), logp (n,), bucket)``. A device error
+        of the step raises here."""
+        out = materialize(call.out)
         with monitoring.traceme("model/after_decode"):
-            self._after_decode(out, n, positions)
-            return (np.asarray(out["next_tok"])[:n],
-                    np.asarray(out["logp"])[:n], sb)
+            self._after_decode(out, call.n, call.positions)
+            return (out["next_tok"][:call.n], out["logp"][:call.n],
+                    call.bucket)
+
+    def decode(self, tokens, positions, page_tables, slots=None):
+        """One decode position for n live sequences, enqueued and
+        collected (:meth:`decode_async`, :meth:`collect`). Returns
+        (next_tok (n,), logp (n,), bucket)."""
+        return self.collect(self.decode_async(tokens, positions,
+                                              page_tables, slots))
 
     def copy_page(self, dst, src):
         """Copy-on-write: duplicate physical page ``src`` into ``dst``

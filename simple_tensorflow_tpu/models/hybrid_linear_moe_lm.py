@@ -505,8 +505,7 @@ class HybridLinearMoEGenerativeModel(CausalLMGenerativeModel):
         return build_paged_lm_program(
             _HybridLinearMoEStack(self.cfg, compute_dtype, scope,
                                   kw["page_len"]),
-            compute_dtype=compute_dtype, scope=scope,
-            max_live=self.num_slots, **kw)
+            compute_dtype=compute_dtype, scope=scope, **kw)
 
     def _after_decode(self, out, n, positions):
         cfg, size = self.cfg, self._compute_dtype.size

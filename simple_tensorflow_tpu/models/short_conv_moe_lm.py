@@ -291,8 +291,7 @@ class ShortConvMoEGenerativeModel(CausalLMGenerativeModel):
     def _build_program(self, *, compute_dtype, scope, tp_axis, **kw):
         return build_paged_lm_program(
             _ShortConvMoEStack(self.cfg, compute_dtype, scope),
-            compute_dtype=compute_dtype, scope=scope,
-            max_live=self.num_slots, **kw)
+            compute_dtype=compute_dtype, scope=scope, **kw)
 
     def _after_decode(self, out, n, positions):
         super()._after_decode(out, n, positions)       # live page share

@@ -355,8 +355,7 @@ class StateSpaceMoEGenerativeModel(CausalLMGenerativeModel):
     def _build_program(self, *, compute_dtype, scope, tp_axis, **kw):
         return build_paged_lm_program(
             _StateSpaceMoEStack(self.cfg, compute_dtype, scope),
-            compute_dtype=compute_dtype, scope=scope,
-            max_live=self.num_slots, **kw)
+            compute_dtype=compute_dtype, scope=scope, **kw)
 
     def _after_decode(self, out, n, positions):
         super()._after_decode(out, n, positions)       # live page share

@@ -22,6 +22,10 @@ scheduler thread:
 - a sequence RETIRES the step it emits EOS, exhausts its token budget,
   or blows its deadline — its slot returns to the free-list and the
   batch keeps going without it. Deadlines are re-checked every token.
+  The paged path enqueues step k + 1 before it fetches step k (the
+  device keeps each slot's last token, so step k + 1 needs no token
+  from the host), so there an EOS is seen one step late and that step's
+  token for it is dropped; budgets are exact (``_step_paged``).
 
 Because the decode program is static per bucket and every row reads
 only its own slot's cache, a sequence's tokens are BIT-IDENTICAL
@@ -31,7 +35,11 @@ tests/test_generative.py).
 Model interface (duck-typed): ``prefill(src_rows, slots)``,
 ``decode(tokens, positions, slots) -> (next_tok, logp, bucket)``,
 ``close()``, attrs ``eos_id / pad_id / num_slots / max_decode_len /
-src_len``.
+src_len``. A paged model (``page_len``) gives ``prefill_chunk`` and
+``copy_page`` instead of ``prefill``, and ``decode_async(tokens,
+positions, page_tables, slots=)`` -> a handle with ``bucket``, whose
+``collect(handle)`` -> ``(next_tok, logp, bucket)``; a token < 0 is the
+one the device holds for the slot.
 
 Two decode-throughput extensions ride the same scheduler loop:
 
@@ -111,6 +119,13 @@ _metric_prefill_seconds = monitoring.Sampler(
     "/stf/serving/decode_prefill_seconds",
     monitoring.ExponentialBuckets(1e-5, 2.0, 22),
     "Seconds encoding joining prompts into their cache slots", "model")
+_metric_overlap = monitoring.Sampler(
+    "/stf/serving/decode_overlap",
+    monitoring.ExplicitBuckets([0.5]),
+    "Per decode step of the paged path: 1 when it was enqueued while the "
+    "engine's previous decode step was still unfetched, else 0 (the "
+    "mean is the share of steps the host prepared behind the device)",
+    "model")
 _metric_fill = monitoring.Sampler(
     "/stf/serving/decode_fill",
     monitoring.ExplicitBuckets(
@@ -267,7 +282,7 @@ class _Sequence:
 
     __slots__ = ("req", "slot", "tokens", "logps", "pos", "last_tok",
                  "budget", "t_start", "pages", "node", "private",
-                 "cow_blk")
+                 "cow_blk", "asked", "done")
 
     def __init__(self, req: GenerateRequest, slot: int, first_tok: int,
                  budget: int):
@@ -285,6 +300,21 @@ class _Sequence:
         # page-table block holding the trie-resident (shared) tail
         # page: the first decode append into it copies-on-write
         self.cow_blk: Optional[int] = None
+        # decode steps enqueued for it whose tokens are not yet delivered
+        # (``pos`` is the position the NEXT step feeds), and whether it
+        # has retired — a step already enqueued may still carry it
+        self.asked = 0
+        self.done = False
+
+
+class _Enqueued:
+    """The paged path's decode step in flight: the model's handle, the
+    sequences that ride it in row order, and when it was enqueued."""
+
+    __slots__ = ("call", "rows", "t0")
+
+    def __init__(self, call, rows, t0):
+        self.call, self.rows, self.t0 = call, rows, t0
 
 
 class GenerativeEngine:
@@ -395,6 +425,7 @@ class GenerativeEngine:
         self._step_s = _metric_step_seconds.get_cell(name)
         self._prefill_s = _metric_prefill_seconds.get_cell(name)
         self._fill = _metric_fill.get_cell(name)
+        self._overlap = _metric_overlap.get_cell(name)
         self._slots_gauge = _metric_slots.get_cell(name)
         self._per_token = _metric_per_token.get_cell(name)
         self._prefix_hits = _metric_prefix_hits.get_cell(name)
@@ -415,6 +446,10 @@ class GenerativeEngine:
             _metric_tp_collective.get_cell(name).set(
                 int(self._tp_info["per_token_collective_bytes"]))
         self._spec_counts = [0, 0]        # lifetime [proposed, accepted]
+        # the paged path's decode step enqueued and not yet fetched, and
+        # when the last step was fetched
+        self._inflight: Optional[_Enqueued] = None
+        self._t_collect = 0.0
         self._prefix_seen = [0, 0]        # last synced [hits, evictions]
         self._closed = False
         self._thread = threading.Thread(
@@ -538,7 +573,7 @@ class GenerativeEngine:
                 # retry inside _admit_batch rejects instead of looping
                 hb, self._holdback = self._holdback, []
                 self._admit_batch(hb)
-            if not self._active:
+            if not self._active and self._inflight is None:
                 with monitoring.traceme("engine/wait"):
                     item = self._queue.get()
                 if item is _DONE:
@@ -551,15 +586,18 @@ class GenerativeEngine:
                 joiners = self._queue.get_available(self._pool.free_count)
                 if joiners:
                     self._admit_batch(joiners)
-            if self._active:
+            if self._active or self._inflight is not None:
                 try:
                     self._step()
                 except BaseException as e:  # noqa: BLE001 — deliver, never die
                     _flight_mod.get_recorder().on_error(
                         e, where="serving_decode_step", model=self.name)
+                    # the live rows of the step in flight and of the one
+                    # being enqueued or fetched: all of them are here
                     for s in self._active:
                         self._retire(s, "error", exc=e)
                     self._active = []
+                    self._inflight = None
                     self._slots_gauge.set(0)
 
     def _admit_batch(self, items):
@@ -765,24 +803,14 @@ class GenerativeEngine:
             self._advance()
 
     def _advance(self):
-        # per-token deadline check: an expired sequence retires NOW —
-        # it never stalls or rides another step
-        now = time.perf_counter()
-        still = []
-        for s in self._active:
-            if s.req.expired(now):
-                self._retire(s, "deadline_exceeded")
-            else:
-                still.append(s)
-        self._active = still
+        if self._paged:
+            self._step_paged()
+            return
+        self._expire()
         if not self._active:
-            self._slots_gauge.set(0)
             return
         if self._spec_enabled:
             self._step_speculative()
-            return
-        if self._paged:
-            self._step_paged()
             return
         n = len(self._active)
         tokens = [s.last_tok for s in self._active]
@@ -800,39 +828,65 @@ class GenerativeEngine:
                 slots = slots + [self._scratch_slot] * pad
         self._decode_step(n, tokens, positions, slots)
 
-    def _decode_step(self, n, tokens, positions, where, **by_slot):
-        """One decode position for the ``n`` live sequences (slot and
-        paged steps both land here), then the shared commit: metrics,
-        streaming, EOS/budget retirement. ``engine/decode`` spans what
-        ``/stf/serving/decode_step_seconds`` samples. ``by_slot``: the
-        sequences' ``slots`` beside their page tables, for a paged model
-        with state outside its pages."""
+    def _expire(self):
+        """Per-token deadline check: an expired sequence retires NOW — it
+        never stalls or rides another step (a step in flight that carries
+        it drops its token)."""
+        now = time.perf_counter()
+        still = []
+        for s in self._active:
+            if s.req.expired(now):
+                self._retire(s, "deadline_exceeded")
+            else:
+                still.append(s)
+        self._active = still
+        self._slots_gauge.set(len(still))
+
+    def _wants(self, s: _Sequence) -> bool:
+        """Whether ``s`` rides the next step: tokens of its budget not yet
+        asked for, and a position left. Known before the tokens of the
+        steps in flight are, so a sequence at its budget never rides a
+        step it has no use for."""
+        return (len(s.tokens) + s.asked < s.budget
+                and s.pos < self._model.max_decode_len)
+
+    def _decode_step(self, n, tokens, positions, slots):
+        """One decode position for the ``n`` live sequences of the slot
+        path, computed and fetched at once, then the commit the paged path
+        shares. ``engine/decode`` spans what
+        ``/stf/serving/decode_step_seconds`` samples."""
+        for s in self._active:
+            s.pos += 1
+            s.asked += 1
         t0 = time.perf_counter()
         with monitoring.traceme("engine/decode", live=n) as sp:
             next_tok, logp, bucket = self._model.decode(tokens, positions,
-                                                        where, **by_slot)
+                                                        slots)
             sp.set_meta(bucket=bucket)
         dur = time.perf_counter() - t0
         self._step_s.add(dur)
         self._fill.add(n / max(bucket, 1))
-        self._tokens.increase_by(n)
-        self._rate.add(n)
-        self._rate_gauge.set(int(self._rate.rate()))
-        rec = _flight_mod.get_recorder()
-        if rec.enabled:
-            rec.record("decode_step", model=self.name, live=n,
-                       bucket=bucket, step_s=round(dur, 6))
+        self._commit(self._active, next_tok, logp, bucket, dur)
+
+    def _commit(self, rows, next_tok, logp, bucket, dur):
+        """A fetched step's tokens to the sequences that rode it, in row
+        order: metrics, streaming, EOS/budget retirement. A row whose
+        sequence retired after the step was enqueued (an end token seen
+        one step late, a deadline, an error) is past its end: its token
+        is dropped."""
         eos = self._model.eos_id
-        max_pos = self._model.max_decode_len - 1
-        still = []
+        n = 0
         with monitoring.traceme("engine/deliver"):
-            for i, s in enumerate(self._active):
+            for i, s in enumerate(rows):
+                if s.done:
+                    continue
+                s.asked -= 1
                 tok = int(next_tok[i])
                 lp = float(logp[i])
                 s.tokens.append(tok)
                 s.logps.append(lp)
-                s.pos += 1
                 s.last_tok = tok
+                n += 1
                 if s.req.on_token is not None:
                     try:
                         s.req.on_token(tok, lp)
@@ -840,69 +894,114 @@ class GenerativeEngine:
                         pass
                 if tok == eos:
                     self._retire(s, "eos")
-                elif len(s.tokens) >= s.budget or s.pos > max_pos:
+                elif not s.asked and not self._wants(s):
                     self._retire(s, "length")
-                else:
-                    still.append(s)
-        self._active = still
-        self._slots_gauge.set(len(still))
+            self._active = [s for s in self._active if not s.done]
+        self._slots_gauge.set(len(self._active))
+        self._tokens.increase_by(n)
+        self._rate.add(n)
+        self._rate_gauge.set(int(self._rate.rate()))
+        rec = _flight_mod.get_recorder()
+        if rec.enabled:
+            rec.record("decode_step", model=self.name, live=n,
+                       bucket=bucket, step_s=round(dur, 6))
 
     def _step_paged(self):
-        """One decode position on the paged path: make sure every
-        sequence's write page exists (allocating private decode pages
-        lazily, page-fault style), then run the page-table decode."""
+        """One step of the paged path, PIPELINED: the rows of step k + 1
+        — every sequence with tokens left to ask for, which the host
+        knows before it has step k's tokens — get their write pages
+        (private decode pages allocated lazily, page-fault style), step
+        k + 1 is enqueued, its tokens left for the device to take from
+        what step k writes (``decode_async``: -1 for every sequence but
+        at its first step), and only then is step k fetched and
+        delivered; admission then runs while step k + 1 does. So an end
+        token is seen one step late: the sequence already rides step
+        k + 1, whose token for it is dropped; its slot and pages go back
+        at once, and only work enqueued after step k + 1 reuses them. A
+        sequence whose budget the step enqueued spends gives them back
+        then, so the joiners admitted next fill its row of step k + 2.
+        With nothing to enqueue, the step in flight is fetched at once."""
+        self._expire()
+        prev = self._inflight
+        with monitoring.traceme("engine/page_faults"):
+            ride = self._page_faults(
+                [s for s in self._active if self._wants(s)])
+        t0 = time.perf_counter()
+        with monitoring.traceme("engine/decode", live=len(ride)) as sp:
+            self._inflight = None
+            if ride:
+                call = self._model.decode_async(
+                    [-1 if s.asked else s.last_tok for s in ride],
+                    [s.pos for s in ride],
+                    np.stack([s.pages for s in ride]),
+                    slots=[s.slot for s in ride])
+                self._overlap.add(float(prev is not None))
+                self._fill.add(len(ride) / max(call.bucket, 1))
+                for s in ride:
+                    s.pos += 1
+                    s.asked += 1
+                    if not self._wants(s):
+                        # its last step: the slot and pages go to the
+                        # joiners admitted next, which ride step k + 2
+                        self._free(s)
+                self._inflight = _Enqueued(call, ride, t0)
+                sp.set_meta(bucket=call.bucket)
+            if prev is not None:
+                next_tok, logp, bucket = self._model.collect(prev.call)
+        if prev is not None:
+            now = time.perf_counter()
+            # a step on the wall: from the fetch before (or, after an
+            # idle stretch, its own enqueue) to its own fetch
+            dur = now - max(self._t_collect, prev.t0)
+            self._t_collect = now
+            self._step_s.add(dur)
+            self._commit(prev.rows, next_tok, logp, bucket, dur)
+
+    def _page_faults(self, rows):
+        """Make sure each of ``rows`` has the page its next position
+        writes; a sequence that cannot get one retires with an error.
+        Returns the rows that can ride."""
         from .prefix_cache import PagesExhaustedError
 
-        with monitoring.traceme("engine/page_faults"):
-            pl = self._model.page_len
-            scratch = self._model.scratch_page
-            still = []
-            for s in self._active:
-                blk = s.pos // pl
-                if s.pages[blk] == scratch:
-                    try:
-                        pg = self._prefix.alloc_page()
-                    except PagesExhaustedError as e:
-                        # every page is held by live sequences: this one
-                        # cannot advance — fail it rather than stall all
-                        self._retire(s, "error",
-                                     exc=errors.ResourceExhaustedError(
-                                         None, None,
-                                         f"model {self.name!r}: out of "
-                                         f"cache pages mid-decode ({e})"))
-                        continue
-                    s.pages[blk] = pg
-                    s.private.append(pg)
-                elif s.cow_blk is not None and blk == s.cow_blk:
-                    # first decode append into the trie-resident tail page:
-                    # copy-on-write so the shared rows stay pristine for
-                    # the next exact-tail hit
-                    shared = int(s.pages[blk])
-                    try:
-                        pg = self._prefix.alloc_page({shared})
-                    except PagesExhaustedError as e:
-                        self._retire(s, "error",
-                                     exc=errors.ResourceExhaustedError(
-                                         None, None,
-                                         f"model {self.name!r}: out of "
-                                         f"cache pages mid-decode ({e})"))
-                        continue
-                    self._model.copy_page(pg, shared)
-                    s.pages[blk] = pg
-                    s.private.append(pg)
-                    s.cow_blk = None
-                still.append(s)
-            self._active = still
-            if not self._active:
-                self._slots_gauge.set(0)
-                return
-            by_slot = ({"slots": [s.slot for s in self._active]}
-                       if self._by_slot else {})
-            rows = (len(self._active),
-                    [s.last_tok for s in self._active],
-                    [s.pos for s in self._active],
-                    np.stack([s.pages for s in self._active]))
-        self._decode_step(*rows, **by_slot)
+        pl = self._model.page_len
+        scratch = self._model.scratch_page
+        for s in rows:
+            blk = s.pos // pl
+            if s.pages[blk] == scratch:
+                try:
+                    pg = self._prefix.alloc_page()
+                except PagesExhaustedError as e:
+                    # every page is held by live sequences: this one
+                    # cannot advance — fail it rather than stall all
+                    self._retire(s, "error",
+                                 exc=errors.ResourceExhaustedError(
+                                     None, None,
+                                     f"model {self.name!r}: out of "
+                                     f"cache pages mid-decode ({e})"))
+                    continue
+                s.pages[blk] = pg
+                s.private.append(pg)
+            elif s.cow_blk is not None and blk == s.cow_blk:
+                # first decode append into the trie-resident tail page:
+                # copy-on-write so the shared rows stay pristine for
+                # the next exact-tail hit
+                shared = int(s.pages[blk])
+                try:
+                    pg = self._prefix.alloc_page({shared})
+                except PagesExhaustedError as e:
+                    self._retire(s, "error",
+                                 exc=errors.ResourceExhaustedError(
+                                     None, None,
+                                     f"model {self.name!r}: out of "
+                                     f"cache pages mid-decode ({e})"))
+                    continue
+                self._model.copy_page(pg, shared)
+                s.pages[blk] = pg
+                s.private.append(pg)
+                s.cow_blk = None
+        self._active = [s for s in self._active if not s.done]
+        self._slots_gauge.set(len(self._active))
+        return [s for s in rows if not s.done]
 
     def _step_speculative(self):
         """One speculative cycle: the draft proposes ``draft_steps``
@@ -982,8 +1081,13 @@ class GenerativeEngine:
                        bucket=bucket, step_s=round(dur, 6),
                        spec_emitted=emitted_total)
 
-    def _retire(self, s: _Sequence, outcome: str,
-                exc: Optional[BaseException] = None):
+    def _free(self, s: _Sequence):
+        """Give ``s``'s slot and pages back: at retirement, or on the paged
+        path as soon as the last step it rides is enqueued — only work
+        enqueued after that step (a joiner's prefill, its decode steps)
+        reuses them, and the device runs it after."""
+        if s.slot is None:
+            return
         if s.pages is not None:
             # decref the shared trie chain (pages stay cached at refs
             # 0 for future prefix hits) and free the private pages
@@ -996,6 +1100,12 @@ class GenerativeEngine:
             s.pages = None
             self._sync_prefix_metrics()
         self._pool.release(s.slot)
+        s.slot = None
+
+    def _retire(self, s: _Sequence, outcome: str,
+                exc: Optional[BaseException] = None):
+        s.done = True
+        self._free(s)
         _metric_sequences.get_cell(self.name, outcome).increase_by(1)
         if s.tokens:
             self._per_token.add(

@@ -1051,7 +1051,8 @@ class TestAdmissionPacksRows:
             pos = chunks * PAGE_LEN
             logits.append(model.session.run(p["logits"], {
                 p["tok"]: [7], p["pos"]: [pos], p["tables"]: table,
-                p["dst"]: table[:, pos // PAGE_LEN], p["off"]: [0]}))
+                p["dst"]: table[:, pos // PAGE_LEN], p["off"]: [0],
+                p["slots"]: [model.scratch_slot_row]}))
         model.close()
         np.testing.assert_array_equal(logits[1], logits[0])
 

@@ -90,7 +90,8 @@ def _decode_logits(model, tok, pos, tables):
     feed = {p["tok"]: np.asarray([tok], np.int32),
             p["pos"]: np.asarray([pos], np.int32), p["tables"]: tables,
             p["dst"]: tables[:, pos // PAGE],
-            p["off"]: np.asarray([pos % PAGE], np.int32)}
+            p["off"]: np.asarray([pos % PAGE], np.int32),
+            p["slots"]: np.asarray([model.scratch_slot_row], np.int32)}
     logits, nxt = model.session.run([p["logits"], p["next_tok"]], feed)
     return logits[0], int(nxt[0])
 
@@ -532,7 +533,9 @@ class TestServed:
         assert cfg.latent_row == 128      # 24 + 8 padded to a lane tile
         assert len(_ops(model, "KVCachePageCopy")) == cfg.num_layers
         # decode and prefill programs read the pool in place: no gather
-        assert not _ops(model, "KVCacheGather")
+        # but of the decode rows' last tokens
+        assert [op.attrs["var_name"] for op in _ops(model, "KVCacheGather")
+                ] == ["causal_lm_pg/last_tok"] * len(model.decode_buckets)
         assert len(_ops(model, "PagedLatentAttention")) == cfg.num_layers * (
             len(model.decode_buckets) + len(model.prefill_buckets))
 
@@ -594,7 +597,8 @@ class TestServed:
         tok[:2], pos[:2], tbl[:2] = [model.pad_id, 7], [15, 23], tables
         out = model._run(plan, {
             p["tok"]: tok, p["pos"]: pos, p["tables"]: tbl,
-            p["dst"]: tbl[np.arange(4), pos // PAGE], p["off"]: pos % PAGE})
+            p["dst"]: tbl[np.arange(4), pos // PAGE], p["off"]: pos % PAGE,
+            p["slots"]: np.full(4, model.scratch_slot_row, np.int32)})
         counts = np.asarray(out["expert_counts"])
         assert counts.shape == (cfg.num_layers - cfg.dense_layers,
                                 cfg.held[1])

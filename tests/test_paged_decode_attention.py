@@ -404,7 +404,12 @@ class TestLint:
             programs = 2 + 2                 # decode buckets + prefill
             assert types.count("PagedDecodeAttention") \
                 == programs * cfg.num_layers
-            assert "KVCacheGather" not in types
+            # no pool is gathered: the one gather of a decode program
+            # reads its rows' last tokens
+            gathers = [op for op in ops if op.type == "KVCacheGather"]
+            assert len(gathers) == 2
+            assert all(op.attrs["var_name"].endswith("/last_tok")
+                       for op in gathers)
             assert "DecodeAttention" not in types
             with model.graph.as_default():
                 diags = analysis.lint_graph(purpose="serving",

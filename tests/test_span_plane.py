@@ -255,9 +255,9 @@ def test_engine_spans_nest_on_the_engines_thread(tmp_path):
 def test_a_paged_step_nests_the_model_and_the_session(tmp_path):
     """The engine's thread, a paged causal LM: ``engine/step`` holds the
     page faults, the decode call and the delivery; ``engine/decode`` the
-    model's feed building, its one ``session/run`` and what it does with
-    the results; ``engine/prefill`` the model's row building and its
-    call."""
+    model's feed building and its one ``session/run`` that enqueues step
+    k + 1, then the fetch of step k and what the model does with its
+    results; ``engine/prefill`` the model's row building and its call."""
     from simple_tensorflow_tpu.models import causal_lm, transformer
 
     model = causal_lm.CausalLMGenerativeModel(
@@ -275,18 +275,25 @@ def test_a_paged_step_nests_the_model_and_the_session(tmp_path):
     (events,) = [evs for evs in lines.values()
                  if any(ev[0] == "stf/engine/step" for ev in evs)]
     steps = [ev for ev in events if ev[0] == "stf/engine/step"]
-    assert 2 <= len(steps) <= 4     # an end token may cut the answer short
-    for step in steps:
+    # a step enqueues a position and fetches the one before: one step
+    # more than tokens (an end token may cut the answer short)
+    assert 3 <= len(steps) <= 5
+    for i, step in enumerate(steps):
         direct = [name for name in _children(events, step)
                   if name.startswith("stf/engine/")]
         assert direct == ["stf/engine/page_faults", "stf/engine/decode",
-                          "stf/engine/deliver"]
-    run = ["stf/session/" + phase for phase in (
-        "run", "prepare", "stage_feeds", "device_execute", "commit",
-        "assemble", "fetch", "await_device", "copy_to_host", "assemble")]
-    for decode in (ev for ev in events if ev[0] == "stf/engine/decode"):
-        assert _children(events, decode) == [
-            "stf/model/decode_feeds", *run, "stf/model/after_decode"]
+                          "stf/engine/deliver"][:2 if i == 0 else 3]
+    enqueue = ["stf/model/decode_feeds"] + ["stf/session/" + phase for phase
+                                            in ("run", "prepare",
+                                                "stage_feeds",
+                                                "device_execute", "commit",
+                                                "assemble", "fetch",
+                                                "assemble")]
+    fetch = ["stf/session/fetch", "stf/session/await_device",
+             "stf/session/copy_to_host", "stf/model/after_decode"]
+    decodes = [ev for ev in events if ev[0] == "stf/engine/decode"]
+    assert [_children(events, decode) for decode in decodes] == \
+        [enqueue] + [enqueue + fetch] * (len(decodes) - 2) + [fetch]
     # a page fault that copies-on-write runs the model's copy_page: the
     # span is the parent of that session/run, the decode span's is not in it
     faults = [ev for ev in events if ev[0] == "stf/engine/page_faults"]

@@ -82,7 +82,8 @@ def _decode_logits(model, tok, pos, tables):
     feed = {p["tok"]: np.asarray([tok], np.int32),
             p["pos"]: np.asarray([pos], np.int32), p["tables"]: tables,
             p["dst"]: tables[:, pos // PAGE],
-            p["off"]: np.asarray([pos % PAGE], np.int32)}
+            p["off"]: np.asarray([pos % PAGE], np.int32),
+            p["slots"]: np.asarray([model.scratch_slot_row], np.int32)}
     logits, nxt = model.session.run([p["logits"], p["next_tok"]], feed)
     return logits[0], int(nxt[0])
 
@@ -378,7 +379,8 @@ class TestServed:
         tok[:2], pos[:2], tbl[:2] = [model.pad_id, 7], [15, 23], tables
         out = model._run(plan, {
             p["tok"]: tok, p["pos"]: pos, p["tables"]: tbl,
-            p["dst"]: tbl[np.arange(4), pos // PAGE], p["off"]: pos % PAGE})
+            p["dst"]: tbl[np.arange(4), pos // PAGE], p["off"]: pos % PAGE,
+            p["slots"]: np.full(4, model.scratch_slot_row, np.int32)})
         counts = np.asarray(out["expert_counts"])
         assert counts.shape == (cfg.num_layers, cfg.num_experts)
         np.testing.assert_array_equal(

@@ -754,7 +754,8 @@ class TestServed:
         # copy-on-write copies pages, not state; every pool is allocated
         assert len(_ops(model, "KVCachePageCopy")) == 2
         assert len(_ops(model, "StatePoolAlloc")) == 2 * 3
-        assert len(_ops(model, "KVCacheAlloc")) == 2
+        # K and V of the one attention layer, and the last-token buffer
+        assert len(_ops(model, "KVCacheAlloc")) == 2 + 1
         programs = len(model.decode_buckets) + len(model.prefill_buckets)
         assert len(_ops(model, "CausalConv1D")) == 3 * programs
         assert len(_ops(model, "SSMStateUpdate")) == 3 * len(
@@ -762,7 +763,10 @@ class TestServed:
         assert len(_ops(model, "SSMChunkScan")) == 3 * len(
             model.prefill_buckets)
         assert len(_ops(model, "PagedDecodeAttention")) == programs
-        assert not _ops(model, "KVCacheGather")
+        # no pool gathered: the one gather a decode program has reads its
+        # rows' last tokens
+        assert [op.attrs["var_name"] for op in _ops(model, "KVCacheGather")
+                ] == ["causal_lm_pg/last_tok"] * len(model.decode_buckets)
         routed = _ops(model, "RoutedFFN")
         assert len(routed) == 2 * programs
         assert all(op.attrs["activation"] == "relu2"
@@ -858,8 +862,9 @@ class TestServed:
 class TestTheOthersAreUnchanged:
     """The state pools, the ``slots`` and ``lens`` feeds and the layer-kind
     loop leave the programs of the configurations the benchmark already
-    has op for op as they were: the digests are of the parent commit's
-    graphs (op types, input counts and attribute names, in order)."""
+    has op for op as they were: the digests are of those graphs, every
+    decode program with its last-token buffer and its ``slots`` feed (op
+    types, input counts and attribute names, in order)."""
 
     KW = dict(page_len=8, pages_per_seq=6, num_pages=30, max_live=4,
               init_fresh=True, seed=0, compute_dtype=stf.float32,
@@ -882,9 +887,9 @@ class TestTheOthersAreUnchanged:
                                               **self.KW)
 
     @pytest.mark.parametrize("which,n_ops,digest", [
-        ("lm-big", 459, "9ad7ece462cb7959"),
-        ("sparse", 499, "be262a6913334b20"),
-        ("latent", 699, "6d8b84dc788ffb2c")])
+        ("lm-big", 480, "8dc98ae8532894f1"),
+        ("sparse", 520, "4d5e386280568f64"),
+        ("latent", 720, "9bfe69b9a304a764")])
     def test_op_list_as_before(self, which, n_ops, digest):
         model = self._build(which)
         try:
@@ -898,6 +903,9 @@ class TestTheOthersAreUnchanged:
             model.close()
         assert len(ops) == n_ops
         assert hashlib.sha256(repr(sig).encode()).hexdigest()[:16] == digest
-        assert not [f for f in feeds if "slots" in f or "lens" in f]
+        # every decode program takes slots (its last-token buffer's
+        # rows); only a stack with state by slot gives prefill slots, lens
+        assert not [f for f in feeds if "prefill" in f
+                    and ("slots" in f or "lens" in f)]
         assert not [t for t, _, _ in sig if t in kvc.STATE_UPDATE_OP_TYPES
                     or t == "StatePoolAlloc"]

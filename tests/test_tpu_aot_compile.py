@@ -313,7 +313,7 @@ def lm_big_programs(one_chip):
                                   v.dtype.base_dtype.np_dtype)
                  for v in stf.global_variables()}
         state.update({c.name: aval(c.stored_shape, c.dtype.np_dtype)
-                      for c in caches})
+                      for c in caches + [prog["last_tok"]]})
 
         def text(fetches, feeds):
             step = sess.plan(fetches, feeds=feeds)._step
@@ -328,7 +328,8 @@ def lm_big_programs(one_chip):
         texts = {
             "decode96": text(
                 {"next_tok": d["next_tok"], "logp": d["logp"]},
-                [d["tok"], d["pos"], d["tables"], d["dst"], d["off"]]),
+                [d["tok"], d["pos"], d["tables"], d["dst"], d["off"],
+                 d["slots"]]),
             "prefill8": text(
                 {"done": p["op"]},
                 [p["tok"], p["base"], p["tables"], p["dst"]]),
@@ -517,21 +518,20 @@ def _paged_serving_programs(one_chip, config_file, stack_of, decode_bucket,
         if kernels_on_tpu:
             mp.setattr(kreg, "backend", lambda: "tpu")
         cfg, stack = stack_of(program["config_kwargs"], kw)
-        # a stack with state by slot sizes its pools from max_live
-        by_slot = ({"max_live": kw["max_live"]}
-                   if getattr(stack, "keeps_state", False) else {})
+        # the last-token buffer, and a stack's pools of state by slot,
+        # are sized from max_live
         prog = causal_lm.build_paged_lm_program(
             stack, page_len=kw["page_len"],
             pages_per_seq=kw["pages_per_seq"], num_pages=kw["num_pages"],
             decode_bucket_sizes=(decode_bucket,),
             prefill_bucket_sizes=(prefill_bucket,),
-            compute_dtype=stf.bfloat16, **by_slot)
+            compute_dtype=stf.bfloat16, max_live=kw["max_live"])
         caches = [c for group in prog["caches"] for c in group]
         state = {v.var_name: aval(v.shape.as_list(),
                                   v.dtype.base_dtype.np_dtype)
                  for v in stf.global_variables()}
         state.update({c.name: aval(c.stored_shape, c.dtype.np_dtype)
-                      for c in caches})
+                      for c in caches + [prog["last_tok"]]})
 
         def compiled(fetches, feeds):
             step = sess.plan(fetches, feeds=feeds)._step
@@ -546,8 +546,8 @@ def _paged_serving_programs(one_chip, config_file, stack_of, decode_bucket,
         programs = {
             f"decode{decode_bucket}": compiled(
                 {"next_tok": d["next_tok"], "logp": d["logp"], **d["extra"]},
-                [d["tok"], d["pos"], d["tables"], d["dst"], d["off"]]
-                + [d[k] for k in ("slots",) if k in d]),
+                [d["tok"], d["pos"], d["tables"], d["dst"], d["off"],
+                 d["slots"]]),
             f"prefill{prefill_bucket}": compiled(
                 {"done": p["op"]},
                 [p["tok"], p["base"], p["tables"], p["dst"]]
